@@ -25,7 +25,7 @@ is the full record; a killed run still leaves the stages that finished):
    "predict_speedup": <serve engine vs seed TreePredictor>}
 
 Stages run in value order (63-bin -> 255-bin -> MSLR -> predict ->
-serve-traffic -> valid-overhead -> resume -> warm-rerun -> reference
+serve-traffic -> valid-overhead -> resume -> sweep -> reference
 parity LAST) and BENCH_BUDGET_S sets a wall-clock budget enforced by an
 obs BudgetGate: a stage is skipped not only once the budget is
 exhausted but also ADAPTIVELY, when its estimated cost (derived from
@@ -49,8 +49,7 @@ Compile-cost accounting (first-class JSON fields): "warmup_s" /
 included), "compile_s" / "compile_s_255bin" (warmup minus steady-state
 iteration cost), "compile_cache_hit" (persistent cache had entries
 before this process compiled), "compile_cache" {dir, entries_before,
-entries_after}, and "warmup_s_warm" + "warm_speedup" from a
-fresh-process rerun of the 63-bin warmup leg (warm-rerun stage).
+entries_after}.
 "compile_cache_misses" {stage: count} attributes persistent-cache
 misses to the stage that paid them — each miss also emits a structured
 compile_cache_miss [Event] naming the traced program signature
@@ -87,14 +86,14 @@ working.
 Env knobs: BENCH_ROWS, BENCH_FEATURES, BENCH_ITERS (measured), BENCH_WARMUP,
 BENCH_LEAVES, BENCH_SMOKE=1 (tiny CPU config), BENCH_BUDGET_S,
 BENCH_SKIP_RANK=1, BENCH_SKIP_255=1, BENCH_SKIP_PREDICT=1,
-BENCH_SKIP_WARM=1, BENCH_SKIP_VALID=1, BENCH_SKIP_REF=1,
+BENCH_SKIP_VALID=1, BENCH_SKIP_REF=1,
 BENCH_SKIP_RESUME=1, BENCH_SKIP_SERVE=1, BENCH_SKIP_SWEEP=1,
 BENCH_PROFILE=0 (disable the
 per-term profiler rounds), BENCH_OUT=<path> (sidecar record),
 BENCH_TRACE=1 + BENCH_TRACE_DIR (obs span tracer + per-stage ledger
 records).
-LGBT_COMPILE_CACHE_DIR / JAX_COMPILATION_CACHE_DIR override the
-persistent-cache location (default: ./.jax_cache).
+JAX_COMPILATION_CACHE_DIR overrides the persistent-cache location
+(default: <checkout>/.jax_cache; see compile_cache.cache_dir).
 """
 import json
 import os
@@ -105,19 +104,6 @@ import time
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-# Persistent XLA compilation cache: repeat bench runs (and real users'
-# repeat processes) skip the multi-minute warmup compiles. Routed through
-# lightgbm_tpu's own tpu_compile_cache_dir wiring rather than raw
-# jax.config: the direct wiring that used to live here kept jax's default
-# 2 s min-compile-time floor, which silently skipped every sub-2 s
-# round-loop program — the cache never hit. config.Config.update() calls
-# compile_cache.init_persistent_cache() with the floor dropped to 0 and
-# the XLA-client caches enabled, before the first trace.
-_cache = os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"))
-os.environ.setdefault("LGBT_COMPILE_CACHE_DIR", _cache)
 
 import lightgbm_tpu as lgb  # noqa: E402
 from lightgbm_tpu import compile_cache  # noqa: E402
@@ -202,6 +188,16 @@ def _stage_done(name, out):
                         "t0": round(t_now - wall, 6),
                         "t1": round(t_now, 6),
                         "wall_s": round(wall, 3)})
+
+
+def _stage_failed(out, name, err):
+    """A stage raised: log the traceback and record it under
+    "stage_errors". Later stages still run; main() exits non-zero."""
+    import traceback
+    log(f"# {name} stage FAILED: {type(err).__name__}: {err}")
+    traceback.print_exc(file=sys.stderr)
+    out.setdefault("stage_errors", {})[name] = \
+        f"{type(err).__name__}: {err}"[:300]
 
 
 def budget_left():
@@ -655,10 +651,8 @@ def run_ref_parity(X, y, hX, hy, leaves):
                        capture_output=True, timeout=600)
         ref_pred = np.loadtxt(os.path.join(td, "ref_pred.txt"))
         auc_ref = auc_of(ref_pred, hy[:nh])
-    except Exception as e:   # the bench's JSON line must still print
-        log(f"# ref parity FAILED: {type(e).__name__}: {e}")
+    finally:
         shutil.rmtree(td, ignore_errors=True)
-        return None, None
     # ours: same data, same config, on the TPU path
     params = {"objective": "binary", "num_leaves": leaves, "max_bin": 63,
               "learning_rate": 0.1, "min_data_in_leaf": 20,
@@ -672,203 +666,7 @@ def run_ref_parity(X, y, hX, hy, leaves):
     log(f"#   ours train+predict: {time.perf_counter() - t0:.1f}s")
     log(f"# ref parity (1M rows, 100 iters, 63-bin): "
         f"ours={auc_ours:.6f} ref={auc_ref:.6f}")
-    shutil.rmtree(td, ignore_errors=True)
     return auc_ours, auc_ref
-
-
-def multichip_child() -> None:
-    """BENCH_MULTICHIP_CHILD=1 mode: one point of the scaling curve in a
-    fresh process whose device topology was fixed by the parent's env
-    (XLA_FLAGS --xla_force_host_platform_device_count=N under CPU
-    emulation; the real device set otherwise). Trains tree_learner=data
-    on the FIXED global row count and emits one JSON line with the
-    per-iteration wall and the per-device HBM claims the accountant
-    attributes to the dist/ shard owners."""
-    import jax
-
-    n = int(os.environ["BENCH_MC_ROWS"])
-    f = int(os.environ.get("BENCH_FEATURES", 28))
-    iters = int(os.environ["BENCH_MC_ITERS"])
-    warmup = max(int(os.environ.get("BENCH_MC_WARMUP", 2)), 1)
-    leaves = int(os.environ.get("BENCH_LEAVES", 31))
-    ndev = int(os.environ["BENCH_MC_NDEV"])
-    data_path = os.environ.get("BENCH_MC_DATA", "")
-    chunk = int(os.environ.get("BENCH_MC_CHUNK", 8192))
-    params = {"objective": "binary", "num_leaves": leaves, "max_bin": 63,
-              "learning_rate": 0.1, "min_data_in_leaf": 20,
-              "verbosity": -1, "metric": "none",
-              # the byte-equal topology contract: f64 hist accumulation
-              # makes the model identical at every mesh width
-              "tpu_use_f64_hist": True,
-              "tree_learner": "data" if ndev > 1 else "serial",
-              "num_machines": ndev}
-    if data_path:
-        # stream-to-shard ingest from the parent's TSV: each chunk is
-        # parsed on the prefetch thread while the previous chunk is
-        # binned on its owner device — the ingest walls below are the
-        # pipeline's own accounting. tpu_stream_shard=on shards even
-        # the 1-wide mesh so every curve point reports shard_bytes.
-        params.update({"tree_learner": "data",
-                       "tpu_stream_chunk_rows": chunk,
-                       "tpu_stream_shard": "on"})
-        ds = lgb.Dataset(data_path, params=params).construct()
-    else:
-        X, y = synth_higgs(n, f)
-        ds = lgb.Dataset(X, label=y, params=params).construct()
-    bst = lgb.Booster(params=dict(params), train_set=ds)
-    g = bst._gbdt
-    from lightgbm_tpu.obs import trace as obs_trace
-    for _ in range(warmup):
-        bst.update()
-    obs_trace.force_fence(g.train_score.score)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        bst.update()
-    obs_trace.force_fence(g.train_score.score)
-    per_iter_ms = (time.perf_counter() - t0) / iters * 1e3
-    from lightgbm_tpu.obs import memory as obs_memory
-    owners = obs_memory.owners_bytes()
-    mb = 1 << 20
-    per_dev = {name.split("/")[-1]: round(info["bytes"] / mb, 2)
-               for name, info in sorted(owners.items())
-               if name.startswith("dist/shard_bytes/")}
-    if not per_dev:   # 1-device baseline: the whole binned matrix on d0
-        per_dev = {"d0": round(sum(
-            i["bytes"] for nm, i in owners.items()
-            if nm.startswith("dataset/bins")) / mb, 2)}
-    rec = {
-        "devices": ndev,
-        "visible_devices": len(jax.devices()),
-        "per_iter_ms": round(per_iter_ms, 2),
-        "hbm_claimed_mb": per_dev,
-    }
-    if ndev > 1:
-        # one extra round, drained shard-by-shard: per-device wait
-        # attribution of a dist round (obs/profiler.py wait-tiling) —
-        # informational skew data for bench_compare, never the timing
-        # loop itself (per_iter_ms above is already committed)
-        from lightgbm_tpu.obs.profiler import _per_device_segments
-        from lightgbm_tpu.obs.straggler import imbalance_ratio
-        t_att = time.perf_counter()
-        bst.update()
-        segs = _per_device_segments(g.train_score.score, t_att)
-        if segs:
-            rec["device_ids"] = [d for d, _ in segs]
-            rec["device_round_ms"] = [round(w, 3) for _, w in segs]
-            ratio = imbalance_ratio([w for _, w in segs])
-            if ratio is not None:
-                rec["device_imbalance"] = round(ratio, 3)
-    h = getattr(ds, "_handle", None) or ds
-    st = getattr(h, "_ingest_stats", None)
-    if st and st.get("sharded"):
-        rec.update({
-            "ingest_s": round(
-                float(getattr(h, "_ingest_ms", 0.0)) / 1e3, 3),
-            "parse_s": round(st["parse_ms"] / 1e3, 3),
-            "bin_s": round(st["bin_ms"] / 1e3, 3),
-            "seq_s": round(st["seq_ms"] / 1e3, 3),
-            "overlap_eff": st["overlap_eff"],
-            "shard_bytes": st["shard_bytes"],
-            "pipeline_depth": st["pipeline_depth"],
-        })
-    print(json.dumps(rec), flush=True)
-
-
-def run_multichip(out):
-    """MULTICHIP scaling curve: fixed global rows re-trained at mesh
-    widths 1..N, each in a fresh child process so the device topology is
-    real (emulated via XLA host-platform device count on CPU, the actual
-    accelerator set otherwise) — speedup numbers never come from
-    re-slicing one process's devices."""
-    import subprocess
-    import tempfile
-    smoke = os.environ.get("BENCH_SMOKE") == "1"
-    n = int(os.environ.get("BENCH_MC_ROWS", 40_000 if smoke else 500_000))
-    iters = int(os.environ.get("BENCH_MC_ITERS", 4 if smoke else 15))
-    max_dev = int(os.environ.get("BENCH_MC_MAX_DEVICES",
-                                 4 if smoke else 8))
-    import jax
-    emulate = jax.default_backend() == "cpu"
-    if not emulate:
-        max_dev = min(max_dev, len(jax.devices()))
-    ns = [1]
-    while ns[-1] * 2 <= max_dev:
-        ns.append(ns[-1] * 2)
-    # one TSV shared by every child: the curve's ingest numbers come
-    # from the stream-to-shard file loader (parse on the prefetch
-    # thread, bin on the owner device), not an in-memory shortcut
-    f = int(os.environ.get("BENCH_FEATURES", 28))
-    X, y = synth_higgs(n, f)
-    td = tempfile.mkdtemp(prefix="bench_mc_")
-    data_path = os.path.join(td, "train.tsv")
-    np.savetxt(data_path, np.column_stack([y, X]), fmt="%.6g",
-               delimiter="\t")
-    del X, y
-    curve = []
-    for ndev in ns:
-        env = dict(os.environ)
-        env["BENCH_MULTICHIP_CHILD"] = "1"
-        env["BENCH_MC_ROWS"] = str(n)
-        env["BENCH_MC_ITERS"] = str(iters)
-        env["BENCH_MC_NDEV"] = str(ndev)
-        env["BENCH_MC_DATA"] = data_path
-        if emulate:
-            flags = [t for t in env.get("XLA_FLAGS", "").split()
-                     if "force_host_platform_device_count" not in t]
-            flags.append(f"--xla_force_host_platform_device_count={ndev}")
-            env["XLA_FLAGS"] = " ".join(flags)
-            env["JAX_PLATFORMS"] = "cpu"
-        t0 = time.perf_counter()
-        res = subprocess.run(
-            [sys.executable, os.path.abspath(__file__)], env=env,
-            capture_output=True, text=True, timeout=1800)
-        if res.returncode != 0:
-            log(f"# multichip {ndev}dev FAILED rc={res.returncode}: "
-                f"{res.stderr.strip().splitlines()[-1:]}")
-            continue
-        rec = json.loads(res.stdout.strip().splitlines()[-1])
-        curve.append(rec)
-        log(f"# multichip {ndev}dev: per_iter_ms={rec['per_iter_ms']} "
-            f"({time.perf_counter() - t0:.1f}s total)")
-    shutil.rmtree(td, ignore_errors=True)
-    if not curve:
-        return {}
-    base = curve[0]["per_iter_ms"]
-    for rec in curve:
-        rec["speedup_vs_1dev"] = round(
-            base / max(rec["per_iter_ms"], 1e-9), 3)
-    out = {"multichip": {"rows": n, "iters": iters,
-                         "tree_learner": "data",
-                         "emulated_cpu_devices": emulate,
-                         "curve": curve}}
-    # hoist the widest leg's ingest pipeline numbers as top-level
-    # scalars: bench_compare judges only top-level keys, so this is
-    # what gates ingest regressions across commits
-    widest = curve[-1]
-    if "ingest_s" in widest:
-        out["mc_ingest_s"] = widest["ingest_s"]
-        out["mc_ingest_overlap"] = widest["overlap_eff"]
-    if "device_imbalance" in widest:
-        out["mc_device_imbalance"] = widest["device_imbalance"]
-    return out
-
-
-def warm_rerun_child() -> None:
-    """BENCH_WARMRERUN_CHILD=1 mode: a fresh process repeating ONLY the
-    63-bin bin+warmup leg on identical data, so the parent can certify
-    the persistent compile cache (warm warmup_s vs its own cold one).
-    Emits a single JSON line."""
-    smoke = os.environ.get("BENCH_SMOKE") == "1"
-    n = int(os.environ.get("BENCH_ROWS", 20_000 if smoke else 10_500_000))
-    f = int(os.environ.get("BENCH_FEATURES", 28))
-    warmup = int(os.environ.get("BENCH_WARMUP", 2 if smoke else 5))
-    leaves = int(os.environ.get("BENCH_LEAVES", 31 if smoke else 255))
-    X, y = synth_higgs(n, f)
-    _, _, _, stats = run_higgs(n, f, leaves, 0, warmup, 63,
-                               None, None, X, y)
-    emit({"warmup_s": stats["warmup_s"], "bin_s": stats["bin_s"],
-          "cache_entries": compile_cache.cache_dir_entries(
-              compile_cache.persistent_cache_dir())})
 
 
 def run_resume(X, y, leaves, iters):
@@ -1039,114 +837,12 @@ def run_sweep_hetero(X, y, iters, M):
             f"sweep_subfleets_m{M}": len(plans)}
 
 
-def run_warm_rerun(out):
-    """Spawn the fresh-process warm rerun and record cold vs warm."""
-    import subprocess
-    env = dict(os.environ)
-    env["BENCH_WARMRERUN_CHILD"] = "1"
-    try:
-        t0 = time.perf_counter()
-        res = subprocess.run(
-            [sys.executable, os.path.abspath(__file__)], env=env,
-            capture_output=True, text=True, timeout=3600)
-        child = json.loads(res.stdout.strip().splitlines()[-1])
-        out["warmup_s_warm"] = child["warmup_s"]
-        cold = out.get("warmup_s")
-        if cold:
-            out["warm_speedup"] = round(cold / max(child["warmup_s"],
-                                                   1e-9), 2)
-        log(f"# warm rerun (fresh process): warmup_s={child['warmup_s']}"
-            f" vs cold={cold} ({time.perf_counter() - t0:.1f}s total)")
-    except Exception as e:   # the summary line must still print
-        log(f"# warm rerun FAILED: {type(e).__name__}: {e}")
-
-
-def run_coldstart(smoke):
-    """Fresh-subprocess cold-start-to-first-score wall, with and without
-    an AOT serving artifact (serve/aot.py), plus per-model HBM residency
-    f32 vs the int8 compact plan. Each `task=serve` twin is a genuinely
-    cold process (no shared jit caches); the AOT twin must reach its
-    first scored request with zero engine compiles."""
-    import subprocess
-    import tempfile
-    work = tempfile.mkdtemp(prefix="bench_coldstart_")
-    root = os.path.dirname(os.path.abspath(__file__))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        rng = np.random.default_rng(11)
-        n, f = (1_500, 10) if smoke else (5_000, 20)
-        X = rng.standard_normal((n, f))
-        y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] > 0).astype(np.float64)
-        ds = lgb.Dataset(X, label=y)
-        bst = lgb.train({"objective": "binary", "num_leaves": 31,
-                         "verbosity": -1}, ds,
-                        num_boost_round=20 if smoke else 100)
-        model = os.path.join(work, "model.txt")
-        bst.save_model(model)
-        data = os.path.join(work, "rows.tsv")
-        with open(data, "w") as fh:
-            for i in range(min(n, 500)):
-                fh.write("0\t" + "\t".join(f"{v:g}" for v in X[i])
-                         + "\n")
-        aot_dir = os.path.join(work, "aot")
-        subprocess.run(
-            [sys.executable,
-             os.path.join(root, "tools", "serve_export.py"),
-             "--model", model, "--out", aot_dir,
-             "--buckets", "256,512"],
-            check=True, capture_output=True, text=True, env=env,
-            timeout=600)
-
-        def serve_wall(extra):
-            args = [sys.executable, "-m", "lightgbm_tpu", "task=serve",
-                    f"input_model=m={model}", f"data={data}",
-                    f"output_result={os.path.join(work, 'out.tsv')}",
-                    "tpu_serve_max_batch_rows=512", "verbosity=1"] + extra
-            t0 = time.perf_counter()
-            res = subprocess.run(args, check=True, capture_output=True,
-                                 text=True, env=env, timeout=600)
-            wall = time.perf_counter() - t0
-            line = [ln for ln in res.stdout.splitlines()
-                    if ln.startswith("Serving stats: ")][-1]
-            stats = json.loads(line[len("Serving stats: "):])
-            return wall, stats["registry"]["models"]["m"]
-
-        cold_s, cold_m = serve_wall([])
-        aot_s, aot_m = serve_wall([f"tpu_serve_aot_dir={aot_dir}"])
-        res = {
-            "coldstart_cold_s": round(cold_s, 2),
-            "coldstart_aot_s": round(aot_s, 2),
-            "coldstart_speedup": round(cold_s / max(aot_s, 1e-9), 2),
-            "coldstart_cold_compiles": int(cold_m["compile_count"]),
-            "coldstart_aot_compiles": int(aot_m["compile_count"]),
-        }
-        # per-model residency: the same forest under f32 vs the int8
-        # compact plan (in-process — device_bytes is shape metadata)
-        from lightgbm_tpu.serve import ForestEngine
-        e32 = ForestEngine(bst.trees, num_class=1, mode="raw")
-        ec = ForestEngine(bst.trees, num_class=1, mode="raw",
-                          compact="int8")
-        mb = float(1 << 20)
-        res["serve_hbm_per_model_mb_f32"] = round(
-            e32.device_bytes() / mb, 4)
-        res["serve_hbm_per_model_mb_compact"] = round(
-            ec.device_bytes() / mb, 4)
-        res["serve_model_density_x"] = round(
-            e32.device_bytes() / max(ec.device_bytes(), 1), 2)
-        return res
-    finally:
-        shutil.rmtree(work, ignore_errors=True)
-
-
 def main() -> None:
-    if os.environ.get("BENCH_MULTICHIP_CHILD") == "1":
-        multichip_child()
-        return
-    if os.environ.get("BENCH_WARMRERUN_CHILD") == "1":
-        warm_rerun_child()
-        return
     global _REC, _LEDGER
+    # persistent XLA compilation cache at THE one location
+    # (compile_cache.cache_dir()), wired before the first trace: repeat
+    # bench runs load compiled executables instead of recompiling
+    compile_cache.init_persistent_cache()
     smoke = os.environ.get("BENCH_SMOKE") == "1"
     n = int(os.environ.get("BENCH_ROWS", 20_000 if smoke else 10_500_000))
     f = int(os.environ.get("BENCH_FEATURES", 28))
@@ -1155,7 +851,7 @@ def main() -> None:
     leaves = int(os.environ.get("BENCH_LEAVES", 31 if smoke else 255))
     n_hold = 4_000 if smoke else 500_000
     entries_before = compile_cache.cache_dir_entries(
-        os.environ.get("LGBT_COMPILE_CACHE_DIR"))
+        compile_cache.persistent_cache_dir())
 
     # the cumulative record exists from second zero: a kill at ANY later
     # point — data gen, first compile, mid-stage — leaves a parseable
@@ -1315,8 +1011,8 @@ def main() -> None:
             for k in ("predict_seed_rows_s", "predict_engine_rows_s",
                       "predict_speedup"):
                 out[k] = pred[k]
-        except Exception as e:   # the summary line must still print
-            log(f"# predict stage FAILED: {type(e).__name__}: {e}")
+        except Exception as e:
+            _stage_failed(out, "predict", e)
         _stage_done("predict", out)
 
     # ---- stage 4.5: serving traffic simulation (serving/ service:
@@ -1335,29 +1031,9 @@ def main() -> None:
                 train_rows=1_500 if smoke else 8_000,
                 train_rounds=20 if smoke else 60,
                 ledger=_LEDGER, verbose=True))
-        except Exception as e:   # the summary line must still print
-            log(f"# serve_traffic stage FAILED: {type(e).__name__}: {e}")
+        except Exception as e:
+            _stage_failed(out, "serve_traffic", e)
         _stage_done("serve_traffic", out)
-
-    # ---- stage 4.6: serving cold start (serve/aot.py artifacts): fresh
-    # subprocess to first score with vs without the AOT artifact, plus
-    # per-model HBM residency f32 vs compact --------------------------
-    if stage_gate(out, "coldstart", "BENCH_SKIP_COLDSTART",
-                  est_s=45 if smoke else 120):
-        _stage("coldstart")
-        try:
-            cs = run_coldstart(smoke)
-            out.update(cs)
-            log(f"# coldstart: cold={cs['coldstart_cold_s']}s "
-                f"aot={cs['coldstart_aot_s']}s "
-                f"({cs['coldstart_speedup']}x, aot_compiles="
-                f"{cs['coldstart_aot_compiles']}); per-model MB "
-                f"f32={cs['serve_hbm_per_model_mb_f32']} vs "
-                f"compact={cs['serve_hbm_per_model_mb_compact']} "
-                f"({cs['serve_model_density_x']}x density)")
-        except Exception as e:   # the summary line must still print
-            log(f"# coldstart stage FAILED: {type(e).__name__}: {e}")
-        _stage_done("coldstart", out)
 
     # ---- stage 5: valid-set overhead (diagnostic) ----------------------
     if stage_gate(out, "valid_overhead", "BENCH_SKIP_VALID",
@@ -1381,8 +1057,8 @@ def main() -> None:
             rr = run_resume(X[:200_000], y[:200_000], leaves,
                             20 if smoke else 60)
             out.update(rr)
-        except Exception as e:   # the summary line must still print
-            log(f"# resume stage FAILED: {type(e).__name__}: {e}")
+        except Exception as e:
+            _stage_failed(out, "resume", e)
         _stage_done("resume", out)
 
     # ---- stage 5.6: many-model sweep (sweep/train_many): one batched
@@ -1430,28 +1106,9 @@ def main() -> None:
             het_rows = min(sw_rows, 2_000 if smoke else 20_000)
             out.update(run_sweep_hetero(X[:het_rows], y[:het_rows],
                                         het_iters, het_m))
-        except Exception as e:   # the summary line must still print
-            log(f"# sweep stage FAILED: {type(e).__name__}: {e}")
+        except Exception as e:
+            _stage_failed(out, "sweep", e)
         _stage_done("sweep", out)
-
-    # ---- stage 5.7: MULTICHIP scaling curve (dist/ runtime): fixed
-    # global rows at mesh widths 1..N, one fresh child per width --------
-    if stage_gate(out, "multichip", "BENCH_SKIP_MULTICHIP",
-                  est_s=_GATE.wall("higgs63") * (0.5 if smoke else 1.2)):
-        _stage("multichip")
-        try:
-            out.update(run_multichip(out))
-        except Exception as e:   # the summary line must still print
-            log(f"# multichip stage FAILED: {type(e).__name__}: {e}")
-        _stage_done("multichip", out)
-
-    # ---- stage 6: fresh-process warm rerun (certifies the persistent
-    # cache: the child re-pays binning but should load, not compile) ----
-    if stage_gate(out, "warm_rerun", "BENCH_SKIP_WARM",
-                  est_s=_GATE.wall("higgs63") * 0.6):
-        _stage("warm_rerun")
-        run_warm_rerun(out)
-        _stage_done("warm_rerun", out)
 
     # ---- stage 7: reference-binary parity (slowest, least perishable) --
     if smoke:
@@ -1459,10 +1116,13 @@ def main() -> None:
     elif stage_gate(out, "ref_parity", "BENCH_SKIP_REF",
                     est_s=max(_GATE.wall("higgs63") * 2.0, 300)):
         _stage("ref_parity")
-        auc_ours_1m, auc_ref = run_ref_parity(X, y, hX, hy, leaves)
-        if auc_ref is not None:
-            out["auc_ours_1m_100it"] = round(auc_ours_1m, 6)
-            out["auc_ref"] = round(auc_ref, 6)
+        try:
+            auc_ours_1m, auc_ref = run_ref_parity(X, y, hX, hy, leaves)
+            if auc_ref is not None:
+                out["auc_ours_1m_100it"] = round(auc_ours_1m, 6)
+                out["auc_ref"] = round(auc_ref, 6)
+        except Exception as e:
+            _stage_failed(out, "ref_parity", e)
         _stage_done("ref_parity", out)
 
     out["wall_s"] = round(time.perf_counter() - _T0, 1)
@@ -1482,6 +1142,8 @@ def main() -> None:
             log(f"# timeline: {path}")
         except Exception as e:  # the record on stdout already landed
             log(f"# timeline export FAILED: {type(e).__name__}: {e}")
+    if out.get("stage_errors"):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

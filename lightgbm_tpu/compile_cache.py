@@ -15,10 +15,9 @@ This module fixes that at two levels:
   of any *data* arrays the closure captures).  Two engines with equal
   keys share one jitted callable and therefore one trace per input
   shape.
-* ``init_persistent_cache(path)`` — one-shot wiring of jax's on-disk
-  compilation cache so a fresh *process* also skips XLA compilation.
-  Exposed to users via the ``tpu_compile_cache_dir`` parameter
-  (see ``config.py``); ``bench.py`` goes through the same entry point.
+* ``init_persistent_cache()`` — one-shot wiring of jax's on-disk
+  compilation cache at ``cache_dir()`` so a fresh *process* also skips
+  XLA compilation.
 
 ``note_trace()`` / ``trace_count()`` implement the compile-count
 regression contract: every registered program body bumps the counter
@@ -313,22 +312,18 @@ def _note_persistent_cache_hit(module_name: str, cache_key: str = "") -> None:
     _pcache_hits += 1
 
 
-def install_cache_event_hooks() -> bool:
+def install_cache_event_hooks() -> None:
     """Wrap jax's persistent-cache logging seam
     (`jax._src.compiler.log_persistent_cache_{miss,hit}` — called
     exactly once per compile on the miss/hit path) so every miss lands
-    on the structured log channel with program attribution. Idempotent;
-    returns False when this jax build lacks the seam (counters then stay
-    zero — callers treat that as "no data", not an error)."""
+    on the structured log channel with program attribution.
+    Idempotent."""
     global _hooks_installed
     if _hooks_installed:
-        return True
-    try:
-        from jax._src import compiler as _jax_compiler
-        orig_miss = _jax_compiler.log_persistent_cache_miss
-        orig_hit = _jax_compiler.log_persistent_cache_hit
-    except (ImportError, AttributeError):
-        return False
+        return
+    from jax._src import compiler as _jax_compiler
+    orig_miss = _jax_compiler.log_persistent_cache_miss
+    orig_hit = _jax_compiler.log_persistent_cache_hit
 
     def miss(module_name, cache_key, *a, **kw):
         note_persistent_cache_miss(getattr(module_name, "name",
@@ -343,7 +338,6 @@ def install_cache_event_hooks() -> bool:
     _jax_compiler.log_persistent_cache_miss = miss
     _jax_compiler.log_persistent_cache_hit = hit
     _hooks_installed = True
-    return True
 
 
 def persistent_cache_dir() -> Optional[str]:
@@ -360,44 +354,42 @@ def cache_dir_entries(path: Optional[str]) -> int:
     return n
 
 
-def init_persistent_cache(path: str) -> str:
-    """Point jax's persistent compilation cache at ``path`` (one-shot).
+def cache_dir() -> str:
+    """THE persistent-compile-cache location: ``$JAX_COMPILATION_CACHE_DIR``
+    when set, else the fixed ``<checkout>/.jax_cache``. The path is part
+    of jax's cache key, so nothing else in the tree names a directory:
+    ``init_persistent_cache`` (called by ``bench.py``, ``chip_smoke.py``
+    and ``Config.update``) resolves through here."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
 
-    The earlier bench-only wiring missed for two reasons: it kept the
-    default ``min_compile_time_secs`` floor of 2 s (the round loop is
-    dozens of sub-2 s programs — none were written), and on non-TPU
-    backends jax additionally requires the XLA-client caches to be
-    opted in before anything persists. Both are forced here, and the
-    setup runs before the first trace because ``Config.update`` calls
-    it when ``tpu_compile_cache_dir`` is parsed.
 
-    Idempotent: the first directory wins for the process lifetime
-    (jax's cache config cannot be swapped once populated).
+def init_persistent_cache() -> str:
+    """Point jax's persistent compilation cache at ``cache_dir()``
+    (one-shot; returns the directory).
+
+    jax alone would honour the environment variable but keep its 1 s
+    ``min_compile_time_secs`` floor (the round loop is dozens of faster
+    programs — none would be written) and, on non-TPU backends, leave
+    the XLA-client caches off. Both are forced here. Must run before the
+    first compile; process entry points call it first thing, and
+    ``Config.update`` calls it whenever the environment names a
+    directory.
     """
     global _persistent_cache_dir
     if _persistent_cache_dir is not None:
         return _persistent_cache_dir
-    path = os.path.abspath(os.path.expanduser(path))
+    path = os.path.abspath(os.path.expanduser(cache_dir()))
     os.makedirs(path, exist_ok=True)
 
     import jax
 
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    for opt, val in (
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-        # Required for cache hits on the CPU backend; harmless on TPU.
-        ("jax_persistent_cache_enable_xla_caches", "all"),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except Exception:
-            pass  # older jax: option absent, dir + floor still apply
-    try:
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.set_cache_dir(path)
-    except Exception:
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # Required for cache hits on the CPU backend; harmless on TPU.
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     install_cache_event_hooks()
     _persistent_cache_dir = path
     return path

@@ -8,26 +8,5 @@ sharded-score checkpoint rescatter. The reference implements this plane
 in `src/network/` (Allreduce/ReduceScatter/Allgather over MPI sockets);
 here every collective is an XLA op inside one jitted SPMD program,
 lowered to ICI all-reduces on real hardware.
-
-This module also owns the one `shard_map` compatibility seam: newer jax
-exposes `jax.shard_map(..., check_vma=)`, older releases only
-`jax.experimental.shard_map.shard_map(..., check_rep=)`. Every
-shard_map in the tree routes through `dist.shard_map` so the learners
-run on both.
 """
-from __future__ import annotations
-
-import jax
-
-__all__ = ["shard_map", "runtime", "binning"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` across jax versions (check_vma == check_rep)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+__all__ = ["runtime", "binning"]

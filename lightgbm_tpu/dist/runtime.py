@@ -11,12 +11,11 @@ Topology resolution (``num_shards``):
    for that many shards;
 3. else every visible device joins the mesh.
 
-Either way the request is clamped to the devices that exist, so a config
-written for a v5p-16 also runs under 8 emulated CPU devices, just
-narrower. All three params are runtime-only (model_text/checkpoint
-RUNTIME_ONLY_PARAMS), matching the reference: with ``tpu_use_f64_hist``
-the data-parallel model is bitwise-independent of topology, so the dump
-must be too.
+A request for more shards than there are visible devices is an error,
+never a silently narrower mesh. All three params are runtime-only
+(model_text/checkpoint RUNTIME_ONLY_PARAMS), matching the reference: with
+``tpu_use_f64_hist`` the data-parallel model is bitwise-independent of
+topology, so the dump must be too.
 """
 from __future__ import annotations
 
@@ -29,14 +28,21 @@ _PARALLEL_MODES = ("data", "feature", "voting")
 
 
 def num_shards(cfg) -> int:
-    """Mesh width the config asks for, clamped to visible devices."""
+    """Mesh width the config asks for; raises when it exceeds the
+    visible devices."""
     import jax
     nd = len(jax.devices())
     if int(getattr(cfg, "tpu_dist_devices", 0)) > 0:
-        return max(1, min(int(cfg.tpu_dist_devices), nd))
-    if int(cfg.num_machines) > 1:
-        return max(1, min(int(cfg.num_machines), nd))
-    return nd
+        want, knob = int(cfg.tpu_dist_devices), "tpu_dist_devices"
+    elif int(cfg.num_machines) > 1:
+        want, knob = int(cfg.num_machines), "num_machines"
+    else:
+        return nd
+    if want > nd:
+        raise ValueError(
+            f"{knob}={want} asks for more shards than the {nd} visible "
+            f"{jax.default_backend()} device(s)")
+    return want
 
 
 def active(cfg) -> bool:

@@ -140,7 +140,7 @@ class DeviceBinner:
             return (jnp.asarray(arr) if device is None
                     else jax.device_put(arr, device))
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             # f64 on device: created inside enable_x64 so the dtype
             # survives canonicalization (a plain asarray would silently
             # downcast to f32 and break bitwise parity with the host)
@@ -175,7 +175,7 @@ class DeviceBinner:
         lower AND run inside the x64 ctx: the jit cache keys on the x64
         flag, so every call staying inside the ctx reuses one
         genuinely-f64 program."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             if self.device is None:
                 vals_dev = jnp.asarray(vals, dtype=jnp.float64)
             else:

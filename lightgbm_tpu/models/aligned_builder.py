@@ -41,7 +41,6 @@ import numpy as np
 from jax import lax
 
 from .. import compile_cache
-from ..dist import shard_map as dist_shard_map
 from ..ops.aligned import (META_BAG, META_LABEL, META_LABEL_MASK,
                            META_RID_MASK, R_CAT,
                            R_COPY, R_DL, R_MT, R_SHIFT, _bpw_for_bits,
@@ -1143,10 +1142,10 @@ class AlignedEngine:
 
                 wrapped = traced
                 if self.axis is not None and specs is not None:
-                    wrapped = dist_shard_map(wrapped, mesh=self.mesh,
-                                            in_specs=specs[0],
-                                            out_specs=specs[1],
-                                            check_vma=False)
+                    wrapped = jax.shard_map(wrapped, mesh=self.mesh,
+                                           in_specs=specs[0],
+                                           out_specs=specs[1],
+                                           check_vma=False)
                 return jax.jit(wrapped, donate_argnums=donate)
 
             fn = compile_cache.program(

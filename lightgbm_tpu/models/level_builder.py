@@ -204,7 +204,7 @@ def make_level_build_fn(learner):
         root_hist = _gsum(histogram_from_words(words0, gw, hw, live, F, B,
                                                chunk, precision))
         if precision == "f64":
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 root_g = _gsum(jnp.sum(gw.astype(jnp.float64)))
                 root_h = _gsum(jnp.sum(hw.astype(jnp.float64)))
         else:

@@ -3,14 +3,15 @@
 The reference keeps its whole ingest pipeline in C++ (TextReader /
 Parser / DatasetLoader with OpenMP); the Python package is a thin ctypes
 wrapper over `lib_lightgbm.so` (python-package/lightgbm/basic.py:25-36).
-This module is the same seam for the tpu build: `liblgbt_native.so` is
-loaded via ctypes, built lazily from source with the system toolchain when
-missing, and every caller has a pure-Python fallback, so the package works
-without a compiler.
+This module is the same seam for the tpu build: the library is loaded via
+ctypes, built lazily from source with the system toolchain when the build
+for the current sources is missing, and every caller has a pure-Python
+fallback, so the package works without a compiler.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional, Tuple
@@ -23,7 +24,10 @@ _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC_DIR = os.path.join(os.path.dirname(_PKG_DIR), "src", "native")
 if not os.path.isdir(_SRC_DIR):
     _SRC_DIR = os.path.join(_PKG_DIR, "_native_src")
-_LIB_NAME = "liblgbt_native.so"
+# hashed in THIS order — the Makefile's default HASH is
+# `cat $(SRCS) Makefile | sha256sum`, so a manual `make` lands on the
+# same file name
+_SOURCES = ("text_parser.cpp", "binning.cpp", "predictor.cpp", "Makefile")
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
 
@@ -32,22 +36,25 @@ _FMT_NAMES = {FMT_CSV: "csv", FMT_TSV: "tsv", FMT_LIBSVM: "libsvm"}
 
 
 def _build():
-    """(path-or-None, reason): locate or build the .so; `reason` explains
-    a None path (sources absent vs an actual make/compiler failure)."""
-    path = os.path.join(_SRC_DIR, _LIB_NAME)
-    src = os.path.join(_SRC_DIR, "text_parser.cpp")
-    if not os.path.isfile(src):
-        if os.path.isfile(path):
-            return path, ""
-        return None, "native sources not present and no prebuilt .so"
+    """(path-or-None, reason): the .so built from THESE sources. Its
+    file name carries a digest of the sources, so a binary built from
+    other sources (a stale one copied along with the tree) is never
+    loaded and make's mtime comparison never decides freshness."""
+    h = hashlib.sha256()
     try:
-        # make is a no-op when the .so is newer than every source
-        subprocess.run(["make", "-C", _SRC_DIR], check=True,
-                       capture_output=True, timeout=120)
-    except Exception as e:
-        # a prebuilt .so (if any) still works
-        if os.path.isfile(path):
-            return path, ""
+        for name in _SOURCES:
+            with open(os.path.join(_SRC_DIR, name), "rb") as fh:
+                h.update(fh.read())
+    except OSError:
+        return None, "native sources not present"
+    digest = h.hexdigest()[:12]
+    path = os.path.join(_SRC_DIR, f"liblgbt_native-{digest}.so")
+    if os.path.isfile(path):
+        return path, ""
+    try:
+        subprocess.run(["make", "-C", _SRC_DIR, f"HASH={digest}"],
+                       check=True, capture_output=True, timeout=300)
+    except (OSError, subprocess.SubprocessError) as e:
         return None, f"build failed ({e})"
     return (path, "") if os.path.isfile(path) else \
         (None, "build produced no library")
@@ -85,21 +92,18 @@ def get_lib() -> Optional[ctypes.CDLL]:
     p64, pf64, p32, p8, pu32 = (c.POINTER(c.c_int64), c.POINTER(c.c_double),
                                 c.POINTER(c.c_int32), c.POINTER(c.c_int8),
                                 c.POINTER(c.c_uint32))
-    try:  # a stale prebuilt .so may predate these symbols
-        lib.lgbt_find_bin_numerical.restype = c.c_int32
-        lib.lgbt_find_bin_numerical.argtypes = [
-            pf64, c.c_int64, c.c_int64, c.c_int32, c.c_int32, pf64]
-        lib.lgbt_bin_matrix.restype = c.c_int32
-        lib.lgbt_bin_matrix.argtypes = [
-            c.c_void_p, c.c_int32, c.c_int64, c.c_int64, p32, c.c_int64, p32,
-            p32, p32, pf64, p64, p64, p32, p64, c.c_int32, c.c_void_p]
-        lib.lgbt_predict.restype = c.c_int32
-        lib.lgbt_predict.argtypes = [
-            pf64, c.c_int64, c.c_int64, c.c_int32, p64, p64, p32, p32, p32,
-            pf64, p8, pf64, p64, p32, p64, pu32, p32, p32, c.c_int32,
-            c.c_int32, c.c_int32, c.c_double, pf64]
-    except AttributeError:
-        pass
+    lib.lgbt_find_bin_numerical.restype = c.c_int32
+    lib.lgbt_find_bin_numerical.argtypes = [
+        pf64, c.c_int64, c.c_int64, c.c_int32, c.c_int32, pf64]
+    lib.lgbt_bin_matrix.restype = c.c_int32
+    lib.lgbt_bin_matrix.argtypes = [
+        c.c_void_p, c.c_int32, c.c_int64, c.c_int64, p32, c.c_int64, p32,
+        p32, p32, pf64, p64, p64, p32, p64, c.c_int32, c.c_void_p]
+    lib.lgbt_predict.restype = c.c_int32
+    lib.lgbt_predict.argtypes = [
+        pf64, c.c_int64, c.c_int64, c.c_int32, p64, p64, p32, p32, p32,
+        pf64, p8, pf64, p64, p32, p64, pu32, p32, p32, c.c_int32,
+        c.c_int32, c.c_int32, c.c_double, pf64]
     _lib = lib
     return _lib
 
@@ -107,7 +111,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
 def _warn_unavailable(reason: str) -> None:
     from .utils import log
     log.warning(
-        f"native helper library ({_LIB_NAME}) unavailable — {reason}; "
+        f"native helper library (liblgbt_native) unavailable — {reason}; "
         f"text parsing, bin finding, and batch prediction fall back to "
         f"the (slower) pure-Python path")
 
@@ -165,7 +169,7 @@ def find_bin_numerical(values: np.ndarray, total_sample_cnt: int,
     native library is unavailable or the search degenerates (caller falls
     back to the Python implementation)."""
     lib = get_lib()
-    if lib is None or max_bin < 2 or not hasattr(lib, "lgbt_find_bin_numerical"):
+    if lib is None or max_bin < 2:
         return None
     values = np.ascontiguousarray(values, dtype=np.float64)
     out = np.empty(max_bin + 1, np.float64)
@@ -185,7 +189,7 @@ def bin_matrix(data: np.ndarray, col_idx: np.ndarray, bin_type: np.ndarray,
     """Full-matrix value->bin ingest in C++ with OpenMP over rows
     (binning.cpp lgbt_bin_matrix); None when unavailable."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "lgbt_bin_matrix"):
+    if lib is None:
         return None
     if data.dtype == np.float64:
         dtype_code = 0
@@ -223,7 +227,7 @@ def predict_forest(X: np.ndarray, flat: dict, num_class: int,
     OpenMP over rows; None when the native library is unavailable.
     `flat` is `ops.predict.flatten_forest(trees)`."""
     lib = get_lib()
-    if lib is None or not hasattr(lib, "lgbt_predict"):
+    if lib is None:
         return None
     X = np.ascontiguousarray(X, dtype=np.float64)
     n, num_feat = X.shape

@@ -44,8 +44,8 @@ EVENTS: Dict[str, str] = {
     # ranking
     "rank_buckets": "bucketed lambdarank pad ladder: per-bucket query/"
                     "doc counts and pair-padding waste",
-    "rank_fused": "segment-fused lambdarank kernel status: tile stats "
-                  "on build, or a fallback with its reason",
+    "rank_fused": "segment-fused lambdarank kernel built: tile stats, "
+                  "oversize-query leftovers, interpret flag",
     # prediction / serving
     "predict_route": "Booster.predict routing decision (device engine "
                      "vs native host walk) and why",

@@ -60,42 +60,37 @@ from . import trace
 from .terms import TERMS, term_for_site
 
 # ---------------------------------------------------------------------------
-# device roofline table: (peak dense f32-ish TFLOP/s, HBM GB/s) by
-# device_kind substring. Numbers are nominal public peaks — the
-# classification (compute- vs bandwidth-bound) only needs the RATIO to
-# be in the right regime, and program_costs.json records which row was
-# used so a reader can re-derive with better constants.
+# device roofline table: (peak dense bf16 TFLOP/s, HBM GB/s) by
+# device_kind substring — published per-chip peaks (Google Cloud TPU
+# documentation); program_costs.json records which row was used. A TPU
+# that is not in the table is an error, not a default.
 _ROOFLINES: Tuple[Tuple[str, float, float], ...] = (
-    ("v6", 918.0, 1640.0),         # Trillium (bf16 peak / HBM)
+    ("v6", 918.0, 1640.0),         # Trillium
     ("v5p", 459.0, 2765.0),
-    ("v5", 197.0, 819.0),          # v5e
+    ("v5", 197.0, 819.0),          # v5e ("TPU v5 lite")
     ("v4", 275.0, 1228.0),
     ("v3", 123.0, 900.0),
     ("v2", 45.0, 700.0),
-    ("cpu", 0.1, 50.0),            # nominal host core: keeps the ratio
-                                   # meaningful for CPU smoke runs
 )
-_DEFAULT_ROOFLINE = ("unknown", 100.0, 800.0)
 
 
-def device_roofline() -> Dict[str, Any]:
-    """{kind, peak_tflops, hbm_gbps, ridge_flops_per_byte} for the
-    first visible jax device (table above; "unknown" fallback)."""
-    kind = "unknown"
-    try:
-        import jax
-        kind = str(jax.devices()[0].device_kind).lower()
-    except Exception:
-        pass
-    name, tflops, gbps = _DEFAULT_ROOFLINE
-    for sub, tf, gb in _ROOFLINES:
+def device_roofline() -> Optional[Dict[str, Any]]:
+    """{kind, matched, peak_tflops, hbm_gbps, ridge_flops_per_byte} for
+    the first visible jax device. None on the CPU backend (a host core
+    has no published roofline worth classifying against); an accelerator
+    whose ``device_kind`` matches no table row raises."""
+    import jax
+    if jax.default_backend() == "cpu":
+        return None
+    kind = str(jax.devices()[0].device_kind).lower()
+    for sub, tflops, gbps in _ROOFLINES:
         if sub in kind:
-            name, tflops, gbps = sub, tf, gb
-            break
-    return {"kind": kind, "matched": name, "peak_tflops": tflops,
-            "hbm_gbps": gbps,
-            "ridge_flops_per_byte": round(tflops * 1e12 / (gbps * 1e9),
-                                          2)}
+            return {"kind": kind, "matched": sub, "peak_tflops": tflops,
+                    "hbm_gbps": gbps,
+                    "ridge_flops_per_byte": round(
+                        tflops * 1e12 / (gbps * 1e9), 2)}
+    raise ValueError(f"no roofline row for device_kind {kind!r}; add its "
+                     f"published peaks to obs/profiler._ROOFLINES")
 
 
 def classify_program(flops: float, bytes_accessed: float,
@@ -164,7 +159,7 @@ def collect_program_costs() -> Dict[str, Any]:
             flops = cost.get("flops", 0.0)
             byts = cost.get("bytes_accessed", 0.0)
             row.update({"flops": flops, "bytes_accessed": byts})
-            if flops or byts:
+            if roofline is not None and (flops or byts):
                 row.update(classify_program(flops, byts, roofline))
         except Exception as e:  # noqa: BLE001 — per-program, keep going
             row["error"] = f"{type(e).__name__}: {str(e)[:200]}"

@@ -42,7 +42,7 @@ def _chunk_histogram(bins_chunk: jax.Array, payload: jax.Array,
         # so psum-of-shard-partials == serial total bit-for-bit. This is the
         # topology-invariance anchor of the distributed runtime (the
         # reference's hist_t is double for the same reason).
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             oh = onehot.astype(jnp.float64)
             return jnp.einsum("kfb,kw->fbw", oh,
                               payload.astype(jnp.float64),
@@ -128,7 +128,7 @@ def histogram_from_gathered_gh(bins_rows: jax.Array, gh: jax.Array,
     if precision == "f64":
         # the scan carry must be f64 too — a f32 carry would round every
         # chunk boundary and break the order-independence argument above
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             init = jnp.zeros((f, max_bin, NUM_HIST_STATS), dtype=jnp.float64)
             acc, _ = lax.scan(body, init, (bins_c, pay_c))
         return acc

@@ -12,8 +12,9 @@ the TPU's vector memory:
   TILES of `tile` doc slots, aligned so that no query straddles a
   128-slot SUBTILE boundary unless it is itself longer than a subtile
   (long queries get an exclusive, boundary-aligned run of subtiles);
-* one Pallas program per dataset streams the score / label-gain /
-  rank-position lanes of each tile through VMEM: rank positions come
+* one Pallas program per dataset streams one packed [8, tile] block
+  (score / label-gain / label / query-id / inverse-max-DCG rows) of
+  each tile through VMEM: rank positions come
   from a stable descending pair-count (no sort), DCG discounts from an
   exact one-hot MXU lookup against the same f64-derived table as the
   bucketed path, sigmoid pair factors are bf16 with f32 accumulation
@@ -45,25 +46,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import compile_cache
 from .ranking import dcg_discounts
-
-try:  # pallas is TPU-only here; import lazily-guarded for CPU test runs
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-    # jax renamed TPUCompilerParams -> CompilerParams (and grew fields
-    # along the way). Accept either vintage.
-    _CP_CLS = getattr(pltpu, "CompilerParams",
-                      getattr(pltpu, "TPUCompilerParams", None))
-
-    def _CompilerParams(**kw):
-        import dataclasses
-        known = {f.name for f in dataclasses.fields(_CP_CLS)}
-        return _CP_CLS(**{k: v for k, v in kw.items() if k in known})
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
 
 SUBTILE = 128   # query alignment quantum = one lane register width
 
@@ -168,16 +155,23 @@ def _band_range(c: int, nb: int, band: int):
     return range(max(0, c - band + 1), min(nb, c + band))
 
 
-def _rank_tile_kernel(sr_ref, sc_ref, gr_ref, gc_ref, lr_ref, lc_ref,
-                      qr_ref, qc_ref, invc_ref, disc_ref,
-                      ga_ref, ha_ref, gb_ref, hb_ref, *,
+# sublane rows of the packed per-tile input block (all bitcast to i32)
+_ROW_SCORE, _ROW_GAIN, _ROW_LABEL, _ROW_QID, _ROW_INV = range(5)
+_PACK_ROWS = 8      # one full 8-sublane tile per grid step
+
+
+def _rank_tile_kernel(in_ref, disc_ref, out_ref, *,
                       tile: int, sub: int, band: int, sigmoid: float,
                       lut_bins: int):
-    """One grid step = one tile. Row-layout refs are [1, tile] blocks,
-    col-layout refs [tile, 1]; outputs split the per-doc sums into a
-    column side ([tile, 1]: doc as the HIGHER-labelled pair member) and
-    a row side ([1, tile]: doc as the lower member) so no in-kernel
-    transpose is needed — the caller combines g = colsum.T - rowsum.
+    """One grid step = one tile. in_ref is the [8, tile] packed block
+    (rows _ROW_*: score/gain/inv as f32 bits, label/qid as i32), every
+    doc a LANE. The pair math needs each doc both as a lane (the lower
+    pair member, [1, sub] rows) and as a sublane (the higher member,
+    [sub, 1] columns); Mosaic only takes blocks whose last two dims are
+    (8, 128)-aligned, so a [tile, 1] column input cannot exist — the
+    column forms are derived in-kernel by an identity-masked lane
+    reduce, which moves single terms and is therefore exact. out_ref is
+    [2, tile]: per-doc gradient and hessian sums, lane-oriented.
 
     Numerics mirror the bucketed oracle op-for-op: bf16 pair factors,
     f32 score differences and f32 accumulation, exact discount values
@@ -186,39 +180,52 @@ def _rank_tile_kernel(sr_ref, sc_ref, gr_ref, gc_ref, lr_ref, lc_ref,
     f32 = jnp.float32
     bf = jnp.bfloat16
     nb = tile // sub
-    s_row = sr_ref[...]
-    s_col = sc_ref[...]
-    q_row = qr_ref[...]
-    q_col = qc_ref[...]
-    l_row = lr_ref[...]
-    l_col = lc_ref[...]
-    g_row = gr_ref[...]
-    g_col = gc_ref[...]
-    inv_col = invc_ref[...]
+
+    def rows(k, dtype):
+        """Per-subtile [1, sub] row forms of packed row k — sliced off
+        the REF (a lane-offset slice of a loaded value picks up a layout
+        Mosaic rejects)."""
+        out = [in_ref[k:k + 1, b * sub:(b + 1) * sub] for b in range(nb)]
+        if dtype != jnp.int32:
+            out = [lax.bitcast_convert_type(x, dtype) for x in out]
+        return out
+
+    s_rows = rows(_ROW_SCORE, f32)
+    g_rows = rows(_ROW_GAIN, f32)
+    inv_rows = rows(_ROW_INV, f32)
+    l_rows = rows(_ROW_LABEL, jnp.int32)
+    q_rows = rows(_ROW_QID, jnp.int32)
     disc_tab = disc_ref[...]                      # [1, tile] f32
-
-    def blk_r(x, b):                              # [1, sub]
-        return x[:, b * sub:(b + 1) * sub]
-
-    def blk_c(x, a):                              # [sub, 1]
-        return x[a * sub:(a + 1) * sub, :]
 
     iota_i = lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
     iota_j = lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    eye = iota_i == iota_j
     NEG = jnp.float32(-3.4e38)
     POS = jnp.float32(3.4e38)
+
+    def cols(xs):
+        """[sub, 1] column forms of per-subtile [1, sub] rows."""
+        zero = jnp.zeros((), xs[0].dtype)
+        return [jnp.sum(jnp.where(eye, x, zero), axis=1, keepdims=True)
+                for x in xs]
+
+    def to_row(c):                                # [sub, 1] -> [1, sub]
+        return jnp.sum(jnp.where(eye, c, 0.0), axis=0, keepdims=True)
+
+    s_cols, q_cols, l_cols = cols(s_rows), cols(q_rows), cols(l_rows)
+    g_cols, inv_cols = cols(g_rows), cols(inv_rows)
 
     # ---- pass 1a: rank / best / worst per COLUMN block (doc as i) ----
     ranks_c, norm_c = [], []
     for a in range(nb):
-        sa = blk_c(s_col, a)
-        qa = blk_c(q_col, a)
+        sa = s_cols[a]
+        qa = q_cols[a]
         rank = jnp.zeros((sub, 1), jnp.int32)
         best = jnp.full((sub, 1), NEG, f32)
         worst = jnp.full((sub, 1), POS, f32)
         for b in _band_range(a, nb, band):
-            sb = blk_r(s_row, b)
-            qb = blk_r(q_row, b)
+            sb = s_rows[b]
+            qb = q_rows[b]
             same = (qa == qb) & (qa >= 0)
             gi = iota_i + a * sub
             gj = iota_j + b * sub
@@ -237,12 +244,12 @@ def _rank_tile_kernel(sr_ref, sc_ref, gr_ref, gc_ref, lr_ref, lc_ref,
     # ---- pass 1b: rank per ROW block (doc as j) ----------------------
     ranks_r = []
     for b in range(nb):
-        sb = blk_r(s_row, b)
-        qb = blk_r(q_row, b)
+        sb = s_rows[b]
+        qb = q_rows[b]
         rank = jnp.zeros((1, sub), jnp.int32)
         for a in _band_range(b, nb, band):
-            sa = blk_c(s_col, a)
-            qa = blk_c(q_col, a)
+            sa = s_cols[a]
+            qa = q_cols[a]
             same = (qa == qb) & (qb >= 0)
             gi = iota_i + a * sub
             gj = iota_j + b * sub
@@ -276,18 +283,18 @@ def _rank_tile_kernel(sr_ref, sc_ref, gr_ref, gc_ref, lr_ref, lc_ref,
     acc_gb = [jnp.zeros((1, sub), f32) for _ in range(nb)]
     acc_hb = [jnp.zeros((1, sub), f32) for _ in range(nb)]
     for a in range(nb):
-        sa = blk_c(s_col, a)
-        qa = blk_c(q_col, a)
-        la = blk_c(l_col, a)
-        gna = blk_c(g_col, a).astype(bf)
-        inva = blk_c(inv_col, a).astype(bf)
+        sa = s_cols[a]
+        qa = q_cols[a]
+        la = l_cols[a]
+        gna = g_cols[a].astype(bf)
+        inva = inv_cols[a].astype(bf)
         dca = disc_c[a]
         na = norm_c[a]
         for b in _band_range(a, nb, band):
-            sb = blk_r(s_row, b)
-            qb = blk_r(q_row, b)
-            lb = blk_r(l_row, b)
-            gnb = blk_r(g_row, b).astype(bf)
+            sb = s_rows[b]
+            qb = q_rows[b]
+            lb = l_rows[b]
+            gnb = g_rows[b].astype(bf)
             same = (qa == qb) & (qa >= 0)
             ds = (sa - sb).astype(bf)             # diff in f32 FIRST
             dgap = gna - gnb
@@ -317,10 +324,12 @@ def _rank_tile_kernel(sr_ref, sc_ref, gr_ref, gc_ref, lr_ref, lc_ref,
                                             keepdims=True)
             acc_hb[b] = acc_hb[b] + jnp.sum(hes.astype(f32), axis=0,
                                             keepdims=True)
-    ga_ref[...] = jnp.concatenate(acc_ga, axis=0)
-    ha_ref[...] = jnp.concatenate(acc_ha, axis=0)
-    gb_ref[...] = jnp.concatenate(acc_gb, axis=1)
-    hb_ref[...] = jnp.concatenate(acc_hb, axis=1)
+    # doc as the HIGHER-labelled member gets +lam (column sums), as the
+    # lower member -lam (row sums); both get +hes
+    out_ref[0:1, :] = jnp.concatenate(
+        [to_row(acc_ga[b]) - acc_gb[b] for b in range(nb)], axis=1)
+    out_ref[1:2, :] = jnp.concatenate(
+        [to_row(acc_ha[b]) + acc_hb[b] for b in range(nb)], axis=1)
 
 
 def make_fused_grad_fn(num_data: int, num_tiles: int, tile: int,
@@ -330,8 +339,6 @@ def make_fused_grad_fn(num_data: int, num_tiles: int, tile: int,
     (g[n], h[n]). All tables are runtime args, so one compiled program
     serves every booster at the same shapes; register the result under
     `compile_cache.program` keyed by `fused_program_key(...)`."""
-    if not HAS_PALLAS:  # pragma: no cover - import guard
-        raise RuntimeError("pallas unavailable")
     kernel = functools.partial(
         _rank_tile_kernel, tile=tile, sub=sub, band=band,
         sigmoid=float(sigmoid), lut_bins=int(lut_bins))
@@ -340,26 +347,24 @@ def make_fused_grad_fn(num_data: int, num_tiles: int, tile: int,
     def grad_fn(score, doc_idx, qid, gain, label, inv, disc_tab):
         compile_cache.note_trace()
         sc = jnp.where(qid >= 0, score[doc_idx], 0.0).astype(jnp.float32)
-        row = pl.BlockSpec((1, T), lambda i: (i, 0))
-        col = pl.BlockSpec((T, 1), lambda i: (0, i))
-        gA, hA, gB, hB = pl.pallas_call(
+        bits = functools.partial(lax.bitcast_convert_type,
+                                 new_dtype=jnp.int32)
+        pad = jnp.zeros_like(qid)
+        packed = jnp.stack(
+            [bits(sc), bits(gain), label, qid, bits(inv)]
+            + [pad] * (_PACK_ROWS - 5), axis=1)       # [NT, 8, T] i32
+        gh = pl.pallas_call(
             kernel,
             grid=(NT,),
-            in_specs=[row, col, row, col, row, col, row, col, col,
+            in_specs=[pl.BlockSpec((None, _PACK_ROWS, T),
+                                   lambda i: (i, 0, 0)),
                       pl.BlockSpec((1, T), lambda i: (0, 0))],
-            out_specs=[col, col, row, row],
-            out_shape=[
-                jax.ShapeDtypeStruct((T, NT), jnp.float32),
-                jax.ShapeDtypeStruct((T, NT), jnp.float32),
-                jax.ShapeDtypeStruct((NT, T), jnp.float32),
-                jax.ShapeDtypeStruct((NT, T), jnp.float32),
-            ],
-            compiler_params=_CompilerParams(vmem_limit_bytes=128 << 20),
+            out_specs=pl.BlockSpec((None, 2, T), lambda i: (i, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((NT, 2, T), jnp.float32),
             interpret=interpret,
-        )(sc, sc.T, gain, gain.T, label, label.T, qid, qid.T,
-          inv.T, disc_tab)
-        g_t = jnp.where(qid >= 0, gA.T - gB, 0.0)
-        h_t = jnp.where(qid >= 0, hA.T + hB, 0.0)
+        )(packed, disc_tab)
+        g_t = jnp.where(qid >= 0, gh[:, 0, :], 0.0)
+        h_t = jnp.where(qid >= 0, gh[:, 1, :], 0.0)
         flat = doc_idx.reshape(-1)
         g = jnp.zeros((num_data,), jnp.float32).at[flat].add(
             g_t.reshape(-1))

@@ -29,7 +29,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import Config
-from ..dist import shard_map as dist_shard_map
 from ..io.dataset import Dataset
 from ..models.device_learner import DeviceTreeLearner, TreeRecord, _pow2ceil
 
@@ -143,7 +142,7 @@ class DataParallelTreeLearner:
             leaf_begin=P(ax), leaf_cnt_part=P(ax))
 
         if root_contiguous:
-            mapped = dist_shard_map(
+            mapped = jax.shard_map(
                 build, mesh=self.mesh,
                 in_specs=(P(ax), P(None, ax), P(ax), P(ax), P()),
                 out_specs=(P(ax), rec_specs),
@@ -163,7 +162,7 @@ class DataParallelTreeLearner:
         def per_shard(bins, bins_T, indices, grad, hess, counts, fmask):
             return build(bins, bins_T, indices, grad, hess, counts[0], fmask)
 
-        mapped = dist_shard_map(
+        mapped = jax.shard_map(
             per_shard, mesh=self.mesh,
             in_specs=(P(ax), P(None, ax), P(ax), P(ax), P(ax), P(ax), P()),
             out_specs=(P(ax), rec_specs),
@@ -192,7 +191,7 @@ class DataParallelTreeLearner:
             leaves = traverse_record(bins, trav, nb, db, mt)
             return score + scale * trav["leaf_value"][leaves]
 
-        mapped = dist_shard_map(
+        mapped = jax.shard_map(
             per_shard, mesh=self.mesh,
             in_specs=(P(ax), P(ax), P(), P(), P(), P(), P()),
             out_specs=P(ax), check_vma=False)
@@ -233,7 +232,7 @@ class DataParallelTreeLearner:
             delta = unpermute_to_rows(indices[:per], fill, cnt, per)
             return score + scale * delta
 
-        mapped = dist_shard_map(
+        mapped = jax.shard_map(
             per_shard, mesh=self.mesh,
             in_specs=(P(ax), P(ax), P(ax), P(), P(ax), P()),
             out_specs=P(ax), check_vma=False)
@@ -330,7 +329,7 @@ class DataParallelTreeLearner:
             rid=P(ax), n_exec=P(), execF=P(), execI=P(), execB=P(),
             bestF=P(), bestI=P(), bestB=P(), leafF=P(), leafI=P(ax),
             block_begin=P(ax), block_cnt=P(ax))
-        mapped = dist_shard_map(
+        mapped = jax.shard_map(
             build, mesh=self.mesh,
             in_specs=(P(None, ax), P(ax), P(ax), P()),
             out_specs=spec_specs,

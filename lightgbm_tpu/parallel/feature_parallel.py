@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import Config
-from ..dist import shard_map as dist_shard_map
 from ..io.dataset import Dataset
 from ..models.device_learner import DeviceTreeLearner, TreeRecord, _pow2ceil
 from .data_parallel import default_mesh
@@ -83,7 +82,7 @@ class FeatureParallelTreeLearner:
         build = self.inner._make_build_fn(root_padded, root_contiguous)
         rec_specs = TreeRecord(*([P()] * len(TreeRecord._fields)))
         n_in = 5 if root_contiguous else 7
-        mapped = dist_shard_map(
+        mapped = jax.shard_map(
             build, mesh=self.mesh,
             in_specs=tuple([P()] * n_in),
             out_specs=(P(), rec_specs),

@@ -48,7 +48,7 @@ _CKPT_PREFIX = "ckpt_"
 RUNTIME_ONLY_PARAMS = frozenset({
     "tpu_checkpoint_dir", "tpu_checkpoint_freq", "tpu_snapshot_keep",
     "tpu_fault_spec", "tpu_retry_max", "tpu_retry_backoff_s",
-    "tpu_trace", "tpu_trace_dir", "tpu_compile_cache_dir",
+    "tpu_trace", "tpu_trace_dir",
     "snapshot_freq", "output_model", "input_model", "output_result",
     "num_threads", "verbosity",
     "tpu_serve_hbm_budget_mb", "tpu_serve_max_batch_wait_ms",

@@ -843,7 +843,6 @@ class ForestEngine:
         (`shard_map` over a 1-D 'rows' mesh). Returns margins
         [N, num_class] f64. Forest arrays are replicated; the traversal is
         embarrassingly row-parallel so no collective runs."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         devices = list(devices if devices is not None else jax.devices())
@@ -859,11 +858,12 @@ class ForestEngine:
         if key not in self._sharded_cache:
             mesh = Mesh(np.asarray(devices), ("rows",))
             spec_in = tuple(P(None, "rows") for _ in planes)
-            fn = shard_map(lambda stk, pl: self._run(stk, pl)[0],
-                           mesh=mesh,
-                           in_specs=(jax.tree_util.tree_map(
-                               lambda _: P(), self._stk), spec_in),
-                           out_specs=P(None, "rows"), check_rep=False)
+            fn = jax.shard_map(lambda stk, pl: self._run(stk, pl)[0],
+                               mesh=mesh,
+                               in_specs=(jax.tree_util.tree_map(
+                                   lambda _: P(), self._stk), spec_in),
+                               out_specs=P(None, "rows"),
+                               check_vma=False)
             self._sharded_cache[key] = jax.jit(fn)
         out = self._sharded_cache[key](self._stk, planes)
         return np.asarray(out)[:, :n].T.astype(np.float64)

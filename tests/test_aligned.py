@@ -2,9 +2,9 @@
 
 The chunk-aligned builder must reproduce the leaf-wise reference path
 exactly (same splits, same leaf values within float noise) — the same
-contract the sort-based level builder carries (tests/test_level.py). The
-kernels themselves are oracle-checked in tools/proto_aligned.py and on
-TPU; here the full builder + GBDT integration runs in interpret mode.
+contract the sort-based level builder carries (tests/test_level.py). Here
+the full builder + GBDT integration runs in interpret mode; the compiled
+kernels run on the chip through chip_smoke.py.
 """
 import numpy as np
 import pytest

@@ -1,0 +1,72 @@
+"""chip_smoke.py on the CPU tier: every leg at toy size with the Pallas
+kernels under the interpreter (the four-chip leg on conftest's 8-device
+CPU mesh), main() refusing a backend without a chip, and the one
+compile-cache resolver. The chip itself is only ever reached through the
+chip tool (`python chip_smoke.py`)."""
+import gc
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from lightgbm_tpu import compile_cache  # noqa: E402
+
+# the smoke's own route on the chip is tpu_grow_mode=auto with compiled
+# kernels; off the chip the same kernels run interpreted
+INTERPRET = {"tpu_grow_mode": "aligned", "tpu_aligned_interpret": True}
+
+
+def test_train_and_score_legs():
+    res, bst, hold_X = chip_smoke.leg_train(
+        1500, 8, iters=2, num_leaves=7, holdout_rows=400,
+        extra_params=INTERPRET)
+    assert res["path"].startswith("aligned") and res["fallbacks"] == 0
+    assert res["walls"]["first_iter_s"] > 0
+    assert len(res["walls"]["iter_host_s"]) == 2
+    score = chip_smoke.leg_score(
+        bst, hold_X, subset=200, requests=2, request_rows=8,
+        predict_params={"tpu_predict_device": "on"})
+    assert score["engine_calls"] > 0
+    assert score["http_codes"] == [200] * 4
+
+
+def test_rank_leg_spills_and_stays_fused():
+    # a 0.25 MB budget forces the HBM spill ring at toy width (at the
+    # real 137 x 255-bin width the default 48 MB budget spills by itself)
+    res = chip_smoke.leg_rank(
+        1500, 8, iters=2, num_leaves=7,
+        extra_params=dict(INTERPRET, tpu_rank_fused="on",
+                          tpu_hist_spill_vmem_mb=0.25))
+    assert res["hist_spill"] and res["rank_fused_active"]
+    assert res["rank_grad_rel_err"] < 1e-5
+
+
+def test_multichip_leg_on_cpu_mesh():
+    try:
+        res = chip_smoke.leg_multichip(2000, 8, shards=4, iters=2,
+                                       num_leaves=7, extra_params=INTERPRET)
+    finally:
+        # the program registry's closures keep the engine — and through
+        # it the sharded Dataset and its HBM-accountant rows — alive
+        compile_cache.clear_programs()
+        gc.collect()
+    assert res["shards"] == 4 and len(set(res["devices"])) == 4
+    assert len(set(res["placed_bytes"]["bins"].values())) == 1
+
+
+def test_main_refuses_a_backend_without_a_chip(capsys):
+    assert chip_smoke.main() != 0
+    out = capsys.readouterr()
+    assert "no TPU" in out.err
+    assert '"ok"' not in out.out          # no result line
+    # and it bailed out before wiring the persistent cache
+    assert compile_cache.persistent_cache_dir() is None
+
+
+def test_cache_dir_resolver(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.cache_dir() == os.path.join(REPO, ".jax_cache")

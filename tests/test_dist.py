@@ -63,9 +63,11 @@ def test_num_shards_resolution():
     assert dist_runtime.num_shards(
         Config(tree_learner="data", num_machines=4,
                tpu_dist_devices=2)) == 2
-    # requests are clamped to the devices that exist
-    assert dist_runtime.num_shards(
-        Config(tree_learner="data", num_machines=64)) == nd
+    # asking for more shards than devices is an error, never a
+    # silently narrower mesh
+    for over in (dict(num_machines=64), dict(tpu_dist_devices=9)):
+        with pytest.raises(ValueError, match="more shards than the 8 visible"):
+            dist_runtime.num_shards(Config(tree_learner="data", **over))
     assert not dist_runtime.active(Config())           # serial
     assert not dist_runtime.active(
         Config(tree_learner="data", tpu_dist_devices=1))

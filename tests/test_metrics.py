@@ -469,14 +469,27 @@ def test_bench_compare_incomplete_records_excluded(tmp_path):
     assert bc.main([p1, p2]) == 2
 
 
-def test_bench_compare_repo_trajectory():
-    """The committed BENCH series must reproduce the known history:
-    Higgs improving (0.146x -> 0.825x of baseline), MSLR flat (0.341x),
-    r05 excluded as incomplete."""
-    paths = [os.path.join(_REPO, f"BENCH_r{i:02d}.json")
-             for i in range(1, 6)]
-    if not all(os.path.isfile(p) for p in paths):
-        pytest.skip("BENCH record series not present")
+def test_bench_compare_series_trajectory(tmp_path):
+    """A five-record driver series (the shape of the pre-round r01-r05
+    records, rebuilt inline) must read as: Higgs improving (0.146x ->
+    0.825x of baseline), MSLR flat (0.341x), the timed-out fifth record
+    excluded as incomplete."""
+    higgs = {"metric": "higgs_synth_500iter_s", "unit": "s"}
+    series = [
+        dict(higgs, value=1630.93, vs_baseline=0.146),
+        dict(higgs, value=1631.07, vs_baseline=0.146),
+        dict(higgs, value=426.43, vs_baseline=0.559, auc=0.737437,
+             value_255bin=554.25, ndcg10=0.610759, mslr_500iter_s=631.69,
+             mslr_vs_baseline=0.341),
+        dict(higgs, value=289.22, vs_baseline=0.825, auc=0.737585,
+             value_255bin=363.03, ndcg10=0.610759, mslr_500iter_s=631.99,
+             mslr_vs_baseline=0.341),
+        None,                                         # rc=124, parsed:null
+    ]
+    paths = []
+    for i, parsed in enumerate(series, 1):
+        paths.append(str(tmp_path / f"BENCH_r{i:02d}.json"))
+        json.dump(_wrap(i, parsed), open(paths[-1], "w"))
     bc = _load_bench_compare()
     v = bc.compare([bc.load_record(p) for p in paths])
     assert v["incomplete"] == ["r05"]

@@ -268,12 +268,14 @@ def test_cost_analysis_smoke(tmp_path):
         assert all(isinstance(s, jax.ShapeDtypeStruct)
                    for s in ent["spec_args"])
         costs = obs_profiler.collect_program_costs()
-        assert costs["device"]["matched"]
+        # the CPU backend gets NO roofline (never a nominal one), so
+        # the rows carry XLA's counts but no bound classification
+        assert costs["device"] is None
         tag = ent["tag"]
         row = costs["programs"][tag]
         assert "error" not in row, row
         assert row["flops"] > 0 and row["bytes_accessed"] > 0
-        assert row["bound"] in ("compute", "bandwidth")
+        assert "bound" not in row
         assert row["dispatch_ms_per_call"] > 0
         path = obs_profiler.write_program_costs(
             str(tmp_path / "program_costs.json"))

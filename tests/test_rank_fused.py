@@ -10,9 +10,6 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.ops import pallas_rank
 from lightgbm_tpu.ops.objectives import LambdarankNDCG
 
-pytestmark = pytest.mark.skipif(
-    not pallas_rank.HAS_PALLAS, reason="pallas unavailable")
-
 
 def _boundaries(counts):
     return np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)

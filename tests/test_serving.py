@@ -464,8 +464,9 @@ def test_cli_serve_matches_raw_predict(tmp_path):
         f"output_result={out_pred}", "predict_raw_score=true",
         "verbosity=-1",
     ]).run()
+    # both files carry ~1e-6 text precision: compare absolutely
     np.testing.assert_allclose(np.loadtxt(out_serve),
-                               np.loadtxt(out_pred), rtol=1e-6)
+                               np.loadtxt(out_pred), atol=1e-6)
 
 
 def test_cli_serve_requires_a_model_source():
@@ -514,8 +515,7 @@ def test_budget_gate_scale_iters_and_unbounded():
 # ------------------------------------------------- compile-cache miss events
 
 def test_persistent_cache_miss_event_attribution(events):
-    if not compile_cache.install_cache_event_hooks():
-        pytest.skip("jax persistent-cache logging seam not present")
+    compile_cache.install_cache_event_hooks()
     from jax._src import compiler as jax_compiler
     before = compile_cache.persistent_cache_events()["misses"]
     with compile_cache.attribution("unit:probe"):
