@@ -30,13 +30,17 @@ any leg runs. Depth is cut (10 / 3 iterations, not 500); widths are not.
 The legs are plain functions of their sizes so tests/test_chip_smoke.py
 can drive them at toy size under the Pallas interpreter.
 
-The last stdout line is one JSON object: {"ok": true, "device": {...},
-..., "claim": null}.
+The last stdout line is the verdict, one JSON object with exactly these
+keys: {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}
+("ok": false, and a non-zero exit, when a leg failed). The line before it,
+prefixed "summary: ", is the JSON summary of every leg, ending
+"claim": null. Without a TPU nothing is printed as a verdict at all.
 """
 import http.client
 import json
 import sys
 import time
+import traceback
 
 import numpy as np
 
@@ -446,17 +450,38 @@ def main() -> int:
     cache = compile_cache.init_persistent_cache()
     entries0 = compile_cache.cache_dir_entries(cache)
     say(f"compile_cache: dir={cache} entries_before={entries0}")
-    t_all = time.perf_counter()
     out = {"ok": False, "device": device}
+    try:
+        _run_legs(out, cache, entries0)
+        out["ok"] = True
+    except Exception:
+        # the first failed leg ends the run; the verdict still goes out
+        traceback.print_exc()
+        say("chip_smoke: FAILED (traceback on stderr)")
+    return report(out)
+
+
+def report(out) -> int:
+    """Print the summary of every leg, then the verdict — exactly
+    {"ok", "device"} — as the last line of stdout; returns the exit code."""
+    out["claim"] = None
+    say("summary: " + json.dumps(out))
+    say(json.dumps({"ok": out["ok"], "device": out["device"]}))
+    return 0 if out["ok"] else 1
+
+
+def _run_legs(out, cache, entries0) -> None:
+    from lightgbm_tpu import compile_cache
+    t_all = time.perf_counter()
     out["train"], bst, hold_X = leg_train(HIGGS_ROWS, HIGGS_FEATURES)
     out["score"] = leg_score(bst, hold_X)
     del bst, hold_X
     out["rank"] = leg_rank(MSLR_ROWS, MSLR_FEATURES)
-    if device["count"] >= 4:
+    if out["device"]["count"] >= 4:
         out["multichip"] = leg_multichip(HIGGS_ROWS, HIGGS_FEATURES)
     else:
         out["multichip"] = None
-        say(f"multichip: not run ({device['count']} device)")
+        say(f"multichip: not run ({out['device']['count']} device)")
     events = compile_cache.persistent_cache_events()
     out["compile_cache"] = {
         "dir": cache, "entries_before": entries0,
@@ -465,10 +490,6 @@ def main() -> int:
     say(f"compile_cache: hits={events['hits']} misses={events['misses']} "
         f"entries_after={out['compile_cache']['entries_after']}")
     out["wall_s"] = round(time.perf_counter() - t_all, 1)
-    out["ok"] = True
-    out["claim"] = None
-    print(json.dumps(out), flush=True)
-    return 0
 
 
 if __name__ == "__main__":

@@ -4,6 +4,7 @@ CPU mesh), main() refusing a backend without a chip, and the one
 compile-cache resolver. The chip itself is only ever reached through the
 chip tool (`python chip_smoke.py`)."""
 import gc
+import json
 import os
 import sys
 
@@ -63,6 +64,19 @@ def test_main_refuses_a_backend_without_a_chip(capsys):
     assert '"ok"' not in out.out          # no result line
     # and it bailed out before wiring the persistent cache
     assert compile_cache.persistent_cache_dir() is None
+
+
+def test_report_ends_with_the_exact_verdict(capsys):
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    for ok in (True, False):
+        rc = chip_smoke.report({"ok": ok, "device": device,
+                                "train": {"path": "aligned"}})
+        lines = capsys.readouterr().out.splitlines()
+        assert (rc == 0) == ok
+        # the last line holds exactly these keys and nothing else
+        assert json.loads(lines[-1]) == {"ok": ok, "device": device}
+        assert lines[-2].startswith("summary: ")
+        assert lines[-2].endswith('"claim": null}')
 
 
 def test_cache_dir_resolver(monkeypatch, tmp_path):
