@@ -24,6 +24,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from ..config import Config
+from ..obs import trace as obs_trace
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN,
                       MISSING_NONE, MISSING_ZERO, BinMapper)
 
@@ -454,62 +455,63 @@ class Dataset:
         With ``reference`` the sample may be None: mappers are shared so
         a streamed validation set aligns with the training set.
         """
-        cfg = config or Config()
-        self = cls()
-        self.num_data = int(n_total)
-        self.metadata = Metadata(self.num_data)
-        self.max_bin = cfg.max_bin
-        self.min_data_in_bin = cfg.min_data_in_bin
-        self.use_missing = cfg.use_missing
-        self.zero_as_missing = cfg.zero_as_missing
+        with obs_trace.seam("ingest.find_bins", rows=int(n_total)):
+            cfg = config or Config()
+            self = cls()
+            self.num_data = int(n_total)
+            self.metadata = Metadata(self.num_data)
+            self.max_bin = cfg.max_bin
+            self.min_data_in_bin = cfg.min_data_in_bin
+            self.use_missing = cfg.use_missing
+            self.zero_as_missing = cfg.zero_as_missing
 
-        if reference is not None:
-            f = reference.num_total_features
-            self.num_total_features = f
-            self.mappers = reference.mappers
-            self.used_feature_map = reference.used_feature_map
-            self.real_feature_idx = reference.real_feature_idx
-            self.max_bin = reference.max_bin
-            self.monotone_constraints = reference.monotone_constraints
-            self.feature_penalty = reference.feature_penalty
-            self.feature_names = reference.feature_names
-        else:
-            sample = np.asarray(sample, np.float64)
-            f = sample.shape[1]
-            self.num_total_features = f
-            self.feature_names = (list(feature_names) if feature_names
-                                  else [f"Column_{i}" for i in range(f)])
-            cat_set = _cat_set_from(cfg, categorical_feature)
-            self.mappers = []
-            for j in range(f):
-                col = sample[:, j]
-                nonzero = col[~((col >= -1e-35) & (col <= 1e-35))]
-                m = BinMapper()
-                bt = BIN_CATEGORICAL if j in cat_set else BIN_NUMERICAL
-                m.find_bin(nonzero, total_sample_cnt=len(col),
-                           max_bin=cfg.max_bin,
-                           min_data_in_bin=cfg.min_data_in_bin,
-                           min_split_data=cfg.min_data_in_leaf,
-                           bin_type=bt, use_missing=cfg.use_missing,
-                           zero_as_missing=cfg.zero_as_missing)
-                self.mappers.append(m)
-            _finalize_used_features(self, cfg, f)
+            if reference is not None:
+                f = reference.num_total_features
+                self.num_total_features = f
+                self.mappers = reference.mappers
+                self.used_feature_map = reference.used_feature_map
+                self.real_feature_idx = reference.real_feature_idx
+                self.max_bin = reference.max_bin
+                self.monotone_constraints = reference.monotone_constraints
+                self.feature_penalty = reference.feature_penalty
+                self.feature_names = reference.feature_names
+            else:
+                sample = np.asarray(sample, np.float64)
+                f = sample.shape[1]
+                self.num_total_features = f
+                self.feature_names = (list(feature_names) if feature_names
+                                      else [f"Column_{i}" for i in range(f)])
+                cat_set = _cat_set_from(cfg, categorical_feature)
+                self.mappers = []
+                for j in range(f):
+                    col = sample[:, j]
+                    nonzero = col[~((col >= -1e-35) & (col <= 1e-35))]
+                    m = BinMapper()
+                    bt = BIN_CATEGORICAL if j in cat_set else BIN_NUMERICAL
+                    m.find_bin(nonzero, total_sample_cnt=len(col),
+                               max_bin=cfg.max_bin,
+                               min_data_in_bin=cfg.min_data_in_bin,
+                               min_split_data=cfg.min_data_in_leaf,
+                               bin_type=bt, use_missing=cfg.use_missing,
+                               zero_as_missing=cfg.zero_as_missing)
+                    self.mappers.append(m)
+                _finalize_used_features(self, cfg, f)
 
-        used = self.real_feature_idx
-        max_nb = max((self.mappers[j].num_bin for j in used), default=2)
-        dtype = np.uint8 if max_nb <= 256 else np.uint16
-        self._bins_dtype = dtype
-        if alloc_bins:
-            self.bins = np.zeros((self.num_data, len(used)), dtype=dtype)
-        # else: stream-to-shard ingest — rows go straight to their owner
-        # device's shard slice and the [n, U] host matrix never exists
-        self._push_cfg = cfg
-        self._push_ref = reference
-        self._push_pos = 0
-        self._push_label = None
-        self._push_weight = None
-        self._push_init = None
-        return self
+            used = self.real_feature_idx
+            max_nb = max((self.mappers[j].num_bin for j in used), default=2)
+            dtype = np.uint8 if max_nb <= 256 else np.uint16
+            self._bins_dtype = dtype
+            if alloc_bins:
+                self.bins = np.zeros((self.num_data, len(used)), dtype=dtype)
+            # else: stream-to-shard ingest — rows go straight to their owner
+            # device's shard slice and the [n, U] host matrix never exists
+            self._push_cfg = cfg
+            self._push_ref = reference
+            self._push_pos = 0
+            self._push_label = None
+            self._push_weight = None
+            self._push_init = None
+            return self
 
     def push_rows(self, data: np.ndarray, label=None, weight=None,
                   init_score=None) -> None:
@@ -520,37 +522,43 @@ class Dataset:
         if getattr(self, "_push_pos", None) is None:
             raise RuntimeError(
                 "push_rows requires a dataset made by create_from_sample")
-        data = np.asarray(data)
-        if data.dtype not in (np.float32, np.float64):
-            data = data.astype(np.float64)
-        k = data.shape[0]
-        pos = self._push_pos
-        if pos + k > self.num_data:
-            raise ValueError(
-                f"push_rows overflow: {pos + k} > n_total={self.num_data}")
-        used = self.real_feature_idx
-        dtype = self.bins.dtype
-        chunk = self._native_bin_matrix(data, used, dtype)
-        if chunk is None:
-            chunk = np.empty((k, len(used)), dtype=dtype)
-            for col_idx, j in enumerate(used):
-                chunk[:, col_idx] = self.mappers[j].values_to_bins(
-                    np.asarray(data[:, j], np.float64)).astype(dtype)
-        self.bins[pos:pos + k] = chunk
-        if label is not None:
-            if self._push_label is None:
-                self._push_label = np.zeros(self.num_data, np.float64)
-            self._push_label[pos:pos + k] = np.asarray(label, np.float64)
-        if weight is not None:
-            if self._push_weight is None:
-                self._push_weight = np.ones(self.num_data, np.float64)
-            self._push_weight[pos:pos + k] = np.asarray(weight, np.float64)
-        if init_score is not None:
-            if self._push_init is None:
-                self._push_init = np.zeros(self.num_data, np.float64)
-            self._push_init[pos:pos + k] = np.asarray(init_score,
-                                                      np.float64)
-        self._push_pos = pos + k
+        with obs_trace.seam("ingest.push_rows") as sm:
+            data = np.asarray(data)
+            if data.dtype not in (np.float32, np.float64):
+                data = data.astype(np.float64)
+            k = data.shape[0]
+            pos = self._push_pos
+            if pos + k > self.num_data:
+                raise ValueError(f"push_rows overflow: {pos + k} > "
+                                 f"n_total={self.num_data}")
+            used = self.real_feature_idx
+            dtype = self.bins.dtype
+            chunk = self._native_bin_matrix(data, used, dtype)
+            # a silent fall-back to the Python binner shows here, not only
+            # as a slower ingest
+            sm.attrs.update(rows=int(k), native=chunk is not None)
+            if chunk is None:
+                chunk = np.empty((k, len(used)), dtype=dtype)
+                for col_idx, j in enumerate(used):
+                    chunk[:, col_idx] = self.mappers[j].values_to_bins(
+                        np.asarray(data[:, j], np.float64)).astype(dtype)
+            self.bins[pos:pos + k] = chunk
+            if label is not None:
+                if self._push_label is None:
+                    self._push_label = np.zeros(self.num_data, np.float64)
+                self._push_label[pos:pos + k] = np.asarray(label,
+                                                           np.float64)
+            if weight is not None:
+                if self._push_weight is None:
+                    self._push_weight = np.ones(self.num_data, np.float64)
+                self._push_weight[pos:pos + k] = np.asarray(weight,
+                                                            np.float64)
+            if init_score is not None:
+                if self._push_init is None:
+                    self._push_init = np.zeros(self.num_data, np.float64)
+                self._push_init[pos:pos + k] = np.asarray(init_score,
+                                                          np.float64)
+            self._push_pos = pos + k
 
     def push_binned_rows(self, binned: np.ndarray, label=None, weight=None,
                          init_score=None) -> None:
@@ -657,12 +665,13 @@ class Dataset:
         if pos != self.num_data:
             raise ValueError(
                 f"finish_load: {pos} rows pushed, {self.num_data} declared")
-        if self._push_label is not None:
-            self.metadata.set_label(self._push_label)
-        self.metadata.set_weight(self._push_weight)
-        self.metadata.set_group(group)
-        self.metadata.set_init_score(self._push_init)
-        self._maybe_bundle(self._push_cfg, self._push_ref)
+        with obs_trace.seam("ingest.finish_load", rows=int(pos)):
+            if self._push_label is not None:
+                self.metadata.set_label(self._push_label)
+            self.metadata.set_weight(self._push_weight)
+            self.metadata.set_group(group)
+            self.metadata.set_init_score(self._push_init)
+            self._maybe_bundle(self._push_cfg, self._push_ref)
         self._push_cfg = self._push_ref = None
         self._push_pos = None
         self._push_label = self._push_weight = self._push_init = None

@@ -41,6 +41,7 @@ import numpy as np
 from jax import lax
 
 from .. import compile_cache
+from ..obs import trace as obs_trace
 from ..ops.aligned import (META_BAG, META_LABEL, META_LABEL_MASK,
                            META_RID_MASK, R_CAT,
                            R_COPY, R_DL, R_MT, R_SHIFT, _bpw_for_bits,
@@ -58,6 +59,21 @@ from .level_builder import (SF_GAIN, SF_IVAL, SF_LOUT, SF_ROUT, SF_W,
                             SI_DEFLEFT, SI_FEAT, SI_ISCAT, SI_LC, SI_RC,
                             SI_SLOT, SI_THR, SI_W, replay_leafwise,
                             spec_slots)
+
+
+# columns of AlignedSpec.round_stats, one row per while-loop round: what
+# the round scheduled, summed over vectors the round's body already has
+# (per shard under data-parallel; the host records shard 0's)
+ROUND_STATS = (
+    "chunks_split",    # live chunks routed through move_pass's compute path
+    "chunks_copied",   # live chunks shifted whole by one HBM->HBM DMA
+    "rows_split",      # rows of the leaves split this round
+    "leaves_split",    # k, the leaves split this round
+    "spill_slots",     # histogram slots flushed through the HBM spill ring
+    "chunks_dead",     # chunks of no live block that still take move_pass's
+                       # compute path: the chunk map hands the free tail to
+                       # the last slot, and its route word says "split"
+)
 
 
 class AlignedSpec(NamedTuple):
@@ -78,6 +94,7 @@ class AlignedSpec(NamedTuple):
     first_c: jax.Array     # i32[S+1]
     nxt_c: jax.Array       # i32[Sm1+1]
     cover: jax.Array       # f32[S+1]
+    round_stats: jax.Array  # i32[Sm1, len(ROUND_STATS)], rows < rounds
 
 
 def slot_in_any_map(begin, count, nc, chunk):
@@ -148,6 +165,90 @@ class AlignedEngine:
         # NC at 65k chunks
         from ..ops.aligned import chunk_for
         self.C = C = chunk_for(self.cfg, learner.num_features, learner.n)
+        # host work over all rows: what the readers of this seam need
+        # of the layout rides on it
+        with obs_trace.seam("aligned.pack", rows=int(learner.n)) as sm:
+            rec_all, cnts_all = self._pack_host(
+                learner, objective, init_row_scores, bagged, num_class)
+            nbytes = int(rec_all.nbytes) + int(cnts_all.nbytes)
+            sm.attrs.update(
+                bytes=nbytes, W=int(self.W), w_used=int(self.w_used),
+                C=int(C), NC=int(self.NC), bits=int(self.bits),
+                shards=int(self.nd), count_pass=self.count_pass)
+        # the span is the ENQUEUE of the transfer: nothing here waits for
+        # it, so what the host does not copy synchronously lands in the
+        # first program's wait (the first train.drain)
+        with obs_trace.seam("aligned.upload", bytes=nbytes):
+            if self.axis is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                sh = NamedSharding(self.mesh, P(self.axis))
+                self.rec = jax.device_put(rec_all, sh)
+                self.cnts = jax.device_put(cnts_all, sh)
+            else:
+                self.rec = jnp.asarray(rec_all)
+                self.cnts = jnp.asarray(cnts_all)
+        from ..obs import memory as obs_memory
+        obs_memory.track(
+            "train/aligned_records", self,
+            lambda e: int(e.rec.nbytes) + int(e.cnts.nbytes))
+        self._pgrad = objective.point_grad_fn()
+        if self._pgrad is not None:
+            # hash/eq by signature: the point-grad closure rides into
+            # move_pass/slot_hist_pass as a STATIC jit arg, and a fresh
+            # closure per engine would retrace the module-level kernels
+            self._pgrad = compile_cache.HashableFn(
+                self._pgrad, ("pgrad", objective.trace_signature()))
+        self._programs = {}
+        # process-wide program identity: everything the engine's program
+        # factories bake into their traces (the learner signature covers
+        # config + bin metadata + mesh; the objective signature covers
+        # gradient closures incl. content-hashed label/weight data)
+        import os as _os
+        self._trace_sig = (
+            "aligned", learner.trace_signature(),
+            objective.trace_signature(), self.C, self.NC, self.S,
+            self.W, self.wcnt, self.w_used, self.bits,
+            tuple(sorted(self.lanes.items())), self.compact, self.ext,
+            self.gh_off, self.num_class, self.mc_mode, self.interpret,
+            self.bagged, self.axis, self.nd, self.per_shard,
+            _os.environ.get("LGBT_KCAP", ""),
+            str(self.mesh) if self.mesh is not None else None)
+        self._score_cache = None     # (iter_tag, np array)
+        self._iter_tag = 0
+        # exactness of the LAST dispatched program (device scalar): the
+        # next dispatch gates its score update on it, so a successor of
+        # an inexact tree is a guaranteed score no-op (see build())
+        self._last_exact = jnp.asarray(True)
+        # multiclass deferred application: (spec, class_k, scale) of the
+        # last dispatch, applied at the start of the NEXT dispatch (or by
+        # flush_pending_apply), gated by the exactness CHAIN self._gate
+        self._mc_pending = None
+        self._gate = jnp.asarray(True)
+
+    @property
+    def count_pass(self) -> bool:
+        """Whether every round of the build program runs `count_pass`.
+        Fixed per engine, so it rides the `aligned.pack` seam and is no
+        per-round counter."""
+        # above 2^24 rows the f32 histogram count sums lose row-level
+        # exactness for the biggest leaves, so the PHYSICAL layout takes
+        # its counts from the exact i32 count pass (split-decision
+        # counts stay histogram-driven: only leaves larger than 2^24
+        # rows see sub-ppm count fuzz there, far from any min_data
+        # guard; documented divergence)
+        big_n = (self.n > (1 << 24)
+                 or bool(getattr(self.cfg, "tpu_force_big_n", False)))
+        # bagging and data-parallel: see the round body
+        return bool(self.bagged or self.axis is not None or big_n)
+
+    # ------------------------------------------------------------------
+    def _pack_host(self, learner, objective, init_row_scores, bagged,
+                   num_class):
+        """The host-side half of construction: choose the record layout,
+        pack every shard's rows into [nc_local, W, C] records
+        (`pack_records`), pad to the static chunk grid and fill the score
+        lanes. Returns (rec_all, cnts_all) as numpy, ready to upload."""
+        C = self.C
         bins = np.asarray(learner.ds.bins)
         # feature-parallel zero-padding only; under EFB bundling
         # ds.bins holds the [N, G] bundled storage whose column count
@@ -280,51 +381,7 @@ class AlignedEngine:
         else:
             rec_all = np.concatenate(shard_recs, axis=0)
             cnts_all = np.concatenate(shard_cnts)
-        if self.axis is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            sh = NamedSharding(self.mesh, P(self.axis))
-            self.rec = jax.device_put(rec_all, sh)
-            self.cnts = jax.device_put(cnts_all, sh)
-        else:
-            self.rec = jnp.asarray(rec_all)
-            self.cnts = jnp.asarray(cnts_all)
-        from ..obs import memory as obs_memory
-        obs_memory.track(
-            "train/aligned_records", self,
-            lambda e: int(e.rec.nbytes) + int(e.cnts.nbytes))
-        self._pgrad = objective.point_grad_fn()
-        if self._pgrad is not None:
-            # hash/eq by signature: the point-grad closure rides into
-            # move_pass/slot_hist_pass as a STATIC jit arg, and a fresh
-            # closure per engine would retrace the module-level kernels
-            self._pgrad = compile_cache.HashableFn(
-                self._pgrad, ("pgrad", objective.trace_signature()))
-        self._programs = {}
-        # process-wide program identity: everything the engine's program
-        # factories bake into their traces (the learner signature covers
-        # config + bin metadata + mesh; the objective signature covers
-        # gradient closures incl. content-hashed label/weight data)
-        import os as _os
-        self._trace_sig = (
-            "aligned", learner.trace_signature(),
-            objective.trace_signature(), self.C, self.NC, self.S,
-            self.W, self.wcnt, self.w_used, self.bits,
-            tuple(sorted(self.lanes.items())), self.compact, self.ext,
-            self.gh_off, self.num_class, self.mc_mode, self.interpret,
-            self.bagged, self.axis, self.nd, self.per_shard,
-            _os.environ.get("LGBT_KCAP", ""),
-            str(self.mesh) if self.mesh is not None else None)
-        self._score_cache = None     # (iter_tag, np array)
-        self._iter_tag = 0
-        # exactness of the LAST dispatched program (device scalar): the
-        # next dispatch gates its score update on it, so a successor of
-        # an inexact tree is a guaranteed score no-op (see build())
-        self._last_exact = jnp.asarray(True)
-        # multiclass deferred application: (spec, class_k, scale) of the
-        # last dispatch, applied at the start of the NEXT dispatch (or by
-        # flush_pending_apply), gated by the exactness CHAIN self._gate
-        self._mc_pending = None
-        self._gate = jnp.asarray(True)
+        return rec_all, cnts_all
 
     # ------------------------------------------------------------------
     def row_scores_dev(self):
@@ -498,14 +555,7 @@ class AlignedEngine:
         prev_lane_off = ln["score"] + ((class_k - 1) % K_cls)
         axis = lr.axis_name
         dp = axis is not None and lr.parallel_mode == "data"
-        # above 2^24 rows the f32 histogram count sums lose row-level
-        # exactness for the biggest leaves, so the PHYSICAL layout takes
-        # its counts from the exact i32 count pass (split-decision
-        # counts stay histogram-driven: only leaves larger than 2^24
-        # rows see sub-ppm count fuzz there, far from any min_data
-        # guard; documented divergence)
-        big_n = (self.n > (1 << 24)
-                 or bool(getattr(self.cfg, "tpu_force_big_n", False)))
+        counted = self.count_pass
 
         def _gsum(x):
             return lax.psum(x, axis) if dp else x
@@ -741,7 +791,8 @@ class AlignedEngine:
             state = (jnp.int32(0), rec, cnts_pc, leafF, leafI, bestF,
                      bestI, bestB, hist_store, execF, execI, execB,
                      need0, jnp.zeros(Sm1 + 1, bool), jnp.int32(0),
-                     jnp.int32(0))
+                     jnp.int32(0),
+                     jnp.zeros((Sm1, len(ROUND_STATS)), jnp.int32))
 
             def cond(state):
                 done, need = state[0], state[12]
@@ -750,7 +801,7 @@ class AlignedEngine:
             def body(state):
                 (done, rec, cnts_pc, leafF, leafI, bestF, bestI, bestB,
                  hist_store, execF, execI, execB, need, _commit,
-                 _ncommit, rounds) = state
+                 _ncommit, rounds, round_stats) = state
                 s_ids = jnp.arange(S + 1, dtype=jnp.int32)
                 gains = bestF[:, BF_GAIN]
                 # K also caps per-round splits: compact hist ids must fit
@@ -835,7 +886,7 @@ class AlignedEngine:
                 meta_pc = (cnt_of
                            | (first.astype(jnp.int32) << 20)
                            | (last.astype(jnp.int32) << 21))
-                if bagged or dp or big_n:
+                if counted:
                     # the histogram count channel cannot drive the
                     # physical layout when it is IN-BAG only (bagging,
                     # gbdt.cpp:209-275) or GLOBAL (data-parallel: BI_LC
@@ -890,6 +941,20 @@ class AlignedEngine:
                     | ((~smaller_is_left).astype(jnp.int32) << 24),
                     K)
                 hslots_pc = jnp.where(in_any, hslot_s[slot_of], K)
+                # ---- what this round schedules (ROUND_STATS order): the
+                # kernel's split path runs wherever the chunk's route word
+                # has the copy bit clear, live block or not, and a
+                # spilling store is flushed once per split block, on its
+                # last chunk
+                split_pc = sel[slot_of]
+
+                def nsum(x):
+                    return jnp.sum(x.astype(jnp.int32))
+                round_stats = round_stats.at[rounds].set(jnp.stack([
+                    nsum(split_pc & in_any), nsum(copy_pc),
+                    nsum(jnp.where(sel, leafI[:, LI_COUNT], 0)), k,
+                    nsum(split_pc & last) if spill else jnp.int32(0),
+                    nsum(split_pc & ~in_any)]))
                 rec, hout = move_pass(rec, r1_pc, r2_pc, bl_pc, br_pc,
                                       meta_pc, wsel_pc, hslots_pc, cbits,
                                       C, W, wcnt, K, G, BH, group,
@@ -1045,11 +1110,11 @@ class AlignedEngine:
 
                 return (done + k, rec, cnts_pc, leafF, leafI, bestF, bestI,
                         bestB, hist_store, execF, execI, execB, need2,
-                        commit, ncommit, rounds + 1)
+                        commit, ncommit, rounds + 1, round_stats)
 
             (n_exec, rec, cnts_pc, leafF, leafI, bestF, bestI, bestB,
              _, execF, execI, execB, need_end, _commit_c, _ncommit_c,
-             rounds) = lax.while_loop(cond, body, state)
+             rounds, round_stats) = lax.while_loop(cond, body, state)
             # authoritative final replay: the in-loop replay may have been
             # skipped on the last round (all_needed shortcut), and a tree
             # that stops growing early must still commit its real splits
@@ -1115,7 +1180,8 @@ class AlignedEngine:
                                bestF=bestF[:S], bestI=bestI[:S],
                                bestB=bestB[:S], leafF=leafF[:S],
                                leafI=leafI[:S], first_c=first_c,
-                               nxt_c=nxt_c, cover=cover)
+                               nxt_c=nxt_c, cover=cover,
+                               round_stats=round_stats)
             return rec, cnts_pc, spec, exact, ncommit, applied
 
         return build
@@ -1151,7 +1217,30 @@ class AlignedEngine:
             fn = compile_cache.program(
                 self._trace_sig + ("prog", key), build_jit)
             self._programs[key] = fn
+            return self._first_call(key, fn)
         return fn
+
+    @staticmethod
+    def _first_call(key, fn):
+        """`fn` under an `aligned.program` seam: this engine's first call
+        of a program is where it is traced, lowered and compiled or
+        loaded from the persistent cache, all on the host before the
+        execution is enqueued. Later lookups hand out `fn` itself."""
+        def run(*args, **kwargs):
+            traces = compile_cache.trace_count()
+            before = compile_cache.persistent_cache_events()
+            with obs_trace.seam("aligned.program", key=str(key)) as sm:
+                out = fn(*args, **kwargs)
+                after = compile_cache.persistent_cache_events()
+                if compile_cache.trace_count() == traces:
+                    sm.attrs["cache"] = "memory"    # compiled in-process
+                elif (after["hits"] > before["hits"]
+                      and after["misses"] == before["misses"]):
+                    sm.attrs["cache"] = "hit"
+                else:       # compiled here, persistent cache wired or not
+                    sm.attrs["cache"] = "miss"
+            return out
+        return run
 
     def _specs(self, kind):
         """(in_specs, out_specs) for the DP shard_map wrap of each
@@ -1165,7 +1254,7 @@ class AlignedEngine:
         spec_out = AlignedSpec(
             rounds=P(), n_exec=P(), execF=P(), execI=P(), execB=P(),
             bestF=P(), bestI=P(), bestB=P(), leafF=P(), leafI=P(ax),
-            first_c=P(), nxt_c=P(), cover=P())
+            first_c=P(), nxt_c=P(), cover=P(), round_stats=P())
         if kind == "build":
             return ((P(ax), P(ax), P(), P(), P()),
                     (P(ax), P(ax), spec_out, P(), P(), P()))
@@ -1184,21 +1273,23 @@ class AlignedEngine:
 
     def train_iter(self, scale: float,
                    feature_mask: Optional[np.ndarray] = None,
-                   grads=None):
+                   grads=None, boost_iter: Optional[int] = None):
         """One boosting iteration: gradients + tree build + score-lane
         update. Returns (spec, ncommit_dev, exact_dev, applied_dev) —
         ALL device values, no sync. `applied_dev` = exact & prev_ok: True
         iff this program's score-lane update actually happened (a
         dispatch following an inexact predecessor is a guaranteed no-op
         and will be discarded by the host). `grads` = (g_rows, h_rows)
-        device arrays for non-pointwise objectives."""
-        from ..obs import trace as obs_trace
+        device arrays for non-pointwise objectives. `boost_iter` names
+        the boosting iteration on the dispatch seam (the engine's own
+        dispatch count where the caller gives none)."""
         fmask = self.learner._fmask_arr(feature_mask)
-        # host-side dispatch span only — this boundary must stay free of
-        # device syncs (the round loop pipelines on it), so the tracer
-        # observes dispatch latency here and device drain at the round
-        # fence in gbdt._train_one_iter_traced
-        with obs_trace.span("aligned.dispatch", iter=self._iter_tag):
+        # host-side dispatch seam only — this boundary must stay free of
+        # device syncs (the round loop pipelines on it): the seam times
+        # the enqueue, always on, and never fences
+        with obs_trace.seam("aligned.dispatch",
+                            iter=self._iter_tag if boost_iter is None
+                            else boost_iter):
             if grads is not None:
                 fn = self._program(
                     "build_ext",
@@ -1246,7 +1337,6 @@ class AlignedEngine:
         pre-iteration scores. Returns (spec, ncommit_dev, exact_dev,
         applied_dev) — all device values, no sync; `applied_dev` is the
         chain gate under which this spec's values will apply."""
-        from ..obs import trace as obs_trace
         fmask = self.learner._fmask_arr(feature_mask)
         fn = self._program(
             ("build_mc", class_k),

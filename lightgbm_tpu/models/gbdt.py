@@ -24,6 +24,7 @@ import numpy as np
 
 from ..config import Config
 from ..io.dataset import Dataset
+from ..obs import trace as obs_trace
 from ..ops.metrics import Metric, create_metrics
 from ..ops.objectives import ObjectiveFunction, create_objective
 from ..ops.predict import TreePredictor, stack_trees, _predict_binned_stacked
@@ -56,6 +57,18 @@ class LazyTree:
         if abs(self.bias) > K_EPSILON:
             tree.add_bias(self.bias)
         return tree
+
+
+def _record_aligned_iter(it: int, rounds, table) -> None:
+    """One `aligned.iter` seam record for a resolved aligned iteration:
+    the build program's round count and its per-round counters
+    (`aligned_builder.ROUND_STATS` order, rows up to `rounds`), as pulled
+    with the exactness flags. Data-parallel: shard 0's counters."""
+    from .aligned_builder import ROUND_STATS
+    rounds = int(rounds)
+    obs_trace.seam_record("aligned.iter", iter=int(it), rounds=rounds,
+                          columns=list(ROUND_STATS),
+                          table=np.asarray(table)[:rounds].tolist())
 
 
 class LazyAlignedTree(LazyTree):
@@ -1157,7 +1170,10 @@ class GBDT:
         q = getattr(self, "_aligned_pending", None) or []
         q.append((exact_dev, list(init_scores),
                   fmask if fmask is None else fmask.copy(),
-                  self.bag_data_indices, self.bag_data_cnt))
+                  self.bag_data_indices, self.bag_data_cnt,
+                  # [5], [6]: the spec, whose counters ride the flag
+                  # pull, and the iteration they are recorded under
+                  spec, self.iter - 1))
         self._aligned_pending = q
         # valid-set scores: walk the committed tree ON DEVICE from the
         # spec, still pipelined — the walk is gated by the program's own
@@ -1284,7 +1300,8 @@ class GBDT:
             grads = (gd[0], hd[0])
         return self._dispatch_device(
             "engine.train_iter",
-            lambda: eng.train_iter(self.shrinkage_rate, fmask, grads=grads))
+            lambda: eng.train_iter(self.shrinkage_rate, fmask, grads=grads,
+                                   boost_iter=self.iter))
 
     def _aligned_pipeline_depth(self) -> int:
         """How many dispatched rounds may stay unresolved before the
@@ -1323,11 +1340,18 @@ class GBDT:
         if not final and len(q) < self._aligned_pipeline_depth():
             return None
         self._aligned_pending = None
-        if len(q) == 1:
-            flags = [bool(q[0][0])]
-        else:
-            flags = [bool(v) for v in
-                     jax.device_get(jnp.stack([p[0] for p in q]))]
+        # the one place the loop's host blocks. The per-round counters of
+        # the queued programs ride the same pull as they are (no stack,
+        # no concatenate: nothing here may compile a new program)
+        with obs_trace.seam("train.flag_pull", iter=self.iter,
+                            queued=len(q), final=final):
+            flags, stats = jax.device_get((
+                q[0][0] if len(q) == 1 else jnp.stack([p[0] for p in q]),
+                [(p[5].rounds, p[5].round_stats) for p in q]))
+        flags = [bool(v) for v in np.atleast_1d(flags)]
+        for p, ok, (rounds, table) in zip(q, flags, stats):
+            if ok:      # a discarded dispatch is rebuilt, and recorded then
+                _record_aligned_iter(p[6], rounds, table)
         if all(flags):
             return None
         j = flags.index(False)
@@ -1339,12 +1363,12 @@ class GBDT:
         del self._pending_numsplits[-drop:]
         self.iter -= drop
         if not final and j == len(q) - 1:
-            return ("redo",) + tuple(q[j][1:])
+            return ("redo",) + tuple(q[j][1:5])
         eng = self._aligned_eng_ref
         self._note_aligned_fallback(eng, "inexact replay in pending batch")
         stop = self._aligned_fallback_iter(q[j][1], eng, q[j][2],
                                            q[j][3], q[j][4])
-        for (_e, init_r, fmask_r, _bi, _bc) in q[j + 1:]:
+        for (_e, init_r, fmask_r, *_rest) in q[j + 1:]:
             if stop:
                 break
             stop = self._aligned_replay_round(eng, init_r, fmask_r)
@@ -1360,9 +1384,14 @@ class GBDT:
         valid walk to replay)."""
         spec, ncommit_dev, exact_dev, _applied = \
             self._dispatch_aligned(eng, fmask)
-        if not bool(exact_dev):
+        with obs_trace.seam("train.flag_pull", iter=self.iter, queued=1,
+                            final=True):
+            exact, rounds, table = jax.device_get(
+                (exact_dev, spec.rounds, spec.round_stats))
+        if not bool(exact):
             self._note_aligned_fallback(eng, "inexact replay")
             return self._aligned_fallback_iter(init_scores, eng, fmask)
+        _record_aligned_iter(self.iter, rounds, table)
         self._train_score_stale = True
         lazy = LazyAlignedTree(spec, self.shrinkage_rate, init_scores[0],
                                self.learner,
@@ -1419,21 +1448,26 @@ class GBDT:
     def _sync_train_score(self) -> None:
         """Materialize row-order training scores from the aligned engine
         (lazy: only metrics / renewal / rollback need them)."""
-        self._discard_eager()
-        self._resolve_aligned_pending(final=True)
-        res = self._resolve_aligned_pending_mc()
-        if res is not None:
-            self._aligned_mc_fallback(res)
-        if getattr(self, "_train_score_stale", False):
-            eng = getattr(self, "_aligned_eng_ref", None)
-            if eng is not None:
+        eng = getattr(self, "_aligned_eng_ref", None)
+        if eng is None:         # no aligned state: nothing to drain
+            self._train_score_stale = False
+            return
+        # the drain: the last flags, then the materialise program, whose
+        # result the host waits for
+        with obs_trace.seam("train.drain", iter=self.iter):
+            self._discard_eager()
+            self._resolve_aligned_pending(final=True)
+            res = self._resolve_aligned_pending_mc()
+            if res is not None:
+                self._aligned_mc_fallback(res)
+            if getattr(self, "_train_score_stale", False):
                 if getattr(eng, "num_class", 1) > 1:
                     self.train_score.score = jnp.asarray(
                         eng.row_scores_mc())
                 else:
                     self.train_score.score = jnp.asarray(
                         eng.row_scores())[None, :]
-            self._train_score_stale = False
+                self._train_score_stale = False
 
     def _drop_aligned(self) -> None:
         """Leave aligned mode permanently (rollback and other mutations
@@ -1582,14 +1616,19 @@ class GBDT:
     def materialized_models(self) -> List[Tree]:
         """Convert any LazyTree records to host Trees in ONE batched
         device->host transfer."""
-        if getattr(self, "_aligned_pending", None) is not None:
-            self._resolve_aligned_pending(final=True)
-        lazies = [(i, m) for i, m in enumerate(self.models)
-                  if isinstance(m, LazyTree)]
-        if lazies:
-            recs = jax.device_get([m.record for _, m in lazies])
-            for (i, m), rec in zip(lazies, recs):
-                self.models[i] = m.materialize(rec)
+        pending = getattr(self, "_aligned_pending", None) is not None
+        if not pending and not any(isinstance(m, LazyTree)
+                                   for m in self.models):
+            return self.models
+        with obs_trace.seam("train.drain", iter=self.iter):
+            if pending:
+                self._resolve_aligned_pending(final=True)
+            lazies = [(i, m) for i, m in enumerate(self.models)
+                      if isinstance(m, LazyTree)]
+            if lazies:
+                recs = jax.device_get([m.record for _, m in lazies])
+                for (i, m), rec in zip(lazies, recs):
+                    self.models[i] = m.materialize(rec)
         return self.models
 
     # ------------------------------------------------------------------

@@ -51,13 +51,16 @@ def _build():
     path = os.path.join(_SRC_DIR, f"liblgbt_native-{digest}.so")
     if os.path.isfile(path):
         return path, ""
+    reason = "build produced no library"
     try:
         subprocess.run(["make", "-C", _SRC_DIR, f"HASH={digest}"],
                        check=True, capture_output=True, timeout=300)
     except (OSError, subprocess.SubprocessError) as e:
-        return None, f"build failed ({e})"
-    return (path, "") if os.path.isfile(path) else \
-        (None, "build produced no library")
+        reason = f"build failed ({e})"
+    # look again whatever make said: another process building the same
+    # digest at the same time may have put the library there (the
+    # Makefile renames a per-process temporary into place)
+    return (path, "") if os.path.isfile(path) else (None, reason)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

@@ -21,14 +21,24 @@ Completed spans accumulate in memory and — when a trace directory is
 configured — append to ``<dir>/spans-<pid>.jsonl`` one JSON record per
 span, flushed per line so a killed process keeps everything closed so
 far.
+
+SEAMS (ISSUE 25) are the other half: a handful of host boundaries
+(``seam()``) that record ALWAYS, whatever ``tpu_trace`` says, into one
+bounded ring (``seams()``), and always enter a
+``jax.profiler.TraceAnnotation`` so a live profiler session sees them
+on the device operations' clock. A seam never fences and never touches
+``_block``: turning nothing on, it changes no program and no pipeline.
+``span`` / ``fence`` / ``tpu_trace`` stay the operator's FENCED mode.
 """
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 fence_count = 0          # fences issued while tracing (test probe)
 _enabled = False
@@ -39,6 +49,10 @@ _depth = 0
 _lock = threading.Lock()
 _block = None            # resolved lazily to jax.block_until_ready
 _efh = None              # events-<pid>.jsonl tee (timeline join)
+SEAM_RING = 8192         # seam records kept (oldest dropped first)
+_seams: Deque[Dict[str, Any]] = collections.deque(maxlen=SEAM_RING)
+_seam_ids = itertools.count(1)
+_seam_open = threading.local()   # .stack: this thread's open seams
 
 
 def enabled() -> bool:
@@ -102,10 +116,11 @@ def tee_event(kind: str, fields: Dict[str, Any]) -> None:
 
 
 def reset() -> None:
-    """Clear accumulated spans and the fence counter (tests)."""
+    """Clear accumulated spans, seams and the fence counter (tests)."""
     global fence_count
     with _lock:
         _spans.clear()
+        _seams.clear()
         fence_count = 0
 
 
@@ -179,6 +194,99 @@ class _Span:
                           + "\n")
                 _fh.flush()
         return False
+
+
+def _keep_seam(rec: Dict[str, Any]) -> None:
+    """Into the ring; and, while the fenced tracer is on, into its span
+    list and file in a span's shape (`dur_ms`, `depth`), so its summary
+    and the timeline keep the boundaries that became seams."""
+    with _lock:
+        _seams.append(rec)
+        if not _enabled:
+            return
+        as_span = dict(rec, kind="span", depth=_depth,
+                       dur_ms=round((rec["t1"] - rec["t0"]) * 1e3, 4))
+        _spans.append(as_span)
+        if _fh is not None:
+            _fh.write(json.dumps(as_span, sort_keys=True, default=str)
+                      + "\n")
+            _fh.flush()
+
+
+def _seam_rec(name: str, it: Optional[int], t: float) -> Dict[str, Any]:
+    return {"kind": "seam", "name": name, "id": next(_seam_ids),
+            "parent": None, "iter": it, "t0": t, "t1": t}
+
+
+class _Seam:
+    """One open seam. ``attrs`` may be filled while it is open (sizes
+    that are only known at the end of the region)."""
+    __slots__ = ("rec", "attrs", "_ann")
+
+    def __init__(self, name: str, it: Optional[int],
+                 attrs: Dict[str, Any]):
+        self.rec = _seam_rec(name, it, 0.0)
+        self.attrs = attrs
+        self._ann = None
+
+    def __enter__(self):
+        stack = getattr(_seam_open, "stack", None)
+        if stack is None:
+            stack = _seam_open.stack = []
+        if stack:
+            up = stack[-1].rec
+            self.rec["parent"] = up["id"]
+            if self.rec["iter"] is None:
+                self.rec["iter"] = up["iter"]
+        stack.append(self)
+        self._ann = _profiler_annotation(self.rec["name"])
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.rec["t0"] = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.rec["t1"] = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _seam_open.stack.pop()
+        self.rec.update(self.attrs)
+        _keep_seam(self.rec)
+        return False
+
+
+def seam(name: str, iter: Optional[int] = None, **attrs) -> _Seam:
+    """Context manager over one host boundary of the training path.
+    ALWAYS on: the record (``name``, ``id``, ``parent`` = the enclosing
+    seam's id, ``iter`` = the boosting iteration it belongs to, inherited
+    from the enclosing seam when not given, ``t0``/``t1`` on
+    ``time.perf_counter``, plus ``attrs``) goes to the ring behind
+    ``seams()``, and, while the fenced tracer is on, to ``spans()`` and
+    ``spans-<pid>.jsonl`` as well. It enters a
+    ``jax.profiler.TraceAnnotation`` of the same name, which is an atomic
+    load while no profiler session is live. It never fences: placing one
+    changes no program and no pipeline."""
+    return _Seam(name, iter, attrs)
+
+
+def seam_record(name: str, iter: Optional[int] = None, **attrs) -> None:
+    """A seam with no extent: one record at now (``t0 == t1``), for
+    facts the host learns at a point, such as an iteration's counters
+    arriving with a flag pull."""
+    rec = _seam_rec(name, iter, time.perf_counter())
+    stack = getattr(_seam_open, "stack", None)
+    if stack:
+        rec["parent"] = stack[-1].rec["id"]
+    rec.update(attrs)
+    _keep_seam(rec)
+
+
+def seams(name: Optional[str] = None) -> List[Dict[str, Any]]:
+    """The ring's seam records in completion order (an enclosing seam
+    after the seams it encloses), all or those called ``name``."""
+    with _lock:
+        out = list(_seams)
+    return out if name is None else [r for r in out if r["name"] == name]
 
 
 def span(name: str, **attrs):

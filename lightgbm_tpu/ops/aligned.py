@@ -952,6 +952,7 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 << 20, has_side_effects=True),
         interpret=interpret,
+        name="move_pass",
     )(r1p, r2, blbr, meta, hslots, cbits, fetch_idx, records, records)
     hist = _hist_store_finalize(hist, num_slots, num_features,
                                 b_pad, group, subbin)
@@ -1044,6 +1045,7 @@ def count_pass(records, r1, r2, meta, wsel, kslots, cbits, num_slots,
         out_shape=jax.ShapeDtypeStruct((num_slots + 1,), jnp.int32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=100 << 20),
         interpret=interpret,
+        name="count_pass",
     )(r1, r2, meta, wsel, kslots, cbits, records)
     return out[:num_slots]
 
@@ -1129,6 +1131,7 @@ def slot_hist_pass(records, slots, meta, num_slots, num_features, b_pad,
         out_shape=jax.ShapeDtypeStruct(store_shape, jnp.float32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=100 << 20),
         interpret=interpret,
+        name="slot_hist_pass",
     )(slots, meta, records)
     return _hist_store_finalize(out, num_slots, num_features, b_pad,
                                 group, subbin)
