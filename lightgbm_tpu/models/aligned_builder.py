@@ -159,11 +159,11 @@ class AlignedEngine:
         self.interpret = interpret
         self.bagged = bagged
         self.num_class = num_class
-        # 512 measured best on v5e at 10.5M rows: 256 halves the
-        # permutation matmul but doubles grid/DMA/glue fixed costs
-        # (1148 vs 999 ms/iter); destinations pack 16-bit, capping
-        # NC at 65k chunks
-        from ..ops.aligned import chunk_for
+        # the chunk is the unit of the grid, the DMA, the flush and the
+        # route words (destinations pack 16-bit, capping NC at 65k
+        # chunks); move_pass partitions it in sub-tiles of route_tile(C)
+        # rows, so the permutation matmul no longer grows with it
+        from ..ops.aligned import chunk_for, route_tile
         self.C = C = chunk_for(self.cfg, learner.num_features, learner.n)
         # host work over all rows: what the readers of this seam need
         # of the layout rides on it
@@ -174,7 +174,8 @@ class AlignedEngine:
             sm.attrs.update(
                 bytes=nbytes, W=int(self.W), w_used=int(self.w_used),
                 C=int(C), NC=int(self.NC), bits=int(self.bits),
-                shards=int(self.nd), count_pass=self.count_pass)
+                shards=int(self.nd), count_pass=self.count_pass,
+                route_tile=route_tile(C), route_tiles=C // route_tile(C))
         # the span is the ENQUEUE of the transfer: nothing here waits for
         # it, so what the host does not copy synchronously lands in the
         # first program's wait (the first train.drain)

@@ -70,14 +70,21 @@ R_CAT = 25         # bit 25      categorical split (bitset routing)
 
 
 def effective_chunk(cfg, num_features: int = 0) -> int:
-    """The chunk size the aligned engine will actually run at. 1024
-    measured best on v5e at the HIGGS shape (10.5M x 28) once the route
-    one-hot was factored to [C, C] — per-chunk fixed costs dominate the
-    split path, so halving the chunk count beats the narrower one-hot —
-    but WIDE records regress hard at 1024 (F=137: 2.0 s/iter vs 0.66 at
-    512; per-chunk VMEM temps scale with W*C), so records wider than
-    ~40 features stay at 512. 2048 regresses on VMEM pressure
-    everywhere. tpu_chunk overrides."""
+    """The chunk size the aligned engine will actually run at: the unit
+    of the grid, the DMA, the flush and the route words. move_pass's
+    split path works in sub-tiles of route_tile(C) rows, so its cost a
+    row does not grow with C any more (PERF.md section 6, PR 27).
+
+    The rule below is older than that and is NOT retuned: 1024 measured
+    best on v5e at the HIGGS shape (10.5M x 28), where per-chunk fixed
+    costs dominate the split path, and WIDE records regressed hard at
+    1024 (F=137: 2.0 s/iter vs 0.66 at 512; per-chunk VMEM temps scale
+    with W*C), so records wider than ~40 features stay at 512. "2048
+    regresses everywhere" (HIGGS shape, 2.2 s/iter against 0.53) was
+    measured on the untiled kernel with eight inlined copies of the
+    histogram code in it; at the Criteo-67 record that code alone cost
+    every split chunk 39 us of 45, and without it a 2,048-row chunk
+    costs 10.1 us tiled, 17.2 untiled. tpu_chunk overrides."""
     C = int(getattr(cfg, "tpu_chunk", 0) or 0)
     if C > 0:
         return C
@@ -87,11 +94,13 @@ def effective_chunk(cfg, num_features: int = 0) -> int:
 def chunk_for(cfg, num_features: int, n: int) -> int:
     """effective_chunk, scaled up so the move pass's 6 per-chunk route
     words fit the 1 MB scalar-prefetch SMEM budget (NC <= ~40K): very
-    large n doubles the chunk until NC fits — slower per row (wider
-    one-hots) but the only way a 50M+-row dataset trains aligned on one
-    chip at all. An explicit tpu_chunk is escalated the same way (the
-    pinned size would fail SMEM allocation outright), with a warning so
-    a user who benchmarked at the pinned size knows why timing moved."""
+    large n doubles the chunk until NC fits, the only way a 50M+-row
+    dataset trains aligned on one chip at all. The larger chunk costs
+    fewer grid steps and no wider one-hots: the split path's tile is
+    route_tile(C) whatever C is. An explicit tpu_chunk is escalated the
+    same way (the pinned size would fail SMEM allocation outright), with
+    a warning so a user who benchmarked at the pinned size knows why
+    timing moved."""
     C0 = C = effective_chunk(cfg, num_features)
     while n // C > 40_000:
         C *= 2
@@ -101,6 +110,24 @@ def chunk_for(cfg, num_features: int, n: int) -> int:
             f"tpu_chunk={C0} cannot hold {n} rows within the kernel's "
             f"scalar-prefetch budget; using tpu_chunk={C} instead")
     return C
+
+
+# Rows move_pass's split path partitions at a time. Its rank mask, its
+# one-hot and its route matmul are S x S, so the work a row is
+# proportional to S and not to the chunk size, which the SMEM budget and
+# the row count set (chunk_for). Chosen by tools/route_tile_sweep.py on
+# the v5e (PERF.md section 6, PR 27): us a chunk at 128 / 256 / 512 /
+# 1024 / untiled, Criteo-67 record at C=2048: - / 10.1 / 10.4 / 12.6 /
+# 17.2 (63 bins: 8.3 / 8.6 / - / 14.5); HIGGS record at C=1024:
+# 2.47 / 2.50 / 2.95; 128 ran 2 us behind 256 in an earlier sweep.
+ROUTE_TILE = 256
+
+
+def route_tile(chunk: int) -> int:
+    """S: the sub-tile of a `chunk`-row chunk that move_pass's split
+    path partitions at a time. ROUTE_TILE wherever it divides the chunk,
+    else the whole chunk (one tile)."""
+    return ROUTE_TILE if chunk % ROUTE_TILE == 0 else chunk
 
 
 def aligned_num_chunks(n: int, cfg, spec_slots: int,
@@ -548,14 +575,15 @@ def _hi_lo6(pay):
 def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                  hslot_ref, cbits_ref, fetch_ref, rec_ref, rec_hbm_ref,
                  out_ref, hist_ref, stag,
-                 fbuf, hacc, hstage, cur_ref, sems, *, chunk, w_pad,
+                 fbuf, hacc, hstage, tri, cur_ref, sems, *, chunk, w_pad,
                  w_used, wcnt, num_features, b_pad, group, dummy,
                  bag_lane, bits, grad_fn, num_class, gh_off, bundled,
                  subbin, spill):
     """One grid step of the fused move+hist pass.
 
     SPLIT chunks: partition rows into the block's left/right staging
-    rings (exact byte-plane one-hot matmul), flush full chunks to dynamic
+    rings (exact byte-plane one-hot matmul, in sub-tiles of
+    route_tile(chunk) rows), flush full chunks to dynamic
     destination chunks, and accumulate the smaller child's histogram
     DIRECTLY from the chunk's smaller-side rows into a VMEM-resident
     store indexed by COMPACT per-round slot ids (constant out-spec: the
@@ -596,6 +624,10 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
         # flags and saved src/dst indices before any use
         for j in range(48):
             cur_ref[j] = 0
+        # the rank mask does not depend on the data: built once a pass
+        tri[...] = (lax.broadcasted_iota(jnp.int32, tri.shape, 0)
+                    < lax.broadcasted_iota(jnp.int32, tri.shape, 1)
+                    ).astype(jnp.bfloat16)
         if not spill:
             hist_ref[...] = jnp.zeros_like(hist_ref)
 
@@ -610,12 +642,11 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
         # the compact store once per block on its last chunk
         hacc[...] = jnp.zeros_like(hacc)
 
-    rec = rec_ref[0]                                  # [W, C]
-    pos = lax.broadcasted_iota(jnp.int32, (1, C), 1)[0]
     cntv = meta & ((1 << 20) - 1)
-    valid = pos < cntv
     is_copy = (r1 >> R_COPY) & 1
     hs = hslot_ref[i]
+    hslot = hs & 0xFFFFFF        # compact slot of the smaller child
+    hside = (hs >> 24) & 1       # its side: 0 = the chunk's left rows
 
     def wait_slot(slot):
         if slot < 4:            # static: flush slots DMA from VMEM
@@ -684,96 +715,119 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                 cur_ref[28 + slot] = i
 
     # ---- split path
+    S = route_tile(C)
+    T = C // S
+
     @pl.when(is_copy == 0)
     def _():
         wsel = (r1 >> R_WSEL) & 255
-        word = rec[0, :]
-        for wj in range(1, wcnt):
-            word = jnp.where(wsel == wj, rec[wj, :], word)
-        binv = (word >> ((r1 >> R_SHIFT) & 31)) & bmask
-        if bundled:
-            binv = _unpack_bundle(binv, r2_ref[i])
-        catw = _cat_word(cbits_ref, hs & 0xFFFFFF, binv)
-        left = _goes_left(binv, r1, r2_ref[i], valid, catw)
-
-        # ranks via one triangular matmul (measured FASTER on the MXU
-        # than log2(C) pltpu.roll prefix sums: 3.33 vs 3.82 ns/row)
-        li = left.astype(jnp.bfloat16)[None, :]
-        vi = valid.astype(jnp.bfloat16)[None, :]
-        both = jnp.concatenate([li, vi], axis=0)          # [2, C]
-        iota_s = lax.broadcasted_iota(jnp.int32, (C, C), 0)
-        iota_d = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-        tri = (iota_s < iota_d).astype(jnp.bfloat16)
-        ranks = lax.dot_general(both, tri, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        rank_l = ranks[0].astype(jnp.int32)
-        rank_v = ranks[1].astype(jnp.int32)
-        k_l = jnp.sum(left.astype(jnp.int32))
-        k_v = jnp.sum(valid.astype(jnp.int32))
-        rank_r = rank_v - rank_l
-
-        cur_l = cur_ref[0]
-        cur_r = cur_ref[1]
-        dst = jnp.where(left, (cur_l + rank_l) % (2 * C),
-                        2 * C + (cur_r + rank_r) % (2 * C))
-        dst = jnp.where(valid, dst, 4 * C + 5)
-
-        # only the USED lanes ride the route matmul (w_used <= w_pad:
-        # 8-sublane padding and, under the compact layout, the unused
-        # tail lanes carry no data — pad lanes of the output stay stale,
-        # which is fine because no kernel reads past w_used)
+        r2 = r2_ref[i]
         U = w_used
-        # int8 byte planes: the MXU takes s8 x s8 -> s32 at twice the
-        # bf16 rate and the f32 -> i32 output converts disappear; byte
-        # values wrap to signed but `& 255` after the single-term
-        # selection recovers them exactly
-        planes = jnp.concatenate(
-            [((rec[:U] >> (8 * b)) & 255).astype(jnp.int8)
-             for b in range(4)], axis=0)                  # [4U, C]
-        # FACTORED route: dst = sc*C + lo (sc = staging chunk 0..3).
-        # A flat [C, 4C] one-hot costs 4C int32 compares per row on the
-        # VPU (2048 at C=512 — measured the dominant term of the split
-        # path); factoring into a per-sc payload split (4 compares +
-        # 4*4U products per row) times ONE [C, C] one-hot (C compares)
-        # cuts the VPU work ~3x at identical MXU MACs, and the sc blocks
-        # of the output are exactly the 4 staging chunks. Exact: each
-        # output (sc, lo) receives a single term < 256.
-        sc_of = dst // C                                  # 4 = invalid
-        lo_of = dst % C
-        Z = jnp.concatenate(
-            [jnp.where((sc_of == sc)[None, :], planes, 0)
-             for sc in range(4)], axis=0)                 # [4*4U, C]
-        iota_c2 = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-        oh_lo = (lo_of[:, None] == iota_c2).astype(jnp.int8)
-        moved = lax.dot_general(Z, oh_lo, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.int32)
+        posS = lax.broadcasted_iota(jnp.int32, (1, S), 1)[0]
 
-        posc = lax.broadcasted_iota(jnp.int32, (1, C), 1)[0]
-        lo_l = cur_l % (2 * C)
-        hi_l = lo_l + k_l
-        lo_r = cur_r % (2 * C)
-        hi_r = lo_r + k_v - k_l
-        for sc in range(4):
-            blk = moved[sc * 4 * U:(sc + 1) * 4 * U] & 255
-            mrows = (blk[:U] | (blk[U:2 * U] << 8)
-                     | (blk[2 * U:3 * U] << 16) | (blk[3 * U:] << 24))
-            if U < w_pad:
-                mrows = jnp.concatenate(
-                    [mrows, jnp.zeros((w_pad - U, C), jnp.int32)], axis=0)
-            if sc < 2:
-                pos = sc * C + posc
-                m = ((pos >= lo_l) & (pos < hi_l)) \
-                    | ((pos + 2 * C >= lo_l) & (pos + 2 * C < hi_l))
-            else:
-                pr = (sc - 2) * C + posc
-                m = ((pr >= lo_r) & (pr < hi_r)) \
-                    | ((pr + 2 * C >= lo_r) & (pr + 2 * C < hi_r))
-            stag[sc] = jnp.where(m[None, :], mrows, stag[sc])
+        def route_tile_rows(t, cur):
+            """Partition rows [t*S, (t+1)*S) of the chunk into the
+            block's staging rings; cur = (left, right) rows of the block
+            staged so far. The work is S x S whatever C is."""
+            cur_l, cur_r = cur
+            rec = rec_ref[0, :, pl.ds(pl.multiple_of(t * S, S), S)]
+            valid = t * S + posS < cntv
+            word = rec[0, :]
+            for wj in range(1, wcnt):
+                word = jnp.where(wsel == wj, rec[wj, :], word)
+            binv = (word >> ((r1 >> R_SHIFT) & 31)) & bmask
+            if bundled:
+                binv = _unpack_bundle(binv, r2)
+            catw = _cat_word(cbits_ref, hslot, binv)
+            left = _goes_left(binv, r1, r2, valid, catw)
 
-        new_l = cur_l + k_l
-        new_r = cur_r + k_v - k_l
+            # ranks via one triangular matmul (measured FASTER on the
+            # MXU than log2(C) pltpu.roll prefix sums: 3.33 vs 3.82
+            # ns/row)
+            li = left.astype(jnp.bfloat16)[None, :]
+            vi = valid.astype(jnp.bfloat16)[None, :]
+            both = jnp.concatenate([li, vi], axis=0)          # [2, S]
+            ranks = lax.dot_general(both, tri[...],
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            rank_l = ranks[0].astype(jnp.int32)
+            rank_r = ranks[1].astype(jnp.int32) - rank_l
+            k_l = jnp.sum(left.astype(jnp.int32))
+            k_r = jnp.sum(valid.astype(jnp.int32)) - k_l
+
+            # a side's rows of one tile land in ONE contiguous interval
+            # of its 2C ring, at most S long, so in at most two of the
+            # ring's S-aligned windows: the one the cursor is in (0) and
+            # the next (1). q = offset from the start of window 0.
+            a_l = cur_l % S
+            a_r = cur_r % S
+            q = jnp.where(left, a_l + rank_l, a_r + rank_r)   # < 2S
+            over = (q >= S).astype(jnp.int32)
+            lo_of = q - S * over
+            grp = jnp.where(valid, jnp.where(left, 0, 2) + over, 4)
+
+            # only the USED lanes ride the route matmul (w_used <=
+            # w_pad: 8-sublane padding and, under the compact layout,
+            # the unused tail lanes carry no data — pad lanes of the
+            # output stay stale, which is fine because no kernel reads
+            # past w_used).
+            # int8 byte planes: the MXU takes s8 x s8 -> s32 at twice
+            # the bf16 rate and the f32 -> i32 output converts
+            # disappear; byte values wrap to signed but `& 255` after
+            # the single-term selection recovers them exactly
+            planes = jnp.concatenate(
+                [((rec[:U] >> (8 * b)) & 255).astype(jnp.int8)
+                 for b in range(4)], axis=0)                  # [4U, S]
+            # FACTORED route: (side, window) x offset in the window. A
+            # flat one-hot over the four windows costs 4S int32 compares
+            # per row on the VPU (measured the dominant term of the
+            # split path); factoring into a per-window payload split (4
+            # compares + 4*4U products per row) times ONE [S, S] one-hot
+            # (S compares) cuts the VPU work ~3x at identical MXU MACs.
+            # Exact: each output (window, offset) receives a single
+            # term < 256.
+            Z = jnp.concatenate(
+                [jnp.where((grp == g)[None, :], planes, 0)
+                 for g in range(4)], axis=0)                  # [4*4U, S]
+            # the one-hot is built TRANSPOSED, [offset, row]: the row's
+            # offset broadcasts along sublanes as it lies, where
+            # [row, offset] needs a lane -> sublane relayout first
+            # (measured 0.6 us a chunk), and the MXU contracts either
+            iota_o = lax.broadcasted_iota(jnp.int32, (S, S), 0)
+            oh_lo = (lo_of[None, :] == iota_o).astype(jnp.int8)
+            moved = lax.dot_general(Z, oh_lo, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.int32)
+
+            for g in range(4):
+                side, w = divmod(g, 2)
+                blk = moved[g * 4 * U:(g + 1) * 4 * U] & 255
+                mrows = (blk[:U] | (blk[U:2 * U] << 8)
+                         | (blk[2 * U:3 * U] << 16) | (blk[3 * U:] << 24))
+                if U < w_pad:
+                    mrows = jnp.concatenate(
+                        [mrows, jnp.zeros((w_pad - U, S), jnp.int32)],
+                        axis=0)
+                a, k, cur_s = ((a_l, k_l, cur_l), (a_r, k_r, cur_r))[side]
+                if w == 0:
+                    m = (posS >= a) & (posS < a + k)
+                else:
+                    m = posS < a + k - S
+                # window -> staging chunk and lane offset (a dynamic
+                # multiple of S >= 128, which Mosaic can slice)
+                win = ((cur_s % (2 * C)) // S + w) % (2 * T)
+                dst = (side * 2 + win // T, slice(None),
+                       pl.ds(pl.multiple_of((win % T) * S, S), S))
+                stag[dst] = jnp.where(m[None, :], mrows, stag[dst])
+            return cur_l + k_l, cur_r + k_r
+
+        # tiles past the chunk's last row hold nothing to route
+        new_l, new_r = lax.fori_loop(0, (cntv + S - 1) // S,
+                                     route_tile_rows,
+                                     (cur_ref[0], cur_ref[1]))
         cur_ref[0] = jnp.where(is_last != 0, 0, new_l)
         cur_ref[1] = jnp.where(is_last != 0, 0, new_r)
+
+        fl_h0 = cur_ref[2 + hside]
 
         def flush_side(side, fl_slot, base, cur_val):
             for _ in range(2):    # at most 2 flushes per side per step
@@ -797,22 +851,33 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                                 sems.at[slot]).start()
                             cur_ref[4 + slot] = 1
                             cur_ref[16 + slot] = base + fl
-
-                            @pl.when(((hs & 0xFFFFFF) != dummy)
-                                     & (((hs >> 24) & 1) == side))
-                            def _():
-                                hist_flushed(
-                                    fbuf[slot],
-                                    jnp.minimum(cur_val - fl * C, C))
                     cur_ref[fl_slot] = fl + 1
 
         flush_side(0, 2, bl_i, new_l)
         flush_side(1, 3, br_i, new_r)
 
-        @pl.when((is_last != 0) & ((hs & 0xFFFFFF) != dummy))
+        # the smaller child's histogram, from the chunks its side flushed
+        # in this step (at most two, each still whole in its flush
+        # buffer). ONE call site for the four (side, buffer) cases: the
+        # accumulation unrolls over the features, and eight inlined
+        # copies of it made the kernel so large that EVERY split-path
+        # grid step paid for it, histogram or not (45 us a chunk at 67
+        # features against 6 at 8; PERF.md section 6, PR 27)
+        @pl.when(hslot != dummy)
+        def _():
+            cur_h = jnp.where(hside == 0, new_l, new_r)
+
+            def hist_one(fl, carry):
+                hist_flushed(fbuf[hside * 2 + fl % 2],
+                             jnp.minimum(cur_h - fl * C, C))
+                return carry
+
+            lax.fori_loop(fl_h0, cur_ref[2 + hside], hist_one, 0)
+
+        @pl.when((is_last != 0) & (hslot != dummy))
         def _():
             if not spill:
-                hist_ref[hs & 0xFFFFFF] += hacc[...]
+                hist_ref[hslot] += hacc[...]
             else:
                 # 2-deep spill ring: stage the finished block histogram
                 # and DMA it to its HBM slot WITHOUT waiting — the next
@@ -826,10 +891,10 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                         def _():
                             wait_spill(p)
                         hstage[p] = hacc[...]
-                        cur_ref[43 + p] = hs & 0xFFFFFF
+                        cur_ref[43 + p] = hslot
                         pltpu.make_async_copy(
                             hstage.at[p],
-                            hist_ref.at[hs & 0xFFFFFF],
+                            hist_ref.at[hslot],
                             sems.at[12 + p]).start()
                         cur_ref[41 + p] = 1
                 cur_ref[40] = cur_ref[40] + 1
@@ -938,6 +1003,7 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
             pltpu.VMEM((4, w_pad, chunk), jnp.int32),   # flush bufs
             pltpu.VMEM(hacc_shape, jnp.float32),
             pltpu.VMEM(hstage_shape, jnp.float32),      # spill ring
+            pltpu.VMEM((route_tile(chunk),) * 2, jnp.bfloat16),   # tri
             pltpu.SMEM((48,), jnp.int32),
             pltpu.SemaphoreType.DMA((14,)),
         ],

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
+from lightgbm_tpu.ops.aligned import ROUTE_TILE
 
 pytestmark = pytest.mark.slow
 
@@ -50,9 +51,13 @@ def _tree_tuples(bst):
     return out
 
 
-def test_aligned_matches_leafwise_binary():
+@pytest.mark.parametrize("chunk", [256, 2 * ROUTE_TILE])
+def test_aligned_matches_leafwise_binary(chunk):
+    """At 2 x ROUTE_TILE move_pass routes every chunk in two sub-tiles:
+    chunk map, flush, histogram and replay run on a multi-tile kernel."""
     X, y = _make()
-    a = _train(X, y, "aligned")
+    a = _train(X, y, "aligned", extra={"tpu_chunk": chunk})
+    assert a._gbdt._aligned_eng_ref.C == chunk
     b = _train(X, y, "leafwise")
     ta, tb = _tree_tuples(a), _tree_tuples(b)
     assert len(ta) == len(tb)

@@ -1,0 +1,234 @@
+"""`move_pass` against an independent partition (CPU: Pallas interpret mode).
+
+The split path routes a chunk in sub-tiles of `route_tile(C)` rows, so a
+chunk larger than `ROUTE_TILE` runs the tile loop more than once. Every
+training-level test pins `tpu_chunk = 256`, where the loop has one trip;
+here hand-built records and route words go through the kernel at
+C = 1, 2 and 4 tiles and are compared, bit for bit, with a numpy stable
+partition, and the fused histograms with a numpy histogram.
+"""
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from lightgbm_tpu.ops.aligned import (META_LABEL, META_LABEL_MASK, R_COPY,
+                                      R_SHIFT, ROUTE_TILE, _bpw_for_bits,
+                                      lane_layout, move_pass, pack_records,
+                                      pack_route2, route_tile)
+
+F = 6
+NC = 20          # one grid for every case of a shape: one compile
+K = 4            # histogram slots of the round (the dummy is K)
+LAYOUTS = {      # name -> (max_bin, compact)
+    "std8": (255, False),
+    "std6": (63, False),
+    "compact": (63, True),
+}
+
+
+def _point_grad(score, label, weight):
+    p = 1.0 / (1.0 + jnp.exp(-score))
+    return p - label, p * (1.0 - p)
+
+
+def _scenario(C, heavy):
+    """Blocks of the old layout, in chunk order. A split block is
+    (rows per chunk, share of rows going to the `heavy` side, which side
+    is histogrammed or None); "copy" blocks shift whole; "dead" chunks
+    take the split path with no row."""
+    def share(x):
+        return x if heavy == "left" else 1.0 - x
+    return [
+        # >= 3 chunks, 90% to one side: its ring wraps and flushes twice
+        ("split", [C, C, C, C // 2 + 7], share(0.9), 0),
+        ("copy", [C, C // 3]),
+        # sparse chunks inside one block, as the root round inherits
+        # them: tiles past a chunk's last row are skipped, and the
+        # cursors stop off every tile boundary, so the next tile's rows
+        # straddle two windows of both rings
+        ("split", [C - 5, 1, C // 4 + 3, C], share(0.35), 1),
+        ("dead", 1),
+        ("copy", [77]),
+        # a block smaller than one tile, nearly all to one side
+        ("split", [ROUTE_TILE // 2 + 1], share(0.01), None),
+        ("split", [C, 2 * C // 3], 0.5, 0),
+        ("dead", 2),
+    ]
+
+
+def _build(C, layout, heavy, seed):
+    max_bin, compact = LAYOUTS[layout]
+    rng = np.random.default_rng(seed)
+    bits = 8 if max_bin > 64 else 6
+    bpw = _bpw_for_bits(bits)
+    chunks, cnts = [], []          # old layout
+    blocks = []                    # (kind, chunk ids, route)
+    rid = 0
+    for bi, blk in enumerate(_scenario(C, heavy)):
+        kind = blk[0]
+        if kind == "dead":
+            ids = list(range(len(cnts), len(cnts) + blk[1]))
+            # rows of an earlier tree: nothing of them may leak
+            garbage = rng.integers(-2**31, 2**31, (blk[1], 1, C))
+            chunks.append(np.broadcast_to(garbage, (blk[1], W, C))
+                          .astype(np.int32))
+            cnts += [0] * blk[1]
+            blocks.append((kind, ids, None))
+            continue
+        feat = int(rng.integers(F))
+        thr = int(rng.integers(4, max_bin - 4))
+        ids = []
+        for n in blk[1]:
+            bins = rng.integers(0, max_bin, (n, F)).astype(np.uint8)
+            if kind == "split":
+                goes = rng.random(n) < blk[2]
+                bins[:, feat] = np.where(
+                    goes, rng.integers(0, thr + 1, n),
+                    rng.integers(thr + 1, max_bin, n))
+            label = rng.integers(0, 2, n).astype(np.float32)
+            rec, wcnt, W, c, pbits = pack_records(
+                bins, label, None, C, compact=compact, max_bin=max_bin,
+                rid_base=rid)
+            assert (rec.shape[0], pbits) == (1, bits)
+            rid += n
+            ids.append(len(cnts))
+            chunks.append(rec)
+            cnts.append(n)
+        route = (feat, thr, blk[3]) if kind == "split" else None
+        blocks.append((kind, ids, route))
+    rec = np.concatenate(chunks)
+    lanes, _ = lane_layout(wcnt, compact=compact)
+    w_used = max(lanes.values()) + 1
+    # every byte plane of every value lane carries random bits
+    live = np.arange(C)[None, :] < np.asarray(cnts)[:, None]
+    f32 = rng.standard_normal((len(cnts), C)).astype(np.float32)
+    rec[:, lanes["score"], :] = np.where(live, f32.view(np.int32), 0)
+    if not compact:
+        for name in ("grad", "hess"):
+            v = rng.standard_normal((len(cnts), C)).astype(np.float32)
+            rec[:, lanes[name], :] = np.where(live, v.view(np.int32), 0)
+    return dict(rec=rec, cnts=np.asarray(cnts), blocks=blocks, bits=bits,
+                bpw=bpw, wcnt=wcnt, W=W, w_used=w_used, lanes=lanes,
+                compact=compact, max_bin=max_bin)
+
+
+def _bin_of(rows, f, bits, bpw):
+    """[n] bins of feature f from [n, W] record rows."""
+    return (rows[:, f // bpw] >> ((f % bpw) * bits)) & ((1 << bits) - 1)
+
+
+def _reference(sc, C):
+    """The new layout, the route arrays that ask for it, and what the
+    kernel must give: {dest chunk: [cnt, w_used] rows} and hist."""
+    rec, cnts = sc["rec"], sc["cnts"]
+    nc_old = len(cnts)
+    bits, bpw, lanes = sc["bits"], sc["bpw"], sc["lanes"]
+    b_pad = 256 if sc["max_bin"] > 64 else 64
+    r1 = np.full(nc_old, 1 << R_COPY, np.int32)
+    wsel = np.zeros(nc_old, np.int32)
+    meta = cnts.astype(np.int32).copy()
+    basel = np.zeros(nc_old, np.int32)
+    baser = np.zeros(nc_old, np.int32)
+    hslots = np.full(nc_old, K, np.int32)
+    hist = np.zeros((K, F, b_pad, 3), np.float64)
+    expect = {}
+    sides = []                     # (rows, is_right, block index)
+    for bi, (kind, ids, route) in enumerate(sc["blocks"]):
+        if kind == "dead":
+            r1[ids] = 7            # copy bit clear: the split path
+            continue
+        meta[ids[0]] |= 1 << 20
+        meta[ids[-1]] |= 1 << 21
+        rows = np.concatenate(
+            [rec[c, :, :cnts[c]].T for c in ids])       # [n, W], in order
+        if kind == "copy":
+            sides.append((rows, False, bi))
+            continue
+        feat, thr, hside = route
+        r1[ids] = thr | ((feat % bpw) * bits) << R_SHIFT
+        wsel[ids] = feat // bpw
+        left = _bin_of(rows, feat, bits, bpw) <= thr
+        sides.append((rows[left], False, bi))           # stable
+        sides.append((rows[~left], True, bi))
+        if hside is not None:
+            slot = len(np.unique(hslots)) - 1    # one block a slot
+            hslots[ids] = slot | (hside << 24)
+            side = rows[~left] if hside else rows[left]
+            score = side[:, lanes["score"]].view(np.float32)
+            if sc["compact"]:
+                label = ((side[:, lanes["meta"]] >> META_LABEL)
+                         & META_LABEL_MASK).astype(np.float32)
+                p = 1.0 / (1.0 + np.exp(-score.astype(np.float64)))
+                g, h = p - label, p * (1.0 - p)
+            else:
+                g = side[:, lanes["grad"]].view(np.float32)
+                h = side[:, lanes["hess"]].view(np.float32)
+            for f in range(F):
+                b = _bin_of(side, f, bits, bpw)
+                np.add.at(hist[slot, f, :, 0], b, g)
+                np.add.at(hist[slot, f, :, 1], b, h)
+                np.add.at(hist[slot, f, :, 2], b, 1.0)
+    # left children and copied blocks keep the blocks' order, the right
+    # children follow them all (fresh slots), as the builder lays out
+    at = 0
+    for rows, is_right, bi in sorted(sides, key=lambda s: s[1]):
+        kind, ids, _ = sc["blocks"][bi]
+        if kind == "copy":
+            basel[ids] = at + np.arange(len(ids))
+        elif is_right:
+            baser[ids] = at
+        else:
+            basel[ids] = at
+        for j in range(0, len(rows), C):
+            expect[at] = rows[j:j + C, :sc["w_used"]]
+            at += 1
+    assert max(at, nc_old) <= NC
+    pad = NC - nc_old              # the grid's free tail: dead chunks
+    route = dict(r1=np.pad(r1, (0, pad), constant_values=7),
+                 r2=np.full(NC, pack_route2(0, sc["max_bin"]), np.int32),
+                 basel=np.pad(basel, (0, pad)),
+                 baser=np.pad(baser, (0, pad)),
+                 meta=np.pad(meta, (0, pad)), wsel=np.pad(wsel, (0, pad)),
+                 hslots=np.pad(hslots, (0, pad), constant_values=K))
+    rec_in = np.pad(rec, ((0, pad), (0, 0), (0, 0)), constant_values=-1)
+    return rec_in, route, expect, hist, b_pad
+
+
+# spill changes the histogram's flush and nothing of the tiles, and four
+# tiles under the interpreter compile longest: one layout each is enough
+CASES = [(C, layout, spill, heavy)
+         for C in (ROUTE_TILE, 2 * ROUTE_TILE, 4 * ROUTE_TILE)
+         for layout in LAYOUTS
+         for spill in (False, True)
+         for heavy in ("left", "right")
+         if layout == "std8" and (not spill or C != 2 * ROUTE_TILE)
+         or not spill and C < 4 * ROUTE_TILE]
+
+
+@pytest.mark.parametrize("C,layout,spill,heavy", CASES)
+def test_move_pass_matches_numpy_partition(C, layout, spill, heavy):
+    assert C // route_tile(C) == C // ROUTE_TILE
+    sc = _build(C, layout, heavy, seed=C + len(layout) + len(heavy))
+    rec_in, rt, expect, hist_ref, b_pad = _reference(sc, C)
+    out, hist = move_pass(
+        jnp.asarray(rec_in), *(jnp.asarray(rt[k]) for k in (
+            "r1", "r2", "basel", "baser", "meta", "wsel", "hslots")),
+        jnp.zeros((K + 1) * 8, jnp.int32), C, sc["W"], sc["wcnt"], K, F,
+        b_pad, 4 if b_pad > 64 else 8, bits=sc["bits"],
+        grad_fn=_point_grad if sc["compact"] else None,
+        w_used=sc["w_used"], interpret=True, subbin=True, spill=spill)
+    out = np.asarray(out)
+    assert len(expect) >= 14       # every block of the scenario landed
+    for chunk, rows in expect.items():
+        got = out[chunk, :sc["w_used"], :len(rows)].T
+        np.testing.assert_array_equal(got, rows, err_msg=f"chunk {chunk}")
+    np.testing.assert_allclose(np.asarray(hist), hist_ref, rtol=2e-5,
+                               atol=2e-4)
+    assert hist_ref[..., 2].sum() > C       # histograms were asked for
+
+
+def test_route_tile_divides_every_chunk():
+    """One tile wherever ROUTE_TILE does not divide the chunk: a pinned
+    `tpu_chunk` of any multiple of 128 still runs."""
+    for C in (128, 256, 384, ROUTE_TILE, 640, 3 * ROUTE_TILE, 2048, 4096):
+        assert route_tile(C) == (C if C % ROUTE_TILE else ROUTE_TILE)

@@ -1,0 +1,88 @@
+#!/usr/bin/env python
+"""Which ROUTE_TILE? One `move_pass` call with every chunk on the split
+path (the root round's shape), timed on the chip for each candidate tile.
+
+python tools/route_tile_sweep.py [shape ...] [tile ...]
+
+Shapes: criteo255 / criteo63 (the benchmark cells' records, W=24,
+C=2048, fused histogram of the smaller child) and higgs (compact W=16,
+C=1024, no histogram). A tile equal to the chunk is the untiled kernel.
+Prints one JSON line per (shape, tile): ms a call and us a live chunk.
+"""
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from lightgbm_tpu.obs import trace as obs_trace
+from lightgbm_tpu.ops import aligned
+
+NC = 4096          # 0.8 GB of records at W=24, C=2048
+LIVE = NC - 64
+SHAPES = {         # W, C, wcnt, w_used, features, b_pad, bits, spill, hist
+    "criteo255": (24, 2048, 17, 23, 67, 256, 8, True, True),
+    "criteo63": (24, 2048, 14, 20, 67, 64, 6, False, True),
+    "higgs": (16, 1024, 7, 9, 28, 256, 8, False, False),
+}
+K = 256
+
+
+def one(shape, tile, reps=3):
+    W, C, wcnt, w_used, F, b_pad, bits, spill, hist = SHAPES[shape]
+    aligned.ROUTE_TILE = tile
+    jax.clear_caches()
+    rec = jax.random.bits(jax.random.PRNGKey(tile), (NC, W, C),
+                          jnp.uint32).astype(jnp.int32)
+    iota = jnp.arange(NC, dtype=jnp.int32)
+    # one block over the first LIVE chunks, split at the middle bin of
+    # feature 0: half the rows go left, to chunk 0 on, half right, to
+    # the chunk past the middle on; the grid's tail is dead chunks, the
+    # room a split needs
+    thr = (1 << bits) // 2 - 1
+    meta = (jnp.where(iota < LIVE, C, 0) | ((iota == 0) << 20)
+            | ((iota == LIVE - 1) << 21))
+    zeros = jnp.zeros(NC, jnp.int32)
+    args = (jnp.full(NC, thr, jnp.int32),
+            jnp.full(NC, aligned.pack_route2(0, 1 << bits), jnp.int32),
+            zeros, zeros + NC // 2, meta.astype(jnp.int32), zeros,
+            zeros if hist else zeros + K, jnp.zeros((K + 1) * 8, jnp.int32))
+
+    def call():
+        obs_trace.force_fence(aligned.move_pass(
+            rec, *args, C, W, wcnt, K, F, b_pad, 4 if b_pad > 64 else 8,
+            bits=bits, w_used=w_used, subbin=True, spill=spill))
+
+    t0 = time.perf_counter()
+    call()
+    first = time.perf_counter() - t0
+    walls = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        call()
+        walls.append(time.perf_counter() - t0)
+    best = min(walls)
+    print(json.dumps({
+        "shape": shape, "C": C, "tile": aligned.route_tile(C),
+        "hist": hist,
+        "ms_per_call": round(best * 1e3, 2),
+        "us_per_chunk": round(best * 1e6 / LIVE, 2),
+        "first_call_s": round(first, 1),
+        "device": jax.devices()[0].device_kind}), flush=True)
+
+
+if __name__ == "__main__":
+    shapes = [a for a in sys.argv[1:] if a in SHAPES] or list(SHAPES)
+    tiles = [int(a) for a in sys.argv[1:] if a.isdigit()] \
+        or [256, 512, 1024, 2048]
+    if jax.default_backend() != "tpu":
+        sys.exit("route_tile_sweep: no TPU; a CPU time is not a "
+                 "device metric")
+    for shape in shapes:
+        for tile in tiles:
+            if tile <= SHAPES[shape][1]:
+                one(shape, tile)
