@@ -81,11 +81,16 @@ class Generator:
                 m += np.float32(w) * (z[ja] > qa)
             else:
                 m += np.float32(w) * np.sign((z[ja] - qa) * (z[jb] - qb))
-        u = self._rng(block, self.features).random(b, dtype=np.float32)
-        y = (u * (1.0 + np.exp(-m)) < 1.0).astype(np.float32)
+        y = self._label(m, self._rng(block, self.features).random(
+            b, dtype=np.float32))
         for j in range(self.features):
             self._value(j, z[j])
         return z, y
+
+    def _label(self, m: np.ndarray, u: np.ndarray) -> np.ndarray:
+        """0/1 with probability sigmoid(margin), from one uniform draw a
+        row (a generator of another label overrides this alone)."""
+        return (u * (1.0 + np.exp(-m)) < 1.0).astype(np.float32)
 
     def block(self, block: int, z=None, x=None):
         """(X [block_rows, F] float32 row-major, y [block_rows] float32).

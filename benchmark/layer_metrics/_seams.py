@@ -97,18 +97,26 @@ def per_iter(ctx, name: str):
     return sum(column(win["iters"], name)) / ctx["iterations"]
 
 
-def driver_host_ms_per_iter(ctx):
-    """The window on the host's clock less the time the loop is blocked
-    on the device (`WAITS`, a pull inside a drain counted once), per
-    iteration: what the driver itself costs, whatever the kernels do."""
+def blocked_s(ctx):
+    """(seconds of the window, seconds of them in which the loop is
+    blocked on the device: `WAITS`, a pull inside a drain counted once),
+    or None where the ring does not hold the window."""
     recs = ring()
     win = window(recs, ctx["iterations"])
     if win is None:
         return None
     inside = [r for r in named(recs, *WAITS)
               if r["t0"] >= win["t0"] and r["t1"] <= win["t1"]]
-    own = win["t1"] - win["t0"] - seconds(outermost(inside))
-    return 1e3 * own / ctx["iterations"]
+    return win["t1"] - win["t0"], seconds(outermost(inside))
+
+
+def driver_host_ms_per_iter(ctx):
+    """The window on the host's clock less the blocked part, per
+    iteration: what the driver itself costs, whatever the kernels do."""
+    found = blocked_s(ctx)
+    if found is None:
+        return None
+    return 1e3 * (found[0] - found[1]) / ctx["iterations"]
 
 
 def move_events(ctx) -> list:

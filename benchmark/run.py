@@ -15,8 +15,13 @@ only then. With `--trace 1` the window is a few iterations under the
 profiler and the line carries the per-layer metrics, each read by
 `benchmark/layer_metrics/<name>.py`.
 
-A new configuration, traffic mix, generator or per-layer metric is a new
-file and a new entry in `BENCHMARK.json`; nothing here names one.
+What belongs to the kind of learning problem (whether rows come in query
+groups, what quality means, how tree 0 is held to the first gradients) is
+the configuration's task, `benchmark/tasks/<task>.py`. This file keeps the
+loop, the window, the clocks and the checks that no objective changes.
+
+A new configuration, traffic mix, generator, task or per-layer metric is a
+new file and a new entry in `BENCHMARK.json`; nothing here names one.
 
 There is no CPU mode: without a TPU, or with fewer chips than the cell
 asks for, the process exits non-zero and prints no result. The toy-size
@@ -38,6 +43,7 @@ import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
+import types  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -50,10 +56,6 @@ from benchmark import reference, sizing, xplane  # noqa: E402
 
 SPAN = "bench."             # prefix of the harness's own trace annotations
 WALK_ROWS = 4096
-# the documented arithmetic of the program, not slack: row counts are f32
-# and fuzz above 2^24 rows (exact below), and histogram sums are f32
-ROOT_COUNT_TOL = 4e-6
-ROOT_GAIN_RTOL = 1e-3
 
 
 def say(msg: str) -> None:
@@ -141,7 +143,8 @@ class HostMemory(threading.Thread):
         return self.lowest_gib
 
 
-def ingest(gen, cfg, rows: int, first_row: int = 0, reference_ds=None):
+def ingest(gen, cfg, rows: int, first_row: int = 0, reference_ds=None,
+           grouped: bool = False):
     """Rows [first_row, first_row + rows) through the program's push-rows
     ingest (`create_from_sample` / `push_rows` / `finish_load`, the
     reference's `LGBM_DatasetCreateFromSampledColumn` + `PushRows` flow):
@@ -149,8 +152,12 @@ def ingest(gen, cfg, rows: int, first_row: int = 0, reference_ds=None):
     48M x 67, beside the float32 one. Blocks are made on threads into
     recycled buffers while this thread bins them, in row order.
 
-    Returns (core dataset, labels float32, walls). `program_s` is the time
-    inside the program's three calls; the rest is waiting for rows."""
+    Where the task's rows are `grouped`, the generator's query sizes for
+    these rows go to `finish_load`.
+
+    Returns (core dataset, labels float32, query sizes or None, walls).
+    `program_s` is the time inside the program's three calls; the rest is
+    waiting for rows."""
     from lightgbm_tpu.io.dataset import Dataset as CoreDataset
     b, f = gen.block_rows, gen.features
     walls = {"program_s": 0.0, "wait_rows_s": 0.0}
@@ -195,9 +202,27 @@ def ingest(gen, cfg, rows: int, first_row: int = 0, reference_ds=None):
             labels[at:at + len(y)] = y
             at += len(y)
             free.append(bufs)
-    timed("program_s", core.finish_load)
+    groups = gen.groups(first_row, rows) if grouped else None
+    timed("program_s", core.finish_load, group=groups)
     walls["phase_s"] = time.perf_counter() - t_phase
-    return core, labels, walls
+    return core, labels, groups, walls
+
+
+def walked(gen, model, first_row: int, rows: int):
+    """(the numpy walk's raw scores, labels) of rows [first_row, first_row
+    + rows), made again from the seed: block by block of the generator, on
+    a few threads (one block at a time, 4.8M rows took longer than the
+    window)."""
+    b, end = gen.block_rows, first_row + rows
+    cuts = [first_row, *range((first_row // b + 1) * b, end, b), end]
+
+    def part(lo, hi):
+        x, y = gen.rows(lo, hi)
+        return reference.raw_scores(model, x), y
+    with concurrent.futures.ThreadPoolExecutor(4) as pool:
+        parts = list(pool.map(part, cuts, cuts[1:]))
+    return (np.concatenate([p[0] for p in parts]),
+            np.concatenate([p[1] for p in parts]))
 
 
 def wrap(core, params):
@@ -254,6 +279,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     rows, holdout = int(config["rows"]), int(config["holdout_rows"])
     valid_rows = int(traffic["valid_rows"])
     on_chip = jax.default_backend() == "tpu"
+    task = importlib.import_module(
+        "benchmark.tasks." + config.get("task", "binary"))
 
     compile_cache.init_persistent_cache()
     events = Events()
@@ -266,16 +293,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     # ---- set-up: rows, binning, first iteration, warm-up
     cfg = Config.from_params(params)
-    core, labels, w = ingest(gen, cfg, rows)
+    core, labels, groups, w = ingest(gen, cfg, rows, grouped=task.GROUPED)
     walls.update(ingest_bin_s=w["program_s"], ingest_wait_rows_s=w["wait_rows_s"],
                  ingest_phase_s=w["phase_s"])
     train_set = wrap(core, params)
     bst = lgb.Booster(params=params, train_set=train_set)
     if valid_rows:
-        vcore, _, _ = ingest(gen, cfg, valid_rows, first_row=rows + holdout,
-                             reference_ds=core)
+        vcore, _, _, _ = ingest(gen, cfg, valid_rows, first_row=rows + holdout,
+                                reference_ds=core, grouped=task.GROUPED)
         bst.add_valid(wrap(vcore, params), "valid")
     gc.collect()    # the generator's buffers, before the engine packs
+    evals = []      # what the program said of the validation set, last
 
     def step(n):
         """n iterations, then the drain; host wall at each return."""
@@ -284,7 +312,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             with jax.profiler.TraceAnnotation(SPAN + "update"):
                 bst.update()
                 if valid_rows:
-                    bst.eval_valid()
+                    evals[:] = bst.eval_valid()
             marks.append(time.perf_counter())
         with jax.profiler.TraceAnnotation(SPAN + "drain"):
             bst.eval_train()
@@ -347,23 +375,36 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     }
 
     x_hold, y_hold = gen.rows(rows, rows + holdout)
-    auc_trees = int(traffic["auc_trees"])
+    quality_trees = int(traffic["auc_trees"])   # the key's name is PR 24's
     t = time.perf_counter()
-    auc = reference.auc(bst.predict(x_hold, num_iteration=auc_trees), y_hold)
+    quality = task.quality(
+        bst.predict(x_hold, num_iteration=quality_trees), y_hold,
+        gen.groups(rows, holdout) if task.GROUPED else None)
     walls["predict_holdout_s"] = time.perf_counter() - t
     model = bst.dump_model()
     walk = reference.raw_scores(model, x_hold[:WALK_ROWS])
     checks["predict_equals_walk"] = bool(np.allclose(
         bst.predict(x_hold[:WALK_ROWS], raw_score=True), walk,
         rtol=1e-5, atol=1e-6))
-    root = reference.root_check(
-        model, gen.column(model["tree_info"][0]["tree_structure"]
-                          ["split_feature"], 0, rows), labels,
-        lambda_l2=float(params.get("lambda_l2", 0.0)))
-    checks["root_split"] = (
-        root["left_count_err"] <= (ROOT_COUNT_TOL if rows > 1 << 24 else 0.0)
-        and root["gain_rel_err"] <= ROOT_GAIN_RTOL)
-    checks["auc_floor"] = auc >= float(config["auc_floor"])
+    # each number compared, beside its limit: (number, limit, "<=" or ">=")
+    quality_name = f"holdout_{task.QUALITY}_{quality_trees}"
+    compared = {quality_name: (quality, float(config.get(
+        "quality_floor", config.get("auc_floor"))), ">=")}
+    first, first_detail = task.first_tree(types.SimpleNamespace(
+        model=model, gen=gen, rows=rows, labels=labels, groups=groups,
+        params=params, booster=bst))
+    compared.update({k: (v, lim, "<=") for k, (v, lim) in first.items()})
+    if valid_rows:
+        # the program's last word on the validation set against the task's
+        # quality of the numpy walk over the same rows, made again here
+        want = task.quality(
+            *walked(gen, model, rows + holdout, valid_rows),
+            gen.groups(rows + holdout, valid_rows) if task.GROUPED else None)
+        said = evals[0][2] if evals else float("nan")
+        compared["valid_metric_err"] = (
+            abs(said - want), float(traffic["valid_metric_tol"]), "<=")
+    checks.update({k: (v <= lim if op == "<=" else v >= lim)
+                   for k, (v, lim, op) in compared.items()})
     walls["checks_s"] = time.perf_counter() - t_after
 
     # ---- the line
@@ -383,7 +424,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         wanted = metrics["end_to_end"]
         values = {"setup_s": setup_s,
                   "train_ms_per_iter": 1e3 * window_s / n_window,
-                  "holdout_auc_" + str(auc_trees): auc}
+                  quality_name: quality}
     checks = {k: bool(v) for k, v in checks.items()}
     result = {
         "correct": all(checks.values()),
@@ -396,20 +437,31 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     }
     if trace:
         result["breakdown"] = ctx["trace"]["breakdown"]
+    # last on the line: every number compared, beside its limit; the yes
+    # or no checks as 1 or 0 against 1
+    result["compared"] = dict(
+        {k: {"value": float(v), "limit": float(lim), "holds": op}
+         for k, (v, lim, op) in compared.items()},
+        **{k: {"value": int(v), "limit": 1, "holds": ">="}
+           for k, v in checks.items() if k not in compared})
     result["detail"] = {
         "checks": checks, "walls": walls, "in_window": in_window,
         "compiles_in_setup": before, "compiles": compile_counts(),
         "missed": [r.get("module") for r in events.of("compile_cache_miss")],
         "window_s": window_s,
-        "holdout_auc": auc, "root": root,
-        "sizing": size, "sizing_persistent_gib": size["persistent_bytes"]
-        / sizing.GIB, "measured_peak_gib": device["memory_peak_bytes"]
-        / sizing.GIB,
+        quality_name: quality, "first_tree": first_detail,
+        "valid_said": evals, "sizing": size,
+        "sizing_persistent_gib": size["persistent_bytes"] / sizing.GIB,
+        "measured_peak_gib": device["memory_peak_bytes"] / sizing.GIB,
+        "peak_over_sizing_gib": sizing.held_over_gib(
+            device["memory_peak_bytes"], size),
         "engine": None if eng is None else {
             "chunk": int(eng.C), "lanes": int(eng.W), "chunks": int(eng.NC),
             "bits": int(eng.bits), "compact": bool(eng.compact),
-            "hist_spill": bool(eng.hist_spill),
-            "hist_subbin": bool(eng.hist_subbin)},
+            # set as the build program is traced: absent from a second
+            # engine of one process that found the program made
+            "hist_spill": getattr(eng, "hist_spill", None),
+            "hist_subbin": getattr(eng, "hist_subbin", None)},
         "leaves": [int(t.num_leaves) for t in trees],
         "host_peak_rss_gib": resource.getrusage(
             resource.RUSAGE_SELF).ru_maxrss * 1024 / sizing.GIB,
@@ -440,6 +492,10 @@ def main(argv=None) -> int:
     detail = result.pop("detail")
     say("detail: " + json.dumps(detail, default=lambda o: o.item()))
     say(json.dumps(result))
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']!r} {c['holds']} {c['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

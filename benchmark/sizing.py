@@ -6,7 +6,8 @@ sizes what it keeps on the device (`ops/aligned.py`: `chunk_for`,
 `level_builder.spec_slots`; `AlignedEngine.__init__`'s `NC`), copied and
 not imported: it is how the rows of a configuration were chosen against
 the driver's floor of a quarter of the chip's memory, and each run prints
-it beside the measured peak. It gates nothing, so a later change that
+it beside the measured peak, with what the peak holds over it
+(`held_over_gib`). It gates nothing, so a later change that
 shrinks the records is not stopped by the yardstick; it only makes the
 printed figure stale, which the measured one beside it shows.
 """
@@ -53,3 +54,11 @@ def persistent_bytes(rows: int, features: int, max_bin: int,
             "slot_bytes": slot, "spill": spill,
             "spill_store_bytes": store if spill else 0,
             "persistent_bytes": records + (store if spill else 0)}
+
+
+def held_over_gib(peak_bytes: int, size: dict) -> float:
+    """What the measured peak holds that the arithmetic does not count, in
+    GiB: scores, gradients and tables in row order, the programs' own
+    temporaries. Measured at 1.0 GiB on the standard layout's cells
+    (PERF.md section 4); the EXT layout's adds the rank kernel's tables."""
+    return (peak_bytes - size["persistent_bytes"]) / GIB
