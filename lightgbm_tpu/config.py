@@ -255,7 +255,21 @@ class Config:
     xgboost_dart_mode: bool = False
     uniform_drop: bool = False
     drop_seed: int = 4
+    # boosting=goss (goss.hpp:96-160): after the first 1 / learning_rate
+    # iterations every row whose |gradient x hessian| is at least the
+    # int(n x top_rate)-th largest trains at weight 1 (ties at the
+    # threshold are all kept). On the aligned engine the selection is a
+    # device program over the records (two counting selects, no sort,
+    # nothing pulled: models/aligned_builder.py goss_select); the fused
+    # leaf-wise path and the sweep trainer run the same ops/goss.py in
+    # row order. Multiclass and tree_learner=data keep off the aligned
+    # engine under goss (the train_path event's `rejected` says so)
     top_rate: float = 0.2
+    # boosting=goss: of the rows under the top_rate threshold, the
+    # int(n x other_rate) with the smallest key (an integer function of
+    # the row id and the iteration's seed, drawn from bagging_seed's
+    # stream) train at weight (n - top_k) / other_k; every other row is
+    # left out of the tree's histograms and counts, and still scored
     other_rate: float = 0.1
     min_data_per_group: int = 100
     max_cat_threshold: int = 32

@@ -152,12 +152,21 @@ class AlignedEngine:
 
     def __init__(self, learner, objective, interpret: bool = False,
                  init_row_scores=None, bagged: bool = False,
-                 num_class: int = 1):
+                 num_class: int = 1, bag_multiplier: bool = False):
         self.learner = learner
         self.objective = objective
         self.cfg = learner.cfg
         self.interpret = interpret
         self.bagged = bagged
+        # the bag lane holds a per-row f32 MULTIPLIER (0 = out of the
+        # sample, else the weight of the row's gradients) that a device
+        # program writes (`goss_select`), not a host-drawn 0/1 mask. The
+        # kernels need nothing new: gradients are multiplied by the lane
+        # where they are written, and a row is in the bag, and counted
+        # once, where its lane is over 0.5 (`_payload_gh`)
+        self.bag_multiplier = bag_multiplier
+        self.bag_sampled = False     # a selection has written the lane
+        assert bagged or not bag_multiplier
         self.num_class = num_class
         # the chunk is the unit of the grid, the DMA, the flush and the
         # route words (destinations pack 16-bit, capping NC at 65k
@@ -295,6 +304,8 @@ class AlignedEngine:
                 objective.point_grad_fn() is not None
                 and weight is None and lab01
                 and learner.n <= (1 << 24)   # rid must fit 24 meta bits
+                # the compact record's bag is one BIT of the meta lane
+                and not self.bag_multiplier
                 # tpu_force_big_n exercises the big-n physical layout
                 # (exact i32 count pass + route-word repack) at small n,
                 # which the compact layout would otherwise shadow
@@ -1539,6 +1550,62 @@ class AlignedEngine:
                            specs=self._specs("setbag")
                            if self.axis else None)
         self.rec = fn(self.rec, jnp.asarray(mask_rows, jnp.float32))
+        self.bag_sampled = False
+
+    def goss_select(self, seed: int, top_k: int, other_k: int,
+                    multiply: float, grads=None,
+                    boost_iter: Optional[int] = None):
+        """Queue one iteration's GOSS selection (`ops/goss.py`) over the
+        records as they lie: a = |g x h| from the score and label lanes
+        (or `grads` = row-order (g, h) gathered by row id, for an
+        objective that is not pointwise), before any multiplier; the
+        per-row multiplier goes into the bag lane, which the build
+        program queued next reads. Nothing is pulled and nothing is
+        uploaded but the seed. Returns the device counters (kept_top,
+        kept_other, threshold)."""
+        assert self.bag_multiplier and self.axis is None
+        from ..ops.goss import PASSES
+        with obs_trace.seam("goss.select", iter=boost_iter, seed=int(seed),
+                            top_k=top_k, other_k=other_k,
+                            multiplier=multiply, passes=2 * PASSES):
+            fn = self._program(
+                ("goss_select", top_k, other_k, grads is not None),
+                lambda: self._goss_select_program(top_k, other_k, multiply,
+                                                  grads is not None),
+                donate=(0,))
+            self.rec, stats = fn(self.rec, self.cnts, jnp.uint32(seed),
+                                 *(grads or ()))
+        self.bag_sampled = True
+        return stats
+
+    def _goss_select_program(self, top_k, other_k, multiply, external):
+        from ..ops.goss import goss_multipliers
+        ln = self.lanes
+        n, C = self.n, self.C
+
+        def goss_select(rec, cnts, seed, g_rows=None, h_rows=None):
+            rid = rec[:, ln["rid"], :]
+            live = (jnp.arange(C, dtype=jnp.int32)[None, :]
+                    < cnts[:, None]) & (rid < n)
+            if external:
+                at = jnp.clip(rid, 0, n - 1)
+                g, h = g_rows[at], h_rows[at]
+            else:
+                g, h = self._pgrad(
+                    _f32(rec[:, ln["score"], :]),
+                    _f32(rec[:, ln["label"], :]),
+                    _f32(rec[:, ln["weight"], :])
+                    if self.objective.weight is not None else None)
+            mult, stats = goss_multipliers(jnp.abs(g * h), rid, live, seed,
+                                           top_k, other_k, multiply)
+            return rec.at[:, ln["bag"], :].set(_i32(mult)), stats
+        return goss_select
+
+    def row_bag(self) -> np.ndarray:
+        """The bag lane in ROW order (a check's accessor; pulls N)."""
+        fn = self._program(("mat", "bag"),
+                           lambda: self._materialize_program("bag"))
+        return np.asarray(fn(self.rec, self.cnts))
 
     def _set_bag_program(self):
         ln = self.lanes
@@ -1594,14 +1661,14 @@ class AlignedEngine:
         self._score_cache = out
         return out
 
-    def _materialize_program(self):
+    def _materialize_program(self, lane: str = "score"):
         ln = self.lanes
         n, C, NC = self.n, self.C, self.NC
         ax = self.axis
 
         def fn(rec, cnts):
             rid = self._rid_lanes(rec).reshape(-1)
-            sc = _f32(rec[:, ln["score"], :]).reshape(-1)
+            sc = _f32(rec[:, ln[lane], :]).reshape(-1)
             pos = jnp.arange(C, dtype=jnp.int32)
             valid = (pos[None, :] < cnts[:, None]).reshape(-1)
             rid = jnp.where(valid & (rid < n), rid, n)

@@ -14,39 +14,46 @@ import numpy as np
 
 from ..config import Config
 from ..io.dataset import Dataset
+from ..ops.goss import goss_multipliers
 from .gbdt import GBDT, K_EPSILON, _ScoreUpdater
 from .tree import Tree
 
 
+def goss_sizes(cfg: Config, n: int) -> Tuple[int, int, float]:
+    """(top_k, other_k, the sampled rest's multiplier) over `n` rows:
+    exact counts, not the reference's per-thread-block shares."""
+    top_k = max(1, int(n * cfg.top_rate))
+    other_k = max(1, int(n * cfg.other_rate))
+    return top_k, other_k, (n - top_k) / other_k
+
+
 def goss_select_body(g, h, seed, n: int, top_k: int, other_k: int):
-    """The raw device GOSS selection (goss.hpp:96-134) — single source
-    of truth for the sequential per-model program AND the sweep
-    trainer's vmapped fleet select (sweep/batched.py), so their bitwise
-    parity is by construction. |g*h| summed over classes, threshold at
-    the top_k'th value, the rest sampled without replacement as the
-    other_k smallest uniform keys under ``PRNGKey(seed)`` (row-index
-    tie-broken via a stable argsort rank — f32 keys collide ~every
-    other iteration at 10M rows). Returns the [N] keep-mask and the
-    [N] small-gradient re-weight multiplier."""
-    multiply = (n - top_k) / other_k
+    """The device GOSS selection (goss.hpp:96-134) in ROW order — the
+    sequential per-model program AND the sweep trainer's vmapped fleet
+    select (sweep/batched.py) both call it, so their bitwise parity is
+    by construction; the aligned engine runs the same
+    `ops.goss.goss_multipliers` over its permuted records. |g*h| summed
+    over classes, every row at or above the top_k'th value kept, of the
+    rest the other_k smallest integer keys of (row id, seed). Returns
+    the [N] keep-mask and the [N] multiplier (0 = left out)."""
     a = jnp.abs(g * h).sum(axis=0)
-    s = jnp.sort(a)
-    threshold = s[n - top_k]
-    big = a >= threshold
-    u = jax.random.uniform(jax.random.PRNGKey(seed), (n,))
-    order = jnp.argsort(jnp.where(big, 2.0, u), stable=True)
-    rank = jnp.zeros(n, jnp.int32).at[order].set(
-        jnp.arange(n, dtype=jnp.int32))
-    sampled = (~big) & (rank < other_k)
-    mask = big | sampled
-    mult = jnp.where(sampled, jnp.float32(multiply), 1.0)
-    return mask, mult
+    mult, _ = goss_multipliers(
+        a, jnp.arange(n, dtype=jnp.int32), jnp.ones(n, bool), seed,
+        top_k, other_k, (n - top_k) / other_k)
+    return mult > 0, mult
 
 
 class GOSS(GBDT):
     """Gradient-based one-side sampling (goss.hpp:25-160): keep the
     top_rate fraction by |g*h|, sample other_rate of the rest and up-weight
-    their gradients by (1-top_rate)/other_rate."""
+    their gradients by (1-top_rate)/other_rate.
+
+    On the aligned engine the sample never exists on the host: each
+    iteration's seed is drawn here, the engine's `goss_select` program
+    writes the multipliers into the bag lane ahead of the build, and a
+    fallback makes the same sample again from (scores, seed)."""
+
+    _bag_on_device = True
 
     def __init__(self, cfg: Config, train_data: Dataset, objective=None):
         super().__init__(cfg, train_data, objective)
@@ -55,45 +62,113 @@ class GOSS(GBDT):
         if cfg.top_rate <= 0.0 or cfg.other_rate <= 0.0:
             raise ValueError("top_rate and other_rate must be positive")
         self._goss_multiplier = None     # device [N] or None
+        self._goss_mask = None           # device [N] keep-mask, not pulled
         self._goss_select_fn = None
 
+    # the sample's row indices are pulled from the device mask only when
+    # a host partition asks for them (the fused path's root partition, an
+    # aligned fallback, a checkpoint): never on the aligned hot path
+    @property
+    def bag_data_indices(self):
+        if self._bag_idx is None and self._goss_mask is not None:
+            self._bag_idx = np.nonzero(np.asarray(self._goss_mask))[0] \
+                .astype(np.int32)
+            self._bag_cnt = len(self._bag_idx)
+        return self._bag_idx
+
+    @bag_data_indices.setter
+    def bag_data_indices(self, value):
+        self._bag_idx = value
+        self._goss_mask = None
+
+    @property
+    def bag_data_cnt(self):
+        if self._goss_mask is not None:
+            self.bag_data_indices       # pulls, and counts
+        return self._bag_cnt
+
+    @bag_data_cnt.setter
+    def bag_data_cnt(self, value):
+        self._bag_cnt = value
+
+    def _will_bag(self) -> bool:
+        return True
+
+    def _goss_seed(self, iter_idx: int) -> Optional[int]:
+        """This iteration's sampling seed, or None inside the first
+        1/learning_rate iterations (goss.hpp:141-160). Drawn from the
+        bagging RNG stream so runs stay reproducible under bagging_seed
+        and a checkpoint carries the stream's state."""
+        if iter_idx < int(1.0 / self.cfg.learning_rate):
+            return None
+        return int(self._bag_rng.randint(0, 2**31 - 1))
+
     def _bagging(self, iter_idx: int) -> None:
-        """goss.hpp:141-160: no subsampling during the first
-        1/learning_rate iterations. The selection runs ON DEVICE
-        (|g*h| ranking, threshold, uniform-key sampling of the rest) —
-        only the final [N] keep-mask is pulled for the host-side
-        partition indices, not the 2xN float gradient arrays."""
-        cfg = self.cfg
+        self._goss_sample(self._goss_seed(iter_idx))
+
+    def _goss_sample(self, seed: Optional[int]) -> None:
+        """Select in row order from `_cur_grad` / `_cur_hess` ON DEVICE;
+        the mask stays there until a host partition asks for indices."""
         self._goss_multiplier = None
-        if iter_idx < int(1.0 / cfg.learning_rate):
-            self.bag_data_indices = None
-            self.bag_data_cnt = self.num_data
+        self.bag_data_indices = None
+        self.bag_data_cnt = self.num_data
+        if seed is None:
             return
         n = self.num_data
-        top_k = max(1, int(n * cfg.top_rate))
-        other_k = max(1, int(n * cfg.other_rate))
-        # per-iteration device key drawn from the bagging RNG stream so
-        # runs stay reproducible under bagging_seed
-        seed = int(self._bag_rng.randint(0, 2**31 - 1))
         fn = self._goss_select_fn
         if fn is None:
+            top_k, other_k, _ = goss_sizes(self.cfg, n)
+
             def select(g, h, seed_arr):
                 return goss_select_body(g, h, seed_arr[0], n, top_k,
                                         other_k)
             fn = jax.jit(select)
             self._goss_select_fn = fn
-        mask_dev, mult_dev = fn(self._cur_grad, self._cur_hess,
-                                jnp.asarray([seed], jnp.uint32))
-        sel = np.nonzero(np.asarray(mask_dev))[0]
-        self.bag_data_indices = sel.astype(np.int32)
-        self.bag_data_cnt = len(sel)
-        self._goss_multiplier = mult_dev
+        self._goss_mask, self._goss_multiplier = fn(
+            self._cur_grad, self._cur_hess, jnp.asarray([seed], jnp.uint32))
 
     def _post_bagging_gradients(self, gdev, hdev):
         if self._goss_multiplier is None:
             return gdev, hdev
         m = jnp.asarray(self._goss_multiplier)[None, :]
         return gdev * m, hdev * m
+
+    # ---- the aligned engine's side (gbdt._train_one_iter_aligned)
+    def _aligned_variant_gate(self) -> Optional[str]:
+        if self.num_tree_per_iteration > 1:
+            return ("boosting=goss with multiclass: the compact record's "
+                    "bag bit holds no multiplier")
+        if getattr(self.learner, "mode", "") == "data":
+            return ("boosting=goss under tree_learner=data: the device "
+                    "selects do not sum their counts over the mesh")
+        return None
+
+    def _maybe_rebag(self, eng) -> None:
+        self._aligned_sample = self._goss_seed(self.iter)
+
+    def _aligned_apply_sample(self, eng, seed, grads):
+        """Queue the selection of the iteration about to be built, ahead
+        of its build program; returns its device counters. An unsampled
+        iteration after a sampled dispatch (a replay across the warm-up's
+        end, cold) puts the lane back to ones."""
+        if seed is None:
+            if eng.bag_sampled:
+                eng.set_bag(np.ones(self.num_data, np.float32))
+            return None
+        return eng.goss_select(seed, *goss_sizes(self.cfg, self.num_data),
+                               grads=grads, boost_iter=self.iter)
+
+    def _aligned_fallback_sample(self, seed, bag_idx, bag_cnt, gdev, hdev):
+        """The sample the failed device build trained on, made again in
+        row order from the synced scores and the same seed. No mask is
+        left behind: the aligned loop stashes `bag_data_indices` with
+        every round, and must find nothing there to pull."""
+        self._cur_grad, self._cur_hess = gdev, hdev
+        self._goss_sample(seed)
+        out = (self.bag_data_indices, self.bag_data_cnt,
+               *self._post_bagging_gradients(gdev, hdev))
+        self._goss_sample(None)
+        return out
 
 
 class DART(GBDT):
@@ -117,6 +192,10 @@ class DART(GBDT):
         self._drop_rng = np.random.RandomState(cfg.drop_seed)
         self._dropped_this_iter = False
         self.num_init_iteration = 0
+
+    def _aligned_variant_gate(self) -> Optional[str]:
+        return ("boosting=dart: the engine's score lane cannot follow "
+                "dropped trees")
 
     def get_training_score(self) -> jax.Array:
         if not self._dropped_this_iter:
@@ -296,8 +375,13 @@ class RF(GBDT):
                 self._label_np, self._weight_np)
         return new_tree
 
+    def _aligned_variant_gate(self) -> Optional[str]:
+        return ("boosting=rf: one-time gradients and a running-average "
+                "score, its own iteration")
+
     def train_one_iter(self, grad=None, hess=None) -> bool:
         """rf.hpp:103-166."""
+        self._log_train_path("fused" if self.use_fused else "per-tree")
         self._bagging(self.iter)
         gdev, hdev = self._rf_grad, self._rf_hess
         for k in range(self.num_tree_per_iteration):

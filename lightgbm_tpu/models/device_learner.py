@@ -1335,18 +1335,19 @@ class DeviceTreeLearner:
         return notes
 
     def aligned_engine(self, objective, init_row_scores=None,
-                       bagged=False, num_class=1):
+                       bagged=False, num_class=1, bag_multiplier=False):
         """The persistent AlignedEngine for (this learner, objective)."""
         eng = getattr(self, "_aligned_eng", None)
         if eng is None or eng.objective is not objective \
                 or getattr(eng, "bagged", False) != bagged \
-                or getattr(eng, "num_class", 1) != num_class:
+                or getattr(eng, "num_class", 1) != num_class \
+                or eng.bag_multiplier != bag_multiplier:
             from .aligned_builder import AlignedEngine
             eng = AlignedEngine(
                 self, objective,
                 interpret=bool(self.cfg.tpu_aligned_interpret),
                 init_row_scores=init_row_scores, bagged=bagged,
-                num_class=num_class)
+                num_class=num_class, bag_multiplier=bag_multiplier)
             self._aligned_eng = eng
         return eng
 

@@ -59,16 +59,23 @@ class LazyTree:
         return tree
 
 
-def _record_aligned_iter(it: int, rounds, table) -> None:
+def _record_aligned_iter(it: int, rounds, table, sampled=None) -> None:
     """One `aligned.iter` seam record for a resolved aligned iteration:
     the build program's round count and its per-round counters
     (`aligned_builder.ROUND_STATS` order, rows up to `rounds`), as pulled
-    with the exactness flags. Data-parallel: shard 0's counters."""
+    with the exactness flags. Data-parallel: shard 0's counters.
+    `sampled` = the device selection's counters of an iteration that
+    sampled its rows on the device (`goss_kept_top`, `goss_kept_other`,
+    `goss_threshold`); absent from an iteration that did not."""
     from .aligned_builder import ROUND_STATS
     rounds = int(rounds)
+    extra = {} if sampled is None else dict(
+        goss_kept_top=int(sampled[0]), goss_kept_other=int(sampled[1]),
+        goss_threshold=float(sampled[2]))
     obs_trace.seam_record("aligned.iter", iter=int(it), rounds=rounds,
                           columns=list(ROUND_STATS),
-                          table=np.asarray(table)[:rounds].tolist())
+                          table=np.asarray(table)[:rounds].tolist(),
+                          **extra)
 
 
 class LazyAlignedTree(LazyTree):
@@ -838,8 +845,9 @@ class GBDT:
                 except Exception:
                     why = None
                 if why is None:
-                    why = "gbdt-level eligibility (custom hooks, " \
-                        "renew-output objective, or multi-tree class gating)"
+                    why = self._aligned_variant_gate() \
+                        or "gbdt-level eligibility (renew-output " \
+                           "objective, or multi-tree class gating)"
             if why is not None:
                 msg += f" (aligned engine rejected: {why})"
         self._gate_notes = notes
@@ -923,11 +931,20 @@ class GBDT:
                 and not getattr(self.objective, "is_renew_tree_output",
                                 False)
                 and self.learner.aligned_mode_ok(self.objective)
-                ) and (
-                type(self).get_training_score is GBDT.get_training_score
-                ) and (
-                type(self)._post_bagging_gradients
-                is GBDT._post_bagging_gradients)
+                and self._aligned_variant_gate() is None)
+
+    def _aligned_variant_gate(self) -> Optional[str]:
+        """Why this boosting variant keeps off the aligned engine, by
+        name (the `train_path` event's `rejected`), or None. The engine
+        owns the score lane and computes gradients in its records, so a
+        subclass that reshapes either is out unless it says how it rides
+        (GOSS does: boosting_variants.py)."""
+        if (type(self).get_training_score is not GBDT.get_training_score
+                or type(self)._post_bagging_gradients
+                is not GBDT._post_bagging_gradients):
+            return (f"{type(self).__name__}: custom get_training_score / "
+                    "_post_bagging_gradients hooks")
+        return None
 
     def _aligned_mc_eligible(self) -> bool:
         """Multiclass on the aligned engine: K score lanes + per-class
@@ -944,11 +961,7 @@ class GBDT:
                 and not getattr(self.objective, "is_renew_tree_output",
                                 False)
                 and self.learner.aligned_mode_ok(self.objective)
-                ) and (
-                type(self).get_training_score is GBDT.get_training_score
-                ) and (
-                type(self)._post_bagging_gradients
-                is GBDT._post_bagging_gradients)
+                and self._aligned_variant_gate() is None)
 
     def _train_one_iter_aligned_mc(self, init_scores) -> bool:
         """One multiclass boosting iteration on the aligned engine: K
@@ -1118,7 +1131,8 @@ class GBDT:
             eng = self.learner.aligned_engine(
                 self.objective,
                 init_row_scores=np.asarray(self.train_score.score[0]),
-                bagged=self._will_bag())
+                bagged=self._will_bag(),
+                bag_multiplier=self._bag_on_device)
             self._aligned_eng_ref = eng
         stash = getattr(self, "_aligned_next", None)
         if stash is not None:
@@ -1127,10 +1141,12 @@ class GBDT:
             # the device busy through per-iteration valid evals
             self._aligned_next = None
             out, fmask, _rng_snap = stash
+            sample = self._aligned_sample
         else:
             self._maybe_rebag(eng)
+            sample = self._aligned_sample
             fmask = self.learner.feature_mask()
-            out = self._dispatch_aligned(eng, fmask)
+            out = self._dispatch_aligned(eng, fmask, sample)
         # resolve PREVIOUS iterations while this one runs on device.
         # With metric rounds / bagging this checks the one pending round
         # (depth 1); on the pure training loop the flags accumulate and
@@ -1144,7 +1160,7 @@ class GBDT:
                 # resolve; only the current dispatch needs a redo
                 if redo[1]:
                     return True
-                out = self._dispatch_aligned(eng, fmask)
+                out = self._dispatch_aligned(eng, fmask, sample)
             else:
                 # previous tree was inexact: the current dispatch rebuilt
                 # the same (failed) tree on unchanged scores — discard
@@ -1152,11 +1168,10 @@ class GBDT:
                 # iteration fresh
                 self._note_aligned_fallback(
                     eng, "speculative successor discarded")
-                stop = self._aligned_fallback_iter(redo[1], eng, redo[2],
-                                                   redo[3], redo[4])
+                stop = self._aligned_fallback_iter(*redo[1:])
                 if stop:
                     return True
-                out = self._dispatch_aligned(eng, fmask)
+                out = self._dispatch_aligned(eng, fmask, sample)
         spec, ncommit_dev, exact_dev, applied_dev = out
         self._train_score_stale = True
         lazy = LazyAlignedTree(spec, self.shrinkage_rate, init_scores[0],
@@ -1166,14 +1181,16 @@ class GBDT:
         self.iter += 1
         # the bag draw is stashed with the pending iteration: a fallback
         # must rebuild tree i on the SAME bag mask the device build used,
-        # not on the next iteration's freshly-resampled one
+        # not on the next iteration's freshly-resampled one. A sample
+        # drawn on the device (GOSS) is stashed as what makes it again,
+        # [7], with its device counters, [8]; it has no indices here
         q = getattr(self, "_aligned_pending", None) or []
         q.append((exact_dev, list(init_scores),
                   fmask if fmask is None else fmask.copy(),
                   self.bag_data_indices, self.bag_data_cnt,
                   # [5], [6]: the spec, whose counters ride the flag
                   # pull, and the iteration they are recorded under
-                  spec, self.iter - 1))
+                  spec, self.iter - 1, sample, self._aligned_sample_stats))
         self._aligned_pending = q
         # valid-set scores: walk the committed tree ON DEVICE from the
         # spec, still pipelined — the walk is gated by the program's own
@@ -1231,11 +1248,13 @@ class GBDT:
             rng_snap = (self.learner._feat_rng.get_state()
                         if hasattr(self.learner, "_feat_rng") else None,
                         self._bag_rng.get_state(),
-                        self.bag_data_indices, self.bag_data_cnt)
+                        self.bag_data_indices, self.bag_data_cnt,
+                        self._aligned_sample)
             self._maybe_rebag(eng)
             fmask_n = self.learner.feature_mask()
-            self._aligned_next = (self._dispatch_aligned(eng, fmask_n),
-                                  fmask_n, rng_snap)
+            self._aligned_next = (
+                self._dispatch_aligned(eng, fmask_n, self._aligned_sample),
+                fmask_n, rng_snap)
         if len(self._pending_numsplits) >= 16 * self.num_tree_per_iteration:
             res = self._resolve_aligned_pending(final=True)
             if res is not None and res[1]:
@@ -1273,14 +1292,31 @@ class GBDT:
         eng.undo_spec_scores(spec, applied_dev, self.shrinkage_rate)
         # rewind the sampling state the eager preparation consumed so a
         # later re-dispatch draws the same mask/bag as a non-eager run
-        feat_state, bag_state, bag_idx, bag_cnt = rng_snap
+        feat_state, bag_state, bag_idx, bag_cnt, sample = rng_snap
         if feat_state is not None:
             self.learner._feat_rng.set_state(feat_state)
         self._bag_rng.set_state(bag_state)
+        self._aligned_sample = sample
         self.bag_data_indices = bag_idx
         self.bag_data_cnt = bag_cnt
 
-    def _dispatch_aligned(self, eng, fmask):
+    # ---- a sample drawn ON THE DEVICE (GOSS overrides all four): the
+    # host holds only what makes the sample again, `_aligned_sample`
+    _bag_on_device = False          # the bag lane holds multipliers that
+    #                                 a device program writes each iteration
+    _aligned_sample = None          # of the iteration about to be built
+    _aligned_sample_stats = None    # its selection's device counters
+
+    def _aligned_apply_sample(self, eng, sample, grads):
+        """Queue `sample`'s selection ahead of the build; returns its
+        device counters, or None."""
+        return None
+
+    def _aligned_fallback_sample(self, sample, bag_idx, bag_cnt, gdev, hdev):
+        """(bag indices, bag count, g, h) an exact fallback trains on."""
+        return bag_idx, bag_cnt, gdev, hdev
+
+    def _dispatch_aligned(self, eng, fmask, sample=None):
         grads = None
         if eng._pgrad is None:
             # non-pointwise objective (ranking): gradients need ROW order
@@ -1298,6 +1334,8 @@ class GBDT:
                 scores = eng.row_scores_dev()
                 gd, hd = self.objective.get_gradients(scores[None, :])
             grads = (gd[0], hd[0])
+        self._aligned_sample_stats = self._aligned_apply_sample(
+            eng, sample, grads)
         return self._dispatch_device(
             "engine.train_iter",
             lambda: eng.train_iter(self.shrinkage_rate, fmask, grads=grads,
@@ -1306,8 +1344,13 @@ class GBDT:
     def _aligned_pipeline_depth(self) -> int:
         """How many dispatched rounds may stay unresolved before the
         host pulls their exactness flags. Per-iteration metric evals,
-        bagging, and multiclass sync every round anyway, so they keep
-        depth 1 (the classic one-behind pipeline). The pure training
+        host-drawn bagging (its mask is uploaded at every re-bag), and
+        multiclass sync every round anyway, so they keep
+        depth 1 (the classic one-behind pipeline). GOSS runs at the pure
+        loop's depth: its selection is a device program queued ahead of
+        each build from the record's own score lane, so it needs no host
+        sync, and each queued round carries the seed that makes its
+        sample again, so recovery replays it as drawn. The pure training
         loop (the bench hot path) batches 8 rounds per pull: one
         device_get per 8 iterations instead of per iteration. Safe
         because an inexact round's successors are chain-gated score
@@ -1315,7 +1358,8 @@ class GBDT:
         original column draws, reproducing the depth-1 sequence
         bit-exactly (and fallbacks measure ZERO at the default
         tpu_level_spec=4.5 budget, so the recovery path is cold)."""
-        if (self.valid_scores or self._will_bag()
+        if (self.valid_scores
+                or (self._will_bag() and not self._bag_on_device)
                 or self.num_tree_per_iteration > 1):
             return 1
         return 8
@@ -1324,7 +1368,8 @@ class GBDT:
         """Resolve queued speculative rounds' exactness flags (one
         batched device_get — see _aligned_pipeline_depth). Returns:
         - None: queue not full yet, or every queued round was exact;
-        - ("redo", init_scores, fmask, bag_idx, bag_cnt): final=False
+        - ("redo", init_scores, eng, fmask, bag_idx, bag_cnt, sample):
+          `_aligned_fallback_iter`'s arguments, final=False
           and the NEWEST queued round was inexact (popped; the caller
           discards its identical in-flight dispatch, grows the round
           exactly, and re-dispatches);
@@ -1347,11 +1392,11 @@ class GBDT:
                             queued=len(q), final=final):
             flags, stats = jax.device_get((
                 q[0][0] if len(q) == 1 else jnp.stack([p[0] for p in q]),
-                [(p[5].rounds, p[5].round_stats) for p in q]))
+                [(p[5].rounds, p[5].round_stats, p[8]) for p in q]))
         flags = [bool(v) for v in np.atleast_1d(flags)]
-        for p, ok, (rounds, table) in zip(q, flags, stats):
+        for p, ok, counters in zip(q, flags, stats):
             if ok:      # a discarded dispatch is rebuilt, and recorded then
-                _record_aligned_iter(p[6], rounds, table)
+                _record_aligned_iter(p[6], *counters)
         if all(flags):
             return None
         j = flags.index(False)
@@ -1362,36 +1407,41 @@ class GBDT:
         del self.models[-drop:]
         del self._pending_numsplits[-drop:]
         self.iter -= drop
-        if not final and j == len(q) - 1:
-            return ("redo",) + tuple(q[j][1:5])
         eng = self._aligned_eng_ref
+
+        def fallback_args(p):
+            return (p[1], eng, p[2], p[3], p[4], p[7])
+        if not final and j == len(q) - 1:
+            return ("redo",) + fallback_args(q[j])
         self._note_aligned_fallback(eng, "inexact replay in pending batch")
-        stop = self._aligned_fallback_iter(q[j][1], eng, q[j][2],
-                                           q[j][3], q[j][4])
-        for (_e, init_r, fmask_r, *_rest) in q[j + 1:]:
+        stop = self._aligned_fallback_iter(*fallback_args(q[j]))
+        for p in q[j + 1:]:
             if stop:
                 break
-            stop = self._aligned_replay_round(eng, init_r, fmask_r)
+            stop = self._aligned_replay_round(eng, p[1], p[2], p[7])
         if final:
             return ("fellback", stop)
         return ("caught_up", stop)
 
-    def _aligned_replay_round(self, eng, init_scores, fmask) -> bool:
+    def _aligned_replay_round(self, eng, init_scores, fmask,
+                              sample=None) -> bool:
         """Re-dispatch one discarded pipeline round on its ORIGINAL
-        column draw and resolve it synchronously. Only runs during
-        batched-pipeline failure recovery (depth > 1 implies no bagging
-        and no valid sets, so there is no bag mask to restore and no
-        valid walk to replay)."""
+        column draw and device `sample`, and resolve it synchronously.
+        Only runs during batched-pipeline failure recovery (depth > 1
+        implies no host-drawn bag and no valid sets, so there is no bag
+        mask to restore and no valid walk to replay)."""
         spec, ncommit_dev, exact_dev, _applied = \
-            self._dispatch_aligned(eng, fmask)
+            self._dispatch_aligned(eng, fmask, sample)
         with obs_trace.seam("train.flag_pull", iter=self.iter, queued=1,
                             final=True):
-            exact, rounds, table = jax.device_get(
-                (exact_dev, spec.rounds, spec.round_stats))
+            exact, *counters = jax.device_get(
+                (exact_dev, spec.rounds, spec.round_stats,
+                 self._aligned_sample_stats))
         if not bool(exact):
             self._note_aligned_fallback(eng, "inexact replay")
-            return self._aligned_fallback_iter(init_scores, eng, fmask)
-        _record_aligned_iter(self.iter, rounds, table)
+            return self._aligned_fallback_iter(init_scores, eng, fmask,
+                                               sample=sample)
+        _record_aligned_iter(self.iter, *counters)
         self._train_score_stale = True
         lazy = LazyAlignedTree(spec, self.shrinkage_rate, init_scores[0],
                                self.learner,
@@ -1402,18 +1452,22 @@ class GBDT:
         return False
 
     def _aligned_fallback_iter(self, init_scores, eng, fmask,
-                               bag_idx=None, bag_cnt=0) -> bool:
+                               bag_idx=None, bag_cnt=0,
+                               sample=None) -> bool:
         # (callers guarantee no unresolved pending iteration here)
         """Exact leaf-wise tree for an iteration whose speculative build
         could not be replayed exactly (the aligned analogue of the level
         builder's fallback). `bag_idx`/`bag_cnt` = the bag draw the
-        failed device build trained on."""
+        failed device build trained on; `sample` = what makes a
+        device-drawn one again (the rows and g, h follow from it)."""
         cfg = self.cfg
         # any stashed metric scalars were computed on pre-fallback scores
         self._valid_eval_stash = None
         self._train_eval_stash = None
         self._sync_train_score()
         gdev, hdev = self._gradients()
+        bag_idx, bag_cnt, gdev, hdev = self._aligned_fallback_sample(
+            sample, bag_idx, bag_cnt, gdev, hdev)
         bagged = self._will_bag() and bag_idx is not None
         if bagged:
             # mirror the fused bagged branch: partition over the bagged

@@ -124,6 +124,21 @@ def reset() -> None:
         fence_count = 0
 
 
+def forget_seams_since(t: float) -> int:
+    """Take the seam records that began at or after `t` (on
+    ``time.perf_counter``) out of the ring again; returns how many. For a
+    check that drives the training path AFTER a measured window (the
+    benchmark's `binary_goss` task makes one more `update()`): a seam
+    always records, so the check's own iteration would be the ring's
+    newest, where the window's readers look for the window."""
+    with _lock:
+        keep = [r for r in _seams if r["t0"] < t]
+        dropped = len(_seams) - len(keep)
+        _seams.clear()
+        _seams.extend(keep)
+    return dropped
+
+
 def spans() -> List[Dict[str, Any]]:
     """Completed span records, in completion order."""
     return list(_spans)

@@ -134,13 +134,12 @@ def test_aligned_train_score_sync():
 
 def test_aligned_fallbacks_to_leafwise_when_ineligible():
     X, y = _make(n=1500)
-    # GOSS re-weights gradients through a host hook, which the aligned
-    # engine's in-lane gradients cannot honor; training must still work
-    # on the leafwise path (bagging itself is aligned-supported since
-    # round 4 — tests/test_aligned_bagging.py)
+    # DART drops trees out of the training score every iteration, which
+    # the engine's score lane cannot follow; training must still work on
+    # the leafwise path (bagging and GOSS are aligned-supported:
+    # tests/test_aligned_bagging.py, tests/test_aligned_goss.py)
     bst = _train(X, y, "aligned", iters=3,
-                 extra={"boosting": "goss", "top_rate": 0.3,
-                        "other_rate": 0.3})
+                 extra={"boosting": "dart", "drop_rate": 0.5})
     assert bst._gbdt.iter == 3
     assert getattr(bst._gbdt, "_aligned_eng_ref", None) is None
 

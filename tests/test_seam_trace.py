@@ -98,6 +98,18 @@ def test_ring_is_bounded():
     assert kept[0]["iter"] == 50 and kept[-1]["iter"] == len(kept) + 49
 
 
+def test_a_check_takes_its_own_records_out_again():
+    import time
+    obs_trace.reset()
+    with obs_trace.seam("train.drain", iter=3):
+        pass
+    t = time.perf_counter()
+    with obs_trace.seam("aligned.dispatch", iter=4):
+        obs_trace.seam_record("aligned.iter", iter=4, rounds=1)
+    assert obs_trace.forget_seams_since(t) == 2
+    assert [r["name"] for r in obs_trace.seams()] == ["train.drain"]
+
+
 def test_a_raising_body_still_closes_its_seam():
     obs_trace.reset()
     with pytest.raises(ValueError):
