@@ -79,6 +79,8 @@ ROUND_STATS = (
 class AlignedSpec(NamedTuple):
     """Device outputs of one aligned speculative build (small arrays)."""
     rounds: jax.Array      # i32 scalar: while-loop rounds executed
+    norm_passes: jax.Array  # i32 scalar, 0 or 1: the copy that brought the
+                            # rows back from the round loop's second buffer
     n_exec: jax.Array      # i32 scalar
     execF: jax.Array       # f32[Sm1, SF_W]
     execI: jax.Array       # i32[Sm1, SI_W]
@@ -125,6 +127,23 @@ def _f32(x):
 
 def _i32(x):
     return lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _add_to_lane(rec, lane: int, addend):
+    """`rec` with f32 lane `lane` increased by `addend` [NC, 1 or C].
+
+    Written over the aligned window of 8 lanes that holds the lane, not
+    over the lane's own [NC, 1, C] slice: the records are tiled (8, 128)
+    over (lane, row), a one-lane slice pads eightfold in that tiling, and
+    XLA, to avoid that, gave the slice another layout and copied the
+    WHOLE matrix into it first (4.5 GiB, 14.5 ms a tree: `copy.997`,
+    PERF.md section 6, PR 30)."""
+    lo = lane - lane % 8
+    win = lax.slice_in_dim(rec, lo, lo + 8, axis=1)
+    lanes = lax.broadcasted_iota(jnp.int32, win.shape, 1)
+    win = jnp.where(lanes == lane - lo,
+                    _i32(_f32(win) + addend[:, None, :]), win)
+    return lax.dynamic_update_slice_in_dim(rec, win, lo, axis=1)
 
 
 def replay_spec(spec_host, num_leaves):
@@ -800,20 +819,31 @@ class AlignedEngine:
 
             need0 = jnp.zeros(S + 1, bool).at[0].set(
                 bestF[0, BF_GAIN] > 0.0)
-            state = (jnp.int32(0), rec, cnts_pc, leafF, leafI, bestF,
-                     bestI, bestB, hist_store, execF, execI, execB,
+            # the round loop PING-PONGS between two record buffers: round
+            # r reads the buffer `rounds % 2` names (0 = rec) and
+            # move_pass writes the other, both aliased operand to output.
+            # Every carried buffer is then updated in its own place; with
+            # one buffer carried and a fresh one out of every round, XLA
+            # copied the whole matrix back into the carry after each
+            # (4.5 GiB, 14.6 ms, 13 times a tree at Criteo's size:
+            # PERF.md section 6, PR 30). The second buffer is a temporary
+            # of this program, in the place of that fresh output
+            state = (jnp.int32(0), rec, jnp.zeros_like(rec), cnts_pc,
+                     leafF, leafI, bestF, bestI, bestB, hist_store,
+                     execF, execI, execB,
                      need0, jnp.zeros(Sm1 + 1, bool), jnp.int32(0),
                      jnp.int32(0),
                      jnp.zeros((Sm1, len(ROUND_STATS)), jnp.int32))
 
             def cond(state):
-                done, need = state[0], state[12]
+                done, need = state[0], state[13]
                 return (done < Sm1) & jnp.any(need)
 
             def body(state):
-                (done, rec, cnts_pc, leafF, leafI, bestF, bestI, bestB,
-                 hist_store, execF, execI, execB, need, _commit,
+                (done, rec_a, rec_b, cnts_pc, leafF, leafI, bestF, bestI,
+                 bestB, hist_store, execF, execI, execB, need, _commit,
                  _ncommit, rounds, round_stats) = state
+                src = rounds % 2
                 s_ids = jnp.arange(S + 1, dtype=jnp.int32)
                 gains = bestF[:, BF_GAIN]
                 # K also caps per-round splits: compact hist ids must fit
@@ -912,8 +942,8 @@ class AlignedEngine:
                     ks_s = jnp.where(sel, jnp.clip(selrank, 0, K - 1), K)
                     ks_pc = jnp.where(in_any & sel[slot_of],
                                       ks_s[slot_of], K)
-                    phys = count_pass(rec, r1_pc, r2_pc, meta_pc,
-                                      wsel_pc, ks_pc, cbits, K, C,
+                    phys = count_pass(rec_a, rec_b, src, r1_pc, r2_pc,
+                                      meta_pc, wsel_pc, ks_pc, cbits, K, C,
                                       bits=bits, bundled=bundled,
                                       interpret=interpret)
                     left_local = jnp.where(
@@ -967,16 +997,14 @@ class AlignedEngine:
                     nsum(jnp.where(sel, leafI[:, LI_COUNT], 0)), k,
                     nsum(split_pc & last) if spill else jnp.int32(0),
                     nsum(split_pc & ~in_any)]))
-                rec, hout = move_pass(rec, r1_pc, r2_pc, bl_pc, br_pc,
-                                      meta_pc, wsel_pc, hslots_pc, cbits,
-                                      C, W, wcnt, K, G, BH, group,
-                                      bag_lane=bag_lane, bits=bits,
-                                      grad_fn=gfn, num_class=K_cls,
-                                      w_used=self.w_used,
-                                      gh_off=self.gh_off,
-                                      bundled=bundled,
-                                      interpret=interpret,
-                                      subbin=subbin, spill=spill)
+                rec_a, rec_b, hout = move_pass(
+                    rec_a, rec_b, src, r1_pc, r2_pc, bl_pc, br_pc,
+                    meta_pc, wsel_pc, hslots_pc, cbits,
+                    C, W, wcnt, K, G, BH, group,
+                    bag_lane=bag_lane, bits=bits, grad_fn=gfn,
+                    num_class=K_cls, w_used=self.w_used,
+                    gh_off=self.gh_off, bundled=bundled,
+                    interpret=interpret, subbin=subbin, spill=spill)
 
                 # ---- updated tables (begins relaid for ALL slots)
                 depth_new = leafI[:, LI_DEPTH] + 1
@@ -1120,13 +1148,26 @@ class AlignedEngine:
                     2 * (done + k) + 1 < Lm1_commit, all_needed,
                     full_replay, operand=None)
 
-                return (done + k, rec, cnts_pc, leafF, leafI, bestF, bestI,
-                        bestB, hist_store, execF, execI, execB, need2,
-                        commit, ncommit, rounds + 1, round_stats)
+                return (done + k, rec_a, rec_b, cnts_pc, leafF, leafI,
+                        bestF, bestI, bestB, hist_store, execF, execI,
+                        execB, need2, commit, ncommit, rounds + 1,
+                        round_stats)
 
-            (n_exec, rec, cnts_pc, leafF, leafI, bestF, bestI, bestB,
-             _, execF, execI, execB, need_end, _commit_c, _ncommit_c,
-             rounds, round_stats) = lax.while_loop(cond, body, state)
+            (n_exec, rec, rec_b, cnts_pc, leafF, leafI, bestF, bestI,
+             bestB, _, execF, execI, execB, need_end, _commit_c,
+             _ncommit_c, rounds, round_stats) = lax.while_loop(
+                 cond, body, state)
+            # a tree of an odd number of rounds leaves its rows in the
+            # second buffer: one copy a tree, at most, where the carried
+            # single buffer cost one a round. A loop of no or one trip and
+            # no `lax.cond`: a loop's carry is overwritten in its place,
+            # where a conditional's result is a third buffer that both
+            # branches copy into (4.5 GiB more, and a copy on every tree)
+            norm_passes = rounds % 2
+            rec, _, _ = lax.while_loop(
+                lambda st: st[2] == 1,
+                lambda st: (st[1], st[1], jnp.int32(0)),
+                (rec, rec_b, norm_passes))
             # authoritative final replay: the in-loop replay may have been
             # skipped on the last round (all_needed shortcut), and a tree
             # that stops growing early must still commit its real splits
@@ -1182,11 +1223,11 @@ class AlignedEngine:
                 exists_f = jnp.arange(S + 1) <= n_exec
                 slot_f, _, _, _, in_any_f = chunk_maps(leafI, exists_f)
                 valmap = jnp.where(in_any_f & applied, cover[slot_f], 0.0)
-                sc = _f32(rec[:, score_lane, :]) \
-                    + valmap[:, None] * scale_in
-                rec = rec.at[:, score_lane, :].set(_i32(sc))
+                rec = _add_to_lane(rec, score_lane,
+                                   valmap[:, None] * scale_in)
 
-            spec = AlignedSpec(rounds=rounds, n_exec=n_exec,
+            spec = AlignedSpec(rounds=rounds, norm_passes=norm_passes,
+                               n_exec=n_exec,
                                execF=execF[:Sm1],
                                execI=execI[:Sm1], execB=execB[:Sm1],
                                bestF=bestF[:S], bestI=bestI[:S],
@@ -1264,8 +1305,9 @@ class AlignedEngine:
         from jax.sharding import PartitionSpec as P
         ax = self.axis
         spec_out = AlignedSpec(
-            rounds=P(), n_exec=P(), execF=P(), execI=P(), execB=P(),
-            bestF=P(), bestI=P(), bestB=P(), leafF=P(), leafI=P(ax),
+            rounds=P(), norm_passes=P(), n_exec=P(), execF=P(), execI=P(),
+            execB=P(), bestF=P(), bestI=P(), bestB=P(), leafF=P(),
+            leafI=P(ax),
             first_c=P(), nxt_c=P(), cover=P(), round_stats=P())
         if kind == "build":
             return ((P(ax), P(ax), P(), P(), P()),
@@ -1319,9 +1361,11 @@ class AlignedEngine:
                     self.rec, self.cnts, fmask, jnp.float32(scale),
                     self._last_exact)
         self._last_exact = exact_dev
-        # records AND per-chunk counts were donated (in-place round
-        # loop): the physical layout advances either
-        # way (harmless — the next root re-reads everything); the SCORE
+        # records AND per-chunk counts were donated (the round loop
+        # ping-pongs between the donated matrix and a temporary of the
+        # program, and ends in the donated one): the physical layout
+        # advances either way (harmless — the next root re-reads
+        # everything); the SCORE
         # lane was updated on device only when the replay was exact.
         # NOTHING is pulled here: the caller checks `exact_dev` one
         # iteration later, hiding the host round-trip behind device

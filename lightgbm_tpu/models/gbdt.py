@@ -59,9 +59,12 @@ class LazyTree:
         return tree
 
 
-def _record_aligned_iter(it: int, rounds, table, sampled=None) -> None:
+def _record_aligned_iter(it: int, rounds, norm_passes, table,
+                         sampled=None) -> None:
     """One `aligned.iter` seam record for a resolved aligned iteration:
-    the build program's round count and its per-round counters
+    the build program's round count, whether it copied the rows back out
+    of the round loop's second buffer (`norm_passes`: 1 after an odd
+    number of rounds) and its per-round counters
     (`aligned_builder.ROUND_STATS` order, rows up to `rounds`), as pulled
     with the exactness flags. Data-parallel: shard 0's counters.
     `sampled` = the device selection's counters of an iteration that
@@ -73,6 +76,7 @@ def _record_aligned_iter(it: int, rounds, table, sampled=None) -> None:
         goss_kept_top=int(sampled[0]), goss_kept_other=int(sampled[1]),
         goss_threshold=float(sampled[2]))
     obs_trace.seam_record("aligned.iter", iter=int(it), rounds=rounds,
+                          norm_passes=int(norm_passes),
                           columns=list(ROUND_STATS),
                           table=np.asarray(table)[:rounds].tolist(),
                           **extra)
@@ -1392,7 +1396,8 @@ class GBDT:
                             queued=len(q), final=final):
             flags, stats = jax.device_get((
                 q[0][0] if len(q) == 1 else jnp.stack([p[0] for p in q]),
-                [(p[5].rounds, p[5].round_stats, p[8]) for p in q]))
+                [(p[5].rounds, p[5].norm_passes, p[5].round_stats, p[8])
+                 for p in q]))
         flags = [bool(v) for v in np.atleast_1d(flags)]
         for p, ok, counters in zip(q, flags, stats):
             if ok:      # a discarded dispatch is rebuilt, and recorded then
@@ -1435,8 +1440,8 @@ class GBDT:
         with obs_trace.seam("train.flag_pull", iter=self.iter, queued=1,
                             final=True):
             exact, *counters = jax.device_get(
-                (exact_dev, spec.rounds, spec.round_stats,
-                 self._aligned_sample_stats))
+                (exact_dev, spec.rounds, spec.norm_passes,
+                 spec.round_stats, self._aligned_sample_stats))
         if not bool(exact):
             self._note_aligned_fallback(eng, "inexact replay")
             return self._aligned_fallback_iter(init_scores, eng, fmask,

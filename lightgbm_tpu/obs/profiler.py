@@ -536,16 +536,17 @@ def _calibrate_build_terms(eng: Any, cfg: Any, chain: int,
         def mk(k):
             @jax.jit
             def f(r):
-                def body(i, r):
-                    r2_, _ = move_pass(
-                        r, *a, cb0, C, W, wcnt, K, G, BH, group,
+                def body(i, bufs):
+                    # pass i reads buffer i % 2 and writes the other, as
+                    # the build program's round loop does
+                    return move_pass(
+                        *bufs, i % 2, *a, cb0, C, W, wcnt, K, G, BH, group,
                         bag_lane=bag_lane, bits=eng.bits, grad_fn=gfn,
                         num_class=eng.num_class, w_used=eng.w_used,
                         gh_off=eng.gh_off, bundled=lr.bundled,
                         interpret=eng.interpret, subbin=subbin,
-                        spill=spill)
-                    return r2_
-                return lax.fori_loop(0, k, body, r)
+                        spill=spill)[:2]
+                return lax.fori_loop(0, k, body, (r, jnp.zeros_like(r)))
             return f
         return mk
 

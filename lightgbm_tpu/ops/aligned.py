@@ -573,13 +573,21 @@ def _hi_lo6(pay):
 
 
 def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
-                 hslot_ref, cbits_ref, fetch_ref, rec_ref, rec_hbm_ref,
-                 out_ref, hist_ref, stag,
+                 hslot_ref, cbits_ref, fetch_ref, src_ref, reca_ref,
+                 recb_ref, bufa_ref, bufb_ref, hist_ref, stag,
                  fbuf, hacc, hstage, tri, cur_ref, sems, *, chunk, w_pad,
                  w_used, wcnt, num_features, b_pad, group, dummy,
                  bag_lane, bits, grad_fn, num_class, gh_off, bundled,
                  subbin, spill):
     """One grid step of the fused move+hist pass.
+
+    TWO record buffers, A and B, each an operand aliased to an output
+    (bufa_ref / bufb_ref are the buffers in HBM, reca_ref / recb_ref the
+    blocked fetch of each): the pass reads the one `src_ref[0]` names
+    (0 = A) and writes the other, so a caller that carries both through
+    a loop never has the fresh output of one round copied back over the
+    input of the next. The buffer that is not the source keeps its
+    blocked index frozen, so only the source is fetched.
 
     SPLIT chunks: partition rows into the block's left/right staging
     rings (exact byte-plane one-hot matmul, in sub-tiles of
@@ -617,6 +625,18 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
     r1 = r1_ref[i]
     meta = meta_ref[i]
     is_last = (meta >> 21) & 1
+    a_is_src = src_ref[0] == 0
+
+    def start_copy(make):
+        """Start the DMA `make(source buffer, destination buffer)` gives,
+        for the direction this pass runs in."""
+        @pl.when(a_is_src)
+        def _():
+            make(bufa_ref, bufb_ref).start()
+
+        @pl.when(~a_is_src)
+        def _():
+            make(bufb_ref, bufa_ref).start()
 
     @pl.when(i == 0)
     def _():
@@ -649,13 +669,15 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
     hside = (hs >> 24) & 1       # its side: 0 = the chunk's left rows
 
     def wait_slot(slot):
+        # a wait reads its semaphore and the size of its destination,
+        # one chunk in either buffer: it names A whatever the direction
         if slot < 4:            # static: flush slots DMA from VMEM
             pltpu.make_async_copy(fbuf.at[slot],
-                                  out_ref.at[cur_ref[16 + slot]],
+                                  bufa_ref.at[cur_ref[16 + slot]],
                                   sems.at[slot]).wait()
         else:                   # copy slots DMA HBM->HBM
-            pltpu.make_async_copy(rec_hbm_ref.at[cur_ref[28 + slot]],
-                                  out_ref.at[cur_ref[16 + slot]],
+            pltpu.make_async_copy(bufa_ref.at[cur_ref[28 + slot]],
+                                  bufa_ref.at[cur_ref[16 + slot]],
                                   sems.at[slot]).wait()
         cur_ref[4 + slot] = 0
 
@@ -707,9 +729,8 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                 @pl.when(cur_ref[4 + slot] != 0)
                 def _():
                     wait_slot(slot)
-                pltpu.make_async_copy(
-                    rec_hbm_ref.at[i], out_ref.at[bl_i],
-                    sems.at[slot]).start()
+                start_copy(lambda src, dst: pltpu.make_async_copy(
+                    src.at[i], dst.at[bl_i], sems.at[slot]))
                 cur_ref[4 + slot] = 1
                 cur_ref[16 + slot] = bl_i
                 cur_ref[28 + slot] = i
@@ -730,7 +751,11 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
             block's staging rings; cur = (left, right) rows of the block
             staged so far. The work is S x S whatever C is."""
             cur_l, cur_r = cur
-            rec = rec_ref[0, :, pl.ds(pl.multiple_of(t * S, S), S)]
+            # both buffers' tiles are loaded and one is selected: a branch
+            # here, eight times a chunk, cost 0.05 us a chunk
+            rows_t = pl.ds(pl.multiple_of(t * S, S), S)
+            rec = jnp.where(a_is_src, reca_ref[0, :, rows_t],
+                            recb_ref[0, :, rows_t])
             valid = t * S + posS < cntv
             word = rec[0, :]
             for wj in range(1, wcnt):
@@ -846,9 +871,10 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                             def _():
                                 wait_slot(slot)
                             fbuf[slot] = stag[side * 2 + p]
-                            pltpu.make_async_copy(
-                                fbuf.at[slot], out_ref.at[base + fl],
-                                sems.at[slot]).start()
+                            start_copy(
+                                lambda src, dst: pltpu.make_async_copy(
+                                    fbuf.at[slot], dst.at[base + fl],
+                                    sems.at[slot]))
                             cur_ref[4 + slot] = 1
                             cur_ref[16 + slot] = base + fl
                     cur_ref[fl_slot] = fl + 1
@@ -921,8 +947,9 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
     "chunk", "w_pad", "wcnt", "num_slots", "num_features", "b_pad",
     "group", "bag_lane", "bits", "grad_fn", "num_class", "w_used",
     "gh_off", "bundled", "interpret", "subbin", "spill"))
-def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
-              chunk, w_pad, wcnt, num_slots, num_features, b_pad, group,
+def move_pass(records, other, src, r1, r2, basel, baser, meta, wsel,
+              hslots, cbits, chunk, w_pad, wcnt, num_slots, num_features,
+              b_pad, group,
               bag_lane=-1, bits=8, grad_fn=None, num_class=1,
               w_used=0, gh_off=2, bundled=False,
               interpret=False, subbin=False, spill=False):
@@ -934,7 +961,14 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
     16+16-bit word (so <= 65535 chunks) — callers must respect both
     bounds (aligned_mode_ok does).
 
-    records: [NC, W, C] i32; r1/r2/basel/baser/meta/wsel: [NC] i32
+    records, other: the two [NC, W, C] i32 record buffers, A and B;
+    src: i32 scalar, 0 = the rows are read from A and land in B, 1 = the
+    other way. Both buffers are operands ALIASED to outputs
+    (`input_output_aliases`), so a `lax.while_loop` that carries both
+    and flips `src` each round updates every carried buffer in its own
+    place: with one buffer in and a fresh one out, XLA copied the whole
+    matrix back into the loop's carry after every round (PERF.md section
+    6, PR 30). r1/r2/basel/baser/meta/wsel: [NC] i32
     per-chunk routing (see module docstring bit layouts; wsel = split
     word lane index of the chunk's block). hslots[i] packs the smaller
     child's accumulation slot | side << 24 (side 0 = left rows of the
@@ -944,9 +978,10 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
     at B=256), so callers must remap tree slots to the round's selected
     split ranks.
 
-    Returns (records_out, hist[num_slots, F, b_pad, 3]). Chunks not
-    covered by the new layout keep stale rows; hist slots never present
-    in hslots are zero.
+    Returns (A, B, hist[num_slots, F, b_pad, 3]): the source buffer as
+    it was, the destination with the new layout. Destination chunks not
+    covered by the new layout keep what they held (rows of an earlier
+    round); hist slots never present in hslots are zero.
 
     `spill` keeps the [num_slots+1, ...] store in HBM (streamed through
     the kernel's 2-deep VMEM staging ring) instead of VMEM-resident —
@@ -979,15 +1014,24 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
     iota_nc = jnp.arange(nc, dtype=jnp.int32)
     is_split = ((r1 >> R_COPY) & 1) == 0
     fetch_idx = lax.cummax(jnp.where(is_split, iota_nc, 0))
+    # only the source buffer's blocked fetch moves; the other's index
+    # stays at chunk 0, fetched once
+    src1 = jnp.reshape(src, (1,)).astype(jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=8,
         grid=(nc,),
         in_specs=[
             pl.BlockSpec((1, w_pad, chunk),
-                         lambda i, a, b, c, d, e, f, g: (g[i], 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.HBM),   # DMA src for copies
+                         lambda i, a, b, c, d, e, f, g, s:
+                         (jnp.where(s[0] == 0, g[i], 0), 0, 0)),
+            pl.BlockSpec((1, w_pad, chunk),
+                         lambda i, a, b, c, d, e, f, g, s:
+                         (jnp.where(s[0] == 0, 0, g[i]), 0, 0)),
         ],
         out_specs=[
+            # the two buffers in HBM, each aliased to its operand: the
+            # kernel's DMAs read the source and write the destination
+            pl.BlockSpec(memory_space=pltpu.HBM),
             pl.BlockSpec(memory_space=pltpu.HBM),
             # spill: the store stays in HBM, written slot-by-slot by the
             # kernel's DMA ring. Otherwise a constant index map keeps
@@ -995,7 +1039,7 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
             # written back once at the end.
             pl.BlockSpec(memory_space=pltpu.HBM) if spill else
             pl.BlockSpec(store_shape,
-                         lambda i, a, b, c, d, e, f, g:
+                         lambda i, a, b, c, d, e, f, g, s:
                          tuple(0 for _ in store_shape)),
         ],
         scratch_shapes=[
@@ -1008,18 +1052,20 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
             pltpu.SemaphoreType.DMA((14,)),
         ],
     )
-    out, hist = pl.pallas_call(
+    buf_a, buf_b, hist = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(records.shape, jnp.int32),
+            jax.ShapeDtypeStruct(records.shape, jnp.int32),
             jax.ShapeDtypeStruct(store_shape, jnp.float32),
         ],
+        input_output_aliases={8: 0, 9: 1},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 << 20, has_side_effects=True),
         interpret=interpret,
         name="move_pass",
-    )(r1p, r2, blbr, meta, hslots, cbits, fetch_idx, records, records)
+    )(r1p, r2, blbr, meta, hslots, cbits, fetch_idx, src1, records, other)
     hist = _hist_store_finalize(hist, num_slots, num_features,
                                 b_pad, group, subbin)
     if spill:
@@ -1029,28 +1075,63 @@ def move_pass(records, r1, r2, basel, baser, meta, wsel, hslots, cbits,
             .at[hslots & 0xFFFFFF].max(1)
         hist = jnp.where((visited[:num_slots] > 0)[:, None, None, None],
                          hist, 0.0)
-    return out, hist
+    return buf_a, buf_b, hist
 
 
 # ---------------------------------------------------------------------------
 # physical left-count pass
 # ---------------------------------------------------------------------------
 def _count_kernel(r1_ref, r2_ref, meta_ref, wsel_ref, ks_ref,
-                  cbits_ref, rec_ref, out_ref, cacc, *, chunk, dummy,
-                  bits, bundled):
-    """Exact i32 count of PHYSICAL rows routed left per selected split.
+                  cbits_ref, src_ref, reca_ref, recb_ref, out_ref, win,
+                  cacc, sems, *, chunk, dummy, bits, bundled):
+    """Exact i32 count of PHYSICAL rows routed left per selected split,
+    over the record buffer `src_ref[0]` names (0 = A), as `move_pass`
+    reads it.
 
     Streams only each block's split-word sublane (4 B/row). Needed when
     the histogram count channel cannot drive the physical layout: bagging
     (counts there are in-bag only, gbdt.cpp:209-275) or n > 2^24 (f32
-    count sums lose exactness)."""
+    count sums lose exactness).
+
+    The kernel fetches for itself, one chunk ahead into a 2-deep ring
+    (`win`), the 8-sublane window that holds the split word (DMAs move
+    whole (8, 128) tiles) of the chunks it counts, and of no other: a
+    blocked operand for each of the two buffers cost every grid step the
+    pipeline's bookkeeping twice (0.39 us a chunk against 0.32: PERF.md
+    section 6, PR 30), and fetched the chunks of unsplit blocks too."""
     i = pl.program_id(0)
+    last = pl.num_programs(0) - 1
     meta = meta_ref[i]
+
+    def window(j, buf_ref):
+        """The DMA of chunk j's window from `buf_ref` into its slot."""
+        lanes = pl.ds(pl.multiple_of((wsel_ref[j] >> 3) * 8, 8), 8)
+        return pltpu.make_async_copy(buf_ref.at[j, lanes], win.at[j % 2],
+                                     sems.at[j % 2])
+
+    def fetch(j):
+        @pl.when(src_ref[0] == 0)
+        def _():
+            window(j, reca_ref).start()
+
+        @pl.when(src_ref[0] != 0)
+        def _():
+            window(j, recb_ref).start()
 
     @pl.when(i == 0)
     def _():
         for k in range(out_ref.shape[0]):     # SMEM table: scalar clears
             out_ref[k] = 0
+
+        @pl.when(ks_ref[0] != dummy)
+        def _():
+            fetch(0)
+
+    nxt = jnp.minimum(i + 1, last)
+
+    @pl.when((i < last) & (ks_ref[nxt] != dummy))
+    def _():
+        fetch(nxt)
 
     @pl.when(((meta >> 20) & 1) != 0)
     def _():
@@ -1058,13 +1139,16 @@ def _count_kernel(r1_ref, r2_ref, meta_ref, wsel_ref, ks_ref,
 
     @pl.when(ks_ref[i] != dummy)
     def _():
-        # the fetched block is an 8-sublane window containing the split
-        # word (TPU blocks must be 8-sublane-divisible); pick the word
-        # with a static select chain on wsel & 7
+        # a wait reads its semaphore and its destination's size: it names
+        # buffer A whatever the source was
+        window(i, reca_ref).wait()
+        # pick the split word out of its window with a static select
+        # chain on wsel & 7
         wsub = wsel_ref[i] & 7
-        word = rec_ref[0, 0]
+        blk = win[i % 2]
+        word = blk[0]
         for wj in range(1, 8):
-            word = jnp.where(wsub == wj, rec_ref[0, wj], word)
+            word = jnp.where(wsub == wj, blk[wj], word)
         r1 = r1_ref[i]
         binv = (word >> ((r1 >> R_SHIFT) & 31)) & ((1 << bits) - 1)
         if bundled:
@@ -1083,27 +1167,29 @@ def _count_kernel(r1_ref, r2_ref, meta_ref, wsel_ref, ks_ref,
 @functools.partial(jax.jit, static_argnames=("num_slots", "chunk",
                                              "bits", "bundled",
                                              "interpret"))
-def count_pass(records, r1, r2, meta, wsel, kslots, cbits, num_slots,
-               chunk, bits=8, bundled=False, interpret=False):
+def count_pass(records, other, src, r1, r2, meta, wsel, kslots, cbits,
+               num_slots, chunk, bits=8, bundled=False, interpret=False):
     """[num_slots] i32 physical left counts per compact slot id.
 
+    records, other, src: the round loop's two record buffers and which of
+    them holds the rows, as for move_pass; only that one is read.
     kslots[i] = compact id of chunk i's selected split (num_slots =
     skip); r1/r2/meta/wsel as for move_pass (copy bit must be CLEAR for
     counted chunks)."""
     compile_cache.note_trace()
     nc = records.shape[0]
-    w_pad = records.shape[1]
     kernel = functools.partial(_count_kernel, chunk=chunk,
                                dummy=num_slots, bits=bits,
                                bundled=bundled)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(nc,),
-        in_specs=[pl.BlockSpec((1, 8, chunk),
-                               lambda i, a, b, m, w, k, cb:
-                               (i, w[i] >> 3, 0))],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        scratch_shapes=[pltpu.SMEM((8,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((2, 8, chunk), jnp.int32),
+                        pltpu.SMEM((8,), jnp.int32),
+                        pltpu.SemaphoreType.DMA((2,))],
     )
     out = pl.pallas_call(
         kernel,
@@ -1112,7 +1198,8 @@ def count_pass(records, r1, r2, meta, wsel, kslots, cbits, num_slots,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=100 << 20),
         interpret=interpret,
         name="count_pass",
-    )(r1, r2, meta, wsel, kslots, cbits, records)
+    )(r1, r2, meta, wsel, kslots, cbits,
+      jnp.reshape(src, (1,)).astype(jnp.int32), records, other)
     return out[:num_slots]
 
 

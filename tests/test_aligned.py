@@ -51,6 +51,18 @@ def _tree_tuples(bst):
     return out
 
 
+def _same_trees(a, b):
+    """Both boosters hold the same trees (splits equal, leaf values
+    within float noise); returns how many."""
+    ta, tb = _tree_tuples(a), _tree_tuples(b)
+    assert len(ta) == len(tb)
+    for (fa, tha, va), (fb, thb, vb) in zip(ta, tb):
+        assert fa == fb
+        assert tha == thb
+        np.testing.assert_allclose(va, vb, rtol=1e-4, atol=1e-5)
+    return len(ta)
+
+
 @pytest.mark.parametrize("chunk", [256, 2 * ROUTE_TILE])
 def test_aligned_matches_leafwise_binary(chunk):
     """At 2 x ROUTE_TILE move_pass routes every chunk in two sub-tiles:
@@ -59,12 +71,34 @@ def test_aligned_matches_leafwise_binary(chunk):
     a = _train(X, y, "aligned", extra={"tpu_chunk": chunk})
     assert a._gbdt._aligned_eng_ref.C == chunk
     b = _train(X, y, "leafwise")
-    ta, tb = _tree_tuples(a), _tree_tuples(b)
-    assert len(ta) == len(tb)
-    for (fa, tha, va), (fb, thb, vb) in zip(ta, tb):
-        assert fa == fb
-        assert tha == thb
-        np.testing.assert_allclose(va, vb, rtol=1e-4, atol=1e-5)
+    _same_trees(a, b)
+
+
+def test_rows_end_in_the_first_buffer_after_odd_and_even_round_counts():
+    """The round loop ping-pongs between two record buffers, so a tree
+    of an odd number of rounds ends in the second one and is copied back
+    once (`norm_passes` 1); after an even number nothing is copied. Every
+    other program reads the engine's one record matrix: after each tree,
+    the scores it holds are the walk over the model so far."""
+    from benchmark import reference
+    from lightgbm_tpu.obs import trace as obs_trace
+    X, y = _make()
+    obs_trace.reset()
+    iters = 6
+    a = _train(X, y, "aligned", iters=0)
+    for it in range(iters):
+        a.update()
+        eng = a._gbdt._aligned_eng_ref
+        np.testing.assert_allclose(
+            eng.row_scores(), reference.raw_scores(a.dump_model(), X),
+            rtol=1e-5, atol=1e-6, err_msg=f"after tree {it}")
+    a.eval_train()
+    recs = obs_trace.seams("aligned.iter")
+    assert [r["iter"] for r in recs] == list(range(iters))
+    assert [r["norm_passes"] for r in recs] == [r["rounds"] % 2
+                                                for r in recs]
+    assert {r["norm_passes"] for r in recs} == {0, 1}
+    assert _same_trees(a, _train(X, y, "leafwise", iters=iters)) == iters
 
 
 def test_aligned_matches_leafwise_255bin():
@@ -74,12 +108,7 @@ def test_aligned_matches_leafwise_255bin():
     X, y = _make()
     a = _train(X, y, "aligned", extra={"max_bin": 255})
     b = _train(X, y, "leafwise", extra={"max_bin": 255})
-    ta, tb = _tree_tuples(a), _tree_tuples(b)
-    assert len(ta) == len(tb)
-    for (fa, tha, va), (fb, thb, vb) in zip(ta, tb):
-        assert fa == fb
-        assert tha == thb
-        np.testing.assert_allclose(va, vb, rtol=1e-4, atol=1e-5)
+    _same_trees(a, b)
 
 
 def test_aligned_matches_leafwise_15bin():
@@ -91,12 +120,7 @@ def test_aligned_matches_leafwise_15bin():
     from lightgbm_tpu.models.aligned_builder import AlignedEngine  # noqa
     eng = a._gbdt._aligned_eng_ref
     assert eng is not None and eng.bits == 4 and eng.W == 8
-    ta, tb = _tree_tuples(a), _tree_tuples(b)
-    assert len(ta) == len(tb)
-    for (fa, tha, va), (fb, thb, vb) in zip(ta, tb):
-        assert fa == fb
-        assert tha == thb
-        np.testing.assert_allclose(va, vb, rtol=1e-4, atol=1e-5)
+    _same_trees(a, b)
 
 
 def test_aligned_matches_leafwise_regression():

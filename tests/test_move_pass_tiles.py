@@ -205,26 +205,78 @@ CASES = [(C, layout, spill, heavy)
          or not spill and C < 4 * ROUTE_TILE]
 
 
-@pytest.mark.parametrize("C,layout,spill,heavy", CASES)
-def test_move_pass_matches_numpy_partition(C, layout, spill, heavy):
-    assert C // route_tile(C) == C // ROUTE_TILE
-    sc = _build(C, layout, heavy, seed=C + len(layout) + len(heavy))
-    rec_in, rt, expect, hist_ref, b_pad = _reference(sc, C)
-    out, hist = move_pass(
-        jnp.asarray(rec_in), *(jnp.asarray(rt[k]) for k in (
+def _call(sc, bufs, src, rt, b_pad, spill=False):
+    """One pass from buffer `src` of `bufs` into the other."""
+    C = sc["rec"].shape[2]
+    a, b, hist = move_pass(
+        jnp.asarray(bufs[0]), jnp.asarray(bufs[1]), jnp.int32(src),
+        *(jnp.asarray(rt[k]) for k in (
             "r1", "r2", "basel", "baser", "meta", "wsel", "hslots")),
         jnp.zeros((K + 1) * 8, jnp.int32), C, sc["W"], sc["wcnt"], K, F,
         b_pad, 4 if b_pad > 64 else 8, bits=sc["bits"],
         grad_fn=_point_grad if sc["compact"] else None,
         w_used=sc["w_used"], interpret=True, subbin=True, spill=spill)
-    out = np.asarray(out)
-    assert len(expect) >= 14       # every block of the scenario landed
+    return [np.asarray(a), np.asarray(b)], np.asarray(hist)
+
+
+def _check_rows(sc, got, expect):
     for chunk, rows in expect.items():
-        got = out[chunk, :sc["w_used"], :len(rows)].T
-        np.testing.assert_array_equal(got, rows, err_msg=f"chunk {chunk}")
-    np.testing.assert_allclose(np.asarray(hist), hist_ref, rtol=2e-5,
-                               atol=2e-4)
+        np.testing.assert_array_equal(
+            got[chunk, :sc["w_used"], :len(rows)].T, rows,
+            err_msg=f"chunk {chunk}")
+
+
+@pytest.mark.parametrize("src", (0, 1))
+@pytest.mark.parametrize("C,layout,spill,heavy", CASES)
+def test_move_pass_matches_numpy_partition(C, layout, spill, heavy, src):
+    """The rows are read from buffer `src` and land in the other one,
+    whichever of the two aliased operands that is."""
+    assert C // route_tile(C) == C // ROUTE_TILE
+    sc = _build(C, layout, heavy, seed=C + len(layout) + len(heavy))
+    rec_in, rt, expect, hist_ref, b_pad = _reference(sc, C)
+    held = np.full_like(rec_in, 0x5A5A5A5A)    # what the destination held
+    bufs = [held, held]
+    bufs[src] = rec_in
+    bufs, hist = _call(sc, bufs, src, rt, b_pad, spill)
+    assert len(expect) >= 14       # every block of the scenario landed
+    _check_rows(sc, bufs[1 - src], expect)
+    # the source is read only, and a destination chunk that the new
+    # layout does not cover keeps what it held
+    np.testing.assert_array_equal(bufs[src], rec_in)
+    free = sorted(set(range(NC)) - set(expect))
+    np.testing.assert_array_equal(bufs[1 - src][free], held[free])
+    np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
     assert hist_ref[..., 2].sum() > C       # histograms were asked for
+
+
+@pytest.mark.parametrize("src", (0, 1))
+def test_two_passes_end_in_the_buffer_they_started_from(src):
+    """A round loop's ping-pong: the first pass moves the rows out of
+    buffer `src`, the second (a round that splits nothing: every live
+    chunk shifts whole, onto its own index) brings the new layout back
+    into it, over the rows the first pass read."""
+    C = 2 * ROUTE_TILE
+    sc = _build(C, "std8", "left", seed=30)
+    rec_in, rt, expect, hist_ref, b_pad = _reference(sc, C)
+    bufs = [np.zeros_like(rec_in), np.zeros_like(rec_in)]
+    bufs[src] = rec_in
+    bufs, hist = _call(sc, bufs, src, rt, b_pad)
+    np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
+    live = np.zeros(NC, bool)
+    live[list(expect)] = True
+    cnt = np.zeros(NC, np.int32)
+    for chunk, rows in expect.items():
+        cnt[chunk] = len(rows)
+    back = dict(rt, r1=np.where(live, 1 << R_COPY, 7).astype(np.int32),
+                basel=np.arange(NC, dtype=np.int32),
+                baser=np.zeros(NC, np.int32), meta=cnt,
+                hslots=np.full(NC, K, np.int32))
+    moved = bufs[1 - src].copy()
+    bufs, hist = _call(sc, bufs, 1 - src, back, b_pad)
+    assert not hist.any()
+    _check_rows(sc, bufs[src], expect)
+    np.testing.assert_array_equal(bufs[src][live], moved[live])
+    np.testing.assert_array_equal(bufs[1 - src], moved)
 
 
 def test_route_tile_divides_every_chunk():

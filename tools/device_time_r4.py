@@ -95,11 +95,11 @@ def main():
             def mk(k):
                 @jax.jit
                 def f(r):
-                    def body(i, r):
-                        r2_, _ = move_pass(r, *a, cb0, C, W, wcnt,
-                                           S + 1, F, B, group)
-                        return r2_
-                    return lax.fori_loop(0, k, body, r)
+                    def body(i, bufs):  # read buffer i % 2, write the other
+                        return move_pass(*bufs, i % 2, *a, cb0, C, W,
+                                         wcnt, S + 1, F, B, group)[:2]
+                    return lax.fori_loop(0, k, body,
+                                         (r, jnp.zeros_like(r)))
                 return f
             return mk
 
