@@ -59,6 +59,63 @@ def check(cond, what: str) -> None:
         raise AssertionError(what)
 
 
+# ------------------------------------------------------------ fixtures
+def synth_higgs(n: int, f: int, seed: int = 7):
+    """Dense float features with a noisy nonlinear boundary (HIGGS-like:
+    kinematic features + derived high-level features)."""
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, f), dtype=np.float32)
+    k = min(7, f // 4)
+    for j in range(k):
+        X[:, f - 1 - j] = np.abs(X[:, 2 * j] * X[:, 2 * j + 1]) \
+            + 0.1 * X[:, f - 1 - j]
+    w = rng.standard_normal(f).astype(np.float32) / np.sqrt(f)
+    margin = X @ w + 0.5 * np.sin(X[:, 0] * 2.0) * X[:, 1] \
+        - 0.4 * (np.abs(X[:, 2]) > 1.0)
+    p = 1.0 / (1.0 + np.exp(-margin))
+    y = (rng.random(n) < p).astype(np.int8)
+    return X, y
+
+
+def synth_mslr(n: int, f: int, seed: int = 11):
+    """MSLR-shaped ranking data: ~120 docs/query, graded 0-4 relevance
+    correlated with a sparse linear signal."""
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, f), dtype=np.float32)
+    w = np.zeros(f, np.float32)
+    k = min(25, f)
+    idx = rng.choice(f, k, replace=False)
+    w[idx] = rng.standard_normal(k).astype(np.float32)
+    s = X @ w / 5.0 + 0.8 * rng.standard_normal(n).astype(np.float32)
+    # graded labels by within-query quantile
+    sizes = []
+    left = n
+    while left > 0:
+        q = int(rng.integers(80, 160))
+        q = min(q, left)
+        sizes.append(q)
+        left -= q
+    group = np.asarray(sizes, np.int32)
+    y = np.zeros(n, np.float32)
+    pos = 0
+    for q in sizes:
+        sl = s[pos:pos + q]
+        ranks = sl.argsort().argsort() / max(q - 1, 1)
+        y[pos:pos + q] = np.digitize(ranks, [0.55, 0.75, 0.9, 0.97])
+        pos += q
+    return X, y, group
+
+
+def auc_of(pred, y):
+    """Rank-sum AUC; ties take the order `argsort` leaves them in."""
+    order = np.argsort(pred)
+    r = np.empty(len(pred))
+    r[order] = np.arange(len(pred)) + 1
+    pos = y > 0
+    npos, nneg = pos.sum(), (~pos).sum()
+    return float((r[pos].sum() - npos * (npos + 1) / 2) / (npos * nneg))
+
+
 class _Events:
     """Capture the library's structured events (utils/log.py) for the
     duration of a leg; human log lines pass through to stderr."""
@@ -186,7 +243,7 @@ def _round_walls(w):
 
 
 def _params(objective, min_data_in_leaf, num_leaves, max_bin, **more):
-    """The reference experiments' parameters (bench.py's), at INFO
+    """The reference experiments' parameters, at INFO
     verbosity so the structured events reach `_Events`."""
     return dict(objective=objective, num_leaves=num_leaves, max_bin=max_bin,
                 learning_rate=0.1, min_data_in_leaf=min_data_in_leaf,
@@ -198,7 +255,6 @@ def leg_train(rows, features, iters=10, num_leaves=255, max_bin=255,
               holdout_rows=HOLDOUT_ROWS, extra_params=None):
     """Binary training at HIGGS width. Returns (result, booster,
     holdout_X) — the score leg reuses the model and the holdout."""
-    from bench import auc_of, synth_higgs
     params = _params("binary", 20, num_leaves, max_bin)
     hold = {}
 
@@ -225,7 +281,6 @@ def leg_rank(rows, features, iters=3, num_leaves=255, max_bin=255,
              extra_params=None):
     """Lambdarank at MS-LTR width: the leg that compiles the HBM spill
     ring and the segment-fused rank kernel."""
-    from bench import synth_mslr
     params = _params("lambdarank", 50, num_leaves, max_bin)
 
     def make_data():
@@ -372,7 +427,6 @@ def leg_multichip(rows, features, shards=4, iters=10, num_leaves=255,
     real shards, the aligned-DP route."""
     import jax
 
-    from bench import synth_higgs
     from lightgbm_tpu.obs import memory as obs_memory
     params = _params("binary", 20, num_leaves, max_bin,
                      tree_learner="data", num_machines=shards)

@@ -371,7 +371,6 @@ class Booster:
         self._train_set = train_set
         self._gbdt: Optional[GBDT] = None
         self._telemetry = None  # engine.train parks the ledger here
-        self._profiler = None   # ... and the in-run profiler
         self._loaded: Optional[Dict] = None
         self._name_valid_sets: List[str] = []
         self._valid_sets_public: List["Dataset"] = []
@@ -431,14 +430,6 @@ class Booster:
         """The training RoundLedger (obs/ledger.py) when `tpu_trace` is
         on; None otherwise."""
         return getattr(self._gbdt, "telemetry", None) or self._telemetry
-
-    @property
-    def profiler(self):
-        """The in-run RoundProfiler (obs/profiler.py) when `tpu_profile`
-        resolved to enabled; None otherwise. Carries sampled-round
-        terms_ms history, the build calibration, and the artifact
-        writers (summary / write_program_costs)."""
-        return getattr(self._gbdt, "_profiler", None) or self._profiler
 
     def metrics_snapshot(self):
         """Live metrics + HBM accounting snapshot — the API twin of the

@@ -169,20 +169,13 @@ class Application:
             tdir = cfg.tpu_trace_dir or "lgbt_trace"
             # fold the compile-cache story in next to the spans: total
             # persistent-cache hits/misses, which attributed program
-            # each miss blamed, and the process trace count — the
-            # warm-up forensics that used to need a bench run
+            # each miss blamed, and the process trace count
             extra = {"compile_cache": {
                 **compile_cache.persistent_cache_events(),
                 "miss_by_program": compile_cache.miss_attribution(),
                 "traces": compile_cache.trace_count(),
                 "cache_dir": compile_cache.persistent_cache_dir(),
             }}
-            # in-run profiler (tpu_profile): sampled rounds, last
-            # terms_ms, build calibration, the program_costs.json path
-            # (written here), and any jax.profiler capture artifacts
-            prof = getattr(booster, "profiler", None)
-            if prof is not None:
-                extra["profiler"] = prof.summary(tdir)
             dump = obs_trace.write(
                 os.path.join(tdir, "trace_summary.json"), extra=extra)
             print(f"Telemetry: span summary at {dump}")

@@ -39,8 +39,6 @@ _lock = threading.Lock()
 _programs: Dict[Any, Callable] = {}
 _trace_count = 0
 _tls = threading.local()          # per-thread attribution tag
-_arg_capture = False              # profiler opt-in (enable_arg_capture)
-_captured: Dict[Any, Dict[str, Any]] = {}
 
 
 def note_trace() -> None:
@@ -86,81 +84,15 @@ def attribution(tag: str):
         _tls.tag = prev
 
 
-def enable_arg_capture() -> None:
-    """Start recording, for every registered program, the abstract
-    shapes of its call args (as ``jax.ShapeDtypeStruct`` — never live
-    buffers) plus per-key call counts and host dispatch wall. The
-    profiler (obs/profiler.py) flips this on at construction so
-    ``collect_program_costs`` can later ``fn.lower(*specs)`` and read
-    XLA ``cost_analysis()`` without holding inputs alive. Off (the
-    default) the dispatch wrapper pays one module-global bool check."""
-    global _arg_capture
-    _arg_capture = True
-
-
-def arg_capture_enabled() -> bool:
-    return _arg_capture
-
-
-def captured_programs() -> Dict[Any, Dict[str, Any]]:
-    """key -> {tag, fn, spec_args, spec_kwargs, calls, dispatch_ms}
-    for every program dispatched since ``enable_arg_capture``."""
-    return dict(_captured)
-
-
-def clear_captured() -> None:
-    global _arg_capture
-    with _lock:
-        _captured.clear()
-        _arg_capture = False
-
-
-def _abstract_spec(x: Any) -> Any:
-    """Array-likes become ShapeDtypeStruct (drops the buffer); statics
-    (ints, HashableFn, ...) pass through — `lower` needs them as-is."""
-    shape = getattr(x, "shape", None)
-    dtype = getattr(x, "dtype", None)
-    if shape is None or dtype is None:
-        return x
-    import jax
-    return jax.ShapeDtypeStruct(tuple(shape), dtype)
-
-
 def _attributed(key: Any, fn: Callable) -> Callable:
     """Wrap a registered program so any compile its dispatch triggers is
     attributed to its registry key (one thread-local store per call;
-    the jit trace cache keys on `fn`, which stays stable inside). With
-    arg capture on (profiler), the first call per key also stashes the
-    args' abstract specs and every call accumulates count + dispatch
-    wall."""
+    the jit trace cache keys on `fn`, which stays stable inside)."""
     tag = program_tag(key)
 
     def run(*args, **kwargs):
         prev = getattr(_tls, "tag", None)
         _tls.tag = tag
-        if _arg_capture:
-            import time
-            ent = _captured.get(key)
-            if ent is None:
-                try:
-                    ent = {"tag": tag, "fn": fn,
-                           "spec_args": tuple(_abstract_spec(a)
-                                              for a in args),
-                           "spec_kwargs": {k: _abstract_spec(v)
-                                           for k, v in kwargs.items()},
-                           "calls": 0, "dispatch_ms": 0.0}
-                    _captured[key] = ent
-                except Exception:  # noqa: BLE001 — capture is advisory
-                    ent = None
-            t0 = time.perf_counter()
-            try:
-                return fn(*args, **kwargs)
-            finally:
-                if ent is not None:
-                    ent["calls"] += 1
-                    ent["dispatch_ms"] += \
-                        (time.perf_counter() - t0) * 1e3
-                _tls.tag = prev
         try:
             return fn(*args, **kwargs)
         finally:
@@ -358,8 +290,8 @@ def cache_dir() -> str:
     """THE persistent-compile-cache location: ``$JAX_COMPILATION_CACHE_DIR``
     when set, else the fixed ``<checkout>/.jax_cache``. The path is part
     of jax's cache key, so nothing else in the tree names a directory:
-    ``init_persistent_cache`` (called by ``bench.py``, ``chip_smoke.py``
-    and ``Config.update``) resolves through here."""
+    ``init_persistent_cache`` (called by ``chip_smoke.py`` and
+    ``Config.update``) resolves through here."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         ".jax_cache")

@@ -739,41 +739,19 @@ class Config:
     # settable via the LGBT_DEBUG_LOCKS environment variable.
     # Runtime-only: excluded from model text and checkpoint signatures
     tpu_debug_locks: bool = False
-    # in-run bottleneck profiler (obs/profiler.py): "off" (default,
-    # zero added fences — one is-None branch per round), "on", or
-    # "auto" (= on only when tpu_trace or tpu_metrics is already
-    # enabled). On sampled rounds the round's device time is fenced
-    # per dispatch site into a canonical terms_ms dict (ledger round
-    # record, train_term_ms metrics gauges, bench terms_by_stage), the
-    # fused build is decomposed once by in-run chained-k calibration,
-    # and XLA cost_analysis() for every registered program lands in
-    # program_costs.json. Runtime-only: excluded from model text and
-    # checkpoint signatures, like tpu_metrics
-    tpu_profile: str = "off"
-    # profile every Nth round (round 0 is never sampled — it pays the
-    # XLA compiles). Sampled rounds serialize the pipeline, so keep
-    # this sparse on real runs; their wall time is excluded from the
-    # train_round_ms histogram and marked timing="fenced" in the ledger
-    tpu_profile_every: int = 50
-    # "start:stop" round window bracketed in a programmatic
-    # jax.profiler trace; artifact directory paths land in
-    # trace_summary.json. Empty disables capture
-    tpu_profile_capture: str = ""
     # unified run timeline (obs/timeline.py): "auto" (default — live
-    # exactly when tpu_trace is), "on", or "off". Live, the CLI and
-    # bench write a Chrome-trace/Perfetto timeline.json next to
-    # trace_summary.json joining every JSONL/event stream on one
-    # monotonic clock, the round loop runs the zero-fence rolling-
-    # median anomaly watch (round_anomaly ledger notes + events), and
-    # profiler-sampled rounds of distributed runs fence per shard —
-    # per-device terms_ms columns, imbalance ratio, and the
-    # edge-triggered dist_straggler / sweep_subfleet_imbalance
-    # watches. Off adds zero fences and zero work. Runtime-only:
-    # excluded from model text and checkpoint signatures
+    # exactly when tpu_trace is), "on", or "off". Live, the CLI writes
+    # a Chrome-trace/Perfetto timeline.json next to trace_summary.json
+    # joining every JSONL/event stream on one monotonic clock, the
+    # round loop runs the zero-fence rolling-median anomaly watch
+    # (round_anomaly ledger notes + events), and a sweep's fenced
+    # rounds feed the edge-triggered sweep_subfleet_imbalance watch.
+    # Off adds zero fences and zero work. Runtime-only: excluded from
+    # model text and checkpoint signatures
     tpu_timeline: str = "auto"
-    # imbalance ratio (max/median per-device or per-sub-fleet round
-    # time) at or above which the straggler watch counts a sampled
-    # round as imbalanced. Runtime-only, like tpu_timeline
+    # imbalance ratio (max/median per-sub-fleet round time of a sweep)
+    # at or above which the straggler watch counts a sampled round as
+    # imbalanced. Runtime-only, like tpu_timeline
     tpu_straggler_threshold: float = 1.5
     # consecutive imbalanced sampled rounds before the edge-triggered
     # straggler event fires (and consecutive calm rounds below the

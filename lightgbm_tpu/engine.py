@@ -214,12 +214,8 @@ def train(params: Dict[str, Any], train_set: Dataset,
         fresh.params = params
         # the round ledger lives on the training GBDT, which this fresh
         # booster no longer holds — carry the handle so bst.telemetry
-        # still resolves after train() returns (the in-run profiler
-        # rides along the same way for bst.profiler / bench / the CLI
-        # trace-summary fold)
+        # still resolves after train() returns
         fresh._telemetry = telemetry
-        fresh._profiler = getattr(getattr(booster, "_gbdt", None),
-                                  "_profiler", None)
         fresh._preempted = preempted
         fresh._resilience = resilience_stats
         return fresh

@@ -16,7 +16,7 @@ once per eval.
 from __future__ import annotations
 
 import os
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -287,39 +287,15 @@ class GBDT:
             from ..resilience.faults import FaultPlan
             self._fault_plan = FaultPlan.from_config(
                 cfg, telemetry=self.telemetry)
-        # in-run bottleneck profiler (obs/profiler.py): None when off —
-        # the round loop pays one is-None check, and _prof_round is only
-        # non-None DURING a sampled round (the per-site fence seam in
-        # _dispatch_device). With the profiler live, compile_cache also
-        # starts capturing arg specs so program_costs.json can pair XLA
-        # cost_analysis() with measured dispatch wall
-        self._profiler = None
-        self._prof_round = None
-        if cfg.tpu_profile and str(cfg.tpu_profile).lower() != "off":
-            from ..obs.profiler import RoundProfiler
-            self._profiler = RoundProfiler.from_config(cfg)
-            if self._profiler is not None:
-                from .. import compile_cache
-                compile_cache.enable_arg_capture()
-        # unified timeline + watches (obs/timeline.py, obs/straggler.py):
-        # off, the round loop pays one bool check and zero fences. On,
-        # traced rounds feed the rolling-median anomaly watch (pure
-        # host arithmetic over walls the trace fence already measured)
-        # and profiler-sampled rounds on a multi-device mesh attribute
-        # their fenced drains per shard for the straggler watch
-        self._timeline = cfg.tpu_timeline == "on" or (
-            cfg.tpu_timeline == "auto" and cfg.tpu_trace)
+        # rolling-median anomaly watch (obs/straggler.py) over the traced
+        # rounds' walls: pure host arithmetic over what the round fence
+        # already measured; None unless the timeline is live
         self._anomaly = None
-        self._straggler = None
-        if self._timeline:
-            from ..obs.straggler import AnomalyWatch, ImbalanceWatch
-            if cfg.tpu_anomaly_factor > 0:
-                self._anomaly = AnomalyWatch(
-                    factor=cfg.tpu_anomaly_factor,
-                    window=cfg.tpu_anomaly_window)
-            self._straggler = ImbalanceWatch(
-                threshold=cfg.tpu_straggler_threshold,
-                rounds=cfg.tpu_straggler_rounds)
+        from ..obs.timeline import timeline_on
+        if cfg.tpu_anomaly_factor > 0 and timeline_on(cfg):
+            from ..obs.straggler import AnomalyWatch
+            self._anomaly = AnomalyWatch(factor=cfg.tpu_anomaly_factor,
+                                         window=cfg.tpu_anomaly_window)
 
     @staticmethod
     def _reshape_init_score(ds: Dataset) -> Optional[np.ndarray]:
@@ -394,12 +370,6 @@ class GBDT:
         return 0.0
 
     def _gradients(self) -> Tuple[jax.Array, jax.Array]:
-        pr = self._prof_round
-        if pr is not None:
-            return pr.timed(
-                "objective.grad",
-                lambda: self.objective.get_gradients(
-                    self.get_training_score()))
         g, h = self.objective.get_gradients(self.get_training_score())
         return g, h
 
@@ -426,42 +396,22 @@ class GBDT:
                        hess: Optional[np.ndarray] = None) -> bool:
         """reference GBDT::TrainOneIter (gbdt.cpp:367-448). Returns True when
         training should STOP (no splittable tree), mirroring the C API's
-        is_finished flag. With `tpu_trace` on, every round commits one
-        ledger record (see _train_one_iter_traced); off, this is a
-        single None check."""
-        prof = self._profiler
-        if prof is not None:
-            prof.maybe_capture(self.iter)
-            if prof.should_sample(self.iter):
-                return self._train_one_iter_profiled(prof, grad, hess)
-        if self.telemetry is None:
-            if self._metrics is None:
-                return self._train_one_iter_impl(grad, hess)
-            return self._train_one_iter_metered(grad, hess)
-        return self._train_one_iter_traced(grad, hess)
+        is_finished flag. With `tpu_trace` or `tpu_metrics` on the round
+        runs inside _train_one_iter_observed; off, this is two None
+        checks."""
+        if self.telemetry is None and self._metrics is None:
+            return self._train_one_iter_impl(grad, hess)
+        return self._train_one_iter_observed(grad, hess)
 
     def _dispatch_device(self, what: str, fn, *args):
         """Every learner/engine device dispatch funnels through here so
         the resilience layer can inject deterministic faults and retry
-        transient device errors (resilience/retry.py), and the in-run
-        profiler can fence each site on a sampled round (_prof_round is
-        non-None only then). With no fault plan, no retries, and no
-        active sample this is a plain call."""
-        pr = self._prof_round
+        transient device errors (resilience/retry.py). With no fault
+        plan and no retries this is a plain call."""
         plan = self._fault_plan
         if plan is None and self.cfg.tpu_retry_max <= 0:
-            if pr is not None:
-                return pr.timed(what, fn, *args)
             return fn(*args)
         from ..resilience.retry import call_with_retry
-        if pr is not None:
-            # fence OUTSIDE the retry wrapper: a retried dispatch's
-            # whole recovery cost is device time the round really paid
-            return pr.timed(what, lambda: call_with_retry(
-                fn, args, what=what, plan=plan,
-                max_retries=self.cfg.tpu_retry_max,
-                backoff_s=self.cfg.tpu_retry_backoff_s,
-                telemetry=self.telemetry))
         return call_with_retry(
             fn, args, what=what, plan=plan,
             max_retries=self.cfg.tpu_retry_max,
@@ -481,239 +431,86 @@ class GBDT:
             return pend_mc[0]
         return self.train_score.score
 
-    def _dist_allreduce_probe(self) -> None:
-        """Standalone histogram-shaped all-reduce through the fenced
-        dispatch seam, run ONLY inside a profiler-sampled round on a
-        mesh-parallel learner. The in-round psums are fused into the
-        whole-tree build program, so their cost hides inside the "build"
-        term; this probe times one histogram-sized `lax.psum` in
-        isolation, giving the ledger a per-round collective floor
-        (terms_ms["allreduce"], obs/terms.py) without touching the
-        training programs."""
-        if self._prof_round is None:
-            return
-        mesh = getattr(self.learner, "mesh", None)
-        ax = getattr(self.learner, "axis_name", None)
-        if mesh is None or ax is None or int(mesh.devices.size) < 2:
-            return
-        fn = getattr(self, "_allreduce_probe_fn", None)
-        if fn is None:
-            from jax.sharding import PartitionSpec as P
-
-            from ..ops.histogram import NUM_HIST_STATS
-            f = max(int(len(self.learner.meta["num_bin"])), 1)
-            b = max(int(self.cfg.max_bin), 2)
-            x = jnp.ones((f, b, NUM_HIST_STATS), jnp.float32)
-            mapped = jax.shard_map(lambda h: jax.lax.psum(h, ax),
-                                   mesh=mesh, in_specs=P(), out_specs=P(),
-                                   check_vma=False)
-            jfn = jax.jit(mapped)
-            fn = lambda: jfn(x)            # noqa: E731 — tiny closure
-            self._allreduce_probe_fn = fn
-        self._dispatch_device("dist.allreduce", fn)
-
-    def _train_one_iter_traced(self, grad, hess) -> bool:
-        """One traced round: StepTraceAnnotation + span around the
-        untouched implementation, ONE fence to split wall time into the
-        host-visible part and the residual device drain, then a ledger
-        commit. This path only runs when cfg.tpu_trace is set."""
+    def _train_one_iter_observed(self, grad, hess) -> bool:
+        """The one observed round around the untouched implementation.
+        Always: host wall and the trace / fallback counter deltas, fed to
+        the live metrics where `tpu_metrics` is set. Only where
+        `tpu_trace` is set: StepTraceAnnotation + span, ONE fence to split
+        the wall into the host-visible part and the residual device
+        drain, and a ledger commit. Without the tracer nothing fences, so
+        wall_ms is then dispatch wall, not device wall."""
         import time as _time
 
         from ..compile_cache import trace_count
-        from ..obs import trace as obs_trace
+        tel = self.telemetry
         rnd = self.iter
         traces0 = trace_count()
         t0 = _time.perf_counter()
-        with obs_trace.step(rnd):
-            with obs_trace.span("train.round", round=rnd):
-                finished = self._train_one_iter_impl(grad, hess)
-                t_host = _time.perf_counter()
-                with obs_trace.span("train.round.fence", round=rnd):
-                    obs_trace.fence(self._round_fence_target())
-        t1 = _time.perf_counter()
+        if tel is None:
+            finished = self._train_one_iter_impl(grad, hess)
+            t_host = t1 = _time.perf_counter()
+        else:
+            with obs_trace.step(rnd):
+                with obs_trace.span("train.round", round=rnd):
+                    finished = self._train_one_iter_impl(grad, hess)
+                    t_host = _time.perf_counter()
+                    with obs_trace.span("train.round.fence", round=rnd):
+                        obs_trace.fence(self._round_fence_target())
+            t1 = _time.perf_counter()
+        wall_ms = round((t1 - t0) * 1e3, 3)
+        traces = trace_count() - traces0
         eng = getattr(self, "_aligned_eng_ref", None)
         fb = int(getattr(eng, "fallbacks", 0) or 0) if eng is not None \
             else 0
-        path = getattr(self, "_iter_path", "unknown")
-        rec = {
-            "kind": "round", "round": rnd,
-            "wall_ms": round((t1 - t0) * 1e3, 3),
-            "device_ms": round((t1 - t_host) * 1e3, 3),
-            "traces": trace_count() - traces0,
-            "path": path,
-            "aligned": path.startswith("aligned"),
-            "fallbacks": fb - self._obs_fallbacks_seen,
-            "trees": len(self.models),
-            "bag_cnt": int(self.bag_data_cnt),
-            "finished": bool(finished),
-            # raw perf_counter at round start: the timeline's clock
-            # anchor (CLOCK_MONOTONIC — shared across processes on the
-            # host, so spans/ledger/reqtrace join without alignment)
-            "t0": round(t0, 6),
-        }
+        fallbacks = fb - self._obs_fallbacks_seen
         self._obs_fallbacks_seen = fb
-        notes = list(getattr(self, "_gate_notes", ()) or ())
-        if notes:
-            rec["gate_notes"] = notes
-            rec["hist_spill"] = any("spill" in n.lower() for n in notes)
-        self.telemetry.commit(rec)
-        if self._anomaly is not None:
-            # residual-mode walls only: fenced (profiled) rounds
-            # serialize the pipeline and would poison the median
-            self._note_anomaly(rnd, rec["wall_ms"])
+        if tel is not None:
+            path = getattr(self, "_iter_path", "unknown")
+            rec = {
+                "kind": "round", "round": rnd,
+                "wall_ms": wall_ms,
+                "device_ms": round((t1 - t_host) * 1e3, 3),
+                "traces": traces,
+                "path": path,
+                "aligned": path.startswith("aligned"),
+                "fallbacks": fallbacks,
+                "trees": len(self.models),
+                "bag_cnt": int(self.bag_data_cnt),
+                "finished": bool(finished),
+                # raw perf_counter at round start: the timeline's clock
+                # anchor (CLOCK_MONOTONIC — shared across processes on
+                # the host, so spans/ledger/reqtrace join without
+                # alignment)
+                "t0": round(t0, 6),
+            }
+            notes = list(getattr(self, "_gate_notes", ()) or ())
+            if notes:
+                rec["gate_notes"] = notes
+                rec["hist_spill"] = any("spill" in n.lower() for n in notes)
+            tel.commit(rec)
+            if self._anomaly is not None:
+                self._note_anomaly(rnd, wall_ms)
         if self._metrics is not None:
-            self._note_round_metrics(rec["wall_ms"], rec["traces"],
-                                     rec["fallbacks"])
+            self._note_round_metrics(wall_ms, traces, fallbacks)
         return finished
 
     def _note_anomaly(self, rnd: int, wall_ms: float) -> None:
         """Fold one traced round's wall into the rolling-median anomaly
         watch (obs/straggler.py — pure host arithmetic, zero fences). A
         deviation past tpu_anomaly_factor commits a ``round_anomaly``
-        ledger note + event while the run can still react — a bench
-        about to blow its budget says WHERE before the driver's kill."""
+        ledger note + event while the run can still react."""
         hit = self._anomaly.update(wall_ms)
         if hit is None:
             return
         import time as _time
 
         from ..utils import log
-        if self.telemetry is not None:
-            self.telemetry.commit(
-                {"kind": "note", "note": "round_anomaly", "round": rnd,
-                 "wall_ms": round(wall_ms, 3),
-                 "t0": round(_time.perf_counter(), 6), **hit})
+        self.telemetry.commit(
+            {"kind": "note", "note": "round_anomaly", "round": rnd,
+             "wall_ms": wall_ms,
+             "t0": round(_time.perf_counter(), 6), **hit})
         log.event("round_anomaly", round=rnd,
-                  wall_ms=round(wall_ms, 3), **hit)
-
-    def _note_straggler(self, rnd: int, dev: Dict[str, Any]) -> None:
-        """Feed one profiled round's per-device imbalance ratio into
-        the gauge + the edge-triggered straggler watch; a raise/clear
-        transition commits a ``dist_straggler`` ledger note + event."""
-        ratio = dev.get("imbalance")
-        if ratio is None:
-            return
-        from ..obs import metrics as obs_metrics
-        if obs_metrics.enabled():
-            obs_metrics.registry().gauge(
-                "dist_device_imbalance",
-                "max/median per-device round time on the last "
-                "profiled distributed round").set(float(ratio))
-        edge = self._straggler.update(ratio)
-        if edge is None:
-            return
-        import time as _time
-
-        from ..utils import log
-        if self.telemetry is not None:
-            self.telemetry.commit(
-                {"kind": "note", "note": "dist_straggler", "round": rnd,
-                 "state": edge, "imbalance": ratio,
-                 "t0": round(_time.perf_counter(), 6)})
-        log.event("dist_straggler", round=rnd, state=edge,
-                  imbalance=ratio,
-                  devices=len(dev.get("device_ids", ())))
-
-    def _train_one_iter_profiled(self, prof, grad, hess) -> bool:
-        """One profiler-sampled round: drain the pipelined backlog, then
-        run the untouched implementation with _prof_round set so every
-        dispatch site fences individually (obs/profiler.py RoundSample).
-        The resulting record carries timing="fenced" — device_ms is the
-        SUM of fenced site times, NOT the residual-drain convention of
-        _train_one_iter_traced — plus the canonical terms_ms; it is
-        excluded from the train_round_ms histogram so sampled rounds
-        cannot pollute p50/p99."""
-        import time as _time
-
-        from ..compile_cache import trace_count
-        from ..obs import trace as obs_trace
-        rnd = self.iter
-        # drain queued work from previous (pipelined) rounds BEFORE t0
-        # so the first fenced site doesn't absorb the backlog
-        obs_trace.force_fence(self._round_fence_target())
-        per_dev = False
-        if self._timeline:
-            mesh = getattr(self.learner, "mesh", None)
-            per_dev = mesh is not None and int(mesh.devices.size) >= 2
-        sample = prof.begin_round(rnd, per_device=per_dev)
-        self._prof_round = sample
-        traces0 = trace_count()
-        t0 = _time.perf_counter()
-        try:
-            with obs_trace.step(rnd):
-                with obs_trace.span("train.round.profiled", round=rnd):
-                    finished = self._train_one_iter_impl(grad, hess)
-                    # per-round collective visibility on parallel
-                    # learners (terms_ms["allreduce"]); no-op off-mesh
-                    self._dist_allreduce_probe()
-                    # residual drain: device work not covered by a
-                    # fenced site (host-applied trees, lazy syncs)
-                    sample.timed("round_tail", self._round_fence_target)
-        finally:
-            self._prof_round = None
-        t1 = _time.perf_counter()
-        traces = trace_count() - traces0
-        eng = getattr(self, "_aligned_eng_ref", None)
-        # finish AFTER reading the trace delta: the one-time build
-        # calibration compiles chained-k programs of its own
-        terms = prof.finish_round(sample, engine=eng, cfg=self.cfg)
-        fb = int(getattr(eng, "fallbacks", 0) or 0) if eng is not None \
-            else 0
-        path = getattr(self, "_iter_path", "unknown")
-        rec = {
-            "kind": "round", "round": rnd,
-            "wall_ms": round((t1 - t0) * 1e3, 3),
-            "device_ms": round(sample.device_total_ms(), 3),
-            "traces": traces,
-            "path": path,
-            "aligned": path.startswith("aligned"),
-            "fallbacks": fb - self._obs_fallbacks_seen,
-            "trees": len(self.models),
-            "bag_cnt": int(self.bag_data_cnt),
-            "finished": bool(finished),
-            "profiled": True,
-            "timing": "fenced",
-            "terms_ms": terms,
-            "t0": round(t0, 6),
-        }
-        # per-device attribution (timeline on, multi-device mesh): the
-        # fenced wait-attribution columns, their imbalance ratio, and
-        # the allreduce compute-vs-wait split
-        dev = sample.device_columns(prof.objective) if per_dev else None
-        if dev is not None:
-            rec.update(dev)
-        self._obs_fallbacks_seen = fb
-        notes = list(getattr(self, "_gate_notes", ()) or ())
-        if notes:
-            rec["gate_notes"] = notes
-            rec["hist_spill"] = any("spill" in n.lower() for n in notes)
-        if self.telemetry is not None:
-            if prof.calibration is not None \
-                    and not prof.calibration_committed:
-                prof.calibration_committed = True
-                self.telemetry.commit(
-                    {"kind": "note", "note": "profile_calibration",
-                     **prof.calibration})
-            self.telemetry.commit(rec)
-        if dev is not None and self._straggler is not None:
-            self._note_straggler(rnd, dev)
-        m = self._metrics
-        if m is not None:
-            # counters advance, but round_ms.observe is deliberately
-            # SKIPPED: a fenced round's wall is not a residual-mode wall
-            m.rounds.inc()
-            if traces > 0:
-                m.retraces.inc(traces)
-            if rec["fallbacks"] > 0:
-                m.fallbacks.inc(rec["fallbacks"])
-            trees = len(self.models)
-            if trees > self._obs_trees_seen:
-                m.trees.inc(trees - self._obs_trees_seen)
-            self._obs_trees_seen = trees
-            for term, ms in terms.items():
-                if ms is not None:
-                    m.term_ms.labels(term=term).set(ms)
-        return finished
+                  wall_ms=wall_ms, **hit)
 
     def _note_round_metrics(self, wall_ms: float, traces: int,
                             fallbacks: int) -> None:
@@ -729,26 +526,6 @@ class GBDT:
         if trees > self._obs_trees_seen:
             m.trees.inc(trees - self._obs_trees_seen)
         self._obs_trees_seen = trees
-
-    def _train_one_iter_metered(self, grad, hess) -> bool:
-        """Metrics-only round wrapper (`tpu_metrics` without
-        `tpu_trace`): host wall + trace/fallback counter deltas, NO
-        fence — wall_ms here is dispatch wall, not device wall, which is
-        what keeps the enabled overhead in the sub-percent range."""
-        import time as _time
-
-        from ..compile_cache import trace_count
-        traces0 = trace_count()
-        t0 = _time.perf_counter()
-        finished = self._train_one_iter_impl(grad, hess)
-        wall_ms = (_time.perf_counter() - t0) * 1e3
-        eng = getattr(self, "_aligned_eng_ref", None)
-        fb = int(getattr(eng, "fallbacks", 0) or 0) if eng is not None \
-            else 0
-        self._note_round_metrics(wall_ms, trace_count() - traces0,
-                                 fb - self._obs_fallbacks_seen)
-        self._obs_fallbacks_seen = fb
-        return finished
 
     def _train_one_iter_impl(self, grad: Optional[np.ndarray] = None,
                              hess: Optional[np.ndarray] = None) -> bool:
@@ -1011,28 +788,17 @@ class GBDT:
             self.bag_data_indices, self.bag_data_cnt)
         # valid-set scores: committed-tree walks per class, gated by the
         # device-side chain flags (a later-discarded dispatch adds 0)
-        pr = self._prof_round
         for i, su in enumerate(self.valid_scores):
-            def _walk(su=su, i=i):
-                sc = su.score
-                for k, (spec, _nc, _ex, applied) in enumerate(outs):
-                    sc = eng.apply_spec_to_scores(
-                        sc, k, self._valid_bins_dev[i], spec, applied,
-                        self.shrinkage_rate)
-                return sc
-            su.score = (_walk() if pr is None
-                        else pr.timed("score_update", _walk))
+            sc = su.score
+            for k, (spec, _nc, _ex, applied) in enumerate(outs):
+                sc = eng.apply_spec_to_scores(
+                    sc, k, self._valid_bins_dev[i], spec, applied,
+                    self.shrinkage_rate)
+            su.score = sc
         if self.valid_scores:
-            def _stash_evals():
-                st = []
-                for su, ms in zip(self.valid_scores,
-                                  self.valid_metrics):
-                    st.append([m.eval_dev(su.score, self.objective)
-                               for m in ms])
-                return st
-            self._valid_eval_stash = (
-                _stash_evals() if pr is None
-                else pr.timed("eval", _stash_evals))
+            self._valid_eval_stash = [
+                [m.eval_dev(su.score, self.objective) for m in ms]
+                for su, ms in zip(self.valid_scores, self.valid_metrics)]
         if len(self._pending_numsplits) >= 16 * K:
             res = self._resolve_aligned_pending_mc()
             if res is not None:
@@ -1201,34 +967,20 @@ class GBDT:
         # applied flag, so a dispatch the host later discards (inexact
         # predecessor / fallback) contributed exactly 0 and the exact
         # fallback's host application stays correct
-        pr = self._prof_round
         for i, su in enumerate(self.valid_scores):
             # the whole [K, Nv] buffer is donated and updated in place
             # at lane 0 — no gather/scatter copy pair per valid set
-            if pr is not None:
-                su.score = pr.timed(
-                    "score_update", eng.apply_spec_to_scores,
-                    su.score, 0, self._valid_bins_dev[i], spec,
-                    applied_dev, self.shrinkage_rate)
-            else:
-                su.score = eng.apply_spec_to_scores(
-                    su.score, 0, self._valid_bins_dev[i], spec,
-                    applied_dev, self.shrinkage_rate)
+            su.score = eng.apply_spec_to_scores(
+                su.score, 0, self._valid_bins_dev[i], spec,
+                applied_dev, self.shrinkage_rate)
         if self.valid_scores:
             # queue the device metric programs for THIS iteration before
             # the eager next build: the device executes in queue order,
             # so eval scalars resolve right after the walks instead of
             # behind the whole next build
-            def _stash_evals():
-                st = []
-                for su, ms in zip(self.valid_scores,
-                                  self.valid_metrics):
-                    st.append([m.eval_dev(su.score, self.objective)
-                               for m in ms])
-                return st
-            self._valid_eval_stash = (
-                _stash_evals() if pr is None
-                else pr.timed("eval", _stash_evals))
+            self._valid_eval_stash = [
+                [m.eval_dev(su.score, self.objective) for m in ms]
+                for su, ms in zip(self.valid_scores, self.valid_metrics)]
             # train metrics likewise (valid_sets often include the train
             # set): queue device scalars over the materialized score
             # lane so per-iteration train eval doesn't have to discard
@@ -1325,18 +1077,8 @@ class GBDT:
         if eng._pgrad is None:
             # non-pointwise objective (ranking): gradients need ROW order
             # — materialize scores on device, compute, re-ingest by rid
-            pr = self._prof_round
-            if pr is not None:
-                # the materialization exists only to feed the ranking
-                # gradient, so both dispatches bill to the grad site
-                # (→ rank_grad for ranking objectives)
-                gd, hd = pr.timed(
-                    "objective.grad",
-                    lambda: self.objective.get_gradients(
-                        eng.row_scores_dev()[None, :]))
-            else:
-                scores = eng.row_scores_dev()
-                gd, hd = self.objective.get_gradients(scores[None, :])
+            scores = eng.row_scores_dev()
+            gd, hd = self.objective.get_gradients(scores[None, :])
             grads = (gd[0], hd[0])
         self._aligned_sample_stats = self._aligned_apply_sample(
             eng, sample, grads)

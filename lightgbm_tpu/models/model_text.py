@@ -43,8 +43,7 @@ _RUNTIME_ONLY_PARAMS = frozenset({
     "tpu_serve_port", "tpu_serve_qos", "tpu_serve_shed",
     "tpu_serve_shed_high", "tpu_serve_shed_low", "tpu_serve_admit_rows",
     "tpu_serve_devices", "tpu_serve_replicas",
-    "tpu_profile", "tpu_profile_every",
-    "tpu_profile_capture", "tpu_debug_locks",
+    "tpu_debug_locks",
     # timeline + straggler/anomaly watches: observability only
     "tpu_timeline", "tpu_straggler_threshold", "tpu_straggler_rounds",
     "tpu_anomaly_factor", "tpu_anomaly_window",

@@ -1,15 +1,13 @@
-"""Observability: span tracer, per-round metrics ledger, device-time
-measurement protocol, and crash-proof incremental bench records.
+"""Observability: span tracer and always-on seams, per-round metrics
+ledger, live metrics registry, HBM accountant and request traces.
 
-The subsystem is OFF by default and costs nothing when off: `trace.span`
-returns a shared null context, `trace.fence` returns its argument without
-importing jax, and the GBDT round loop takes a single attribute-is-None
-branch. Enable with the `tpu_trace` / `tpu_trace_dir` params (both enter
-`compile_cache.config_signature`, so toggling tracing retraces rather
-than silently reusing a differently-fenced program).
+The fenced tracer is OFF by default and costs nothing when off:
+`trace.span` returns a shared null context, `trace.fence` returns its
+argument without importing jax, and the GBDT round loop takes two
+attribute-is-None checks. Enable with the `tpu_trace` / `tpu_trace_dir`
+params (both enter `compile_cache.config_signature`, so toggling tracing
+retraces rather than silently reusing a differently-fenced program).
 """
-from . import (bench_record, devicetime, ledger, memory,  # noqa: F401
-               metrics, profiler, reqtrace, terms, trace)
+from . import ledger, memory, metrics, reqtrace, terms, trace  # noqa: F401
 
-__all__ = ["bench_record", "devicetime", "ledger", "memory", "metrics",
-           "profiler", "reqtrace", "terms", "trace"]
+__all__ = ["ledger", "memory", "metrics", "reqtrace", "terms", "trace"]

@@ -8,7 +8,7 @@ and ``parse_event`` consumers can rely on the catalog being closed: a
 kind that is not here is a bug, not a new feature.
 
 Why a catalog and not grep: event kinds are the join key between the
-ledger, the bench record, CI assertions (e.g. the serving smoke counts
+ledger, CI assertions (e.g. the serving smoke counts
 ``serve_swap`` notes) and offline tooling. A renamed or misspelled kind
 silently breaks those joins — drift used to be caught only by whichever
 test happened to parse the affected line, or not at all.
@@ -110,10 +110,6 @@ EVENTS: Dict[str, str] = {
                    "score buffers back onto the mesh",
     "dist_shard": "dataset sharded across the mesh: rows per shard, "
                   "per-device HBM bytes, bin-sync wall time",
-    "dist_straggler": "sustained per-device round-time imbalance "
-                      "(max/median over fenced per-shard segments) on "
-                      "profiled distributed rounds crossed or cleared "
-                      "the straggler threshold (edge-triggered)",
     "dist_stream": "stream-to-shard ingest finished: rows, mesh width, "
                    "chunk size, parse/bin walls + overlap efficiency of "
                    "the double-buffered pipeline, per-device shard "

@@ -14,25 +14,18 @@ Record kinds:
   Optional: ``gate_notes`` (e.g. "slot-hist spilled to HBM"),
   ``hist_spill`` bool, ``bag_cnt`` (bagging/GOSS sample size),
   ``finished`` (no-split stop flag), ``eval`` (folded in by the
-  ``log_telemetry`` callback after metrics run), and — on
-  profiler-sampled rounds only — ``profiled`` bool, ``terms_ms``
+  ``log_telemetry`` callback after metrics run), and — on a sweep's
+  trim rounds only (sweep/trainer.py) — ``profiled`` bool, ``terms_ms``
   (canonical per-term device ms, keys from ``obs.terms.TERMS``) and
   ``timing``, which names the round's device-time convention:
   ``"residual"`` (the default: ONE end-of-round fence, ``device_ms``
-  is the pipelined residual drain) vs ``"fenced"`` (profiler-sampled:
-  every dispatch site fenced individually, ``device_ms`` is the SUM of
-  fenced site times). The two are NOT comparable — a fenced round
-  serializes the pipeline — so readers (``tools/bench_compare.py``,
-  round-wall histograms) must split on ``timing``/``profiled`` before
+  is the pipelined residual drain) vs ``"fenced"`` (the round drained
+  the device before and after, ``device_ms`` is the whole fenced
+  dispatch). The two are NOT comparable — a fenced round serializes
+  the pipeline — so readers must split on ``timing`` before
   aggregating; records without the field are ``"residual"``.
-  Traced/profiled rounds also carry ``t0`` (raw ``perf_counter`` at
-  round start — the timeline's clock anchor, obs/timeline.py), and
-  profiler-sampled rounds of DISTRIBUTED runs with the timeline on
-  add ``device_ids``, ``device_terms_ms`` (per-term columns, one per
-  mesh device: fenced wait-attribution segments summing to the term's
-  aggregate), ``device_round_ms``, ``imbalance`` (max/median of the
-  per-device totals) and ``allreduce_split_ms`` (compute-vs-wait
-  split of the allreduce probe).
+  Rounds also carry ``t0`` (raw ``perf_counter`` at round start — the
+  timeline's clock anchor, obs/timeline.py).
 - ``eval``  — per-round metric values, appended by the callback seam
   (the round record is already flushed by then; the eval record carries
   the same ``round`` index so readers can join them).
@@ -55,33 +48,6 @@ ROUND_REQUIRED = ("round", "wall_ms", "device_ms", "traces", "path",
 _KINDS = ("run", "round", "eval", "note")
 
 _seq = 0
-
-
-def _validate_device_terms(dterms: Any) -> Optional[str]:
-    """None when `dterms` is a well-formed ``device_terms_ms`` dict —
-    canonical term keys, equal-length lists of non-negative numbers
-    (one column per mesh device, in ``device_ids`` order); else a
-    reason string. Committed only on profiler-sampled rounds of
-    distributed runs with the timeline on."""
-    if not isinstance(dterms, dict):
-        return f"must be a dict, got {type(dterms).__name__}"
-    from .terms import TERMS
-    width = None
-    for k, v in dterms.items():
-        if k not in TERMS:
-            return f"unknown term {k!r} (not in obs.terms.TERMS)"
-        if not isinstance(v, list) or not v:
-            return f"term {k!r} must map to a non-empty list"
-        if width is None:
-            width = len(v)
-        elif len(v) != width:
-            return (f"ragged device columns: term {k!r} has {len(v)} "
-                    f"entries, expected {width}")
-        for ms in v:
-            if not isinstance(ms, (int, float)) or isinstance(ms, bool) \
-                    or ms < 0:
-                return f"bad value for term {k!r}: {ms!r}"
-    return None
 
 
 def validate_record(rec: Dict[str, Any]) -> None:
@@ -107,15 +73,6 @@ def validate_record(rec: Dict[str, Any]) -> None:
             why = validate_terms_ms(rec["terms_ms"])
             if why is not None:
                 raise ValueError(f"bad terms_ms: {why}")
-        if "device_terms_ms" in rec:
-            why = _validate_device_terms(rec["device_terms_ms"])
-            if why is not None:
-                raise ValueError(f"bad device_terms_ms: {why}")
-        if "imbalance" in rec:
-            v = rec["imbalance"]
-            if not isinstance(v, (int, float)) or isinstance(v, bool) \
-                    or v < 0:
-                raise ValueError(f"bad imbalance: {v!r}")
         timing = rec.get("timing")
         if timing is not None and timing not in ("residual", "fenced"):
             raise ValueError(f"bad timing mode: {timing!r} "

@@ -1,11 +1,10 @@
 """Process-wide metrics plane: counters, gauges, and latency histograms
 behind one thread-safe registry, scrapeable while the process runs.
 
-The one-shot artifacts (span JSONL, round ledger, bench records) answer
-"what happened"; this module answers "what is happening" — the serving
+The one-shot artifacts (span JSONL, round ledger) answer "what
+happened"; this module answers "what is happening" — the serving
 exporter (`serving/exporter.py`) renders the same registry as Prometheus
-text on every scrape, `bst.metrics_snapshot()` returns it as a dict, and
-`bench.py` folds per-stage snapshots into the bench JSON.
+text on every scrape and `bst.metrics_snapshot()` returns it as a dict.
 
 Design constraints (same discipline as `obs/trace.py`):
 
@@ -18,7 +17,7 @@ Design constraints (same discipline as `obs/trace.py`):
   (2^-6 .. 2^14 ms), so p50/p99 estimates come from bucket
   interpolation with no per-observation allocation.
 - ``snapshot()`` emits a versioned schema (``SCHEMA_VERSION``) so the
-  CI scrape and bench_compare can validate shape, not just presence.
+  CI scrape can validate shape, not just presence.
 
 Labeled families: ``registry().counter(name, help, labelnames=("model",))``
 returns a family whose ``labels(model="ctr")`` child is created on first
@@ -453,11 +452,6 @@ def train_instruments() -> Any:
         "train_retry_events_total",
         "resilience retry events by outcome",
         labelnames=("event",))
-    ns.term_ms = r.gauge(
-        "train_term_ms",
-        "per-term fenced device ms of the last profiler-sampled round "
-        "(obs/profiler.py; term names from obs/terms.py)",
-        labelnames=("term",))
     return ns
 
 

@@ -2,8 +2,8 @@
 slow, and which model is burning its SLO budget right now?
 
 The metrics plane (obs/metrics.py) aggregates into histograms and the
-profiler (obs/profiler.py) samples training rounds — neither can answer
-a per-request question. This module is the missing layer: every
+round ledger (obs/ledger.py) records training rounds — neither can
+answer a per-request question. This module is the missing layer: every
 `RequestCoalescer.submit()` mints a trace ID whose span record
 accumulates, across the request's whole life,
 

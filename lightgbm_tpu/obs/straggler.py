@@ -5,8 +5,8 @@ some existing fence or ``perf_counter`` delta produced.
 Two detectors:
 
 ``ImbalanceWatch`` — sustained cross-actor imbalance with hysteresis.
-Fed the max/median ratio of per-device round times (profiled
-distributed rounds) or per-sub-fleet round walls (the batched sweep).
+Fed the max/median ratio of per-sub-fleet round walls (the batched
+sweep).
 ``update(ratio)`` returns ``"raised"`` exactly once after K
 consecutive samples at/above the threshold, ``"cleared"`` exactly once
 after K consecutive samples at/below the clear ratio, and ``None``
@@ -18,8 +18,7 @@ traced round's ``wall_ms``; fires when a wall exceeds ``factor`` x the
 trailing-window median. Anomalous walls are NOT folded into the window
 (a burst must not drag the median up to meet itself), and consecutive
 anomalies fire once (edge-triggered) — a run drifting into trouble
-says so near the FIRST bad round, while its bench budget still has
-room to react.
+says so near the FIRST bad round.
 """
 from __future__ import annotations
 

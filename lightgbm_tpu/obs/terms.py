@@ -1,30 +1,17 @@
 """Canonical device-time term vocabulary.
 
-One table names every term the framework can attribute device time to —
-the in-run profiler's ledger ``terms_ms`` keys, the metrics registry's
-per-term gauges, the bench record's ``terms_by_stage``, and the offline
-chained-k tools (``tools/device_time_r4.py`` / ``device_time_255.py`` /
-``profile_mslr.py``) all draw from THIS dict, so a number labelled
-"rank_grad" in a ledger and one in an offline tool's JSON line are the
-same quantity by construction (asserted by ``tests/test_profiler.py``).
-
-Two kinds of terms share the vocabulary:
-
-- **fenced terms** — measured in-run by fencing one dispatch site on a
-  sampled round (``SITE_TERMS`` maps the ``_dispatch_device`` site
-  string to its term). These are disjoint and sum to the sampled
-  round's fenced device total.
-- **calibration terms** — per-pass kernel costs measured standalone
-  under the chained-k protocol (offline tools, or the profiler's
-  in-run calibration over the live record store). They decompose the
-  fused ``build`` term in the report; they are rates, not round totals.
+One table names every term the framework can attribute device time or
+set-up wall to: the keys a ledger round record's ``terms_ms`` may hold
+(``obs/ledger.py`` validates against THIS dict; today the sweep's
+fenced trim rounds write ``sweep``) and the names the ingest and
+quantisation events report their walls under. The vocabulary is closed:
+a key that is not here is refused at commit.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-# term -> one-line description (the docs table in docs/Profiling.md is
-# generated from the same text)
+# term -> one-line description
 TERMS: Dict[str, str] = {
     # fenced (round-level, disjoint)
     "grad": "pointwise objective gradient + hessian pass",
@@ -50,7 +37,7 @@ TERMS: Dict[str, str] = {
     "flush": "marginal fused sub-binned hist accumulate + slot flush "
              "in the move pass (hist_move minus route)",
     "hist_move": "hist-accumulating move pass (minuend for flush; "
-                 "removed by TermTimer.derive)",
+                 "subtracted out)",
     "copy": "record-store copy move pass (no split, no hist)",
     "split_eval": "split finder over a changed-children histogram "
                   "batch",
@@ -73,35 +60,6 @@ TERMS: Dict[str, str] = {
              "builds + score updates in one dispatch); fenced only on "
              "trim rounds, where the sweep loop drains anyway",
 }
-
-# _dispatch_device site string -> fenced term. Sites not listed fall
-# back to "other" (they still count; the vocabulary stays closed).
-SITE_TERMS: Dict[str, str] = {
-    "objective.grad": "grad",
-    "engine.train_iter": "build",
-    "engine.train_iter_mc": "build",
-    "learner.train": "build",
-    "learner.train_fresh": "build",
-    "learner.train_iter_fused": "build",
-    "score_update": "score_update",
-    "eval": "eval",
-    "dist.allreduce": "allreduce",
-    "round_tail": "other",
-    "sweep.round": "sweep",
-}
-
-# objectives whose gradient pass is the ranking pair term
-RANKING_OBJECTIVES = frozenset({"lambdarank", "rank_xendcg"})
-
-
-def term_for_site(site: str, objective: str = "") -> str:
-    """Fenced term for a dispatch site; the gradient site promotes to
-    ``rank_grad`` for ranking objectives."""
-    term = SITE_TERMS.get(site, "other")
-    if term == "grad" and objective in RANKING_OBJECTIVES:
-        return "rank_grad"
-    return term
-
 
 def validate_terms_ms(terms: Any) -> Optional[str]:
     """None when `terms` is a well-formed ``terms_ms`` dict (canonical

@@ -2,13 +2,12 @@
 joined onto ONE monotonic clock as Chrome-trace / Perfetto JSON.
 
 The observability planes grew up siloed — span trace
-(``spans-<pid>.jsonl``), per-round ledger records with fenced
-``terms_ms`` (``ledger-*.jsonl``), request traces
-(``reqtrace-*.jsonl``), the streaming-ingest pipeline walls, sweep
-per-sub-fleet round dispatches, bench stage boundaries
-(``bench-*.jsonl`` notes + the BENCH record), and compile-cache miss
-events. Each answers its own question; none answers "where did the
-WALL-CLOCK of this run go, across subsystems, per device". This module
+(``spans-<pid>.jsonl``), per-round ledger records
+(``ledger-*.jsonl``), request traces (``reqtrace-*.jsonl``), the
+streaming-ingest pipeline walls, sweep per-sub-fleet round dispatches,
+and compile-cache miss events. Each answers its own question; none
+answers "where did the WALL-CLOCK of this run go, across subsystems".
+This module
 answers that: ``build_timeline`` reads whichever streams exist and
 emits one ``trace_events``-format document loadable in
 ``chrome://tracing`` or https://ui.perfetto.dev.
@@ -16,8 +15,8 @@ emits one ``trace_events``-format document loadable in
 **Clock model.** Every producer stamps ``t0`` with
 ``time.perf_counter()``. On Linux that is CLOCK_MONOTONIC — a single
 system-wide epoch shared by every process on the host — so spans from
-the trainer, the prefetch thread, a bench parent, and its multichip
-children all join WITHOUT cross-stream alignment: the timeline anchors
+the trainer and the prefetch thread join WITHOUT cross-stream
+alignment: the timeline anchors
 at the earliest ``t0`` seen and emits ``ts`` in microseconds relative
 to it. Rows from old producers that lack ``t0`` are placed
 end-to-start after their lane's cursor (ordered, not aligned) and
@@ -29,22 +28,19 @@ subsystem into parallel actors):
 ====== ========= ==================================================
 pid    lane      tid semantics
 ====== ========= ==================================================
-1      train     0 = round loop; 1+k = device k (per-device fenced
-                 segments of profiled distributed rounds)
+1      train     0 = round loop
 2      spans     host span trace (tid = span depth)
 3      serving   request spans (tid 0)
 4      ingest    0 = chunk wall, 1 = parse (prefetch thread),
                  2 = bin (device side)
 5      sweep     tid = sub-fleet id (per-sub-fleet round dispatches)
-6      bench     stage boundaries (tid 0)
 7      events    instant events (compile-cache misses, straggler /
                  anomaly raises, ...) (tid 0)
 ====== ========= ==================================================
 
 Reading is tolerant by construction: torn JSONL tails are dropped
-(mirroring ``obs.ledger.read_ledger``), absent streams contribute no
-lane, and a BENCH record may be the raw parsed dict or the driver
-wrapper (``{"n", "cmd", "rc", "tail", "parsed"}``). Building a
+(mirroring ``obs.ledger.read_ledger``) and absent streams contribute
+no lane. Building a
 timeline never touches jax and never fences — it is pure host-side
 file merging, usable on a machine that never ran the job.
 """
@@ -62,7 +58,7 @@ __all__ = ["LANES", "build_timeline", "collect_streams", "has_data",
 # lane name -> Chrome-trace pid (stable: Perfetto sorts by pid)
 LANES: Dict[str, int] = {
     "train": 1, "spans": 2, "serving": 3, "ingest": 4,
-    "sweep": 5, "bench": 6, "events": 7,
+    "sweep": 5, "events": 7,
 }
 
 # ingest tids within the ingest lane
@@ -106,37 +102,16 @@ def read_jsonl(path: str) -> List[Dict[str, Any]]:
     return rows
 
 
-def _load_bench(bench: Any) -> Optional[Dict[str, Any]]:
-    """Normalize a BENCH input (path / parsed dict / driver wrapper)
-    to the parsed record dict, or None."""
-    if bench is None:
-        return None
-    if isinstance(bench, str):
-        try:
-            with open(bench) as fh:
-                bench = json.load(fh)
-        except (OSError, ValueError):
-            return None
-    if not isinstance(bench, dict):
-        return None
-    if "parsed" in bench and "rc" in bench:     # driver wrapper
-        bench = bench.get("parsed")
-    return bench if isinstance(bench, dict) else None
-
-
 def collect_streams(trace_dir: Optional[str] = None,
-                    ledger_path: Optional[str] = None,
-                    bench: Any = None) -> Dict[str, Any]:
+                    ledger_path: Optional[str] = None
+                    ) -> Dict[str, Any]:
     """Gather every source stream that exists.
 
     ``trace_dir`` is scanned for ``spans-*.jsonl``, ``ledger-*.jsonl``,
-    ``reqtrace-*.jsonl``, ``events-*.jsonl`` and ``bench-*.jsonl``;
-    ``ledger_path`` adds one explicit ledger (deduplicated against the
-    scan); ``bench`` is a BENCH record (path, parsed dict, or driver
-    wrapper)."""
+    ``reqtrace-*.jsonl`` and ``events-*.jsonl``; ``ledger_path`` adds
+    one explicit ledger (deduplicated against the scan)."""
     streams: Dict[str, Any] = {
         "spans": [], "ledger": [], "reqtrace": [], "events": [],
-        "bench_ledger": [], "bench_record": _load_bench(bench),
     }
     ledger_files: List[str] = []
     if trace_dir and os.path.isdir(trace_dir):
@@ -151,9 +126,6 @@ def collect_streams(trace_dir: Optional[str] = None,
         for f in sorted(glob.glob(os.path.join(trace_dir,
                                                "events-*.jsonl"))):
             streams["events"].extend(read_jsonl(f))
-        for f in sorted(glob.glob(os.path.join(trace_dir,
-                                               "bench-*.jsonl"))):
-            streams["bench_ledger"].extend(read_jsonl(f))
     if ledger_path and os.path.abspath(ledger_path) not in (
             os.path.abspath(f) for f in ledger_files):
         ledger_files.append(ledger_path)
@@ -232,7 +204,7 @@ def _find_anchor(streams: Dict[str, Any]) -> float:
     """Earliest monotonic timestamp across every stream (0.0 when no
     stream carries one — everything then places sequentially)."""
     t0s: List[float] = []
-    for key in ("spans", "ledger", "events", "bench_ledger"):
+    for key in ("spans", "ledger", "events"):
         for r in streams.get(key, ()):
             v = r.get("t0")
             if isinstance(v, (int, float)):
@@ -260,14 +232,13 @@ def _fold_spans(b: _Builder, rows: List[Dict[str, Any]]) -> int:
 
 
 def _fold_ledger(b: _Builder, rows: List[Dict[str, Any]]
-                 ) -> Tuple[int, int, int]:
-    """Round records -> train lane (tid 0) + per-device lanes; sweep
-    records -> sweep lane per sub-fleet; bench-style stage notes ->
-    bench lane. Returns (train_rows, sweep_rows, device_lanes)."""
+                 ) -> Tuple[int, int]:
+    """Round records -> train lane; sweep records -> sweep lane per
+    sub-fleet; watch notes -> instants. Returns (train_rows,
+    sweep_rows)."""
     pid_t, pid_s = LANES["train"], LANES["sweep"]
     b.name_tid(pid_t, 0, "round loop")
     n_train = n_sweep = 0
-    dev_lanes: set = set()
     for r in rows:
         kind = r.get("kind")
         if kind == "round":
@@ -275,10 +246,6 @@ def _fold_ledger(b: _Builder, rows: List[Dict[str, Any]]
                     "timing": r.get("timing", "residual")}
             if "terms_ms" in r:
                 args["terms_ms"] = r["terms_ms"]
-            if "imbalance" in r:
-                args["imbalance"] = r["imbalance"]
-            if "allreduce_split_ms" in r:
-                args["allreduce_split_ms"] = r["allreduce_split_ms"]
             if r.get("path") == "sweep":
                 sid = int(r.get("subfleet", 0) or 0)
                 b.name_tid(pid_s, sid, f"sub-fleet {sid}")
@@ -292,35 +259,12 @@ def _fold_ledger(b: _Builder, rows: List[Dict[str, Any]]
                 b.span(pid_t, 0, f"round {r.get('round')}", r.get("t0"),
                        r.get("wall_ms", 0.0), "ledger", args)
                 n_train += 1
-                # derived per-device segments: device k's fenced
-                # wait-attribution share of this profiled round,
-                # stacked end-to-start so the lane tiles the round wall
-                devs = r.get("device_round_ms")
-                ids = r.get("device_ids")
-                if isinstance(devs, list) and devs:
-                    t0 = r.get("t0")
-                    off = 0.0
-                    for k, ms in enumerate(devs):
-                        did = (ids[k] if isinstance(ids, list)
-                               and k < len(ids) else k)
-                        tid = 1 + int(did)
-                        dev_lanes.add(tid)
-                        b.name_tid(pid_t, tid, f"device {did}")
-                        start = (t0 + off / 1e3
-                                 if isinstance(t0, (int, float))
-                                 else None)
-                        b.span(pid_t, tid,
-                               f"round {r.get('round')} d{did}",
-                               start, ms, "ledger.device",
-                               {"device": did})
-                        off += float(ms or 0.0)
-        elif kind == "note" and r.get("note") in (
-                "round_anomaly", "dist_straggler"):
+        elif kind == "note" and r.get("note") == "round_anomaly":
             b.instant(LANES["events"], 0, str(r["note"]), r.get("t0"),
                       "ledger.note",
                       {k: v for k, v in r.items()
                        if k not in ("kind", "note", "t0")})
-    return n_train, n_sweep, len(dev_lanes)
+    return n_train, n_sweep
 
 
 def _fold_reqtrace(b: _Builder, rows: List[Dict[str, Any]]) -> int:
@@ -385,60 +329,24 @@ def _fold_events(b: _Builder, rows: List[Dict[str, Any]]
     return n_ev, n_ing
 
 
-def _fold_bench(b: _Builder, notes: List[Dict[str, Any]],
-                record: Optional[Dict[str, Any]]) -> int:
-    """Bench stage boundaries: prefer the bench ledger's per-stage
-    notes (they carry monotonic t0/t1); fall back to the BENCH record's
-    ``stage_wall`` dict placed sequentially."""
-    pid = LANES["bench"]
-    b.name_tid(pid, 0, "stages")
-    n = 0
-    staged: set = set()
-    for r in notes:
-        if r.get("kind") != "note" or "stage" not in r:
-            continue
-        wall_ms = None
-        if isinstance(r.get("wall_s"), (int, float)):
-            wall_ms = float(r["wall_s"]) * 1e3
-        elif isinstance(r.get("t1"), (int, float)) and \
-                isinstance(r.get("t0"), (int, float)):
-            wall_ms = (r["t1"] - r["t0"]) * 1e3
-        b.span(pid, 0, str(r["stage"]), r.get("t0"), wall_ms or 0.0,
-               "bench", {"t_s": r.get("t_s")})
-        staged.add(r["stage"])
-        n += 1
-    walls = (record or {}).get("stage_wall")
-    if isinstance(walls, dict):
-        for stage, wall_s in walls.items():
-            if stage in staged or not isinstance(wall_s, (int, float)):
-                continue
-            b.span(pid, 0, str(stage), None, wall_s * 1e3,
-                   "bench.record")
-            n += 1
-    return n
-
-
 # ---------------------------------------------------------------------------
 def build_timeline(trace_dir: Optional[str] = None,
-                   ledger_path: Optional[str] = None,
-                   bench: Any = None) -> Dict[str, Any]:
+                   ledger_path: Optional[str] = None
+                   ) -> Dict[str, Any]:
     """The whole merge: collect streams, anchor the clock, fold every
     row into its lane. Returns the Chrome-trace document; inspect
     ``otherData.lanes`` for per-lane row counts (``has_data`` gates
     on them)."""
-    streams = collect_streams(trace_dir, ledger_path, bench)
+    streams = collect_streams(trace_dir, ledger_path)
     anchor = _find_anchor(streams)
     b = _Builder(anchor)
     n_spans = _fold_spans(b, streams["spans"])
-    n_train, n_sweep, n_dev = _fold_ledger(b, streams["ledger"])
+    n_train, n_sweep = _fold_ledger(b, streams["ledger"])
     n_req = _fold_reqtrace(b, streams["reqtrace"])
     n_ev, n_ing = _fold_events(b, streams["events"])
-    n_bench = _fold_bench(b, streams["bench_ledger"],
-                          streams["bench_record"])
     meta: List[Dict[str, Any]] = []
     lanes = {"spans": n_spans, "train": n_train, "sweep": n_sweep,
-             "serving": n_req, "events": n_ev, "ingest": n_ing,
-             "bench": n_bench}
+             "serving": n_req, "events": n_ev, "ingest": n_ing}
     for name, pid in LANES.items():
         if lanes.get(name):
             meta.extend(_meta(pid, name, b.tids.get(pid, {})))
@@ -451,7 +359,6 @@ def build_timeline(trace_dir: Optional[str] = None,
             "clock": "time.perf_counter (CLOCK_MONOTONIC)",
             "anchor_t0": anchor,
             "lanes": lanes,
-            "device_lanes": n_dev,
         },
     }
 

@@ -344,11 +344,10 @@ def fence(x):
 
 def force_fence(x):
     """Drain device work hanging off pytree `x` REGARDLESS of the
-    tracing flag — the in-run profiler's per-site fence on sampled
-    rounds (obs/profiler.py). Shares `_block` and `fence_count` with
+    tracing flag: what a tool that times a kernel by hand fences with
+    (tools/route_tile_sweep.py). Shares `_block` and `fence_count` with
     `fence()` so the tier-1 zero-fence assertion (monkeypatching
-    `_block`) covers profiler fences too: a run with the profiler off
-    must never reach here."""
+    `_block`) covers it too: a training run must never reach here."""
     global fence_count, _block
     if _block is None:
         import jax
@@ -361,8 +360,7 @@ def write(path: str, extra: Optional[Dict[str, Any]] = None) -> str:
     """Dump all completed spans (plus a summary header) to `path` as one
     JSON document — the CLI's end-of-training trace dump. `extra` keys
     merge into the top level (the CLI folds compile-cache hit/miss
-    totals and per-program miss attribution in here, so warm-up
-    forensics don't require a bench run)."""
+    totals and per-program miss attribution in here)."""
     by_name: Dict[str, Dict[str, float]] = {}
     for s in _spans:
         agg = by_name.setdefault(s["name"], {"count": 0, "total_ms": 0.0})

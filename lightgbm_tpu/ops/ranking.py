@@ -58,7 +58,7 @@ def bucket_queries(query_boundaries: np.ndarray, min_size: int = 8,
 
     Emits a `rank_buckets` log event (docs-per-bucket histogram and
     padded-pair waste %) at dataset construct time so ladder re-tuning
-    is data-driven instead of hand-derived each bench round.
+    is data-driven instead of hand-derived.
     """
     qb = np.asarray(query_boundaries, np.int64)
     counts = np.diff(qb)
@@ -70,8 +70,8 @@ def bucket_queries(query_boundaries: np.ndarray, min_size: int = 8,
     # 160 not 192, for at most ~9 extra compiled programs. BELOW 32 the
     # steps are pow2 only: the quarter rungs at 10/12/14/20/24/28 held
     # <2% of MSLR's pair work yet 6 of the ladder's ~15 compiled
-    # programs — measured cold-start XLA compiles for nothing (the r05
-    # mb=255 warm-up cliff; see ROUND7_NOTES.md). Above 256 the
+    # programs — cold-start XLA compiles for nothing (the 255-bin
+    # warm-up cliff: round 0 compiled 15 bucket programs). Above 256 the
     # ladder falls back to ~sqrt(2) spacing (pow2 + 1.5x midpoints) —
     # giant queries are rare enough that halved pair tensors no longer
     # pay for the extra compiles.

@@ -62,8 +62,7 @@ RUNTIME_ONLY_PARAMS = frozenset({
     "tpu_serve_port", "tpu_serve_qos", "tpu_serve_shed",
     "tpu_serve_shed_high", "tpu_serve_shed_low", "tpu_serve_admit_rows",
     "tpu_serve_devices", "tpu_serve_replicas",
-    "tpu_profile", "tpu_profile_every",
-    "tpu_profile_capture", "tpu_debug_locks",
+    "tpu_debug_locks",
     # timeline + straggler/anomaly watches (obs/timeline.py,
     # obs/straggler.py): observability of the run, not training math
     "tpu_timeline", "tpu_straggler_threshold", "tpu_straggler_rounds",
@@ -189,8 +188,8 @@ def read_manifest(directory: str) -> Optional[Dict[str, Any]]:
 
 class CheckpointManager:
     """Owns one checkpoint directory: periodic + preemption writes,
-    manifest maintenance, rolling retention, and write-cost accounting
-    (surfaced by bench.py's resume stage)."""
+    manifest maintenance, rolling retention, and write-cost
+    accounting."""
 
     def __init__(self, directory: str, keep: int = 3, freq: int = 10,
                  signature: str = "") -> None:

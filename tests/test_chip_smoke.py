@@ -8,6 +8,9 @@ import json
 import os
 import sys
 
+import numpy as np
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
@@ -55,6 +58,55 @@ def test_multichip_leg_on_cpu_mesh():
         gc.collect()
     assert res["shards"] == 4 and len(set(res["devices"])) == 4
     assert len(set(res["placed_bytes"]["bins"].values())) == 1
+
+
+def test_fixtures_are_seeded_and_shaped():
+    """The smoke's data comes from its own generators: one seed, one
+    data set, in the shapes the legs train."""
+    X, y = chip_smoke.synth_higgs(3000, 28)
+    X2, y2 = chip_smoke.synth_higgs(3000, 28)
+    assert X.shape == (3000, 28) and X.dtype == np.float32
+    assert y.shape == (3000,) and y.dtype == np.int8
+    assert set(np.unique(y)) == {0, 1}
+    assert np.array_equal(X, X2) and np.array_equal(y, y2)
+    assert not np.array_equal(X, chip_smoke.synth_higgs(3000, 28, seed=8)[0])
+
+    R, g, group = chip_smoke.synth_mslr(5000, 137)
+    R2, g2, group2 = chip_smoke.synth_mslr(5000, 137)
+    assert R.shape == (5000, 137) and R.dtype == np.float32
+    assert g.shape == (5000,) and g.dtype == np.float32
+    assert set(np.unique(g)) <= {0.0, 1.0, 2.0, 3.0, 4.0} and g.max() == 4.0
+    assert group.dtype == np.int32 and int(group.sum()) == 5000
+    assert 80 <= group[:-1].min() and group.max() < 160
+    assert np.array_equal(R, R2) and np.array_equal(g, g2)
+    assert np.array_equal(group, group2)
+
+
+def test_auc_of_against_the_benchmark_reference():
+    """`auc_of` is the rank-sum AUC of `benchmark/reference.py` except
+    that it leaves ties in `argsort`'s order where the reference gives
+    them their average rank: equal on distinct scores, and within half
+    a rank per tied pair of unlike labels otherwise."""
+    from benchmark import reference
+    rng = np.random.default_rng(2000)
+    score = rng.standard_normal(2000)
+    label = (score + rng.standard_normal(2000) > 0).astype(np.int8)
+    assert len(np.unique(score)) == 2000
+    assert chip_smoke.auc_of(score, label) == pytest.approx(
+        reference.auc(score, label), abs=1e-12)
+    tied = np.round(score, 1)
+    pos, neg = tied[label > 0], tied[label == 0]
+    vals, npos = np.unique(pos, return_counts=True)
+    nneg = np.array([(neg == v).sum() for v in vals])
+    unlike_tied_pairs = int((npos * nneg).sum())
+    assert unlike_tied_pairs > 1000          # the ties are really there
+    bound = 0.5 * unlike_tied_pairs / (len(pos) * len(neg))
+    assert abs(chip_smoke.auc_of(tied, label)
+               - reference.auc(tied, label)) <= bound + 1e-12
+    # ties among like labels only: the order within them cannot matter
+    like = np.where(label > 0, np.round(score, 1) + 100.0, score)
+    assert chip_smoke.auc_of(like, label) == pytest.approx(
+        reference.auc(like, label), abs=1e-12)
 
 
 def test_main_refuses_a_backend_without_a_chip(capsys):

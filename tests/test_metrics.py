@@ -1,14 +1,10 @@
 """Metrics plane (`obs/metrics.py`, `obs/memory.py`,
-`serving/exporter.py`, `tools/bench_compare.py`): registry semantics,
-HBM accounting with reconciliation, the scrape endpoint, the
-zero-overhead-when-off guarantee, the torn-tail ledger read, and the
-bench regression sentinel.
+`serving/exporter.py`): registry semantics, HBM accounting with
+reconciliation, the scrape endpoint, the zero-overhead-when-off
+guarantee and the torn-tail ledger read.
 """
 import gc
-import importlib.util
 import json
-import os
-import sys
 import time
 import urllib.error
 import urllib.request
@@ -23,17 +19,6 @@ from lightgbm_tpu.obs import memory as obs_memory
 from lightgbm_tpu.obs import metrics as obs_metrics
 from lightgbm_tpu.obs import trace as obs_trace
 from lightgbm_tpu.serving.exporter import MetricsExporter, PROM_CONTENT_TYPE
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_bench_compare():
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare", os.path.join(_REPO, "tools", "bench_compare.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
 
 @pytest.fixture(autouse=True)
 def _clean_plane():
@@ -405,101 +390,3 @@ def test_trace_write_extra_and_miss_attribution(tmp_path):
     assert "compile_cache" in doc
     assert isinstance(doc["compile_cache"]["miss_by_program"], dict)
     assert doc["summary"]["demo"]["count"] == 1
-
-
-# ---------------------------------------------------------------------------
-# bench_compare regression sentinel
-# ---------------------------------------------------------------------------
-
-def _wrap(n, parsed):
-    return {"n": n, "cmd": "bench", "rc": 0 if parsed else 124,
-            "tail": "", "parsed": parsed}
-
-
-def test_bench_compare_verdicts_and_gate(tmp_path):
-    bc = _load_bench_compare()
-    base = {"metric": "higgs_synth_500iter_s", "unit": "s",
-            "value": 300.0, "vs_baseline": 0.8, "auc": 0.7375}
-    worse = dict(base, value=390.0, auc=0.7300)
-    p1, p2 = str(tmp_path / "a.json"), str(tmp_path / "b.json")
-    json.dump(_wrap(1, base), open(p1, "w"))
-    json.dump(_wrap(2, worse), open(p2, "w"))
-    v = bc.compare([bc.load_record(p1), bc.load_record(p2)])
-    assert v["overall"] == "regressed"
-    assert v["metrics"]["value"]["verdict"] == "regressed"
-    assert v["metrics"]["value"]["delta_pct"] == 30.0
-    # 1% AUC drop trips the tight quality threshold, not the 5% timing one
-    assert v["metrics"]["auc"]["verdict"] == "regressed"
-    assert v["metrics"]["vs_baseline"]["verdict"] == "neutral"
-    out = str(tmp_path / "verdict.json")
-    assert bc.main([p1, p2, "--gate", "--out", out]) == 1
-    assert json.load(open(out))["overall"] == "regressed"
-    # unchanged records pass the gate
-    assert bc.main([p1, p1, "--gate"]) == 0
-
-
-def test_bench_compare_normalizes_absent_and_skipped(tmp_path):
-    bc = _load_bench_compare()
-    old = {"value": 300.0, "vs_baseline": 0.8, "ndcg10": 0.5}
-    new = {"value": 290.0, "vs_baseline": 0.82, "predict_speedup": 3.0,
-           "stage_skips": {"mslr": "budget"}}
-    p1, p2 = str(tmp_path / "a.json"), str(tmp_path / "b.json")
-    json.dump(old, open(p1, "w"))
-    json.dump(new, open(p2, "w"))
-    v = bc.compare([bc.load_record(p1), bc.load_record(p2)])
-    # the candidate dropped ndcg10 via a recorded stage skip: absent with
-    # the reason, never a regression
-    assert v["metrics"]["ndcg10"]["verdict"] == "absent"
-    assert "skipped" in v["metrics"]["ndcg10"]["note"]
-    assert "budget" in v["metrics"]["ndcg10"]["note"]
-    # a metric only the candidate carries has nothing to compare against
-    assert v["metrics"]["predict_speedup"]["verdict"] == "absent"
-    assert v["overall"] == "neutral"
-
-
-def test_bench_compare_incomplete_records_excluded(tmp_path):
-    bc = _load_bench_compare()
-    p1 = str(tmp_path / "r1.json")
-    p2 = str(tmp_path / "r2.json")
-    json.dump(_wrap(1, {"value": 1.0}), open(p1, "w"))
-    json.dump(_wrap(2, None), open(p2, "w"))          # timed-out round
-    v = bc.compare([bc.load_record(p1), bc.load_record(p2)])
-    assert v["overall"] == "insufficient"
-    assert v["incomplete"] == ["r02"]
-    assert bc.main([p1, p2]) == 2
-
-
-def test_bench_compare_series_trajectory(tmp_path):
-    """A five-record driver series (the shape of the pre-round r01-r05
-    records, rebuilt inline) must read as: Higgs improving (0.146x ->
-    0.825x of baseline), MSLR flat (0.341x), the timed-out fifth record
-    excluded as incomplete."""
-    higgs = {"metric": "higgs_synth_500iter_s", "unit": "s"}
-    series = [
-        dict(higgs, value=1630.93, vs_baseline=0.146),
-        dict(higgs, value=1631.07, vs_baseline=0.146),
-        dict(higgs, value=426.43, vs_baseline=0.559, auc=0.737437,
-             value_255bin=554.25, ndcg10=0.610759, mslr_500iter_s=631.69,
-             mslr_vs_baseline=0.341),
-        dict(higgs, value=289.22, vs_baseline=0.825, auc=0.737585,
-             value_255bin=363.03, ndcg10=0.610759, mslr_500iter_s=631.99,
-             mslr_vs_baseline=0.341),
-        None,                                         # rc=124, parsed:null
-    ]
-    paths = []
-    for i, parsed in enumerate(series, 1):
-        paths.append(str(tmp_path / f"BENCH_r{i:02d}.json"))
-        json.dump(_wrap(i, parsed), open(paths[-1], "w"))
-    bc = _load_bench_compare()
-    v = bc.compare([bc.load_record(p) for p in paths])
-    assert v["incomplete"] == ["r05"]
-    assert v["base"] == "r01" and v["candidate"] == "r04"
-    m = v["metrics"]
-    assert m["vs_baseline"]["verdict"] == "improved"
-    assert m["vs_baseline"]["trajectory"] == "improved"
-    assert m["value"]["verdict"] == "improved"
-    assert m["mslr_vs_baseline"]["verdict"] == "neutral"
-    assert m["mslr_vs_baseline"]["trajectory"] == "flat"
-    assert m["mslr_vs_baseline"]["base_record"] == "r03"
-    assert v["overall"] == "improved"
-    assert v["counts"]["regressed"] == 0

@@ -1,21 +1,16 @@
 """Telemetry subsystem (`lightgbm_tpu.obs`): ledger schema, per-round
-records on both training paths, the zero-fence disabled guarantee, and
-crash-proof bench records.
+records on both training paths and the zero-fence disabled guarantee.
 """
 import glob
 import json
 import os
-import signal
-import subprocess
-import sys
-import textwrap
 import time
 
 import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.obs import bench_record, ledger as obs_ledger
+from lightgbm_tpu.obs import ledger as obs_ledger
 from lightgbm_tpu.obs import trace as obs_trace
 
 ALIGNED = {"tpu_grow_mode": "aligned", "tpu_aligned_interpret": True,
@@ -119,6 +114,25 @@ def _check_rounds(tmp_path, led, rounds, aligned):
     return rr, disk
 
 
+def test_ledger_timing_mode_validation():
+    base = {"kind": "round", "round": 0, "wall_ms": 1.0,
+            "device_ms": 0.5, "traces": 0, "path": "fused",
+            "aligned": False, "fallbacks": 0, "trees": 1}
+    obs_ledger.validate_record(dict(base, timing="residual"))
+    obs_ledger.validate_record(dict(base, timing="fenced",
+                                    profiled=True,
+                                    terms_ms={"sweep": 0.5}))
+    with pytest.raises(ValueError, match="timing"):
+        obs_ledger.validate_record(dict(base, timing="banana"))
+    with pytest.raises(ValueError, match="profiled"):
+        obs_ledger.validate_record(dict(base, profiled="yes"))
+    with pytest.raises(ValueError, match="terms_ms"):
+        obs_ledger.validate_record(dict(base,
+                                        terms_ms={"not_a_term": 1.0}))
+    with pytest.raises(ValueError, match="terms_ms"):
+        obs_ledger.validate_record(dict(base, terms_ms={"sweep": "x"}))
+
+
 def test_round_records_fused_path(tmp_path):
     _, led = _train_traced(
         tmp_path, {"bagging_fraction": 0.8, "bagging_freq": 1},
@@ -190,62 +204,6 @@ def test_disabled_training_issues_zero_fences(monkeypatch):
     assert calls == [], "untraced training called the tracing fence"
     assert obs_trace.fence_count == 0
     assert obs_trace.spans() == []
-
-
-# ---------------------------------------------------------------------------
-# crash-proof bench records
-# ---------------------------------------------------------------------------
-
-def test_bench_recorder_stage_flow(tmp_path):
-    out = {"metric": "demo_s", "value": None}
-    path = str(tmp_path / "B.json")
-    rec = bench_record.BenchRecorder(out, path=path, install_traps=False)
-    assert out["incomplete"] is True and out["stage_reached"] is None
-    rec.start_stage("alpha")
-    assert json.load(open(path))["stage_reached"] == "alpha"
-    out["value"] = 1.25
-    rec.stage_done("alpha")
-    rec.start_stage("beta")
-    d = json.load(open(path))
-    assert d["stages_done"] == ["alpha"] and d["stage_reached"] == "beta"
-    assert d["incomplete"] is True and d["value"] == 1.25
-    rec.stage_done("beta")
-    rec.finalize()
-    d = json.load(open(path))
-    assert d["incomplete"] is False
-    assert d["stages_done"] == ["alpha", "beta"]
-    assert not glob.glob(path + ".tmp*"), "atomic tmp file left behind"
-
-
-def test_bench_recorder_survives_sigterm(tmp_path):
-    """A killed run leaves a parseable sidecar: completed stages +
-    incomplete: true + the interrupting signal, and the process still
-    dies by SIGTERM (rc preserved via SIG_DFL re-kill)."""
-    path = str(tmp_path / "K.json")
-    script = textwrap.dedent(f"""
-        import json, os, signal, sys, time
-        sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
-        from lightgbm_tpu.obs.bench_record import BenchRecorder
-        out = {{"metric": "demo_s", "value": None}}
-        rec = BenchRecorder(out, path={path!r})
-        rec.start_stage("alpha")
-        out["value"] = 2.5
-        rec.stage_done("alpha")
-        rec.start_stage("beta")
-        os.kill(os.getpid(), signal.SIGTERM)
-        time.sleep(30)   # never reached
-        rec.finalize()
-    """)
-    proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, timeout=60)
-    assert proc.returncode == -signal.SIGTERM, \
-        (proc.returncode, proc.stderr.decode()[-500:])
-    d = json.load(open(path))
-    assert d["incomplete"] is True
-    assert d["stages_done"] == ["alpha"]
-    assert d["stage_reached"] == "beta"
-    assert d["interrupted_by"] == "SIGTERM"
-    assert d["value"] == 2.5
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Serving service (lightgbm_tpu.serving): model registry with HBM-budget
 LRU eviction, request coalescer SLO behavior, checkpoint watcher under a
-concurrent writer, zero-downtime hot swap, and the bench BudgetGate.
+concurrent writer and zero-downtime hot swap.
 """
 import json
 import os
@@ -12,7 +12,6 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu import compile_cache
-from lightgbm_tpu.obs.bench_record import BudgetGate
 from lightgbm_tpu.obs.ledger import RoundLedger
 from lightgbm_tpu.serving import (CheckpointWatcher, ModelRegistry,
                                   RequestCoalescer, ServingService)
@@ -474,42 +473,6 @@ def test_cli_serve_requires_a_model_source():
     from lightgbm_tpu.cli import Application
     with pytest.raises(LightGBMError):
         Application(["task=serve", "verbosity=-1"]).run()
-
-
-# -------------------------------------------------------------- BudgetGate
-
-def test_budget_gate_adaptive_skip():
-    clock = [0.0]
-    g = BudgetGate(100.0, reserve_frac=0.05, clock=lambda: clock[0])
-    assert g.left() == pytest.approx(95.0)
-    ok, why = g.allow("s1", est_s=90.0)
-    assert ok and why is None
-    g.start("s1")
-    clock[0] = 60.0
-    assert g.done("s1") == pytest.approx(60.0)
-    assert g.wall("s1") == pytest.approx(60.0)
-    # 40s estimate > 35s usable left: adaptive skip BEFORE starting
-    ok, why = g.allow("s2", est_s=40.0)
-    assert not ok and "adaptive skip" in why
-    ok, _ = g.allow("s2", est_s=10.0)
-    assert ok
-    clock[0] = 96.0
-    ok, why = g.allow("s3")
-    assert not ok and "exhausted" in why
-
-
-def test_budget_gate_scale_iters_and_unbounded():
-    clock = [0.0]
-    g = BudgetGate(100.0, reserve_frac=0.0, clock=lambda: clock[0])
-    # 100s left, frac=0.5 -> 50s usable, 2s/iter -> 25 iters max
-    assert g.scale_iters(40, 2.0) == 25
-    assert g.scale_iters(10, 2.0) == 10          # base already fits
-    clock[0] = 99.0
-    assert g.scale_iters(40, 2.0, floor=3) == 3  # floor, not zero
-    unbounded = BudgetGate(0.0)
-    assert unbounded.left() is None
-    assert unbounded.allow("x", est_s=1e9) == (True, None)
-    assert unbounded.scale_iters(40, 2.0) == 40
 
 
 # ------------------------------------------------- compile-cache miss events

@@ -9,10 +9,6 @@ carried. The HBM spill ring (2-deep staging DMA in move_pass when the
 split: aligned training with a forced-tiny budget reproduces the
 leaf-wise reference bit-for-bit at the tree level.
 """
-import json
-import os
-import subprocess
-import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -199,22 +195,3 @@ def test_subbin_efb_aligned_matches_leafwise_255bin():
         preds[mode] = bst.predict(X[:800], raw_score=True)
     np.testing.assert_allclose(preds["aligned"], preds["leafwise"],
                                rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_device_time_255_smoke():
-    """tools/device_time_255.py emits a parseable per-term breakdown on
-    a tiny interpret-mode shape."""
-    tool = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools", "device_time_255.py")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", DT255_ROWS="2048",
-               DT255_FEATURES="8", DT255_CHUNK="512", DT255_SPLITK="2",
-               DT255_REPS="1", DT255_CHAIN="2", DT255_INTERPRET="1")
-    res = subprocess.run([sys.executable, tool], env=env,
-                         capture_output=True, text=True, timeout=900)
-    assert res.returncode == 0, res.stderr[-2000:]
-    rec = json.loads(res.stdout.strip().splitlines()[-1])
-    assert rec["max_bin"] == 255
-    assert rec["subbin"] is True
-    for k in ("hist", "route", "flush", "split_eval"):
-        assert k in rec["terms_ms"], rec

@@ -11,7 +11,7 @@ features, max_bin=63) and a binned matrix (default N=100k rows), then times
 * the serving engine: device-resident forest, depth-synchronized [T, N]
   traversal, fused gather/accumulate, shape-bucketed jit cache.
 
-Importable as `run(...)` (bench.py's predict stage) or a CLI:
+Importable as `run(...)` or a CLI:
 
     JAX_PLATFORMS=cpu python tools/bench_predict.py
 

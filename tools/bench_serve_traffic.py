@@ -27,8 +27,7 @@ current backend:
   deliberately-unmeetable SLO asserting that load shedding trips
   (shed ratio recorded) and that gold traffic is NEVER shed.
 
-Importable as `run(...)` (bench.py's serve_traffic stage and the CI
-smoke both call it) or a CLI:
+Importable as `run(...)` (the CI smoke calls it) or a CLI:
 
     JAX_PLATFORMS=cpu python tools/bench_serve_traffic.py
 

@@ -40,7 +40,7 @@ JSON_SCHEMA_VERSION = 1
 # root. tests/ is deliberately absent: fixtures there VIOLATE the
 # invariants on purpose.
 DEFAULT_SCAN: Tuple[str, ...] = (
-    "lightgbm_tpu", "tools", "bench.py", "__graft_entry__.py")
+    "lightgbm_tpu", "tools", "__graft_entry__.py")
 _SKIP_DIRS = {"__pycache__", ".git", "build", "dist"}
 
 _SUPPRESS_RE = re.compile(

@@ -1,24 +1,18 @@
 """LGT005 — vocabulary drift.
 
 Structured observability only works while the vocabulary is closed:
-dashboards, the bench sentinel, and the trace analyzer all match on
-exact strings. Two catalogs anchor it:
+dashboards and the trace analyzer match on exact strings. One catalog
+anchors it: `obs/events.py` EVENTS — every `log.event(kind, ...)` kind.
+A kind missing from the catalog is either a typo (the event silently
+never matches any consumer) or an undocumented addition.
 
-* `obs/events.py` EVENTS — every `log.event(kind, ...)` kind. A kind
-  missing from the catalog is either a typo (the event silently never
-  matches any consumer) or an undocumented addition;
-* `obs/terms.py` TERMS — the device-time attribution vocabulary;
-  SITE_TERMS must map into it, or a profiler site charges time to a
-  term no report knows.
-
-Checks, anchored on whichever catalogs are present in the scanned set:
+Checks, when the catalog is present in the scanned set:
 
 * literal `log.event("kind", ...)` kinds must be EVENTS keys;
 * a NON-literal kind argument is flagged too — pass-through helpers
   (registry._note) carry an inline suppression plus the runtime
   `__debug__` validation in log.event, which is the dynamic half of
-  this rule;
-* every SITE_TERMS value must be a TERMS key.
+  this rule.
 """
 from __future__ import annotations
 
@@ -75,20 +69,4 @@ def check(files: List[FileInfo]) -> List[Finding]:
                         f"obs/events.py catalog — typo, or an "
                         f"uncatalogued addition"))
 
-    terms_fi = find_file(files, "obs/terms.py")
-    terms = _catalog(files, "obs/terms.py", "TERMS")
-    if terms_fi is not None and terms_fi.tree is not None and \
-            terms is not None:
-        site = _common.module_assign(terms_fi.tree, "SITE_TERMS")
-        if isinstance(site, ast.Dict):
-            for key, val in zip(site.keys, site.values):
-                v = _common.str_const(val)
-                if v is not None and v not in terms:
-                    k = _common.str_const(key) if key is not None \
-                        else None
-                    out.append(Finding(
-                        RULE, terms_fi.relpath, val.lineno,
-                        f"SITE_TERMS[{k!r}] maps to {v!r} which is "
-                        f"not a TERMS key — that site's device time "
-                        f"would be unreportable"))
     return out

@@ -1,14 +1,12 @@
 #!/usr/bin/env python
 """Full-scale reference head-to-head: train the ACTUAL reference binary
-on the bench's exact synthetic HIGGS data (10.5M x 28, seed 7) for 500
+on chip_smoke.py's synthetic HIGGS data (10.5M x 28, seed 7) for 500
 iterations / 255 leaves at max_bin 63 AND 255, score the 500K holdout,
 and cache the AUCs to docs/ref_full_auc.json.
 
-The bench host has ONE CPU core, so this takes hours — it runs
-out-of-band (once per round) and bench.py reads the cached reference
-AUCs while computing OUR full-500-iteration AUCs live on the TPU. The
-bench data is deterministic (seed 7), so the comparison is apples-to-
-apples; the JSON records the protocol for the judge.
+On a one-core host this takes hours, so it runs out-of-band. The data
+is deterministic (seed 7), so a run of this repo on the same rows
+compares like with like; the JSON records the protocol.
 
 python tools/ref_full_headtohead.py [--bins 63,255] [--iters 500]
 """
@@ -59,9 +57,9 @@ def main():
     from test_reference_parity import _ensure_cli, CLI
     assert _ensure_cli(), "reference CLI could not be built"
 
-    import bench
+    import chip_smoke
     t0 = time.perf_counter()
-    Xall, yall = bench.synth_higgs(N + NH, F)
+    Xall, yall = chip_smoke.synth_higgs(N + NH, F)
     log(f"# gen {time.perf_counter() - t0:.1f}s")
     td = tempfile.mkdtemp(prefix="ref_full_")
     train_p = os.path.join(td, "train.tsv")
@@ -72,8 +70,8 @@ def main():
     del Xall, yall
 
     out = {"protocol": {
-        "data": "bench.synth_higgs(11M, 28, seed 7); first 10.5M train, "
-                "last 500K holdout (the bench's exact split)",
+        "data": "chip_smoke.synth_higgs(11M, 28, seed 7); first 10.5M "
+                "train, last 500K holdout",
         "config": f"num_leaves {LEAVES}, learning_rate 0.1, "
                   f"min_data_in_leaf 20, num_trees {iters}",
         "reference": "the CLI built from /root/reference by "
@@ -111,7 +109,7 @@ def main():
             fh.write("\n".join(pconf))
         subprocess.run([CLI, f"config={cpath}"], check=True, timeout=3600)
         pred = np.loadtxt(os.path.join(td, "pred.txt"))
-        auc = bench.auc_of(pred, hy)
+        auc = chip_smoke.auc_of(pred, hy)
         log(f"# ref full AUC mb={mb}: {auc:.6f}")
         out[f"auc_ref_full_{mb}bin"] = round(float(auc), 6)
         out[f"ref_train_1core_s_{mb}bin"] = round(tt, 1)

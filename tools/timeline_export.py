@@ -2,17 +2,14 @@
 """Export the unified run timeline as Chrome-trace JSON.
 
 Merges every wall-clock stream a run left behind — span trace, round
-ledger (with per-device columns on profiled dist rounds), request
-trace, ingest pipeline events, sweep sub-fleet rounds, bench stage
-notes — onto one monotonic clock (obs/timeline.py) and writes a
+ledger, request trace, ingest pipeline events, sweep sub-fleet rounds —
+onto one monotonic clock (obs/timeline.py) and writes a
 ``trace_events`` document that Perfetto (https://ui.perfetto.dev) and
 ``chrome://tracing`` open directly.
 
-  --trace-dir DIR   a tpu_trace / BENCH_TRACE directory; scanned for
-                    spans-/ledger-/reqtrace-/events-/bench-*.jsonl
+  --trace-dir DIR   a tpu_trace directory; scanned for
+                    spans-/ledger-/reqtrace-/events-*.jsonl
   --ledger PATH     one explicit round-ledger JSONL (added to the scan)
-  --bench PATH      a BENCH record (parsed dict or driver wrapper) —
-                    stage walls become the bench lane
   --out PATH        output path (default: <trace-dir>/timeline.json,
                     or ./timeline.json without a trace dir)
   --pretty          indent the JSON (bigger file, diffable)
@@ -39,7 +36,6 @@ def main(argv=None):
         description="merge run telemetry into Chrome-trace JSON")
     ap.add_argument("--trace-dir", default="")
     ap.add_argument("--ledger", default="")
-    ap.add_argument("--bench", default="")
     ap.add_argument("--out", default="")
     ap.add_argument("--pretty", action="store_true")
     args = ap.parse_args(argv)
@@ -47,8 +43,7 @@ def main(argv=None):
     from lightgbm_tpu.obs import timeline
 
     doc = timeline.build_timeline(args.trace_dir or None,
-                                  args.ledger or None,
-                                  args.bench or None)
+                                  args.ledger or None)
     out = args.out or os.path.join(args.trace_dir or ".",
                                    "timeline.json")
     if args.pretty:
@@ -64,11 +59,8 @@ def main(argv=None):
     n_ev = len(doc.get("traceEvents", []))
     log(f"# timeline: {out} ({n_ev} events; lanes: "
         f"{populated or 'NONE'})")
-    ndev = doc.get("otherData", {}).get("device_lanes", 0)
-    if ndev:
-        log(f"# per-device lanes: {ndev}")
     if not timeline.has_data(doc):
-        log("# no lane has data (need --trace-dir/--ledger/--bench "
+        log("# no lane has data (need --trace-dir/--ledger "
             "pointing at a traced run)")
         return 2
     return 0
