@@ -191,7 +191,7 @@ class AlignedEngine:
         # route words (destinations pack 16-bit, capping NC at 65k
         # chunks); move_pass partitions it in sub-tiles of route_tile(C)
         # rows, so the permutation matmul no longer grows with it
-        from ..ops.aligned import chunk_for, route_tile
+        from ..ops.aligned import ROUTE_SELECTORS, chunk_for, route_tile
         self.C = C = chunk_for(self.cfg, learner.num_features, learner.n)
         # host work over all rows: what the readers of this seam need
         # of the layout rides on it
@@ -203,7 +203,8 @@ class AlignedEngine:
                 bytes=nbytes, W=int(self.W), w_used=int(self.w_used),
                 C=int(C), NC=int(self.NC), bits=int(self.bits),
                 shards=int(self.nd), count_pass=self.count_pass,
-                route_tile=route_tile(C), route_tiles=C // route_tile(C))
+                route_tile=route_tile(C), route_tiles=C // route_tile(C),
+                route_selectors=ROUTE_SELECTORS)
         # the span is the ENQUEUE of the transfer: nothing here waits for
         # it, so what the host does not copy synchronously lands in the
         # first program's wait (the first train.drain)

@@ -121,6 +121,11 @@ def chunk_for(cfg, num_features: int, n: int) -> int:
 # 17.2 (63 bins: 8.3 / 8.6 / - / 14.5); HIGGS record at C=1024:
 # 2.47 / 2.50 / 2.95; 128 ran 2 us behind 256 in an earlier sweep.
 ROUTE_TILE = 256
+# Blocks the split path's route matmul selects for a tile: ONE, holding
+# the rows of both sides and of both ring windows a side's rows can
+# reach (route_tile_rows says why that is exact). Static, so it rides the
+# `aligned.pack` seam as `route_selectors`: it dates a trace.
+ROUTE_SELECTORS = 1
 
 
 def route_tile(chunk: int) -> int:
@@ -781,15 +786,24 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
             k_r = jnp.sum(valid.astype(jnp.int32)) - k_l
 
             # a side's rows of one tile land in ONE contiguous interval
-            # of its 2C ring, at most S long, so in at most two of the
-            # ring's S-aligned windows: the one the cursor is in (0) and
-            # the next (1). q = offset from the start of window 0.
+            # [a, a + k) of its 2C ring, a < S and k <= S, so in at most
+            # two of the ring's S-aligned windows: the one the cursor is
+            # in (0) and the next (1).
             a_l = cur_l % S
             a_r = cur_r % S
-            q = jnp.where(left, a_l + rank_l, a_r + rank_r)   # < 2S
-            over = (q >= S).astype(jnp.int32)
-            lo_of = q - S * over
-            grp = jnp.where(valid, jnp.where(left, 0, 2) + over, 4)
+            # ONE selector for the whole tile. An interval no longer
+            # than S is injective modulo S, so a side's rows never share
+            # an offset modulo S whichever window they fall in, and one
+            # [U, S] block holds both windows' rows of the side at their
+            # final offsets: the two masks of the merge below pick each
+            # window's part out of it (disjoint: a + k - S <= a). And
+            # k_l + k_r <= S, so both sides fit ONE block: left rows at
+            # their own offsets, right rows on the cyclic interval that
+            # starts where the left one ends (e_l), rotated to their own
+            # offsets after the matmul. Invalid rows select no offset.
+            e_l = (a_l + k_l) % S
+            q = jnp.where(left, a_l + rank_l, e_l + rank_r)   # < 2S
+            lo_of = jnp.where(valid, jnp.where(q >= S, q - S, q), -1)
 
             # only the USED lanes ride the route matmul (w_used <=
             # w_pad: 8-sublane padding and, under the compact layout,
@@ -803,46 +817,41 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
             planes = jnp.concatenate(
                 [((rec[:U] >> (8 * b)) & 255).astype(jnp.int8)
                  for b in range(4)], axis=0)                  # [4U, S]
-            # FACTORED route: (side, window) x offset in the window. A
-            # flat one-hot over the four windows costs 4S int32 compares
-            # per row on the VPU (measured the dominant term of the
-            # split path); factoring into a per-window payload split (4
-            # compares + 4*4U products per row) times ONE [S, S] one-hot
-            # (S compares) cuts the VPU work ~3x at identical MXU MACs.
-            # Exact: each output (window, offset) receives a single
-            # term < 256.
-            Z = jnp.concatenate(
-                [jnp.where((grp == g)[None, :], planes, 0)
-                 for g in range(4)], axis=0)                  # [4*4U, S]
             # the one-hot is built TRANSPOSED, [offset, row]: the row's
             # offset broadcasts along sublanes as it lies, where
             # [row, offset] needs a lane -> sublane relayout first
-            # (measured 0.6 us a chunk), and the MXU contracts either
+            # (measured 0.6 us a chunk), and the MXU contracts either.
+            # Exact: each output offset receives a single term < 256.
             iota_o = lax.broadcasted_iota(jnp.int32, (S, S), 0)
             oh_lo = (lo_of[None, :] == iota_o).astype(jnp.int8)
-            moved = lax.dot_general(Z, oh_lo, (((1,), (1,)), ((), ())),
+            moved = lax.dot_general(planes, oh_lo,
+                                    (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.int32)
+            blk = moved & 255
+            mrows_l = (blk[:U] | (blk[U:2 * U] << 8)
+                       | (blk[2 * U:3 * U] << 16) | (blk[3 * U:] << 24))
+            if U < w_pad:
+                mrows_l = jnp.concatenate(
+                    [mrows_l, jnp.zeros((w_pad - U, S), jnp.int32)],
+                    axis=0)
+            # the right rows, from e_l + rank to a_r + rank (modulo S)
+            mrows_r = pltpu.roll(mrows_l, (a_r - e_l + S) % S, 1)
 
-            for g in range(4):
-                side, w = divmod(g, 2)
-                blk = moved[g * 4 * U:(g + 1) * 4 * U] & 255
-                mrows = (blk[:U] | (blk[U:2 * U] << 8)
-                         | (blk[2 * U:3 * U] << 16) | (blk[3 * U:] << 24))
-                if U < w_pad:
-                    mrows = jnp.concatenate(
-                        [mrows, jnp.zeros((w_pad - U, S), jnp.int32)],
-                        axis=0)
-                a, k, cur_s = ((a_l, k_l, cur_l), (a_r, k_r, cur_r))[side]
-                if w == 0:
-                    m = (posS >= a) & (posS < a + k)
-                else:
-                    m = posS < a + k - S
-                # window -> staging chunk and lane offset (a dynamic
-                # multiple of S >= 128, which Mosaic can slice)
-                win = ((cur_s % (2 * C)) // S + w) % (2 * T)
-                dst = (side * 2 + win // T, slice(None),
-                       pl.ds(pl.multiple_of((win % T) * S, S), S))
-                stag[dst] = jnp.where(m[None, :], mrows, stag[dst])
+            for side, (mrows_side, a, k, cur_s) in enumerate((
+                    (mrows_l, a_l, k_l, cur_l),
+                    (mrows_r, a_r, k_r, cur_r))):
+                for w in range(2):
+                    if w == 0:
+                        m = (posS >= a) & (posS < a + k)
+                    else:
+                        m = posS < a + k - S
+                    # window -> staging chunk and lane offset (a dynamic
+                    # multiple of S >= 128, which Mosaic can slice)
+                    win = ((cur_s % (2 * C)) // S + w) % (2 * T)
+                    dst = (side * 2 + win // T, slice(None),
+                           pl.ds(pl.multiple_of((win % T) * S, S), S))
+                    stag[dst] = jnp.where(m[None, :], mrows_side,
+                                          stag[dst])
             return cur_l + k_l, cur_r + k_r
 
         # tiles past the chunk's last row hold nothing to route

@@ -6,6 +6,13 @@ training-level test pins `tpu_chunk = 256`, where the loop has one trip;
 here hand-built records and route words go through the kernel at
 C = 1, 2 and 4 tiles and are compared, bit for bit, with a numpy stable
 partition, and the fused histograms with a numpy histogram.
+
+The route matmul selects ONE block a tile, which holds the rows of both
+sides and of both ring windows a side's rows can reach, the right rows
+rotated to their offsets afterwards; `_edge_scenario` puts the cursors
+where the window masks meet and where the rotation wraps, and the `ext`
+layout routes a record whose byte planes (4 x 59 lanes) are no multiple
+of 8 sublanes.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -16,13 +23,17 @@ from lightgbm_tpu.ops.aligned import (META_LABEL, META_LABEL_MASK, R_COPY,
                                       lane_layout, move_pass, pack_records,
                                       pack_route2, route_tile)
 
-F = 6
+F = 6            # features the fused histogram covers
 NC = 20          # one grid for every case of a shape: one compile
 K = 4            # histogram slots of the round (the dummy is K)
-LAYOUTS = {      # name -> (max_bin, compact)
-    "std8": (255, False),
-    "std6": (63, False),
-    "compact": (63, True),
+LAYOUTS = {      # name -> (max_bin, compact, ext, packed features)
+    "std8": (255, False, False, F),
+    "std6": (63, False, False, F),
+    "compact": (63, True, False, F),
+    # the ranking record: 55 bin words + score, gradient, hessian, row
+    # id = 59 used lanes of W = 64. A split may test any of the 220
+    # packed features; the histogram is asked for the first F only
+    "ext": (255, False, True, 220),
 }
 
 
@@ -56,15 +67,46 @@ def _scenario(C, heavy):
     ]
 
 
-def _build(C, layout, heavy, seed):
-    max_bin, compact = LAYOUTS[layout]
+def _exact(n, k):
+    """[n] bools, exactly k of them set, the same on every call."""
+    return np.random.default_rng(1000 * n + k).permutation(np.arange(n) < k)
+
+
+def _edge_scenario(C, heavy):
+    """One block whose tiles, given as (rows, rows to the `heavy` side),
+    stop the cursors where a side's two window masks meet. With a = the
+    side's cursor modulo the tile S and k = its rows of the tile:
+    tile 1 sends all S rows to one side from a = 5 (k == S: both windows
+    filled from one selected block); tile 2 ends both sides on a + k == S
+    (window 1 empty); tile 5 wraps BOTH sides (a = S - 10 and S - 20,
+    k = S / 2 each), which takes the short tile 4 before it, since whole
+    tiles keep the two cursors' sum at a multiple of S; tile 6 sends
+    every row to the other side, whose cursor lies behind the heavy
+    side's (under `heavy` = left: right rows only, a_r < a_l + k_l, so
+    the rotation that brings the right rows home turns backwards)."""
+    S = route_tile(C)
+    tiles = [(S, 5), (S, S), (S, S - 5), (S, S - 10), (S - 30, 0),
+             (S, S // 2), (S, 0)]
+    cnts, masks = [], []
+    for n, k in tiles:
+        if not cnts or cnts[-1] == C or cnts[-1] % S:
+            cnts.append(0)
+            masks.append([])
+        cnts[-1] += n
+        masks[-1].append(_exact(n, k) == (heavy == "left"))
+    return [("split", cnts, [np.concatenate(m) for m in masks], 0),
+            ("dead", 1)]
+
+
+def _build(C, layout, heavy, seed, scenario=_scenario):
+    max_bin, compact, ext, nfeat = LAYOUTS[layout]
     rng = np.random.default_rng(seed)
     bits = 8 if max_bin > 64 else 6
     bpw = _bpw_for_bits(bits)
     chunks, cnts = [], []          # old layout
     blocks = []                    # (kind, chunk ids, route)
     rid = 0
-    for bi, blk in enumerate(_scenario(C, heavy)):
+    for bi, blk in enumerate(scenario(C, heavy)):
         kind = blk[0]
         if kind == "dead":
             ids = list(range(len(cnts), len(cnts) + blk[1]))
@@ -75,20 +117,22 @@ def _build(C, layout, heavy, seed):
             cnts += [0] * blk[1]
             blocks.append((kind, ids, None))
             continue
-        feat = int(rng.integers(F))
+        feat = int(rng.integers(nfeat))
         thr = int(rng.integers(4, max_bin - 4))
         ids = []
-        for n in blk[1]:
-            bins = rng.integers(0, max_bin, (n, F)).astype(np.uint8)
+        for j, n in enumerate(blk[1]):
+            bins = rng.integers(0, max_bin, (n, nfeat)).astype(np.uint8)
             if kind == "split":
-                goes = rng.random(n) < blk[2]
+                # a share draws the rows that go left; a list names them
+                goes = blk[2][j] if isinstance(blk[2], list) \
+                    else rng.random(n) < blk[2]
                 bins[:, feat] = np.where(
                     goes, rng.integers(0, thr + 1, n),
                     rng.integers(thr + 1, max_bin, n))
             label = rng.integers(0, 2, n).astype(np.float32)
             rec, wcnt, W, c, pbits = pack_records(
                 bins, label, None, C, compact=compact, max_bin=max_bin,
-                rid_base=rid)
+                ext=ext, rid_base=rid)
             assert (rec.shape[0], pbits) == (1, bits)
             rid += n
             ids.append(len(cnts))
@@ -97,7 +141,7 @@ def _build(C, layout, heavy, seed):
         route = (feat, thr, blk[3]) if kind == "split" else None
         blocks.append((kind, ids, route))
     rec = np.concatenate(chunks)
-    lanes, _ = lane_layout(wcnt, compact=compact)
+    lanes, _ = lane_layout(wcnt, compact=compact, ext=ext)
     w_used = max(lanes.values()) + 1
     # every byte plane of every value lane carries random bits
     live = np.arange(C)[None, :] < np.asarray(cnts)[:, None]
@@ -109,7 +153,7 @@ def _build(C, layout, heavy, seed):
             rec[:, lanes[name], :] = np.where(live, v.view(np.int32), 0)
     return dict(rec=rec, cnts=np.asarray(cnts), blocks=blocks, bits=bits,
                 bpw=bpw, wcnt=wcnt, W=W, w_used=w_used, lanes=lanes,
-                compact=compact, max_bin=max_bin)
+                compact=compact, max_bin=max_bin, gh_off=1 if ext else 2)
 
 
 def _bin_of(rows, f, bits, bpw):
@@ -195,14 +239,21 @@ def _reference(sc, C):
 
 
 # spill changes the histogram's flush and nothing of the tiles, and four
-# tiles under the interpreter compile longest: one layout each is enough
+# tiles under the interpreter compile longest: one layout each is enough.
+# The wide ext record runs at the two tiles of its cell's chunk
 CASES = [(C, layout, spill, heavy)
          for C in (ROUTE_TILE, 2 * ROUTE_TILE, 4 * ROUTE_TILE)
          for layout in LAYOUTS
          for spill in (False, True)
          for heavy in ("left", "right")
          if layout == "std8" and (not spill or C != 2 * ROUTE_TILE)
-         or not spill and C < 4 * ROUTE_TILE]
+         or layout == "ext" and not spill and C == 2 * ROUTE_TILE
+         or layout in ("std6", "compact") and not spill
+         and C < 4 * ROUTE_TILE]
+# 384: a chunk that ROUTE_TILE does not divide is one tile of its own size
+EDGE_CASES = [(C, "std8") for C in (ROUTE_TILE, 2 * ROUTE_TILE,
+                                    4 * ROUTE_TILE, 384)] \
+    + [(2 * ROUTE_TILE, "ext")]
 
 
 def _call(sc, bufs, src, rt, b_pad, spill=False):
@@ -215,7 +266,8 @@ def _call(sc, bufs, src, rt, b_pad, spill=False):
         jnp.zeros((K + 1) * 8, jnp.int32), C, sc["W"], sc["wcnt"], K, F,
         b_pad, 4 if b_pad > 64 else 8, bits=sc["bits"],
         grad_fn=_point_grad if sc["compact"] else None,
-        w_used=sc["w_used"], interpret=True, subbin=True, spill=spill)
+        w_used=sc["w_used"], gh_off=sc["gh_off"], interpret=True,
+        subbin=True, spill=spill)
     return [np.asarray(a), np.asarray(b)], np.asarray(hist)
 
 
@@ -247,6 +299,27 @@ def test_move_pass_matches_numpy_partition(C, layout, spill, heavy, src):
     np.testing.assert_array_equal(bufs[1 - src][free], held[free])
     np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
     assert hist_ref[..., 2].sum() > C       # histograms were asked for
+
+
+@pytest.mark.parametrize("heavy", ("left", "right"))
+@pytest.mark.parametrize("C,layout", EDGE_CASES)
+def test_move_pass_where_the_window_masks_meet(C, layout, heavy):
+    """Both sides' rows of a tile come out of ONE selected block, from
+    which two masks a side take the ring's two windows: the cursors of
+    `_edge_scenario` put every boundary of those masks on a row."""
+    S = route_tile(C)
+    sc = _build(C, layout, heavy, seed=32, scenario=_edge_scenario)
+    rec_in, rt, expect, hist_ref, b_pad = _reference(sc, C)
+    held = np.full_like(rec_in, 0x5A5A5A5A)
+    bufs, hist = _call(sc, [rec_in, held], 0, rt, b_pad)
+    # 7 S - 30 rows: 3.5 S - 10 to the heavy side, the others 10 fewer
+    sides = (7 * S // 2 - 10, 7 * S // 2 - 20)
+    assert sum(len(r) for r in expect.values()) == sum(sides)
+    assert len(expect) == sum(-(-n // C) for n in sides)
+    _check_rows(sc, bufs[1], expect)
+    free = sorted(set(range(NC)) - set(expect))
+    np.testing.assert_array_equal(bufs[1][free], held[free])
+    np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
 
 
 @pytest.mark.parametrize("src", (0, 1))

@@ -28,7 +28,7 @@ from lightgbm_tpu.models import aligned_builder
 from lightgbm_tpu.models.aligned_builder import ROUND_STATS
 from lightgbm_tpu.models.level_builder import SI_LC, SI_RC
 from lightgbm_tpu.obs import trace as obs_trace
-from lightgbm_tpu.ops.aligned import route_tile
+from lightgbm_tpu.ops.aligned import ROUTE_SELECTORS, route_tile
 
 ALIGNED = {"tpu_grow_mode": "aligned", "tpu_aligned_interpret": True,
            "tpu_chunk": 256}
@@ -297,6 +297,8 @@ def test_pack_seam_carries_the_layout(run16):
     # move_pass's split path: sub-tiles of route_tile rows, whole chunks
     assert pack["route_tile"] * pack["route_tiles"] == eng.C
     assert pack["route_tile"] == route_tile(eng.C)
+    # and what its route matmul selects: one block a tile
+    assert pack["route_selectors"] == ROUTE_SELECTORS == 1
     assert pack["t1"] <= up["t0"]
 
 

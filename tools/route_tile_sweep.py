@@ -5,9 +5,11 @@ path (the root round's shape), timed on the chip for each candidate tile.
 python tools/route_tile_sweep.py [shape ...] [tile ...]
 
 Shapes: criteo255 / criteo63 (the benchmark cells' records, W=24,
-C=2048, fused histogram of the smaller child) and higgs (compact W=16,
-C=1024, no histogram). A tile equal to the chunk is the untiled kernel.
-Prints one JSON line per (shape, tile): ms a call and us a live chunk.
+C=2048, fused histogram of the smaller child), istella255 (the ranking
+cell's EXT record, W=64 with 59 lanes used, C=512, 220 features) and
+higgs (compact W=16, C=1024, no histogram). A tile equal to the chunk is
+the untiled kernel. Prints one JSON line per (shape, tile): ms a call
+and us a live chunk.
 """
 import json
 import os
@@ -22,18 +24,19 @@ import jax.numpy as jnp
 from lightgbm_tpu.obs import trace as obs_trace
 from lightgbm_tpu.ops import aligned
 
-NC = 4096          # 0.8 GB of records at W=24, C=2048
+NC = 4096          # 0.8 GB of records at W=24, C=2048; 0.5 GB at W=64, C=512
 LIVE = NC - 64
-SHAPES = {         # W, C, wcnt, w_used, features, b_pad, bits, spill, hist
-    "criteo255": (24, 2048, 17, 23, 67, 256, 8, True, True),
-    "criteo63": (24, 2048, 14, 20, 67, 64, 6, False, True),
-    "higgs": (16, 1024, 7, 9, 28, 256, 8, False, False),
+SHAPES = {   # W, C, wcnt, w_used, features, b_pad, bits, spill, hist, gh_off
+    "criteo255": (24, 2048, 17, 23, 67, 256, 8, True, True, 2),
+    "criteo63": (24, 2048, 14, 20, 67, 64, 6, False, True, 2),
+    "istella255": (64, 512, 55, 59, 220, 256, 8, True, True, 1),
+    "higgs": (16, 1024, 7, 9, 28, 256, 8, False, False, 2),
 }
 K = 256
 
 
 def one(shape, tile, reps=3):
-    W, C, wcnt, w_used, F, b_pad, bits, spill, hist = SHAPES[shape]
+    W, C, wcnt, w_used, F, b_pad, bits, spill, hist, gh_off = SHAPES[shape]
     aligned.ROUTE_TILE = tile
     jax.clear_caches()
     rec = jax.random.bits(jax.random.PRNGKey(tile), (NC, W, C),
@@ -59,7 +62,8 @@ def one(shape, tile, reps=3):
     step = jax.jit(
         lambda a, b: aligned.move_pass(
             a, b, 0, *args, C, W, wcnt, K, F, b_pad, 4 if b_pad > 64 else 8,
-            bits=bits, w_used=w_used, subbin=True, spill=spill),
+            bits=bits, w_used=w_used, gh_off=gh_off, subbin=True,
+            spill=spill),
         donate_argnums=(0, 1))
     bufs = [rec, jnp.zeros_like(rec)]
 
@@ -79,7 +83,7 @@ def one(shape, tile, reps=3):
     best = min(walls)
     print(json.dumps({
         "shape": shape, "C": C, "tile": aligned.route_tile(C),
-        "hist": hist,
+        "route_selectors": aligned.ROUTE_SELECTORS, "hist": hist,
         "ms_per_call": round(best * 1e3, 2),
         "us_per_chunk": round(best * 1e6 / LIVE, 2),
         "first_call_s": round(first, 1),
