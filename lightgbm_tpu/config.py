@@ -249,6 +249,19 @@ class Config:
     lambda_l1: float = 0.0
     lambda_l2: float = 0.0
     min_gain_to_split: float = 0.0
+    # boosting=dart (dart.hpp:97-196): an iteration that does not skip
+    # (skip_drop) drops every earlier tree with probability drop_rate x
+    # its weight / the mean weight (uniform_drop: drop_rate), at most
+    # max_drop of them, grows its tree without them at learning_rate /
+    # (1 + k) and puts the k back at k / (k + 1) of their weight
+    # (xgboost_dart_mode: learning_rate / (learning_rate + k) and k / (k +
+    # learning_rate)). On the aligned engine dropped trees leave and
+    # re-enter the score lane through a walk of the committed trees over
+    # the records as they lie (ops/aligned.py walk_pass), trees stay on
+    # the device and the drop set rides each queued round. Multiclass,
+    # tree_learner=data, categorical features and non-pointwise
+    # objectives keep boosting=dart off the engine, on the fused
+    # leaf-wise loop (the train_path event's `rejected` says which)
     drop_rate: float = 0.1
     max_drop: int = 50
     skip_drop: float = 0.5

@@ -99,6 +99,19 @@ class AlignedSpec(NamedTuple):
     round_stats: jax.Array  # i32[Sm1, len(ROUND_STATS)], rows < rounds
 
 
+class WalkTree(NamedTuple):
+    """A tree in the compact form the record walk takes
+    (`ops.aligned.walk_expand`): nodes and leaves numbered densely from
+    0, every one naming its parent and the side it hangs on. Made on the
+    device from a committed spec (`AlignedEngine.walk_tree_of_spec`) or
+    in numpy from a host `Tree` (`walk_tree_of_host`)."""
+    nodes: jax.Array       # i32[5, Np]: feature, threshold bin, default
+                           # left, parent (-1: root), side (+1 left, -1)
+    leaves: jax.Array      # i32[2, Lp]: parent, side
+    nn: jax.Array          # i32 scalar: nodes; the leaves are nn + 1
+    base: jax.Array        # f32[Lp]: leaf outputs before shrinkage, bias
+
+
 def slot_in_any_map(begin, count, nc, chunk):
     """(slot_of [nc], in_any [nc]) from monotonic block begins — the
     layout-to-chunk mapping shared by the build program's chunk_maps and
@@ -1361,7 +1374,12 @@ class AlignedEngine:
                 rec, cnts, spec, exact_dev, ncommit_dev, applied_dev = fn(
                     self.rec, self.cnts, fmask, jnp.float32(scale),
                     self._last_exact)
-        self._last_exact = exact_dev
+        # the CHAIN, not this program's own flag: after an inexact
+        # round every successor is a score no-op until the host has
+        # rebuilt it (`set_row_scores`), whatever its own replay says.
+        # A successor that rebuilds the same tree is inexact as well;
+        # one that samples or drops anew (GOSS, DART) need not be
+        self._last_exact = applied_dev
         # records AND per-chunk counts were donated (the round loop
         # ping-pongs between the donated matrix and a temporary of the
         # program, and ends in the donated one): the physical layout
@@ -1588,6 +1606,207 @@ class AlignedEngine:
             return rec.at[:, lane, :].set(_i32(sc))
         return fn
 
+    # ---- the walk of committed trees over the records as they lie
+    # (`ops.aligned.walk_pass`): what lets a boosting variant take an
+    # earlier tree out of the score lane and put it back (DART)
+    def _walk_dims(self):
+        from ..ops.aligned import walk_dims
+        return walk_dims(self.cfg.num_leaves, self.wcnt, self.bits)
+
+    def walk_tree_of_spec(self, spec) -> WalkTree:
+        """The committed tree of a device spec in the walk's compact
+        form, made on the device: nothing is pulled."""
+        fn = self._program("walk_tree", self._walk_tree_program)
+        return fn(spec.execI, spec.first_c, spec.nxt_c, spec.cover)
+
+    def _walk_tree_program(self):
+        S = self.S
+        ne = S                  # exec ids 0 .. S - 1; S is "no exec"
+        np_, lp, _, _ = self._walk_dims()
+
+        def fn(execI, first_c, nxt_c, cover):
+            eidx = jnp.arange(ne, dtype=jnp.int32)
+            # a committed exec is one a committed chain points at
+            is_c = jnp.zeros(ne + 1, bool).at[first_c].set(True) \
+                .at[nxt_c].set(True)[:ne]
+            rank = jnp.cumsum(is_c).astype(jnp.int32) - 1     # its node id
+            ex = jnp.pad(execI, ((0, ne - execI.shape[0]), (0, 0)))
+            slot = ex[:, SI_SLOT]
+            rfirst = first_c[jnp.clip(eidx + 1, 0, S)]
+            l_node = is_c & (nxt_c < ne)
+            r_node = is_c & (rfirst < ne)
+            l_id = rank[jnp.clip(nxt_c, 0, ne - 1)]
+            r_id = rank[jnp.clip(rfirst, 0, ne - 1)]
+
+            def put(size, fill, *pairs):
+                out = jnp.full(size, fill, jnp.int32)
+                for ok, at, val in pairs:
+                    out = out.at[jnp.where(ok, at, size)].set(
+                        val, mode="drop")
+                return out
+            one = jnp.ones(ne, jnp.int32)
+            nodes = jnp.stack([
+                put(np_, 0, (is_c, rank, ex[:, SI_FEAT])),
+                put(np_, 0, (is_c, rank, ex[:, SI_THR])),
+                put(np_, 0, (is_c, rank, ex[:, SI_DEFLEFT])),
+                put(np_, -1, (l_node, l_id, rank), (r_node, r_id, rank)),
+                put(np_, 0, (l_node, l_id, one), (r_node, r_id, -one))])
+            # the left child keeps its parent's slot, the right child of
+            # exec e takes slot e + 1: slot 0 is leaf 0, slot e + 1 leaf
+            # rank[e] + 1
+            l_leaf = is_c & ~l_node
+            r_leaf = is_c & ~r_node
+            l_lid = jnp.where(slot == 0, 0,
+                              rank[jnp.clip(slot - 1, 0, ne - 1)] + 1)
+            leaves = jnp.stack([
+                put(lp, -1, (l_leaf, l_lid, rank), (r_leaf, rank + 1, rank)),
+                put(lp, 0, (l_leaf, l_lid, one), (r_leaf, rank + 1, -one))])
+            base = jnp.zeros(lp, jnp.float32).at[0].set(cover[0]).at[
+                jnp.where(is_c, rank + 1, lp)].set(
+                    cover[jnp.clip(eidx + 1, 0, S)], mode="drop")
+            return WalkTree(nodes, leaves, jnp.sum(is_c).astype(jnp.int32),
+                            base)
+        return fn
+
+    def walk_tree_of_host(self, tree) -> WalkTree:
+        """A host `Tree` in the walk's compact form; its leaf values
+        already hold shrinkage and bias."""
+        np_, lp, _, _ = self._walk_dims()
+        nn = int(tree.num_leaves) - 1
+        nodes = np.zeros((5, np_), np.int32)
+        leaves = np.zeros((2, lp), np.int32)
+        nodes[3], leaves[0] = -1, -1
+        nodes[0, :nn] = tree.split_feature_inner[:nn]
+        nodes[1, :nn] = tree.threshold_in_bin[:nn]
+        nodes[2, :nn] = (tree.decision_type[:nn] & 2) != 0
+        ids = np.arange(nn, dtype=np.int32)
+        for kids, side in ((tree.left_child[:nn], 1),
+                           (tree.right_child[:nn], -1)):
+            inner = kids >= 0
+            nodes[3, kids[inner]], nodes[4, kids[inner]] = ids[inner], side
+            leaves[0, ~kids[~inner]] = ids[~inner]
+            leaves[1, ~kids[~inner]] = side
+        base = np.zeros(lp, np.float32)
+        base[:nn + 1] = tree.leaf_value[:nn + 1]
+        return WalkTree(nodes, leaves, np.int32(nn), base)
+
+    def _null_walk_tree(self) -> WalkTree:
+        np_, lp, _, _ = self._walk_dims()
+        nodes = np.zeros((5, np_), np.int32)
+        leaves = np.zeros((2, lp), np.int32)
+        nodes[3], leaves[0] = -1, -1
+        return WalkTree(nodes, leaves, np.int32(0),
+                        np.zeros(lp, np.float32))
+
+    def walk_trees(self, trees, first, f_first, second=None,
+                   f_second: float = 0.0) -> int:
+        """Score lane of every live row += the sum over `trees` =
+        [(WalkTree, shrinkage, bias)] of f x (shrinkage x leaf output +
+        bias), where f is `f_first` if the device flag `first` holds,
+        else `f_second` if `second` holds, else 0: so a walk queued
+        beside a build can follow that build's own `applied` flag and
+        nothing is pulled. WALK_TREES trees a `walk_pass`; returns the
+        passes made. An empty list still makes one (of no tree): the
+        warm-up's."""
+        from ..ops.aligned import WALK_TREES
+        assert self.axis is None and self.num_class == 1
+        fn = self._program("walk_rec", self._walk_rec_program, donate=(0,))
+        second = first if second is None else second
+        null = None
+        passes = 0
+        for lo in range(0, max(len(trees), 1), WALK_TREES):
+            part = list(trees[lo:lo + WALK_TREES])
+            if len(part) < WALK_TREES and null is None:
+                null = (self._null_walk_tree(), 0.0, 0.0)
+            full = part + [null] * (WALK_TREES - len(part))
+            self.rec = fn(
+                self.rec, self.cnts, np.int32(len(part)), first,
+                np.float32(f_first), second, np.float32(f_second),
+                np.asarray([t[1] for t in full], np.float32),
+                np.asarray([t[2] for t in full], np.float32),
+                tuple(t[0] for t in full))
+            passes += 1
+        self._score_cache = None
+        return passes
+
+    def _walk_rec_program(self):
+        from ..ops.aligned import walk_expand, walk_pass
+        lr = self.learner
+        nb = jnp.asarray(lr.meta["num_bin"], jnp.int32)
+        db = jnp.asarray(lr.meta["default_bin"], jnp.int32)
+        mt = jnp.asarray(lr.meta["missing_type"], jnp.int32)
+        _, _, w8, fp = self._walk_dims()
+        C, wcnt, bits = self.C, self.wcnt, self.bits
+        lane, interpret = self.lanes["score"], self.interpret
+
+        def fn(rec, cnts, ntrees, first, f_first, second, f_second, shr,
+               bias, trees):
+            tabs = jax.vmap(lambda n, l, k: walk_expand(
+                n, l, k, nb, db, mt, w8=w8, bits=bits, fp=fp))(
+                    jnp.stack([jnp.asarray(t.nodes) for t in trees]),
+                    jnp.stack([jnp.asarray(t.leaves) for t in trees]),
+                    jnp.stack([jnp.asarray(t.nn) for t in trees]))
+            base = jnp.stack([jnp.asarray(t.base) for t in trees])
+            f = jnp.where(first, f_first, jnp.where(second, f_second, 0.0))
+            vals = f * (shr[:, None] * base + bias[:, None])
+            return walk_pass(rec, cnts, ntrees, *tabs, vals[:, :, None],
+                             chunk=C, wcnt=wcnt, bits=bits, lane=lane,
+                             interpret=interpret)
+        return fn
+
+    def walk_rows(self, score, lane, vbins, tree: WalkTree, shrinkage,
+                  bias, applied, factor):
+        """score [K, Nv] lane `lane` += factor x (shrinkage x tree(vbins)
+        + bias) where `applied` holds: `apply_spec_to_scores` for a tree
+        in the walk's compact form, so for a host tree as for a spec."""
+        fn = self._program(("walk_rows", vbins.shape),
+                           self._walk_rows_program, donate=(0,))
+        return fn(score, jnp.int32(lane), vbins, tree, jnp.float32(shrinkage),
+                  jnp.float32(bias), applied, jnp.float32(factor))
+
+    def _walk_rows_program(self):
+        lr = self.learner
+        nb = jnp.asarray(lr.meta["num_bin"], jnp.int32)
+        db = jnp.asarray(lr.meta["default_bin"], jnp.int32)
+        mt = jnp.asarray(lr.meta["missing_type"], jnp.int32)
+
+        def fn(score, lane, vb, tree, shrinkage, bias, applied, factor):
+            feat, thr, dl, parent, side = jnp.asarray(tree.nodes)
+            leaves = jnp.asarray(tree.leaves)
+            np_, lp = feat.shape[0], leaves.shape[1]
+            ids = jnp.arange(np_, dtype=jnp.int32)
+            lids = jnp.arange(lp, dtype=jnp.int32)
+
+            def kids(s):
+                """Each node's child on side s: a node id, or ~leaf."""
+                out = jnp.full(np_, -1, jnp.int32)
+                out = out.at[jnp.where((ids < tree.nn) & (side == s),
+                                       parent, np_)].set(ids, mode="drop")
+                return out.at[jnp.where((lids <= tree.nn)
+                                        & (leaves[1] == s), leaves[0],
+                                        np_)].set(~lids, mode="drop")
+            lch, rch = kids(1), kids(-1)
+
+            def body(node):
+                e = jnp.clip(node, 0, np_ - 1)
+                f = feat[e]
+                binv = jnp.take_along_axis(
+                    vb, f[:, None], axis=1)[:, 0].astype(jnp.int32)
+                is_def = ((mt[f] == 1) & (binv == db[f])) \
+                    | ((mt[f] == 2) & (binv == nb[f] - 1))
+                left = jnp.where(is_def, dl[e] != 0, binv <= thr[e])
+                return jnp.where(node >= 0,
+                                 jnp.where(left, lch[e], rch[e]), node)
+
+            node = lax.while_loop(
+                lambda node: jnp.any(node >= 0), body,
+                jnp.full(vb.shape[0], jnp.where(tree.nn > 0, 0, -1),
+                         jnp.int32))
+            val = shrinkage * jnp.asarray(tree.base)[~node] + bias
+            return score.at[lane].add(
+                jnp.where(applied, factor, 0.0) * val)
+        return fn
+
     def set_bag(self, mask_rows):
         """Re-ingest a per-row 0/1 bagging mask into the bag lane (one
         streaming pass; called on bagging_freq boundaries)."""
@@ -1646,11 +1865,17 @@ class AlignedEngine:
             return rec.at[:, ln["bag"], :].set(_i32(mult)), stats
         return goss_select
 
-    def row_bag(self) -> np.ndarray:
-        """The bag lane in ROW order (a check's accessor; pulls N)."""
-        fn = self._program(("mat", "bag"),
-                           lambda: self._materialize_program("bag"))
+    def row_lane(self, lane: str) -> np.ndarray:
+        """One f32 lane of the records in ROW order (a check's accessor;
+        pulls N): "grad" and "hess" hold what the last build trained
+        on."""
+        fn = self._program(("mat", lane),
+                           lambda: self._materialize_program(lane))
         return np.asarray(fn(self.rec, self.cnts))
+
+    def row_bag(self) -> np.ndarray:
+        """The bag lane in ROW order."""
+        return self.row_lane("bag")
 
     def _set_bag_program(self):
         ln = self.lanes

@@ -6,7 +6,7 @@ Re-creates `src/boosting/goss.hpp`, `src/boosting/dart.hpp`,
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +15,9 @@ import numpy as np
 from ..config import Config
 from ..io.dataset import Dataset
 from ..ops.goss import goss_multipliers
-from .gbdt import GBDT, K_EPSILON, _ScoreUpdater
+from ..obs import trace as obs_trace
+from .gbdt import (GBDT, K_EPSILON, LazyAlignedTree, LazyTree,
+                   _ScoreUpdater)
 from .tree import Tree
 
 
@@ -155,8 +157,10 @@ class GOSS(GBDT):
             if eng.bag_sampled:
                 eng.set_bag(np.ones(self.num_data, np.float32))
             return None
-        return eng.goss_select(seed, *goss_sizes(self.cfg, self.num_data),
-                               grads=grads, boost_iter=self.iter)
+        stats = eng.goss_select(seed, *goss_sizes(self.cfg, self.num_data),
+                                grads=grads, boost_iter=self.iter)
+        return dict(zip(("goss_kept_top", "goss_kept_other",
+                         "goss_threshold"), stats))
 
     def _aligned_fallback_sample(self, seed, bag_idx, bag_cnt, gdev, hdev):
         """The sample the failed device build trained on, made again in
@@ -171,18 +175,36 @@ class GOSS(GBDT):
         return out
 
 
+class DropSample(NamedTuple):
+    """What one DART iteration does to the trees before it, drawn on the
+    host from `drop_seed`'s stream and from nothing the device computes:
+    it is known at dispatch and rides the queued round."""
+    iter: int
+    skipped: bool           # the skip_drop draw said: drop nothing
+    dropped: Tuple[int, ...]    # iterations whose trees are dropped
+    shrinkage: float        # of the tree this iteration builds
+    keep: float             # what of its weight a dropped tree keeps
+
+
 class DART(GBDT):
     """Dropouts meet Multiple Additive Regression Trees (dart.hpp:25-209).
 
-    Round 4: trains on the FUSED device learner (whole-tree jitted
-    programs) like plain GBDT — the drop/renormalize machinery already
-    runs on device score arrays via binned traversal
-    (apply_tree_to_score); only the per-iteration tree materialization
-    (one small batched pull in _dropping_trees) touches the host. The
-    aligned engine stays out (its score lane cannot follow dropped
-    scores — get_training_score override gates it), so DART uses the
-    leaf-wise fused path (dart.hpp:58 shares the full-speed core the
-    same way)."""
+    Every iteration draws its drop set on the host (`_draw_drop`), takes
+    the dropped trees out of the training score before the gradients are
+    made, builds at lr / (1 + k) and puts the k trees back at k / (k + 1)
+    of their weight.
+
+    On the aligned engine the score is a lane of records that lie in
+    another order after every tree, so "out" and "back" are walks of the
+    committed trees over the records as they lie (`AlignedEngine
+    .walk_trees`, the `walk_pass` kernel), queued around the build and
+    gated by its flags: an inexact round leaves the lane as it found it.
+    Trees stay device specs and take their accumulated weight when they
+    are materialised; the drop set rides each queued round, so the
+    pipeline runs as deep as plain boosting's and a fallback replays it.
+    Off the engine (`_aligned_variant_gate` names why) the fused
+    leaf-wise loop runs as before: trees pulled each iteration and
+    re-applied over row-order bins."""
 
     def __init__(self, cfg: Config, train_data: Dataset, objective=None):
         super().__init__(cfg, train_data, objective)
@@ -192,10 +214,34 @@ class DART(GBDT):
         self._drop_rng = np.random.RandomState(cfg.drop_seed)
         self._dropped_this_iter = False
         self.num_init_iteration = 0
+        # the engine's side: per dispatched round, what puts the host's
+        # bookkeeping back if the round is discarded
+        self._dart_undo: List[tuple] = []
+        self._dart_rng_before = None
+        self._dart_out: list = []       # the walks of the round in dispatch
 
     def _aligned_variant_gate(self) -> Optional[str]:
-        return ("boosting=dart: the engine's score lane cannot follow "
-                "dropped trees")
+        lr = self.learner
+        if self.num_tree_per_iteration > 1:
+            return ("boosting=dart with multiclass: the record walk "
+                    "follows one score lane")
+        if getattr(lr, "mode", "") == "data":
+            return ("boosting=dart under tree_learner=data: the record "
+                    "walk is not sharded")
+        if getattr(lr, "bundled", False):
+            return ("boosting=dart with bundled features: the record "
+                    "walk reads unbundled bins")
+        if np.any(np.asarray(lr.meta["bin_type"]) != 0):
+            return ("boosting=dart with categorical features: the record "
+                    "walk takes numerical splits")
+        if self.objective is not None \
+                and self.objective.point_grad_fn() is None:
+            return ("boosting=dart with a non-pointwise objective: its "
+                    "row-order gradients are made before the drop")
+        if self.cfg.num_leaves > 1024:
+            return ("boosting=dart above 1024 leaves: the record walk's "
+                    "tables are sized for VMEM")
+        return None
 
     def get_training_score(self) -> jax.Array:
         if not self._dropped_this_iter:
@@ -204,6 +250,10 @@ class DART(GBDT):
         return self.train_score.score
 
     def train_one_iter(self, grad=None, hess=None) -> bool:
+        if grad is None and hess is None and self._aligned_eligible():
+            # the engine's loop keeps the bookkeeping itself, a round
+            # behind the dispatch (`_aligned_after_build`)
+            return super().train_one_iter()
         self._dropped_this_iter = False
         ret = super().train_one_iter(grad, hess)
         if ret:
@@ -223,19 +273,20 @@ class DART(GBDT):
                 self.iter -= 1
                 return True
         self._normalize()
+        self._weigh_new_tree()
+        return False
+
+    def _weigh_new_tree(self) -> None:
         if not self.cfg.uniform_drop:
             self.tree_weight.append(self.shrinkage_rate)
             self.sum_weight += self.shrinkage_rate
-        return False
 
     # ------------------------------------------------------------------
-    def _dropping_trees(self) -> None:
-        """dart.hpp:97-146."""
-        # the fused path appends LazyTree records; dropping needs host
-        # trees (leaf-value mutation + re-application)
-        self.materialized_models()
+    def _draw_drop(self) -> DropSample:
+        """dart.hpp:97-146's draws, in its order: one for the skip, then
+        one a tree until max_drop are dropped."""
         cfg = self.cfg
-        self.drop_index = []
+        drop: List[int] = []
         is_skip = self._drop_rng.rand() < cfg.skip_drop
         if not is_skip:
             drop_rate = cfg.drop_rate
@@ -250,17 +301,35 @@ class DART(GBDT):
                 for i in range(self.iter):
                     if self._drop_rng.rand() < drop_rate \
                             * self.tree_weight[i] * inv_avg:
-                        self.drop_index.append(self.num_init_iteration + i)
-                        if len(self.drop_index) >= cfg.max_drop > 0:
+                        drop.append(self.num_init_iteration + i)
+                        if len(drop) >= cfg.max_drop > 0:
                             break
             else:
                 if cfg.max_drop > 0 and self.iter > 0:
                     drop_rate = min(drop_rate, cfg.max_drop / self.iter)
                 for i in range(self.iter):
                     if self._drop_rng.rand() < drop_rate:
-                        self.drop_index.append(self.num_init_iteration + i)
-                        if len(self.drop_index) >= cfg.max_drop > 0:
+                        drop.append(self.num_init_iteration + i)
+                        if len(drop) >= cfg.max_drop > 0:
                             break
+        k, lr = float(len(drop)), cfg.learning_rate
+        den = self._drop_denominator(k)
+        # (xgboost_dart_mode builds at learning_rate where nothing drops)
+        shrinkage = lr / den if drop or not cfg.xgboost_dart_mode else lr
+        sample = DropSample(self.iter, bool(is_skip), tuple(drop),
+                            shrinkage, k / den)
+        obs_trace.seam_record("dart.drop", iter=sample.iter,
+                              skipped=sample.skipped, k=len(drop),
+                              dropped=list(drop), shrinkage=shrinkage)
+        return sample
+
+    def _dropping_trees(self) -> None:
+        """dart.hpp:97-146."""
+        # the fused path appends LazyTree records; dropping needs host
+        # trees (leaf-value mutation + re-application)
+        self.materialized_models()
+        sample = self._draw_drop()
+        self.drop_index = list(sample.dropped)
         # drop: NEGATE the stored tree (reference Shrinkage(-1),
         # dart.hpp:137-143) then add — the stored sign matters because
         # Normalize's two shrinkage steps continue FROM -1 and must end
@@ -275,15 +344,7 @@ class DART(GBDT):
                     t.apply_shrinkage(-1.0)
                     self.apply_tree_to_score(self.train_score,
                                              self.train_data.bins, t, k, 1.0)
-        if not self.cfg.xgboost_dart_mode:
-            self.shrinkage_rate = self.cfg.learning_rate \
-                / (1.0 + len(self.drop_index))
-        else:
-            if not self.drop_index:
-                self.shrinkage_rate = self.cfg.learning_rate
-            else:
-                self.shrinkage_rate = self.cfg.learning_rate \
-                    / (self.cfg.learning_rate + len(self.drop_index))
+        self.shrinkage_rate = sample.shrinkage
 
     def _normalize(self) -> None:
         """dart.hpp:148-196: renormalize dropped trees and patch scores."""
@@ -310,14 +371,193 @@ class DART(GBDT):
                     self.apply_tree_to_score(self.train_score,
                                              self.train_data.bins, t, cid,
                                              1.0)
-            if not cfg.uniform_drop:
-                if not cfg.xgboost_dart_mode:
-                    self.sum_weight -= self.tree_weight[i] * (1.0 / (k + 1.0))
-                    self.tree_weight[i] *= k / (k + 1.0)
-                else:
-                    self.sum_weight -= self.tree_weight[i] \
-                        * (1.0 / (k + cfg.learning_rate))
-                    self.tree_weight[i] *= k / (k + cfg.learning_rate)
+            self._reweigh_dropped(i, k)
+
+    def _drop_denominator(self, k: float) -> float:
+        """k + 1, or k + learning_rate under xgboost_dart_mode: the new
+        tree is built at learning_rate over it, a dropped tree keeps k
+        over it and loses 1 over it."""
+        cfg = self.cfg
+        return k + (cfg.learning_rate if cfg.xgboost_dart_mode else 1.0)
+
+    def _reweigh_dropped(self, i: int, k: float) -> None:
+        if not self.cfg.uniform_drop:
+            den = self._drop_denominator(k)
+            self.sum_weight -= self.tree_weight[i] * (1.0 / den)
+            self.tree_weight[i] *= k / den
+
+    # ---- the aligned engine's side (gbdt._train_one_iter_aligned)
+    def _maybe_rebag(self, eng) -> None:
+        super()._maybe_rebag(eng)
+        self._dart_rng_before = self._drop_rng.get_state()
+        self._aligned_sample = self._draw_drop()
+
+    def _walked(self, eng, i: int):
+        """(WalkTree, shrinkage, bias) of iteration i's tree as it stands:
+        a device spec keeps its weight beside it, a host tree holds it in
+        its leaf values. None for a tree that never split."""
+        t = self.models[i]
+        if isinstance(t, LazyTree) and not isinstance(t, LazyAlignedTree):
+            t = self.models[i] = t.materialize()    # a fallback's record
+        if isinstance(t, LazyAlignedTree):
+            if t.walk is None:      # made on the device: nothing is pulled
+                t.walk = eng.walk_tree_of_spec(t.record)
+            return t.walk, t.shrinkage, t.bias
+        if t.num_leaves <= 1:
+            return None
+        return eng.walk_tree_of_host(t), 1.0, 0.0
+
+    def _dropped_walks(self, eng, sample) -> list:
+        return [w for w in (self._walked(eng, i) for i in sample.dropped)
+                if w is not None]
+
+    def _aligned_apply_sample(self, eng, sample, grads):
+        """The dropped trees out of the score lane, ahead of the build
+        that makes its gradients there; the counters of the iteration."""
+        if "walk_rec" not in eng._programs:
+            # the walk's program, made before any tree is dropped (a pass
+            # of no tree): a caller that times iterations has warmed up
+            # by the time one is
+            eng.walk_trees([], eng._last_exact, 0.0)
+        if self.models:
+            # the newest tree in the walk's form while nothing waits for
+            # it: a few KB a tree, and the program that makes it is
+            # compiled in the second iteration
+            self._walked(eng, len(self.models) - 1)
+        self.shrinkage_rate = sample.shrinkage
+        trees = self._dart_out = self._dropped_walks(eng, sample)
+        passes = eng.walk_trees(trees, eng._last_exact, -1.0) \
+            if trees else 0
+        return {"dart_dropped": len(sample.dropped),
+                "walk_passes": 2 * passes,
+                "rows_walked": 2 * len(trees) * self.num_data}
+
+    def _scale_tree(self, i: int, factor: float):
+        """Iteration i's tree at `factor` of its weight; returns what
+        puts it back."""
+        t = self.models[i]
+        if isinstance(t, LazyTree):
+            old = (t.shrinkage, t.bias)
+            t.shrinkage, t.bias = old[0] * factor, old[1] * factor
+            return old
+        old = (t.leaf_value.copy(), t.internal_value.copy(), t.shrinkage)
+        t.apply_shrinkage(factor)
+        return old
+
+    def _unscale_tree(self, i: int, old) -> None:
+        t = self.models[i]
+        if isinstance(t, LazyTree):
+            t.shrinkage, t.bias = old
+        else:
+            t.leaf_value, t.internal_value, t.shrinkage = old
+
+    def _commit_sample(self, sample) -> None:
+        """The host's half of dart.hpp:148-196, once the round's device
+        work is queued: dropped trees at `keep` of their weight, the
+        weights the next draw reads, and what undoes both."""
+        k = float(len(sample.dropped))
+        weighed = not self.cfg.uniform_drop
+        undo = (sample.iter, len(self.tree_weight), self.sum_weight,
+                [(i, self.tree_weight[i] if weighed else None,
+                  self._scale_tree(i, sample.keep))
+                 for i in sample.dropped])
+        for i in sample.dropped:
+            self._reweigh_dropped(i, k)
+        self._weigh_new_tree()
+        self._dart_undo.append(undo)
+        # a round leaves the ring once the host has resolved it
+        depth = 2 * self._aligned_pipeline_depth() + 2
+        del self._dart_undo[:-depth]
+
+    def _aligned_after_build(self, eng, sample, out, prev_ok) -> None:
+        """The dropped trees back at `keep` of their weight where the
+        build applied; at all of it where the round ran but its build was
+        inexact (the host rebuilds that round from the lane as it was);
+        not at all in a round behind an inexact one, which took nothing
+        out."""
+        if self._dart_out:      # as they went out: nothing re-weighed yet
+            eng.walk_trees(self._dart_out, out[3], sample.keep, prev_ok, 1.0)
+        self._commit_sample(sample)
+
+    def _aligned_valid_sample(self, eng, sample, score, vbins, applied):
+        # the valid set held the dropped trees whole, at the weight they
+        # had before `_commit_sample` cut it to `keep` of that
+        for i in sample.dropped:
+            w = self._walked(eng, i)
+            if w is not None:
+                score = eng.walk_rows(score, 0, vbins, *w, applied,
+                                      1.0 - 1.0 / sample.keep)
+        return score
+
+    def _aligned_forget_from(self, first_iter: int) -> None:
+        while self._dart_undo and self._dart_undo[-1][0] >= first_iter:
+            _, weights, self.sum_weight, scaled = self._dart_undo.pop()
+            del self.tree_weight[weights:]
+            for i, weight, old in reversed(scaled):
+                if weight is not None:
+                    self.tree_weight[i] = weight
+                if i < len(self.models):
+                    self._unscale_tree(i, old)
+
+    def _discard_eager(self) -> None:
+        stash = getattr(self, "_aligned_next", None)
+        sample = self._aligned_sample       # the eager round's
+        super()._discard_eager()
+        if stash is None:
+            return
+        # the eager round took 1 - keep of its dropped trees out of the
+        # lane where it applied: back in, at the weights they had
+        applied = stash[0][3]
+        self._aligned_forget_from(sample.iter)
+        eng = self._aligned_eng_ref
+        trees = self._dropped_walks(eng, sample)
+        if trees:
+            eng.walk_trees(trees, applied, 1.0 - sample.keep)
+        self._drop_rng.set_state(self._dart_rng_before)
+
+    def _trim_trailing_empty(self) -> bool:
+        stop = super()._trim_trailing_empty()
+        if stop and len(self.tree_weight) > self.iter:
+            # trees that never split are gone from the model; what their
+            # iterations dropped stays re-weighted, in lane and trees alike
+            del self.tree_weight[self.iter:]
+            self.sum_weight = float(sum(self.tree_weight))
+        return stop
+
+    def materialized_models(self):
+        # a round dispatched ahead of its turn has already left its
+        # dropped trees lighter: the model is read without it
+        self._discard_eager()
+        return super().materialized_models()
+
+    def _aligned_fallback_iter(self, init_scores, eng, fmask, bag_idx=None,
+                               bag_cnt=0, sample=None) -> bool:
+        """The exact rebuild of a round the engine could not replay, in
+        row order as the fused loop does it: dropped trees out, the tree
+        grown on those scores at the round's shrinkage, dropped trees
+        back at `keep`, valid sets alike."""
+        self._sync_train_score()
+        for i in sample.dropped:
+            if isinstance(self.models[i], LazyTree):
+                self.models[i] = self.models[i].materialize()
+        dropped = [self.models[i] for i in sample.dropped
+                   if self.models[i].num_leaves > 1]
+        for t in dropped:
+            self.apply_tree_to_score(self.train_score, self.train_data.bins,
+                                     t, 0, -1.0)
+        self.shrinkage_rate = sample.shrinkage
+        self._dropped_this_iter = True      # `_gradients` drops no more
+        stop = super()._aligned_fallback_iter(init_scores, eng, fmask,
+                                              bag_idx, bag_cnt, sample)
+        for t in dropped:
+            self.apply_tree_to_score(self.train_score, self.train_data.bins,
+                                     t, 0, sample.keep)
+            for ds, su in zip(self.valid_sets, self.valid_scores):
+                self.apply_tree_to_score(su, ds.bins, t, 0,
+                                         sample.keep - 1.0)
+        self._commit_sample(sample)
+        eng.set_row_scores(self.train_score.score[0])
+        return stop
 
 
 class RF(GBDT):

@@ -67,14 +67,14 @@ def _record_aligned_iter(it: int, rounds, norm_passes, table,
     number of rounds) and its per-round counters
     (`aligned_builder.ROUND_STATS` order, rows up to `rounds`), as pulled
     with the exactness flags. Data-parallel: shard 0's counters.
-    `sampled` = the device selection's counters of an iteration that
-    sampled its rows on the device (`goss_kept_top`, `goss_kept_other`,
-    `goss_threshold`); absent from an iteration that did not."""
+    `sampled` = the named counters the boosting variant recorded of the
+    iteration, device scalars or the host's own numbers: GOSS's
+    selection (`goss_kept_top`, `goss_kept_other`, `goss_threshold`),
+    DART's walks (`dart_dropped`, `walk_passes`, `rows_walked`); absent
+    where there is none."""
     from .aligned_builder import ROUND_STATS
     rounds = int(rounds)
-    extra = {} if sampled is None else dict(
-        goss_kept_top=int(sampled[0]), goss_kept_other=int(sampled[1]),
-        goss_threshold=float(sampled[2]))
+    extra = {k: np.asarray(v).item() for k, v in (sampled or {}).items()}
     obs_trace.seam_record("aligned.iter", iter=int(it), rounds=rounds,
                           norm_passes=int(norm_passes),
                           columns=list(ROUND_STATS),
@@ -86,6 +86,8 @@ class LazyAlignedTree(LazyTree):
     """A tree still living as a device AlignedSpec; the host leaf-wise
     replay runs at materialization (deterministically identical to the
     on-device replay that committed the tree)."""
+
+    walk = None     # the tree as the record walk takes it, once asked for
 
     def materialize(self, rec_host=None) -> Tree:
         from .aligned_builder import replay_spec
@@ -719,7 +721,7 @@ class GBDT:
         name (the `train_path` event's `rejected`), or None. The engine
         owns the score lane and computes gradients in its records, so a
         subclass that reshapes either is out unless it says how it rides
-        (GOSS does: boosting_variants.py)."""
+        (GOSS and DART do: boosting_variants.py)."""
         if (type(self).get_training_score is not GBDT.get_training_score
                 or type(self)._post_bagging_gradients
                 is not GBDT._post_bagging_gradients):
@@ -973,6 +975,8 @@ class GBDT:
             su.score = eng.apply_spec_to_scores(
                 su.score, 0, self._valid_bins_dev[i], spec,
                 applied_dev, self.shrinkage_rate)
+            su.score = self._aligned_valid_sample(
+                eng, sample, su.score, self._valid_bins_dev[i], applied_dev)
         if self.valid_scores:
             # queue the device metric programs for THIS iteration before
             # the eager next build: the device executes in queue order,
@@ -1072,6 +1076,23 @@ class GBDT:
         """(bag indices, bag count, g, h) an exact fallback trains on."""
         return bag_idx, bag_cnt, gdev, hdev
 
+    # ---- a variant that reaches back to earlier trees (DART overrides
+    # all three): what it does to them rides the queued round as
+    # `sample`, and host state it keeps per round is dropped with a
+    # discarded round
+    def _aligned_after_build(self, eng, sample, out, prev_ok) -> None:
+        """Queued right behind `sample`'s build; `out` is the build's
+        (spec, ncommit, exact, applied), `prev_ok` the chain flag it was
+        dispatched under."""
+
+    def _aligned_valid_sample(self, eng, sample, score, vbins, applied):
+        """A valid set's score after what `sample` did to earlier trees."""
+        return score
+
+    def _aligned_forget_from(self, first_iter: int) -> None:
+        """Iterations `first_iter` and later were dispatched and are
+        discarded (their device work was a gated no-op)."""
+
     def _dispatch_aligned(self, eng, fmask, sample=None):
         grads = None
         if eng._pgrad is None:
@@ -1080,12 +1101,15 @@ class GBDT:
             scores = eng.row_scores_dev()
             gd, hd = self.objective.get_gradients(scores[None, :])
             grads = (gd[0], hd[0])
+        prev_ok = eng._last_exact
         self._aligned_sample_stats = self._aligned_apply_sample(
             eng, sample, grads)
-        return self._dispatch_device(
+        out = self._dispatch_device(
             "engine.train_iter",
             lambda: eng.train_iter(self.shrinkage_rate, fmask, grads=grads,
                                    boost_iter=self.iter))
+        self._aligned_after_build(eng, sample, out, prev_ok)
+        return out
 
     def _aligned_pipeline_depth(self) -> int:
         """How many dispatched rounds may stay unresolved before the
@@ -1096,7 +1120,9 @@ class GBDT:
         loop's depth: its selection is a device program queued ahead of
         each build from the record's own score lane, so it needs no host
         sync, and each queued round carries the seed that makes its
-        sample again, so recovery replays it as drawn. The pure training
+        sample again, so recovery replays it as drawn. DART likewise: its
+        drop set is the host's draw from `drop_seed`'s stream, known at
+        dispatch, and rides the queued round. The pure training
         loop (the bench hot path) batches 8 rounds per pull: one
         device_get per 8 iterations instead of per iteration. Safe
         because an inexact round's successors are chain-gated score
@@ -1154,6 +1180,7 @@ class GBDT:
         del self.models[-drop:]
         del self._pending_numsplits[-drop:]
         self.iter -= drop
+        self._aligned_forget_from(self.iter)
         eng = self._aligned_eng_ref
 
         def fallback_args(p):
@@ -1186,6 +1213,7 @@ class GBDT:
                  spec.round_stats, self._aligned_sample_stats))
         if not bool(exact):
             self._note_aligned_fallback(eng, "inexact replay")
+            self._aligned_forget_from(self.iter)
             return self._aligned_fallback_iter(init_scores, eng, fmask,
                                                sample=sample)
         _record_aligned_iter(self.iter, *counters)

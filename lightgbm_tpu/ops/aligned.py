@@ -1299,6 +1299,169 @@ def slot_hist_pass(records, slots, meta, num_slots, num_features, b_pad,
                                 group, subbin)
 
 
+# ---------------------------------------------------------------------------
+# tree walk over the records as they lie
+# ---------------------------------------------------------------------------
+# Trees one `walk_pass` call holds tables for. The call walks the first
+# `ntrees` of them, a run-time number, so one compiled program serves
+# every drop count and a caller with more trees calls again.
+WALK_TREES = 8
+WALK_NEVER = 1.0e6      # the depth of a leaf no row reaches (padding)
+
+
+def walk_dims(num_leaves: int, wcnt: int, bits: int):
+    """(Np, Lp, W8, Fp) of the walk tables: nodes and leaves padded to
+    whole MXU tiles, the bin words to whole sublane tiles, and the decoded
+    bins (one plane a position in the word, each `W8` rows) to a whole
+    bf16 tile."""
+    lp = max(128, -(-int(num_leaves) // 128) * 128)
+    w8 = -(-wcnt // 8) * 8
+    fp = -(-(_bpw_for_bits(bits) * w8) // 16) * 16
+    return lp, lp, w8, fp
+
+
+def walk_expand(nodes, leaves, nn, nb, db, mt, *, w8, bits, fp):
+    """The kernel's tables of ONE tree from its compact form (vmap it over
+    trees). `nodes` i32 [5, Np]: inner feature, threshold bin, default
+    left, parent node (-1 at the root) and side under it (+1 left, -1
+    right); `leaves` i32 [2, Lp]: parent node and side; `nn` the number
+    of nodes (leaves are nn + 1, both numbered densely from 0). nb / db /
+    mt: the features' num_bin, default_bin and missing type.
+
+    Returns (sel bf16 [Np, Fp]: a node's feature as a one-hot over the
+    decoded bin planes; thr, dbin, dlv f32 [Np, 1]: the threshold, the
+    bin that takes the default side (-1: none) and that side as +-1;
+    path bf16 [Lp, Np]: +1 / -1 where the leaf lies left / right under
+    the node, 0 elsewhere; depth f32 [Lp, 1]: the leaf's ancestors, or
+    WALK_NEVER for a leaf the tree does not have). A row reaches a leaf
+    exactly when its +-1 decisions dotted with the leaf's path row give
+    the leaf's depth."""
+    feat, thr, dl, parent, side = nodes
+    np_, lp = feat.shape[0], leaves.shape[1]
+    bpw = _bpw_for_bits(bits)
+    used = jnp.arange(np_) < nn
+    col = (feat % bpw) * w8 + feat // bpw
+    sel = ((col[:, None] == jnp.arange(fp)[None, :])
+           & used[:, None]).astype(jnp.bfloat16)
+    mtf = mt[feat]
+    dbin = jnp.where(mtf == MISSING_ZERO_C, db[feat],
+                     jnp.where(mtf == MISSING_NAN_C, nb[feat] - 1, -1))
+    dbin = jnp.where(used, dbin, -1)
+    dlv = jnp.where(dl != 0, 1.0, -1.0)
+    lrow = jnp.arange(lp)
+
+    def up(st):
+        path, at, s, steps = st
+        live = at >= 0
+        a = jnp.clip(at, 0, np_ - 1)
+        path = path.at[lrow, a].add(jnp.where(live, s, 0))
+        return (path, jnp.where(live, parent[a], -1),
+                jnp.where(live, side[a], 0), steps + 1)
+
+    has = lrow <= nn
+    # no leaf lies deeper than there are nodes: tables that are no tree
+    # cannot hold the loop
+    path, _, _, _ = lax.while_loop(
+        lambda st: jnp.any(st[1] >= 0) & (st[3] < np_), up,
+        (jnp.zeros((lp, np_), jnp.int32),
+         jnp.where(has, leaves[0], -1), jnp.where(has, leaves[1], 0),
+         jnp.int32(0)))
+    depth = jnp.where(has, jnp.sum(jnp.abs(path), axis=1), WALK_NEVER)
+
+    def colf(x):
+        return x.astype(jnp.float32)[:, None]
+    return (sel, colf(thr), colf(dbin), colf(dlv),
+            path.astype(jnp.bfloat16), colf(depth))
+
+
+def _walk_kernel(cnt_ref, nt_ref, rec_ref, sel_ref, thr_ref, dbin_ref,
+                 dlv_ref, path_ref, depth_ref, val_ref, out_ref, *, chunk,
+                 w8, bits, lane, fp):
+    """One chunk: score lane += the sum over the call's trees of the value
+    of the leaf each live row reaches. No gather: a node's bin is a
+    one-hot product over the chunk's decoded bins, the leaf a product of
+    the +-1 decisions with the tree's path matrix, both exact in bf16 with
+    f32 sums (one term, and whole numbers under 256); the leaf's f32
+    value is picked by a select and a sum over leaves of which one is not
+    0. Only the 8-lane window of the score lane is written back."""
+    i = pl.program_id(0)
+    lo = lane - lane % 8
+    out_ref[0] = rec_ref[0, lo:lo + 8, :]
+    cnt = cnt_ref[i]
+
+    @pl.when((cnt > 0) & (nt_ref[0] > 0))
+    def _():
+        bpw = _bpw_for_bits(bits)
+        words = rec_ref[0, 0:w8, :]
+        planes = [((words >> (bits * k)) & ((1 << bits) - 1))
+                  .astype(jnp.float32) for k in range(bpw)]
+        if fp > bpw * w8:
+            planes.append(jnp.zeros((fp - bpw * w8, chunk), jnp.float32))
+        binsb = jnp.concatenate(planes, axis=0).astype(jnp.bfloat16)
+        live = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) < cnt
+        score = lax.bitcast_convert_type(rec_ref[0, lane:lane + 1, :],
+                                         jnp.float32)
+
+        def tree(t, score):
+            nbin = lax.dot_general(sel_ref[t], binsb,
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+            dec = jnp.where(nbin == dbin_ref[t], dlv_ref[t],
+                            jnp.where(nbin <= thr_ref[t], 1.0, -1.0))
+            reach = lax.dot_general(path_ref[t], dec.astype(jnp.bfloat16),
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            val = jnp.sum(jnp.where(reach == depth_ref[t], val_ref[t], 0.0),
+                          axis=0, keepdims=True)
+            return score + jnp.where(live, val, 0.0)
+
+        score = lax.fori_loop(0, nt_ref[0], tree, score)
+        out_ref[0, lane - lo:lane - lo + 1, :] = \
+            lax.bitcast_convert_type(score, jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "wcnt", "bits",
+                                             "lane", "interpret"))
+def walk_pass(records, cnts, ntrees, sel, thr, dbin, dlv, path, depth,
+              vals, chunk, wcnt, bits, lane, interpret=False):
+    """records with f32 lane `lane` of every live row (position under its
+    chunk's `cnts`) increased, tree after tree in f32, by `vals[t,
+    leaf]` of the leaf the row reaches in tree t < `ntrees`; the tables
+    are `walk_expand`'s, stacked over WALK_TREES trees, `vals` f32 [T, Lp,
+    1]. In place (the records are aliased to the result: donate them), one
+    read of every chunk and one write of the score lane's window."""
+    compile_cache.note_trace()
+    nc, w_pad, _ = records.shape
+    t, np_, fp = sel.shape
+    lp = path.shape[1]
+    w8 = -(-wcnt // 8) * 8
+    kernel = functools.partial(_walk_kernel, chunk=chunk, w8=w8, bits=bits,
+                               lane=lane, fp=fp)
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda i, c, n: (0,) * len(shape))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nc,),
+        in_specs=[pl.BlockSpec((1, w_pad, chunk), lambda i, c, n: (i, 0, 0)),
+                  whole(t, np_, fp), whole(t, np_, 1), whole(t, np_, 1),
+                  whole(t, np_, 1), whole(t, lp, np_), whole(t, lp, 1),
+                  whole(t, lp, 1)],
+        out_specs=pl.BlockSpec((1, 8, chunk),
+                               lambda i, c, n: (i, lane // 8, 0)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(records.shape, records.dtype),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=100 << 20),
+        interpret=interpret,
+        name="walk_pass",
+    )(cnts, jnp.reshape(ntrees, (1,)).astype(jnp.int32), records, sel, thr,
+      dbin, dlv, path, depth, vals)
+
+
 def aligned_available() -> bool:
     """True when the aligned pipeline's kernels compile natively."""
     return jax.default_backend() == "tpu"

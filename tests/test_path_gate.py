@@ -34,6 +34,9 @@ def _data(kind="binary", n=400, f=6):
         return X, np.digitize(m, [-0.5, 0.5]).astype(np.float32), None
     if kind == "classes128":
         return X, (np.arange(n) % 128).astype(np.float32), None
+    if kind == "category":       # column 0 holds five categories
+        X[:, 0] = rng.integers(0, 5, n)
+        return X, (m + X[:, 0] > 2).astype(np.float32), None
     assert kind == "queries"
     return (X, np.digitize(m, [-1, 0, 1, 2]).astype(np.float32),
             np.full(n // 20, 20, np.int32))
@@ -44,6 +47,8 @@ TABLE = [
     # --- taken
     ("binary", dict(objective="binary", **INTERPRET), {}, "aligned", None),
     ("goss", dict(objective="binary", boosting="goss", **INTERPRET), {},
+     "aligned", None),
+    ("dart", dict(objective="binary", boosting="dart", **INTERPRET), {},
      "aligned", None),
     ("bagging", dict(objective="binary", bagging_freq=1,
                      bagging_fraction=0.5, **INTERPRET), {},
@@ -106,9 +111,32 @@ TABLE = [
     ("custom-fobj", dict(objective="none", **INTERPRET), dict(fobj=True),
      "fused", "no objective"),
     # --- refused by the boosting variant
-    ("dart", dict(objective="binary", boosting="dart", **INTERPRET), {},
-     "fused", "boosting=dart: the engine's score lane cannot follow "
-              "dropped trees"),
+    ("dart-multiclass", dict(objective="multiclass", num_class=3,
+                             boosting="dart", **INTERPRET),
+     dict(kind="classes3"), "fused",
+     "boosting=dart with multiclass: the record walk follows one score "
+     "lane"),
+    ("dart-data-parallel", dict(objective="binary", boosting="dart",
+                                tree_learner="data", num_machines=2,
+                                **INTERPRET), {}, "fused",
+     "boosting=dart under tree_learner=data: the record walk is not "
+     "sharded"),
+    # ("bundled features" is a reason no config reaches: the dataset
+    # bundles under boosting=gbdt and goss only)
+    ("dart-categorical", dict(objective="binary", boosting="dart",
+                              categorical_feature="0", **INTERPRET),
+     dict(kind="category"), "fused",
+     "boosting=dart with categorical features: the record walk takes "
+     "numerical splits"),
+    ("dart-lambdarank", dict(objective="lambdarank", boosting="dart",
+                             tpu_grow_mode="aligned", **INTERPRET),
+     dict(kind="queries", n=500), "fused",
+     "boosting=dart with a non-pointwise objective: its row-order "
+     "gradients are made before the drop"),
+    ("dart-1025-leaves", dict(objective="binary", boosting="dart",
+                              num_leaves=1025, **INTERPRET), {}, "fused",
+     "boosting=dart above 1024 leaves: the record walk's tables are "
+     "sized for VMEM"),
     ("rf", dict(objective="binary", boosting="rf", bagging_freq=1,
                 bagging_fraction=0.7, **INTERPRET), {},
      "fused", "boosting=rf: one-time gradients and a running-average "
