@@ -209,7 +209,7 @@ class AlignedEngine:
         # host work over all rows: what the readers of this seam need
         # of the layout rides on it
         with obs_trace.seam("aligned.pack", rows=int(learner.n)) as sm:
-            rec_all, cnts_all = self._pack_host(
+            rec_all, cnts_all, ext_of_row = self._pack_host(
                 learner, objective, init_row_scores, bagged, num_class)
             nbytes = int(rec_all.nbytes) + int(cnts_all.nbytes)
             sm.attrs.update(
@@ -217,7 +217,9 @@ class AlignedEngine:
                 C=int(C), NC=int(self.NC), bits=int(self.bits),
                 shards=int(self.nd), count_pass=self.count_pass,
                 route_tile=route_tile(C), route_tiles=C // route_tile(C),
-                route_selectors=ROUTE_SELECTORS)
+                route_selectors=ROUTE_SELECTORS,
+                grad_layout="rows" if ext_of_row is None else "tiles",
+                grad_slots=int(self.ext_n))
         # the span is the ENQUEUE of the transfer: nothing here waits for
         # it, so what the host does not copy synchronously lands in the
         # first program's wait (the first train.drain)
@@ -230,6 +232,9 @@ class AlignedEngine:
             else:
                 self.rec = jnp.asarray(rec_all)
                 self.cnts = jnp.asarray(cnts_all)
+            # device int32[n], or None for the identity (see _pack_host)
+            self.ext_of_row = (None if ext_of_row is None
+                               else jnp.asarray(ext_of_row))
         from ..obs import memory as obs_memory
         obs_memory.track(
             "train/aligned_records", self,
@@ -253,7 +258,7 @@ class AlignedEngine:
             self.W, self.wcnt, self.w_used, self.bits,
             tuple(sorted(self.lanes.items())), self.compact, self.ext,
             self.gh_off, self.num_class, self.mc_mode, self.interpret,
-            self.bagged, self.axis, self.nd, self.per_shard,
+            self.bagged, self.axis, self.nd, self.per_shard, self.ext_shape,
             _os.environ.get("LGBT_KCAP", ""),
             str(self.mesh) if self.mesh is not None else None)
         self._score_cache = None     # (iter_tag, np array)
@@ -290,7 +295,8 @@ class AlignedEngine:
         """The host-side half of construction: choose the record layout,
         pack every shard's rows into [nc_local, W, C] records
         (`pack_records`), pad to the static chunk grid and fill the score
-        lanes. Returns (rec_all, cnts_all) as numpy, ready to upload."""
+        lanes. Returns (rec_all, cnts_all, ext_of_row) as numpy, ready to
+        upload."""
         C = self.C
         bins = np.asarray(learner.ds.bins)
         # feature-parallel zero-padding only; under EFB bundling
@@ -362,6 +368,19 @@ class AlignedEngine:
         assert self.axis is None or self.mesh is not None, \
             "data-parallel aligned engine needs learner._mesh"
         self.n = learner.n
+        # the EXTERNAL index space: what the EXT record's index lane
+        # counts in, and so the order in which scores leave the records
+        # for the objective and its gradients come back. Where the
+        # objective states a layout of its own (`grad_layout`) it is that
+        # layout's slots, and neither scores nor gradients stop over in
+        # row order between trees; else it is the rows. Row-order callers
+        # compose with `ext_of_row` (None: the identity). A mesh keeps
+        # the rows: its materialise sums shards that own row ranges
+        layout = (objective.grad_layout()
+                  if self.ext and self.axis is None else None)
+        self.ext_shape = (self.n,) if layout is None else layout.shape
+        self.ext_n = self.n if layout is None else layout.slots
+        ext_of_row = None if layout is None else layout.slot_of_row
         L = self.cfg.num_leaves
         # default speculation budget 4.5x num_leaves: late-training
         # iterations speculate far more than early ones (gains converge
@@ -392,7 +411,9 @@ class AlignedEngine:
                 self.C, with_bag=bagged, compact=self.compact,
                 num_class=num_class, with_prob=with_prob,
                 max_bin=pack_max_bin, ext=self.ext,
-                rid_base=lo)
+                rid_base=lo,
+                index=None if ext_of_row is None
+                else (ext_of_row, self.ext_n))
             # every shard's chunk grid has IDENTICAL static shape:
             # ceil(per_shard/C) data chunks + S + 2 fresh
             nc_data = (self.per_shard + C - 1) // C
@@ -426,16 +447,39 @@ class AlignedEngine:
         else:
             rec_all = np.concatenate(shard_recs, axis=0)
             cnts_all = np.concatenate(shard_cnts)
-        return rec_all, cnts_all
+        return rec_all, cnts_all, ext_of_row
 
     # ------------------------------------------------------------------
+    def _ext_args(self):
+        """`ext_of_row` as the trailing operand of a program that maps
+        between row order and the records; nothing under the identity."""
+        return () if self.ext_of_row is None else (self.ext_of_row,)
+
+    def _materialized(self, lane: str = "score", rows: bool = True):
+        """One f32 lane of the records as a DEVICE array, in row order or
+        (`rows=False`) in external order, shaped `ext_shape`. Under the
+        identity the two are one program."""
+        rows = rows or self.ext_of_row is None
+        if not rows:
+            key = ("mat_ext", lane)
+        else:
+            key = "mat" if lane == "score" else ("mat", lane)
+        fn = self._program(
+            key, lambda: self._materialize_program(lane, rows),
+            specs=self._specs("mat") if self.axis else None)
+        return fn(self.rec, self.cnts, *(self._ext_args() if rows else ()))
+
     def row_scores_dev(self):
-        """Training scores in ROW order as a DEVICE array (for objectives
-        whose gradients are not pointwise — ranking needs query-grouped
-        rows, so gradients are computed in row order and re-ingested)."""
-        fn = self._program("mat", self._materialize_program,
-                           specs=self._specs("mat") if self.axis else None)
-        return fn(self.rec, self.cnts)
+        """Training scores in ROW order as a DEVICE array (metrics, the
+        drain, a fallback)."""
+        return self._materialized()
+
+    def ext_scores_dev(self):
+        """Training scores in EXTERNAL order as a DEVICE array: what an
+        objective whose gradients are not pointwise is handed between
+        trees (ranking needs query-grouped documents), and the order its
+        gradients come back in (`train_iter`'s `grads`)."""
+        return self._materialized(rows=False)
 
     # ------------------------------------------------------------------
     def _grad_lanes(self, rec):
@@ -488,8 +532,9 @@ class AlignedEngine:
                        class_k: int = 0):
         """The jitted per-iteration program: gradients + speculative tree
         build. Returns (rec_final, cnts_final, AlignedSpec). With
-        external_grads the g/h lanes come from row-order arrays gathered
-        by the rid lane instead of the pointwise in-lane computation.
+        external_grads the g/h lanes come from external-order arrays
+        (`ext_scores_dev`) gathered by the index lane instead of the
+        pointwise in-lane computation.
 
         MULTICLASS (self.num_class > 1, one program per class_k):
         per-class g/h lanes are written from the K score lanes FIRST
@@ -770,9 +815,9 @@ class AlignedEngine:
             elif external_grads:
                 assert not self.compact, \
                     "external grads need grad lanes (standard layout)"
-                rid = jnp.clip(rec[:, ln["rid"], :], 0, self.n - 1)
-                ge = g_rows[rid]
-                he = h_rows[rid]
+                rid = jnp.clip(rec[:, ln["rid"], :], 0, self.ext_n - 1)
+                ge = g_rows.reshape(-1)[rid]
+                he = h_rows.reshape(-1)[rid]
                 if bagged:
                     bag = _f32(rec[:, ln["bag"], :])
                     ge = ge * bag
@@ -1347,8 +1392,9 @@ class AlignedEngine:
         ALL device values, no sync. `applied_dev` = exact & prev_ok: True
         iff this program's score-lane update actually happened (a
         dispatch following an inexact predecessor is a guaranteed no-op
-        and will be discarded by the host). `grads` = (g_rows, h_rows)
-        device arrays for non-pointwise objectives. `boost_iter` names
+        and will be discarded by the host). `grads` = (g, h) device
+        arrays in external order (`ext_scores_dev`) for non-pointwise
+        objectives. `boost_iter` names
         the boosting iteration on the dispatch seam (the engine's own
         dispatch count where the caller gives none)."""
         fmask = self.learner._fmask_arr(feature_mask)
@@ -1467,7 +1513,8 @@ class AlignedEngine:
                            donate=(0,),
                            specs=self._specs("setsc")
                            if self.axis else None)
-        self.rec = fn(self.rec, jnp.asarray(row_scores, jnp.float32))
+        self.rec = fn(self.rec, jnp.asarray(row_scores, jnp.float32),
+                      *self._ext_args())
         self._score_cache = None
 
     def row_scores_mc_dev(self) -> jax.Array:
@@ -1813,7 +1860,8 @@ class AlignedEngine:
         fn = self._program("setbag", self._set_bag_program, donate=(0,),
                            specs=self._specs("setbag")
                            if self.axis else None)
-        self.rec = fn(self.rec, jnp.asarray(mask_rows, jnp.float32))
+        self.rec = fn(self.rec, jnp.asarray(mask_rows, jnp.float32),
+                      *self._ext_args())
         self.bag_sampled = False
 
     def goss_select(self, seed: int, top_k: int, other_k: int,
@@ -1821,8 +1869,8 @@ class AlignedEngine:
                     boost_iter: Optional[int] = None):
         """Queue one iteration's GOSS selection (`ops/goss.py`) over the
         records as they lie: a = |g x h| from the score and label lanes
-        (or `grads` = row-order (g, h) gathered by row id, for an
-        objective that is not pointwise), before any multiplier; the
+        (or `grads` = external-order (g, h) gathered by the index lane,
+        for an objective that is not pointwise), before any multiplier; the
         per-row multiplier goes into the bag lane, which the build
         program queued next reads. Nothing is pulled and nothing is
         uploaded but the seed. Returns the device counters (kept_top,
@@ -1838,7 +1886,7 @@ class AlignedEngine:
                                                   grads is not None),
                 donate=(0,))
             self.rec, stats = fn(self.rec, self.cnts, jnp.uint32(seed),
-                                 *(grads or ()))
+                                 *(grads or ()), *self._ext_args())
         self.bag_sampled = True
         return stats
 
@@ -1847,13 +1895,19 @@ class AlignedEngine:
         ln = self.lanes
         n, C = self.n, self.C
 
-        def goss_select(rec, cnts, seed, g_rows=None, h_rows=None):
+        def goss_select(rec, cnts, seed, g_rows=None, h_rows=None,
+                        ext_of_row=None):
             rid = rec[:, ln["rid"], :]
             live = (jnp.arange(C, dtype=jnp.int32)[None, :]
-                    < cnts[:, None]) & (rid < n)
+                    < cnts[:, None]) & (rid < self.ext_n)
             if external:
-                at = jnp.clip(rid, 0, n - 1)
-                g, h = g_rows[at], h_rows[at]
+                at = jnp.clip(rid, 0, self.ext_n - 1)
+                g, h = g_rows.reshape(-1)[at], h_rows.reshape(-1)[at]
+                if ext_of_row is not None:
+                    # the sampling key is the ROW's, whatever the lane
+                    # counts in: a fallback draws the sample in row order
+                    rid = self._rows_to_ext(
+                        jnp.arange(n, dtype=jnp.int32), ext_of_row)[at]
             else:
                 g, h = self._pgrad(
                     _f32(rec[:, ln["score"], :]),
@@ -1869,9 +1923,7 @@ class AlignedEngine:
         """One f32 lane of the records in ROW order (a check's accessor;
         pulls N): "grad" and "hess" hold what the last build trained
         on."""
-        fn = self._program(("mat", lane),
-                           lambda: self._materialize_program(lane))
-        return np.asarray(fn(self.rec, self.cnts))
+        return np.asarray(self._materialized(lane))
 
     def row_bag(self) -> np.ndarray:
         """The bag lane in ROW order."""
@@ -1882,7 +1934,7 @@ class AlignedEngine:
         n = self.n
         compact = self.compact
 
-        def fn(rec, mask):
+        def fn(rec, mask, ext_of_row=None):
             if compact:
                 meta = rec[:, ln["meta"], :]
                 rid = jnp.clip(meta & META_RID_MASK, 0, n)
@@ -1892,8 +1944,9 @@ class AlignedEngine:
                 meta = (meta & jnp.int32(0x7FFFFFFF)) | jnp.where(
                     vals > 0.5, jnp.int32(-(1 << 31)), jnp.int32(0))
                 return rec.at[:, ln["meta"], :].set(meta)
-            rid = jnp.clip(rec[:, ln["rid"], :], 0, n)
-            vals = jnp.concatenate([mask, jnp.zeros(1, jnp.float32)])[rid]
+            rid = jnp.clip(rec[:, ln["rid"], :], 0, self.ext_n)
+            vals = jnp.concatenate([self._rows_to_ext(mask, ext_of_row),
+                                    jnp.zeros(1, jnp.float32)])[rid]
             return rec.at[:, ln["bag"], :].set(_i32(vals))
         return fn
 
@@ -1904,19 +1957,25 @@ class AlignedEngine:
         self._last_exact = jnp.asarray(True)   # lane is authoritative again
 
     def _rid_lanes(self, rec):
-        """Row ids per record cell (compact: low 24 meta bits)."""
+        """External ids per record cell: row ids unless the objective
+        stated a layout at pack time (compact: low 24 meta bits)."""
         ln = self.lanes
         if self.compact:
             return rec[:, ln["meta"], :] & META_RID_MASK
         return rec[:, ln["rid"], :]
 
+    def _rows_to_ext(self, x, ext_of_row):
+        """Row-order `x` in external order; slots of no row hold 0."""
+        if ext_of_row is None:
+            return x
+        return jnp.zeros(self.ext_n, x.dtype).at[ext_of_row].set(x)
+
     def _set_scores_program(self, class_k: int = 0):
-        n = self.n
         lane = self.lanes["score"] + class_k
 
-        def fn(rec, scores):
-            rid = jnp.clip(self._rid_lanes(rec), 0, n - 1)
-            vals = scores[rid]
+        def fn(rec, scores, ext_of_row=None):
+            rid = jnp.clip(self._rid_lanes(rec), 0, self.ext_n - 1)
+            vals = self._rows_to_ext(scores, ext_of_row)[rid]
             return rec.at[:, lane, :].set(_i32(vals))
         return fn
 
@@ -1925,18 +1984,16 @@ class AlignedEngine:
         metrics / dumps need this)."""
         if self._score_cache is not None:
             return self._score_cache
-        fn = self._program("mat", self._materialize_program,
-                           specs=self._specs("mat") if self.axis else None)
-        out = np.asarray(fn(self.rec, self.cnts))
+        out = np.asarray(self.row_scores_dev())
         self._score_cache = out
         return out
 
-    def _materialize_program(self, lane: str = "score"):
+    def _materialize_program(self, lane: str = "score", rows: bool = True):
         ln = self.lanes
-        n, C, NC = self.n, self.C, self.NC
+        n, C = self.ext_n, self.C
         ax = self.axis
 
-        def fn(rec, cnts):
+        def fn(rec, cnts, ext_of_row=None):
             rid = self._rid_lanes(rec).reshape(-1)
             sc = _f32(rec[:, ln[lane], :]).reshape(-1)
             pos = jnp.arange(C, dtype=jnp.int32)
@@ -1947,5 +2004,7 @@ class AlignedEngine:
                 # each shard scatters only its own rows; the psum
                 # assembles the full row-order vector on every shard
                 out = lax.psum(out, ax)
-            return out
+            if not rows:
+                return out.reshape(self.ext_shape)
+            return out if ext_of_row is None else out[ext_of_row]
         return fn

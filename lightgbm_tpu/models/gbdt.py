@@ -1096,11 +1096,17 @@ class GBDT:
     def _dispatch_aligned(self, eng, fmask, sample=None):
         grads = None
         if eng._pgrad is None:
-            # non-pointwise objective (ranking): gradients need ROW order
-            # — materialize scores on device, compute, re-ingest by rid
-            scores = eng.row_scores_dev()
-            gd, hd = self.objective.get_gradients(scores[None, :])
-            grads = (gd[0], hd[0])
+            # non-pointwise objective (ranking): the scores leave the
+            # records in the engine's external order, the gradients come
+            # back in it and are re-ingested by the index lane. That
+            # order is the objective's own layout where it stated one at
+            # pack time, else the rows
+            scores = eng.ext_scores_dev()
+            if eng.ext_of_row is None:
+                gd, hd = self.objective.get_gradients(scores[None, :])
+                grads = (gd[0], hd[0])
+            else:
+                grads = self.objective.slot_gradients(scores)
         prev_ok = eng._last_exact
         self._aligned_sample_stats = self._aligned_apply_sample(
             eng, sample, grads)

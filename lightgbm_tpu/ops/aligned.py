@@ -212,12 +212,15 @@ def pack_records(bins: np.ndarray, label: np.ndarray,
                  weight, chunk: int, with_bag: bool = False,
                  compact: bool = False, num_class: int = 1,
                  with_prob: bool = False, max_bin: int = 0,
-                 ext: bool = False, rid_base: int = 0):
+                 ext: bool = False, rid_base: int = 0, index=None):
     """Host-side ingest: [N, F] uint8 bins -> [NC, W, C] int32 records.
 
     Returns (records, wcnt, W, cnts) where cnts[i] is the number of valid
     rows in chunk i (C except the last). rid_base offsets the stored row
     ids (data-parallel shards pack their local rows with GLOBAL ids).
+    `index` = (int32[N] ids, their count) puts each row's id in another
+    index space into the EXT record's index lane in place of its row id;
+    pad cells get the count, one past every id, as they do in row ids.
     """
     n, f = bins.shape
     # bin words pack at the narrowest width the MAPPERS' bin range
@@ -251,7 +254,12 @@ def pack_records(bins: np.ndarray, label: np.ndarray,
     rec = np.zeros((n_pad, w_pad), np.int32)
     rec[:, :wcnt] = packed.astype(np.int64).astype(np.int32)
     if ext:
-        rec[:, lanes["rid"]] = rid_base + np.arange(n_pad, dtype=np.int32)
+        if index is None:
+            rec[:, lanes["rid"]] = rid_base + np.arange(n_pad,
+                                                        dtype=np.int32)
+        else:
+            rec[:n, lanes["rid"]] = index[0]
+            rec[n:, lanes["rid"]] = index[1]
         if with_bag:
             rec[:n, lanes["bag"]] = np.ones(n, np.float32).view(np.int32)
     elif compact:

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +29,19 @@ from ..io.dataset import Metadata
 
 def _sign(x):
     return jnp.sign(x)
+
+
+class GradLayout(NamedTuple):
+    """An objective's own gradient layout: `shape` of the score and
+    gradient arrays `slot_gradients` takes and gives, and each row's flat
+    slot in them. Slots that hold no row take any score and give 0."""
+    shape: Tuple[int, ...]
+    slot_of_row: np.ndarray         # [num_data] int32, a bijection onto
+    #                                 the slots that hold a row
+
+    @property
+    def slots(self) -> int:
+        return int(np.prod(self.shape))
 
 
 class ObjectiveFunction:
@@ -109,6 +122,12 @@ class ObjectiveFunction:
 
     def _point_grad(self, score, label):
         raise NotImplementedError
+
+    def grad_layout(self) -> Optional[GradLayout]:
+        """The layout this objective computes gradients in where that is
+        not row order and it can take scores and give gradients there
+        directly (`slot_gradients`); None: row order, `get_gradients`."""
+        return None
 
     def point_grad_fn(self):
         """Pure elementwise (score, label, weight|None) -> (g, h), or
@@ -732,7 +751,7 @@ class LambdarankNDCG(ObjectiveFunction):
         # propagates.
         self._fused_pack = None
         self._fused_dev = None
-        self._fused_fn = None
+        self._fused_fn = {}
         self._fused_interpret = False
         self.rank_fused_active = False
         self.rank_fused_fallback_queries = 0
@@ -849,9 +868,11 @@ class LambdarankNDCG(ObjectiveFunction):
         return tabs
 
     def _fused_dev_tables(self):
-        """Device-resident per-slot constants for the fused kernel
-        (doc ids, query ids, label gains, labels, inv max DCG, discount
-        table) — uploaded once, like `_bucket_dev_tables`."""
+        """Device-resident constants of the fused kernel: the row tables
+        of the row-order wrapper (`doc_idx`, `slot_of_row`), the per-slot
+        tables (query ids, label gains, labels, inv max DCG, discount
+        table) and the per-slot weights or None; uploaded once, like
+        `_bucket_dev_tables`."""
         tabs = self._fused_dev
         if tabs is None:
             pack = self._fused_pack
@@ -866,33 +887,63 @@ class LambdarankNDCG(ObjectiveFunction):
                 real,
                 self._inv_max_dcg[np.clip(pack.qid, 0, None)],
                 0.0).astype(np.float32)
+            w_t = None if self._weight_np is None else np.where(
+                real, self._weight_np[pack.doc_idx], 0.0).astype(np.float32)
             # see _bucket_dev_tables: cached constants must be concrete
             # even when the first call runs under an outer trace
             with jax.ensure_compile_time_eval():
-                tabs = (jnp.asarray(pack.doc_idx),
-                        jnp.asarray(pack.qid),
-                        jnp.asarray(gain), jnp.asarray(lab),
-                        jnp.asarray(inv),
-                        jnp.asarray(
-                            pallas_rank.discount_table(pack.tile)))
+                tabs = dict(
+                    rows=(jnp.asarray(pack.doc_idx),
+                          jnp.asarray(pack.slot_of_row(self.num_data))),
+                    slots=(jnp.asarray(pack.qid),
+                           jnp.asarray(gain), jnp.asarray(lab),
+                           jnp.asarray(inv),
+                           jnp.asarray(
+                               pallas_rank.discount_table(pack.tile))),
+                    weight=None if w_t is None else jnp.asarray(w_t))
             self._fused_dev = tabs
         return tabs
 
-    def _fused_grads(self, score):
-        pack = self._fused_pack
-        fn = self._fused_fn
+    def _fused_program(self, rows: bool):
+        """The slot-order program or its row-order wrapper: ONE kernel,
+        whichever order the caller keeps its scores in."""
+        fn = self._fused_fn.get(rows)
         if fn is None:
+            pack = self._fused_pack
             lut = int(getattr(self.cfg, "tpu_rank_sigmoid_bins", 0))
             fn = compile_cache.program(
                 pallas_rank.fused_program_key(
-                    self.num_data, pack, float(self.cfg.sigmoid), lut,
-                    self._fused_interpret),
+                    pack, float(self.cfg.sigmoid), lut,
+                    self._fused_interpret, rows),
                 lambda: pallas_rank.make_fused_grad_fn(
-                    self.num_data, pack.num_tiles, pack.tile,
-                    int(pack.band), float(self.cfg.sigmoid), lut,
-                    interpret=self._fused_interpret))
-            self._fused_fn = fn
-        return fn(score, *self._fused_dev_tables())
+                    pack.num_tiles, pack.tile, int(pack.band),
+                    float(self.cfg.sigmoid), lut,
+                    interpret=self._fused_interpret, rows=rows))
+            self._fused_fn[rows] = fn
+        return fn
+
+    def grad_layout(self):
+        """The kernel's tile pack, where every query rides the kernel: a
+        leftover query's rows have no slot, and its bucket works in row
+        order."""
+        pack = self._fused_pack
+        if not self.rank_fused_active or pack.leftover.any():
+            return None
+        return GradLayout((pack.num_tiles, pack.tile),
+                          pack.slot_of_row(self.num_data))
+
+    def slot_gradients(self, score_t):
+        """(g, h) [NT, T] at scores [NT, T], weights folded in: what
+        `get_gradients` computes, without the way in from row order and
+        back out (only under a `grad_layout`)."""
+        tabs = self._fused_dev_tables()
+        return self._fused_program(rows=False)(
+            score_t, *tabs["slots"], tabs["weight"])
+
+    def _fused_grads(self, score):
+        tabs = self._fused_dev_tables()
+        return self._fused_program(rows=True)(
+            score, *tabs["rows"], *tabs["slots"])
 
     def get_gradients(self, scores):
         score = scores[0]

@@ -80,6 +80,15 @@ class QueryTilePack(NamedTuple):
     def tile(self) -> int:
         return int(self.doc_idx.shape[1])
 
+    def slot_of_row(self, num_data: int) -> np.ndarray:
+        """[num_data] int32: each row's flat slot in the pack (the
+        inverse of `doc_idx` over real slots), -1 for a row of a
+        leftover query."""
+        real = (self.qid >= 0).reshape(-1)
+        out = np.full(num_data, -1, np.int32)
+        out[self.doc_idx.reshape(-1)[real]] = np.flatnonzero(real)
+        return out
+
 
 def pack_query_tiles(query_boundaries: np.ndarray, tile: int,
                      sub: int = SUBTILE) -> QueryTilePack:
@@ -332,21 +341,33 @@ def _rank_tile_kernel(in_ref, disc_ref, out_ref, *,
         [to_row(acc_ha[b]) + acc_hb[b] for b in range(nb)], axis=1)
 
 
-def make_fused_grad_fn(num_data: int, num_tiles: int, tile: int,
-                       band: int, sigmoid: float, lut_bins: int = 0,
-                       sub: int = SUBTILE, interpret: bool = False):
-    """Jitted (score[n], doc_idx, qid, gain, label, inv, disc_tab) ->
-    (g[n], h[n]). All tables are runtime args, so one compiled program
-    serves every booster at the same shapes; register the result under
+def make_fused_grad_fn(num_tiles: int, tile: int, band: int,
+                       sigmoid: float, lut_bins: int = 0,
+                       sub: int = SUBTILE, interpret: bool = False,
+                       rows: bool = False):
+    """The jitted gradient program, in the kernel's own layout:
+
+    (score_t[NT, T], qid, gain, label, inv, disc_tab[, w_t])
+        -> (g_t[NT, T], h_t[NT, T]); pad slots give 0 whatever they hold,
+        `w_t` is the per-slot weight where the objective has weights;
+
+    or, with `rows`, its row-order wrapper:
+
+    (score[n], doc_idx, slot_of_row, qid, gain, label, inv, disc_tab)
+        -> (g[n], h[n]): one gather into the tile pack, the same kernel,
+        one gather a lane back out (`slot_of_row` < 0: the row is in no
+        tile and reads 0).
+
+    All tables are runtime args, so one compiled program serves every
+    booster at the same shapes; register the result under
     `compile_cache.program` keyed by `fused_program_key(...)`."""
     kernel = functools.partial(
         _rank_tile_kernel, tile=tile, sub=sub, band=band,
         sigmoid=float(sigmoid), lut_bins=int(lut_bins))
     NT, T = num_tiles, tile
 
-    def grad_fn(score, doc_idx, qid, gain, label, inv, disc_tab):
-        compile_cache.note_trace()
-        sc = jnp.where(qid >= 0, score[doc_idx], 0.0).astype(jnp.float32)
+    def slot_grads(score_t, qid, gain, label, inv, disc_tab, w_t=None):
+        sc = jnp.where(qid >= 0, score_t, 0.0).astype(jnp.float32)
         bits = functools.partial(lax.bitcast_convert_type,
                                  new_dtype=jnp.int32)
         pad = jnp.zeros_like(qid)
@@ -365,21 +386,30 @@ def make_fused_grad_fn(num_data: int, num_tiles: int, tile: int,
         )(packed, disc_tab)
         g_t = jnp.where(qid >= 0, gh[:, 0, :], 0.0)
         h_t = jnp.where(qid >= 0, gh[:, 1, :], 0.0)
-        flat = doc_idx.reshape(-1)
-        g = jnp.zeros((num_data,), jnp.float32).at[flat].add(
-            g_t.reshape(-1))
-        h = jnp.zeros((num_data,), jnp.float32).at[flat].add(
-            h_t.reshape(-1))
-        return g, h
+        if w_t is not None:
+            g_t, h_t = g_t * w_t, h_t * w_t
+        return g_t, h_t
 
-    return jax.jit(grad_fn)
+    # the trace names a kernel after the jitted function that calls it
+    def grad_fn(score_t, qid, gain, label, inv, disc_tab, w_t=None):
+        compile_cache.note_trace()
+        return slot_grads(score_t, qid, gain, label, inv, disc_tab, w_t)
+
+    def grad_rows_fn(score, doc_idx, slot_of_row, *tables):
+        compile_cache.note_trace()
+        g_t, h_t = slot_grads(score[doc_idx], *tables)
+        at = jnp.maximum(slot_of_row, 0)
+        return (jnp.where(slot_of_row >= 0, g_t.reshape(-1)[at], 0.0),
+                jnp.where(slot_of_row >= 0, h_t.reshape(-1)[at], 0.0))
+
+    return jax.jit(grad_rows_fn if rows else grad_fn)
 
 
-def fused_program_key(num_data: int, pack: QueryTilePack, sigmoid: float,
-                      lut_bins: int, interpret: bool):
-    return ("rank_fused", num_data, pack.num_tiles, pack.tile,
-            int(pack.band), SUBTILE, float(sigmoid), int(lut_bins),
-            bool(interpret))
+def fused_program_key(pack: QueryTilePack, sigmoid: float, lut_bins: int,
+                      interpret: bool, rows: bool):
+    return ("rank_fused", pack.num_tiles, pack.tile, int(pack.band),
+            SUBTILE, float(sigmoid), int(lut_bins), bool(interpret),
+            bool(rows))
 
 
 def discount_table(tile: int) -> np.ndarray:
