@@ -184,7 +184,8 @@ class AlignedEngine:
 
     def __init__(self, learner, objective, interpret: bool = False,
                  init_row_scores=None, bagged: bool = False,
-                 num_class: int = 1, bag_multiplier: bool = False):
+                 num_class: int = 1, bag_multiplier: bool = False,
+                 bag_device: bool = False):
         self.learner = learner
         self.objective = objective
         self.cfg = learner.cfg
@@ -198,7 +199,17 @@ class AlignedEngine:
         # once, where its lane is over 0.5 (`_payload_gh`)
         self.bag_multiplier = bag_multiplier
         self.bag_sampled = False     # a selection has written the lane
-        assert bagged or not bag_multiplier
+        # a device program writes the bag and nothing is uploaded: GOSS's
+        # multipliers, or plain bagging's 0/1 draw (`bag_select`), which
+        # fits the compact record's bag bit as the host's mask does
+        self.bag_device = bag_device
+        # what `bag_select` left: the seed of the bag the records hold,
+        # which no program touches until the next draw, and its in-bag
+        # row count as a device scalar
+        self.bag_drawn = None
+        self.bag_kept = None
+        assert bagged or not bag_device
+        assert bag_device or not bag_multiplier
         self.num_class = num_class
         # the chunk is the unit of the grid, the DMA, the flush and the
         # route words (destinations pack 16-bit, capping NC at 65k
@@ -219,7 +230,11 @@ class AlignedEngine:
                 route_tile=route_tile(C), route_tiles=C // route_tile(C),
                 route_selectors=ROUTE_SELECTORS,
                 grad_layout="rows" if ext_of_row is None else "tiles",
-                grad_slots=int(self.ext_n))
+                grad_slots=int(self.ext_n),
+                # who writes the bag: a device program from the index
+                # lane, or the host's mask through `set_bag`
+                bag=("device" if self.bag_device else "host") if bagged
+                else "none")
         # the span is the ENQUEUE of the transfer: nothing here waits for
         # it, so what the host does not copy synchronously lands in the
         # first program's wait (the first train.drain)
@@ -1863,6 +1878,59 @@ class AlignedEngine:
         self.rec = fn(self.rec, jnp.asarray(mask_rows, jnp.float32),
                       *self._ext_args())
         self.bag_sampled = False
+        self.bag_drawn = None
+
+    def bag_select(self, seed: int, cnt: int):
+        """Queue plain bagging's draw (`ops/goss.py:bag_multipliers`) over
+        the records as they lie: the `cnt` rows with the smallest key of
+        (row id, `seed`) are in the bag, the others out of it (the bag
+        lane's 1.0 / 0.0, or the compact record's bag bit). Nothing is
+        uploaded but the seed and nothing is pulled; the bag then moves
+        with its rows, untouched, until the next draw. Returns the in-bag
+        row count, a device scalar (`bag_kept`)."""
+        assert self.bag_device and not self.bag_multiplier \
+            and self.axis is None
+        fn = self._program(("bag_select", cnt),
+                           lambda: self._bag_select_program(cnt),
+                           donate=(0,))
+        self.rec, self.bag_kept = fn(self.rec, self.cnts, jnp.uint32(seed),
+                                     *self._ext_args())
+        self.bag_drawn = int(seed)
+        return self.bag_kept
+
+    def _bag_select_program(self, cnt):
+        from ..ops.goss import bag_multipliers
+
+        def bag_select(rec, cnts, seed, ext_of_row=None):
+            rid, live = self._row_ids(rec, cnts, ext_of_row)
+            mult, kept = bag_multipliers(rid, live, seed, cnt)
+            return self._with_bag(rec, mult), kept
+        return bag_select
+
+    def _with_bag(self, rec, vals):
+        """`rec` with the per-cell 0/1 f32 `vals` as its bag."""
+        ln = self.lanes
+        if not self.compact:
+            return rec.at[:, ln["bag"], :].set(_i32(vals))
+        # the bag bit is the SIGN bit (31): int32-safe clear + set
+        meta = (rec[:, ln["meta"], :] & jnp.int32(0x7FFFFFFF)) | jnp.where(
+            vals > 0.5, jnp.int32(-(1 << 31)), jnp.int32(0))
+        return rec.at[:, ln["meta"], :].set(meta)
+
+    def _row_ids(self, rec, cnts, ext_of_row=None):
+        """(row id, does the cell hold a row) per record cell. The index
+        lane names the row, or under the `tiles` layout its slot in the
+        objective's pack, which `ext_of_row` inverted turns back: a
+        sampling key is the ROW's, whatever the lane counts in, since a
+        fallback draws the sample again in row order."""
+        rid = self._rid_lanes(rec)
+        live = (jnp.arange(self.C, dtype=jnp.int32)[None, :]
+                < cnts[:, None]) & (rid < self.ext_n)
+        if ext_of_row is not None:
+            rid = self._rows_to_ext(
+                jnp.arange(self.n, dtype=jnp.int32),
+                ext_of_row)[jnp.clip(rid, 0, self.ext_n - 1)]
+        return rid, live
 
     def goss_select(self, seed: int, top_k: int, other_k: int,
                     multiply: float, grads=None,
@@ -1893,21 +1961,13 @@ class AlignedEngine:
     def _goss_select_program(self, top_k, other_k, multiply, external):
         from ..ops.goss import goss_multipliers
         ln = self.lanes
-        n, C = self.n, self.C
 
         def goss_select(rec, cnts, seed, g_rows=None, h_rows=None,
                         ext_of_row=None):
-            rid = rec[:, ln["rid"], :]
-            live = (jnp.arange(C, dtype=jnp.int32)[None, :]
-                    < cnts[:, None]) & (rid < self.ext_n)
+            rid, live = self._row_ids(rec, cnts, ext_of_row)
             if external:
-                at = jnp.clip(rid, 0, self.ext_n - 1)
+                at = jnp.clip(rec[:, ln["rid"], :], 0, self.ext_n - 1)
                 g, h = g_rows.reshape(-1)[at], h_rows.reshape(-1)[at]
-                if ext_of_row is not None:
-                    # the sampling key is the ROW's, whatever the lane
-                    # counts in: a fallback draws the sample in row order
-                    rid = self._rows_to_ext(
-                        jnp.arange(n, dtype=jnp.int32), ext_of_row)[at]
             else:
                 g, h = self._pgrad(
                     _f32(rec[:, ln["score"], :]),
@@ -1930,24 +1990,11 @@ class AlignedEngine:
         return self.row_lane("bag")
 
     def _set_bag_program(self):
-        ln = self.lanes
-        n = self.n
-        compact = self.compact
-
         def fn(rec, mask, ext_of_row=None):
-            if compact:
-                meta = rec[:, ln["meta"], :]
-                rid = jnp.clip(meta & META_RID_MASK, 0, n)
-                vals = jnp.concatenate(
-                    [mask, jnp.zeros(1, jnp.float32)])[rid]
-                # bag bit is the SIGN bit (31): int32-safe clear + set
-                meta = (meta & jnp.int32(0x7FFFFFFF)) | jnp.where(
-                    vals > 0.5, jnp.int32(-(1 << 31)), jnp.int32(0))
-                return rec.at[:, ln["meta"], :].set(meta)
-            rid = jnp.clip(rec[:, ln["rid"], :], 0, self.ext_n)
+            rid = jnp.clip(self._rid_lanes(rec), 0, self.ext_n)
             vals = jnp.concatenate([self._rows_to_ext(mask, ext_of_row),
                                     jnp.zeros(1, jnp.float32)])[rid]
-            return rec.at[:, ln["bag"], :].set(_i32(vals))
+            return self._with_bag(rec, vals)
         return fn
 
     def set_row_scores(self, row_scores):
@@ -1995,7 +2042,11 @@ class AlignedEngine:
 
         def fn(rec, cnts, ext_of_row=None):
             rid = self._rid_lanes(rec).reshape(-1)
-            sc = _f32(rec[:, ln[lane], :]).reshape(-1)
+            if lane == "bag" and self.compact:      # the meta lane's sign
+                sc = (rec[:, ln["meta"], :] < 0).astype(
+                    jnp.float32).reshape(-1)
+            else:
+                sc = _f32(rec[:, ln[lane], :]).reshape(-1)
             pos = jnp.arange(C, dtype=jnp.int32)
             valid = (pos[None, :] < cnts[:, None]).reshape(-1)
             rid = jnp.where(valid & (rid < n), rid, n)

@@ -55,7 +55,8 @@ class GOSS(GBDT):
     writes the multipliers into the bag lane ahead of the build, and a
     fallback makes the same sample again from (scores, seed)."""
 
-    _bag_on_device = True
+    _bag_on_device = True       # by its own rule, whatever bagging_* say
+    _bag_multiplier = True
 
     def __init__(self, cfg: Config, train_data: Dataset, objective=None):
         super().__init__(cfg, train_data, objective)
@@ -387,6 +388,11 @@ class DART(GBDT):
             self.tree_weight[i] *= k / den
 
     # ---- the aligned engine's side (gbdt._train_one_iter_aligned)
+    def _host_bag_why(self) -> Optional[str]:
+        return super()._host_bag_why() or (
+            "bagging under boosting=dart: a queued round's sample is "
+            "its drop set")
+
     def _maybe_rebag(self, eng) -> None:
         super()._maybe_rebag(eng)
         self._dart_rng_before = self._drop_rng.get_state()

@@ -1335,19 +1335,22 @@ class DeviceTreeLearner:
         return notes
 
     def aligned_engine(self, objective, init_row_scores=None,
-                       bagged=False, num_class=1, bag_multiplier=False):
+                       bagged=False, num_class=1, bag_multiplier=False,
+                       bag_device=False):
         """The persistent AlignedEngine for (this learner, objective)."""
         eng = getattr(self, "_aligned_eng", None)
         if eng is None or eng.objective is not objective \
                 or getattr(eng, "bagged", False) != bagged \
                 or getattr(eng, "num_class", 1) != num_class \
-                or eng.bag_multiplier != bag_multiplier:
+                or eng.bag_multiplier != bag_multiplier \
+                or eng.bag_device != bag_device:
             from .aligned_builder import AlignedEngine
             eng = AlignedEngine(
                 self, objective,
                 interpret=bool(self.cfg.tpu_aligned_interpret),
                 init_row_scores=init_row_scores, bagged=bagged,
-                num_class=num_class, bag_multiplier=bag_multiplier)
+                num_class=num_class, bag_multiplier=bag_multiplier,
+                bag_device=bag_device)
             self._aligned_eng = eng
         return eng
 

@@ -25,6 +25,7 @@ import numpy as np
 from ..config import Config
 from ..io.dataset import Dataset
 from ..obs import trace as obs_trace
+from ..ops.goss import bag_rows
 from ..ops.metrics import Metric, create_metrics
 from ..ops.objectives import ObjectiveFunction, create_objective
 from ..ops.predict import TreePredictor, stack_trees, _predict_binned_stacked
@@ -332,15 +333,20 @@ class GBDT:
 
     # ------------------------------------------------------------------
     def _bagging(self, iter_idx: int) -> None:
-        """reference GBDT::Bagging (gbdt.cpp:209-275) — per-chunk
-        hypergeometric-ish sampling replaced by exact-count choice; balanced
-        bagging keeps pos/neg fractions separately (gbdt.cpp:177-207)."""
+        """reference GBDT::Bagging (gbdt.cpp:209-275) in ROW order — the
+        per-block `Random::NextFloat` walk replaced by an exact-count
+        select: the `cnt` rows with the smallest integer key of (row id,
+        the re-bag's seed), `ops/goss.py:bag_rows`, the numpy twin of
+        what the aligned engine draws on the device, so every path
+        trains on one bag. Balanced bagging keeps pos/neg fractions
+        separately (gbdt.cpp:177-207), drawn here."""
         cfg = self.cfg
-        need = (cfg.bagging_freq > 0
-                and (cfg.bagging_fraction < 1.0 or self._balanced_bagging))
-        if not need or iter_idx % cfg.bagging_freq != 0:
+        if not self._will_bag():
             return
+        redraw = iter_idx % cfg.bagging_freq == 0
         if self._balanced_bagging:
+            if not redraw:
+                return
             pos = self._label_np > 0
             pos_idx = np.nonzero(pos)[0]
             neg_idx = np.nonzero(~pos)[0]
@@ -349,13 +355,49 @@ class GBDT:
             take_neg = self._bag_rng.rand(len(neg_idx)) \
                 < cfg.neg_bagging_fraction
             sel = np.sort(np.concatenate([pos_idx[take_pos],
-                                          neg_idx[take_neg]]))
+                                          neg_idx[take_neg]])
+                          ).astype(np.int32)
         else:
-            cnt = int(cfg.bagging_fraction * self.num_data)
-            sel = np.sort(self._bag_rng.choice(self.num_data, cnt,
-                                               replace=False))
-        self.bag_data_indices = sel.astype(np.int32)
+            if redraw:
+                seed = self._draw_bag_seed()
+            elif (self.bag_data_indices is None and self._bag_on_device
+                  and self._aligned_sample is not None):
+                seed = self._aligned_sample[0]  # the engine drew it: made
+            else:                               # here from what it holds
+                return
+            sel = bag_rows(self.num_data, seed, self._bag_cnt_plain())
+        self.bag_data_indices = sel
         self.bag_data_cnt = len(sel)
+
+    def _bag_cnt_plain(self) -> int:
+        return int(self.cfg.bagging_fraction * self.num_data)
+
+    def _draw_bag_seed(self) -> int:
+        """A re-bag's seed, from `bagging_seed`'s stream as GOSS draws
+        its."""
+        return int(self._bag_rng.randint(0, 2**31 - 1))
+
+    def _host_bag_why(self) -> Optional[str]:
+        """Why this run's bag is drawn on the host and uploaded
+        (`AlignedEngine.set_bag`, pipeline depth 1) where the engine could
+        draw it, for the `train_path` event's `gate_notes`; None where
+        the device draws it."""
+        if self._balanced_bagging:
+            return ("balanced bagging: pos_/neg_bagging_fraction draw "
+                    "per label from the host's stream")
+        if self.num_tree_per_iteration > 1:
+            return ("bagging on the multiclass engine: it pulls every "
+                    "round's flags anyway, one round in flight")
+        if getattr(self.learner, "mode", "") == "data":
+            return ("bagging under tree_learner=data: the device select "
+                    "does not sum its counts over the mesh")
+        return None
+
+    @property
+    def _bag_on_device(self) -> bool:
+        """The bag lane is written by a device program and never
+        uploaded: plain bagging here, GOSS by its own rule."""
+        return self._will_bag() and self._host_bag_why() is None
 
     # ------------------------------------------------------------------
     def boost_from_average(self, class_id: int) -> float:
@@ -620,6 +662,13 @@ class GBDT:
                         msg += f" ({note})"
                 except Exception:
                     pass
+            if self._will_bag() and not self._bag_on_device:
+                # not a fallback either: the engine trains, but on a bag
+                # the host draws and uploads at every re-bag, one round
+                # in flight
+                note = f"bag drawn on the host: {self._host_bag_why()}"
+                notes.append(note)
+                msg += f" ({note})"
         if not path.startswith("aligned"):
             gate = getattr(self.learner, "aligned_mode_gate", None)
             if gate is not None:
@@ -904,7 +953,8 @@ class GBDT:
                 self.objective,
                 init_row_scores=np.asarray(self.train_score.score[0]),
                 bagged=self._will_bag(),
-                bag_multiplier=self._bag_on_device)
+                bag_multiplier=self._bag_multiplier,
+                bag_device=self._bag_on_device)
             self._aligned_eng_ref = eng
         stash = getattr(self, "_aligned_next", None)
         if stash is not None:
@@ -1023,12 +1073,25 @@ class GBDT:
         return False
 
     def _maybe_rebag(self, eng) -> None:
-        """Resample on bagging_freq boundaries and re-ingest the 0/1 mask
-        into the bag lane (gbdt.cpp:209-275; the engine's histograms and
-        gradients honor it, the physical layout keeps ALL rows so
-        out-of-bag rows still get scores)."""
+        """Resample on bagging_freq boundaries (gbdt.cpp:209-275; the
+        engine's histograms and gradients honor the bag lane, the
+        physical layout keeps ALL rows so out-of-bag rows still get
+        scores). A device-drawn bag is only NAMED here: the seed comes
+        off the stream at a re-bag, (seed, iteration) rides the round as
+        its `sample`, and `_aligned_apply_sample` queues the draw. A
+        host-drawn one (`_host_bag_why`) is drawn and its 0/1 mask
+        re-ingested into the lane."""
         cfg = self.cfg
-        if not (self._will_bag() and self.iter % cfg.bagging_freq == 0):
+        if not self._will_bag():
+            return
+        redraw = self.iter % cfg.bagging_freq == 0
+        if self._bag_on_device:
+            if redraw:
+                self._aligned_sample = (self._draw_bag_seed(), self.iter)
+                self.bag_data_indices = None    # no host copy of it
+                self.bag_data_cnt = self._bag_cnt_plain()
+            return
+        if not redraw:
             return
         self._bagging(self.iter)
         mask = np.zeros(self.num_data, np.float32)
@@ -1060,20 +1123,38 @@ class GBDT:
         self.bag_data_indices = bag_idx
         self.bag_data_cnt = bag_cnt
 
-    # ---- a sample drawn ON THE DEVICE (GOSS overrides all four): the
-    # host holds only what makes the sample again, `_aligned_sample`
-    _bag_on_device = False          # the bag lane holds multipliers that
-    #                                 a device program writes each iteration
+    # ---- a sample drawn ON THE DEVICE (`_bag_on_device`): the host
+    # holds only what makes the sample again, `_aligned_sample`. Plain
+    # bagging's is (seed, iteration drawn at) of the bag in force, kept
+    # from one re-bag to the next and by a checkpoint; GOSS overrides all
+    # three with its per-iteration seed
+    _bag_multiplier = False         # the sample weights its rows (GOSS)
     _aligned_sample = None          # of the iteration about to be built
     _aligned_sample_stats = None    # its selection's device counters
 
     def _aligned_apply_sample(self, eng, sample, grads):
         """Queue `sample`'s selection ahead of the build; returns its
-        device counters, or None."""
-        return None
+        device counters, or None. Plain bagging draws only where the
+        lane holds another bag than `sample`'s: at a re-bag, and when a
+        discarded round is replayed behind a successor that drew anew.
+        Between draws no program touches the lane."""
+        if not self._bag_on_device or sample is None:
+            return None
+        seed, drawn_at = sample
+        if eng.bag_drawn != seed:
+            cnt = self._bag_cnt_plain()
+            with obs_trace.seam("bag.draw", iter=drawn_at, seed=seed,
+                                cnt=cnt, freq=int(self.cfg.bagging_freq)):
+                eng.bag_select(seed, cnt)
+        return {"bag_kept": eng.bag_kept}
 
     def _aligned_fallback_sample(self, sample, bag_idx, bag_cnt, gdev, hdev):
-        """(bag indices, bag count, g, h) an exact fallback trains on."""
+        """(bag indices, bag count, g, h) an exact fallback trains on: a
+        device-drawn bag made again in row order from its seed."""
+        if self._bag_on_device and sample is not None:
+            bag_idx = bag_rows(self.num_data, sample[0],
+                               self._bag_cnt_plain())
+            bag_cnt = len(bag_idx)
         return bag_idx, bag_cnt, gdev, hdev
 
     # ---- a variant that reaches back to earlier trees (DART overrides
@@ -1108,8 +1189,10 @@ class GBDT:
             else:
                 grads = self.objective.slot_gradients(scores)
         prev_ok = eng._last_exact
-        self._aligned_sample_stats = self._aligned_apply_sample(
-            eng, sample, grads)
+        self._aligned_sample_stats = dict(
+            self._aligned_apply_sample(eng, sample, grads) or {},
+            features_used=int(self.learner.num_real_features
+                              if fmask is None else fmask.sum()))
         out = self._dispatch_device(
             "engine.train_iter",
             lambda: eng.train_iter(self.shrinkage_rate, fmask, grads=grads,
@@ -1120,13 +1203,14 @@ class GBDT:
     def _aligned_pipeline_depth(self) -> int:
         """How many dispatched rounds may stay unresolved before the
         host pulls their exactness flags. Per-iteration metric evals,
-        host-drawn bagging (its mask is uploaded at every re-bag), and
-        multiclass sync every round anyway, so they keep
-        depth 1 (the classic one-behind pipeline). GOSS runs at the pure
-        loop's depth: its selection is a device program queued ahead of
-        each build from the record's own score lane, so it needs no host
-        sync, and each queued round carries the seed that makes its
-        sample again, so recovery replays it as drawn. DART likewise: its
+        host-drawn bagging (`_host_bag_why`: its mask is uploaded at
+        every re-bag), and multiclass sync every round anyway, so they
+        keep depth 1 (the classic one-behind pipeline). Plain bagging
+        and GOSS run at the pure loop's depth: the bag is a device
+        program queued ahead of a build from the record's own index (and
+        score) lanes, so it needs no host sync, and each queued round
+        carries the seed that makes its bag again, so recovery replays
+        it as drawn. DART likewise: its
         drop set is the host's draw from `drop_seed`'s stream, known at
         dispatch, and rides the queued round. The pure training
         loop (the bench hot path) batches 8 rounds per pull: one

@@ -1,7 +1,9 @@
-"""GOSS row selection (goss.hpp:96-134) as two SELECTS over arrays of any
-shape and order: the one definition behind the fused leaf-wise path, the
-sweep trainer's vmapped fleet select and the aligned engine's device
-program over its permuted record matrix.
+"""Row sampling as SELECTS over arrays of any shape and order. GOSS
+(goss.hpp:96-134) takes two, plain bagging (gbdt.cpp:209-275,
+`bag_multipliers`) the second of them alone with multiplier 1. GOSS's
+is the one definition behind the fused leaf-wise path, the sweep
+trainer's vmapped fleet select and the aligned engine's device program
+over its permuted record matrix.
 
 A row is kept at multiplier 1 when its a = |g x h| is at least the
 `top_k`-th largest a (ties at the threshold are all kept); of the rest,
@@ -18,7 +20,11 @@ sort, no payload, no scatter, and nothing leaves the device.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 DIGIT_BITS = 4
@@ -79,3 +85,32 @@ def goss_multipliers(a, rid, live, seed, top_k: int, other_k: int,
              jnp.sum(sampled, dtype=jnp.int32),
              lax.bitcast_convert_type(thr, jnp.float32))
     return mult, stats
+
+
+def bag_multipliers(rid, live, seed, cnt: int):
+    """Plain bagging's uniform draw as the same kind of select: (f32 1.0
+    for the `cnt` live rows with the smallest `goss_key(rid, seed)`, 0.0
+    for every other cell; kept i32). One order statistic, `PASSES`
+    counting passes over the key array; keys are distinct, so exactly
+    `cnt` rows are kept wherever at least `cnt` are live."""
+    key = goss_key(rid, seed)
+    kept = live & (key <= _kth(key, live, cnt, largest=False))
+    return kept.astype(jnp.float32), jnp.sum(kept, dtype=jnp.int32)
+
+
+def bag_rows(n: int, seed: int, cnt: int) -> np.ndarray:
+    """`bag_multipliers` in ROW order, for the host: the sorted int32 ids
+    of the `cnt` rows of `n` with the smallest key under `seed`. What the
+    fused leaf-wise loop, the host learner and the engine's row-order
+    fallback train on, so every path draws the bag the engine drew."""
+    cnt = min(int(cnt), n)
+    if cnt <= 0:
+        return np.zeros(0, np.int32)
+    key = np.asarray(_row_keys(n, jnp.uint32(seed)))
+    return np.flatnonzero(
+        key <= np.partition(key, cnt - 1)[cnt - 1]).astype(np.int32)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _row_keys(n: int, seed):
+    return goss_key(jnp.arange(n, dtype=jnp.int32), seed)

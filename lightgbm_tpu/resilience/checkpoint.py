@@ -234,6 +234,10 @@ class CheckpointManager:
             "num_data": int(gbdt.num_data),
             "num_class": int(gbdt.num_tree_per_iteration),
             "bag_data_cnt": int(gbdt.bag_data_cnt),
+            # a device-drawn sample (plain bagging's bag in force: no
+            # indices are held here), as what makes it again
+            "aligned_sample": (gbdt._aligned_sample
+                               if gbdt._bag_on_device else None),
             "shrinkage_rate": float(gbdt.shrinkage_rate),
             "best_iteration": int(getattr(booster, "best_iteration", -1)),
             "rng": capture_rng_states(gbdt),

@@ -121,6 +121,9 @@ def restore(booster, bundle: Dict[str, Any], callbacks=()) -> int:
     else:
         gbdt.bag_data_indices = None
     gbdt.bag_data_cnt = int(state["bag_data_cnt"])
+    sample = state.get("aligned_sample")
+    gbdt._aligned_sample = (tuple(sample) if isinstance(sample, list)
+                            else sample)
 
     install_rng_states(gbdt, state["rng"])
 

@@ -98,7 +98,8 @@ def engine_for(config: dict, rows_made: int):
     learner.n = int(config["rows"])     # the layout follows the row count
     return AlignedEngine(learner, gbdt.objective, interpret=False,
                          bagged=gbdt._will_bag(),
-                         bag_multiplier=gbdt._bag_on_device)
+                         bag_multiplier=gbdt._bag_multiplier,
+                         bag_device=gbdt._bag_on_device)
 
 
 def compile_build(eng):
