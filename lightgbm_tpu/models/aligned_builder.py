@@ -46,7 +46,8 @@ from ..ops.aligned import (META_BAG, META_LABEL, META_LABEL_MASK,
                            META_RID_MASK, R_CAT,
                            R_COPY, R_DL, R_MT, R_SHIFT, _bpw_for_bits,
                            count_pass, lane_layout, move_pass,
-                           pack_records, pack_route2, slot_hist_pass)
+                           pack_records, pack_route2, park_pass,
+                           slot_hist_pass)
 from ..utils import log
 from ..ops.histogram import NUM_HIST_STATS
 from .device_learner import (BF_GAIN, BF_LG, BF_LH, BF_LOUT, BF_RG, BF_RH,
@@ -73,6 +74,7 @@ ROUND_STATS = (
     "chunks_dead",     # chunks of no live block that still take move_pass's
                        # compute path: the chunk map hands the free tail to
                        # the last slot, and its route word says "split"
+                       # (a parked chunk's says "copy": it is none of them)
 )
 
 
@@ -287,6 +289,32 @@ class AlignedEngine:
         # flush_pending_apply), gated by the exactness CHAIN self._gate
         self._mc_pending = None
         self._gate = jnp.asarray(True)
+        # the PARKED block (`parks`): the rows the bag leaves out lie, in
+        # chunks of their own, from chunk `park_begin` to the buffer's
+        # end (NC: nothing is parked), outside every round of a tree and
+        # scored by a walk behind it. `cnts` counts every chunk's rows,
+        # live or parked, so what reads rows where they lie sees both
+        # alike. `_park_kept`: the rows in the bag under the lane as it
+        # stands (a device scalar, the count of whoever wrote it), and
+        # `_park_stale`: the lane was written since the last partition
+        self.park_begin = jnp.int32(self.NC)
+        self._park_kept = jnp.int32(self.n)
+        self._park_stale = False
+        self.park_counters = {}     # of the last build, device scalars
+
+    @property
+    def parks(self) -> bool:
+        """Whether the build program parks the rows a bag leaves out. By
+        what the engine is, no option: a bag, ONE score lane on ONE chip
+        (`walk_trees` asserts the same two), and trees the record walk
+        can follow (`DART._aligned_variant_gate` names the same three:
+        unbundled bins, numerical splits, tables that fit VMEM). Every
+        other bagged engine moves all of its rows, as before PR 36."""
+        lr = self.learner
+        return bool(self.bagged and self.num_class == 1
+                    and self.axis is None and not lr.bundled
+                    and not np.any(np.asarray(lr.meta["bin_type"]) != 0)
+                    and self.cfg.num_leaves <= 1024)
 
     @property
     def count_pass(self) -> bool:
@@ -569,7 +597,7 @@ class AlignedEngine:
         # longer shrinks K (the old K=64 fallback cost rounds AND still
         # blew VMEM at F=137 x 255 bins); the store SPILLS to HBM and
         # streams through the kernel's 2-deep DMA staging ring instead
-        from ..ops.aligned import hist_layout
+        from ..ops.aligned import hist_layout, walk_expand, walk_pass
         _bh = lr.hist_bins if lr.bundled else lr.max_bin_global
         import os as _os
         kcap = int(_os.environ.get("LGBT_KCAP", "0") or 0) or 256  # graftlint: disable=LGT006 sound: LGBT_KCAP is mirrored into _trace_sig, so a changed value changes the cache key
@@ -661,6 +689,10 @@ class AlignedEngine:
         axis = lr.axis_name
         dp = axis is not None and lr.parallel_mode == "data"
         counted = self.count_pass
+        parks = self.parks
+        if parks:
+            walk_tree = self._walk_tree_program()
+            _, _, walk_w8, walk_fp = self._walk_dims()
 
         def _gsum(x):
             return lax.psum(x, axis) if dp else x
@@ -742,9 +774,11 @@ class AlignedEngine:
                 rcond, rbody, st0)
             return commit, need, ncommit
 
-        def chunk_maps(leafI, exists, cnts_pc=None, root_span=None):
+        def chunk_maps(leafI, exists, cnts_pc=None, root_span=None,
+                       park_begin=NC):
             """(slot_of_chunk [NC], cnt_of_chunk [NC], first, last) from
-            the block tables.
+            the block tables. Chunks from `park_begin` on are the parked
+            block's and belong to no slot: the spanning root ends there.
 
             Freshly-moved layouts are table-exact (full chunks, ceil'd
             last), so per-chunk counts come from the clip formula. The
@@ -757,7 +791,7 @@ class AlignedEngine:
             nch = (count + C - 1) // C
             if root_span is not None:
                 is_root = jnp.arange(S + 1) == 0
-                nch = jnp.where(root_span & is_root, NC, nch)
+                nch = jnp.where(root_span & is_root, park_begin, nch)
             # slot/in-range mapping shared with undo_spec_scores (see
             # slot_in_any_map); nch here may carry the root_span
             # override, so the range check stays local
@@ -766,6 +800,8 @@ class AlignedEngine:
             in_any = ((chunk_iota >= begin[slot_of])
                       & (chunk_iota < end_of)
                       & exists[slot_of] & (count[slot_of] > 0))
+            if parks:
+                in_any = in_any & (chunk_iota < park_begin)
             if cnts_pc is None:
                 cnt_of = jnp.clip(count[slot_of]
                                   - (chunk_iota - begin[slot_of]) * C, 0, C)
@@ -788,7 +824,7 @@ class AlignedEngine:
 
         def build(rec, cnts_pc, feature_mask_f32, scale_in, prev_ok,
                   g_rows=None, h_rows=None, pleafI=None, pcover=None,
-                  pn_exec=None, pscale=None):
+                  pn_exec=None, pscale=None, park=None):
             if multiclass:
                 # deferred application of the PREVIOUS dispatch's
                 # committed leaf values to ITS class lane: the valmap is
@@ -842,8 +878,51 @@ class AlignedEngine:
             else:
                 rec = self._grad_lanes(rec)
 
-            # ---------- root ----------
+            rec_b0 = jnp.zeros_like(rec)
             root_slots = jnp.zeros(NC, jnp.int32)
+            round_stats0 = jnp.zeros((Sm1 + int(parks), len(ROUND_STATS)),
+                                     jnp.int32)
+            park_begin, park_rounds = NC, 0     # nothing parked, no partition
+            if parks:
+                # ---------- the partition by the bag ----------
+                # `park` = (where the parked block begins, whether the
+                # lane was written since the last partition, the in-bag
+                # rows under it). One round of its own ahead of the tree's
+                # and a row of the round table, run where the lane is
+                # newer and a row is, or was, out of the bag: every chunk
+                # that holds rows, live or parked, is split by the bag
+                # into the live rows from chunk 0 on and the parked block
+                # at the buffer's end. A loop of no or one trip whose
+                # carry takes the rows back into the first buffer (as the
+                # copy behind an odd round count does, below): the tree's
+                # rounds then find the rows where they always do and the
+                # parked block in BOTH buffers, so no round need carry it
+                park_begin, repark, kept = park
+                rows_all = jnp.sum(cnts_pc).astype(jnp.int32)
+                go = repark & ((rows_all > kept) | (park_begin < NC))
+                park_rounds = go.astype(jnp.int32)
+                round_stats0 = round_stats0.at[0].set(jnp.where(
+                    go, jnp.stack([jnp.sum((cnts_pc > 0).astype(jnp.int32)),
+                                   jnp.int32(0), rows_all, jnp.int32(1),
+                                   jnp.int32(0), jnp.int32(0)]), 0))
+
+                def park_round(st):
+                    _, rec_b, cnts, pb = park_pass(
+                        st[0], st[1], jnp.int32(0), st[2], kept, C, W,
+                        wcnt, bag_lane, bits=bits, w_used=self.w_used,
+                        interpret=interpret)
+                    return rec_b, rec_b, cnts, pb, jnp.bool_(False)
+
+                rec, rec_b0, cnts_pc, park_begin, _ = lax.while_loop(
+                    lambda st: st[4], park_round,
+                    (rec, rec_b0, cnts_pc, park_begin, go))
+                parked = chunk_iota >= park_begin
+                park_cnts = jnp.where(parked, cnts_pc, 0)
+                cnts_pc = jnp.where(parked, 0, cnts_pc)
+                # a parked chunk costs the root histogram a fetch
+                root_slots = parked.astype(jnp.int32)
+
+            # ---------- root ----------
             root_hist_all = slot_hist_pass(rec, root_slots, cnts_pc, 1,
                                            G, BH, C, group, wcnt,
                                            bag_lane=bag_lane, bits=bits,
@@ -902,12 +981,11 @@ class AlignedEngine:
             # (4.5 GiB, 14.6 ms, 13 times a tree at Criteo's size:
             # PERF.md section 6, PR 30). The second buffer is a temporary
             # of this program, in the place of that fresh output
-            state = (jnp.int32(0), rec, jnp.zeros_like(rec), cnts_pc,
+            state = (jnp.int32(0), rec, rec_b0, cnts_pc,
                      leafF, leafI, bestF, bestI, bestB, hist_store,
                      execF, execI, execB,
                      need0, jnp.zeros(Sm1 + 1, bool), jnp.int32(0),
-                     jnp.int32(0),
-                     jnp.zeros((Sm1, len(ROUND_STATS)), jnp.int32))
+                     jnp.int32(0), round_stats0)
 
             def cond(state):
                 done, need = state[0], state[13]
@@ -965,7 +1043,8 @@ class AlignedEngine:
 
                 exists = s_ids <= done
                 slot_of, cnt_of, first, last, in_any = chunk_maps(
-                    leafI, exists, cnts_pc=cnts_pc, root_span=(done == 0))
+                    leafI, exists, cnts_pc=cnts_pc, root_span=(done == 0),
+                    park_begin=park_begin)
 
                 # ---- left counts: serial mode shards see the global
                 # histogram, so the finder's exact left count (BI_LC, an
@@ -1057,6 +1136,24 @@ class AlignedEngine:
                     | ((~smaller_is_left).astype(jnp.int32) << 24),
                     K)
                 hslots_pc = jnp.where(in_any, hslot_s[slot_of], K)
+                copied_pc = copy_pc
+                dead_pc = sel[slot_of] & ~in_any
+                if parks:
+                    # a parked chunk is a copy chunk of no row: a grid
+                    # step, and nothing of the split path (the chunk map
+                    # hands the free tail to the last slot, whose route
+                    # word may say "split"). On a tree that began with no
+                    # partition the second buffer, a temporary of this
+                    # program, lacks the parked block: its chunks ride
+                    # the first round as whole-chunk copies to their own
+                    # place, and either buffer may end the tree
+                    r1_pc = jnp.where(parked, 1 << R_COPY, r1_pc)
+                    ride = (parked & (park_cnts > 0) & (rounds == 0)
+                            & (park_rounds == 0))
+                    meta_pc = jnp.where(ride, park_cnts, meta_pc)
+                    bl_pc = jnp.where(ride, chunk_iota, bl_pc)
+                    copied_pc = copy_pc | ride
+                    dead_pc = dead_pc & ~parked
                 # ---- what this round schedules (ROUND_STATS order): the
                 # kernel's split path runs wherever the chunk's route word
                 # has the copy bit clear, live block or not, and a
@@ -1066,11 +1163,13 @@ class AlignedEngine:
 
                 def nsum(x):
                     return jnp.sum(x.astype(jnp.int32))
-                round_stats = round_stats.at[rounds].set(jnp.stack([
-                    nsum(split_pc & in_any), nsum(copy_pc),
-                    nsum(jnp.where(sel, leafI[:, LI_COUNT], 0)), k,
-                    nsum(split_pc & last) if spill else jnp.int32(0),
-                    nsum(split_pc & ~in_any)]))
+                # the partition by the bag, where one ran, is row 0
+                round_stats = round_stats.at[rounds + park_rounds].set(
+                    jnp.stack([
+                        nsum(split_pc & in_any), nsum(copied_pc),
+                        nsum(jnp.where(sel, leafI[:, LI_COUNT], 0)), k,
+                        nsum(split_pc & last) if spill else jnp.int32(0),
+                        nsum(dead_pc)]))
                 rec_a, rec_b, hout = move_pass(
                     rec_a, rec_b, src, r1_pc, r2_pc, bl_pc, br_pc,
                     meta_pc, wsel_pc, hslots_pc, cbits,
@@ -1242,6 +1341,7 @@ class AlignedEngine:
                 lambda st: st[2] == 1,
                 lambda st: (st[1], st[1], jnp.int32(0)),
                 (rec, rec_b, norm_passes))
+            rounds = rounds + park_rounds   # the partition is a round too
             # authoritative final replay: the in-loop replay may have been
             # skipped on the last round (all_needed shortcut), and a tree
             # that stops growing early must still commit its real splits
@@ -1299,6 +1399,24 @@ class AlignedEngine:
                 valmap = jnp.where(in_any_f & applied, cover[slot_f], 0.0)
                 rec = _add_to_lane(rec, score_lane,
                                    valmap[:, None] * scale_in)
+            if parks:
+                # the parked rows' share of the same update: no chunk map
+                # names their leaf, so the committed tree is walked over
+                # their chunks where they lie (every other chunk's count
+                # is 0 here, and the kernel skips it), under the flag the
+                # live rows' update is under. GOSS's next selection reads
+                # every row's score: once a tree, behind its build
+                tree = walk_tree(execI[:Sm1], first_c, nxt_c, cover)
+                tabs = walk_expand(tree.nodes, tree.leaves, tree.nn,
+                                   nb_dev, db_dev, mt_dev, w8=walk_w8,
+                                   bits=bits, fp=walk_fp)
+                rec = walk_pass(
+                    rec, park_cnts, applied.astype(jnp.int32),
+                    *(t[None] for t in tabs),
+                    (scale_in * tree.base)[None, :, None], chunk=C,
+                    wcnt=wcnt, bits=bits, lane=score_lane,
+                    interpret=interpret)
+                cnts_pc = cnts_pc + park_cnts
 
             spec = AlignedSpec(rounds=rounds, norm_passes=norm_passes,
                                n_exec=n_exec,
@@ -1309,6 +1427,11 @@ class AlignedEngine:
                                leafI=leafI[:S], first_c=first_c,
                                nxt_c=nxt_c, cover=cover,
                                round_stats=round_stats)
+            if parks:
+                return (rec, cnts_pc, spec, exact, ncommit, applied,
+                        (park_begin, jnp.sum(park_cnts),
+                         jnp.sum((park_cnts > 0).astype(jnp.int32)),
+                         park_rounds))
             return rec, cnts_pc, spec, exact, ncommit, applied
 
         return build
@@ -1413,6 +1536,8 @@ class AlignedEngine:
         the boosting iteration on the dispatch seam (the engine's own
         dispatch count where the caller gives none)."""
         fmask = self.learner._fmask_arr(feature_mask)
+        park = {"park": (self.park_begin, np.bool_(self._park_stale),
+                         self._park_kept)} if self.parks else {}
         # host-side dispatch seam only — this boundary must stay free of
         # device syncs (the round loop pipelines on it): the seam times
         # the enqueue, always on, and never fences
@@ -1425,16 +1550,22 @@ class AlignedEngine:
                     lambda: self._build_program(external_grads=True),
                     donate=(0, 1), specs=self._specs("build_ext")
                     if self.axis else None)
-                rec, cnts, spec, exact_dev, ncommit_dev, applied_dev = fn(
-                    self.rec, self.cnts, fmask, jnp.float32(scale),
-                    self._last_exact, grads[0], grads[1])
             else:
                 fn = self._program("build", self._build_program,
                                    donate=(0, 1), specs=self._specs("build")
                                    if self.axis else None)
-                rec, cnts, spec, exact_dev, ncommit_dev, applied_dev = fn(
-                    self.rec, self.cnts, fmask, jnp.float32(scale),
-                    self._last_exact)
+            rec, cnts, spec, exact_dev, ncommit_dev, applied_dev, *parked = \
+                fn(self.rec, self.cnts, fmask, jnp.float32(scale),
+                   self._last_exact, *(grads or ()), **park)
+        if parked:
+            # the layout advances whatever the flags say: a discarded
+            # round leaves the parked rows parked, and unscored as it
+            # leaves the live ones
+            self.park_begin = parked[0][0]
+            self.park_counters = dict(zip(
+                ("rows_parked", "chunks_parked", "park_rounds"),
+                parked[0][1:]))
+            self._park_stale = False
         # the CHAIN, not this program's own flag: after an inexact
         # round every successor is a score no-op until the host has
         # rebuilt it (`set_row_scores`), whatever its own replay says.
@@ -1648,6 +1779,9 @@ class AlignedEngine:
                            if self.axis else None)
         self.rec = fn(self.rec, spec.leafI, spec.cover, spec.n_exec,
                       applied, jnp.float32(scale))
+        if self.parks:      # the parked rows took the tree by a walk
+            self.walk_trees([(self.walk_tree_of_spec(spec), scale, 0.0)],
+                            applied, -1.0, parked_only=True)
         self._score_cache = None
         self._last_exact = jnp.asarray(True)
 
@@ -1761,8 +1895,9 @@ class AlignedEngine:
                         np.zeros(lp, np.float32))
 
     def walk_trees(self, trees, first, f_first, second=None,
-                   f_second: float = 0.0) -> int:
-        """Score lane of every live row += the sum over `trees` =
+                   f_second: float = 0.0, parked_only: bool = False) -> int:
+        """Score lane of every row, live or parked (or with `parked_only`
+        of the parked block's rows alone) += the sum over `trees` =
         [(WalkTree, shrinkage, bias)] of f x (shrinkage x leaf output +
         bias), where f is `f_first` if the device flag `first` holds,
         else `f_second` if `second` holds, else 0: so a walk queued
@@ -1782,7 +1917,9 @@ class AlignedEngine:
                 null = (self._null_walk_tree(), 0.0, 0.0)
             full = part + [null] * (WALK_TREES - len(part))
             self.rec = fn(
-                self.rec, self.cnts, np.int32(len(part)), first,
+                self.rec, self.cnts,
+                self.park_begin if parked_only else np.int32(0),
+                np.int32(len(part)), first,
                 np.float32(f_first), second, np.float32(f_second),
                 np.asarray([t[1] for t in full], np.float32),
                 np.asarray([t[2] for t in full], np.float32),
@@ -1801,8 +1938,10 @@ class AlignedEngine:
         C, wcnt, bits = self.C, self.wcnt, self.bits
         lane, interpret = self.lanes["score"], self.interpret
 
-        def fn(rec, cnts, ntrees, first, f_first, second, f_second, shr,
-               bias, trees):
+        def fn(rec, cnts, lo, ntrees, first, f_first, second, f_second,
+               shr, bias, trees):
+            # chunks below `lo` are none of this walk's
+            cnts = jnp.where(jnp.arange(cnts.shape[0]) >= lo, cnts, 0)
             tabs = jax.vmap(lambda n, l, k: walk_expand(
                 n, l, k, nb, db, mt, w8=w8, bits=bits, fp=fp))(
                     jnp.stack([jnp.asarray(t.nodes) for t in trees]),
@@ -1879,6 +2018,10 @@ class AlignedEngine:
                       *self._ext_args())
         self.bag_sampled = False
         self.bag_drawn = None
+        if self.parks:
+            self._park_kept = jnp.int32(
+                np.count_nonzero(np.asarray(mask_rows) > 0.5))
+            self._park_stale = True
 
     def bag_select(self, seed: int, cnt: int):
         """Queue plain bagging's draw (`ops/goss.py:bag_multipliers`) over
@@ -1896,6 +2039,7 @@ class AlignedEngine:
         self.rec, self.bag_kept = fn(self.rec, self.cnts, jnp.uint32(seed),
                                      *self._ext_args())
         self.bag_drawn = int(seed)
+        self._park_kept, self._park_stale = self.bag_kept, True
         return self.bag_kept
 
     def _bag_select_program(self, cnt):
@@ -1953,9 +2097,10 @@ class AlignedEngine:
                 lambda: self._goss_select_program(top_k, other_k, multiply,
                                                   grads is not None),
                 donate=(0,))
-            self.rec, stats = fn(self.rec, self.cnts, jnp.uint32(seed),
-                                 *(grads or ()), *self._ext_args())
-        self.bag_sampled = True
+            self.rec, stats, self._park_kept = fn(
+                self.rec, self.cnts, jnp.uint32(seed), *(grads or ()),
+                *self._ext_args())
+        self.bag_sampled = self._park_stale = True
         return stats
 
     def _goss_select_program(self, top_k, other_k, multiply, external):
@@ -1976,7 +2121,8 @@ class AlignedEngine:
                     if self.objective.weight is not None else None)
             mult, stats = goss_multipliers(jnp.abs(g * h), rid, live, seed,
                                            top_k, other_k, multiply)
-            return rec.at[:, ln["bag"], :].set(_i32(mult)), stats
+            return (rec.at[:, ln["bag"], :].set(_i32(mult)), stats,
+                    stats[0] + stats[1])
         return goss_select
 
     def row_lane(self, lane: str) -> np.ndarray:

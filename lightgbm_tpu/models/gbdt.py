@@ -71,8 +71,10 @@ def _record_aligned_iter(it: int, rounds, norm_passes, table,
     `sampled` = the named counters the boosting variant recorded of the
     iteration, device scalars or the host's own numbers: GOSS's
     selection (`goss_kept_top`, `goss_kept_other`, `goss_threshold`),
-    DART's walks (`dart_dropped`, `walk_passes`, `rows_walked`); absent
-    where there is none."""
+    DART's walks (`dart_dropped`, `walk_passes`, `rows_walked`), the
+    rows a bag left outside the tree's rounds (`rows_parked`,
+    `chunks_parked`, and `park_rounds`: 1 where the table's first row is
+    the partition by the bag); absent where there is none."""
     from .aligned_builder import ROUND_STATS
     rounds = int(rounds)
     extra = {k: np.asarray(v).item() for k, v in (sampled or {}).items()}
@@ -1197,6 +1199,8 @@ class GBDT:
             "engine.train_iter",
             lambda: eng.train_iter(self.shrinkage_rate, fmask, grads=grads,
                                    boost_iter=self.iter))
+        # what the build left parked, where the engine parks at all
+        self._aligned_sample_stats.update(eng.park_counters)
         self._aligned_after_build(eng, sample, out, prev_ok)
         return out
 

@@ -372,6 +372,16 @@ def _cat_word(cbits_ref, ks, binv):
 
 
 
+def _in_bag(rows, bag_lane, wcnt):
+    """Which rows of a [W, C] block of single-class records are in the
+    bag: the f32 lane `bag_lane` over 0.5 (a 0/1 mask, or a multiplier
+    that is 0 out of the sample), or with `bag_lane` -2 the compact
+    record's bag bit, the sign of its meta lane."""
+    if bag_lane == -2:
+        return ((rows[wcnt + 1, :] >> META_BAG) & 1) != 0
+    return lax.bitcast_convert_type(rows[bag_lane, :], jnp.float32) > 0.5
+
+
 def _payload_gh(rows, nvalid, chunk, wcnt, grad_fn, bag_lane,
                 num_class=1, gh_off=2):
     """(g, h, take) for a [W, C] row block: lane-resident gradients
@@ -396,15 +406,13 @@ def _payload_gh(rows, nvalid, chunk, wcnt, grad_fn, bag_lane,
             .astype(jnp.float32)
         g, h = grad_fn(score, label, None)
         if bag_lane == -2:     # compact bagging: bag bit masks stats
-            take = take & (((meta >> META_BAG) & 1) != 0)
+            take = take & _in_bag(rows, bag_lane, wcnt)
     else:
         g = lax.bitcast_convert_type(rows[wcnt + gh_off, :], jnp.float32)
         h = lax.bitcast_convert_type(rows[wcnt + gh_off + 1, :],
                                      jnp.float32)
         if bag_lane >= 0:
-            bagv = lax.bitcast_convert_type(rows[bag_lane, :],
-                                            jnp.float32)
-            take = take & (bagv > 0.5)
+            take = take & _in_bag(rows, bag_lane, wcnt)
     return g, h, take
 
 
@@ -591,7 +599,7 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                  fbuf, hacc, hstage, tri, cur_ref, sems, *, chunk, w_pad,
                  w_used, wcnt, num_features, b_pad, group, dummy,
                  bag_lane, bits, grad_fn, num_class, gh_off, bundled,
-                 subbin, spill):
+                 subbin, spill, route_bag):
     """One grid step of the fused move+hist pass.
 
     TWO record buffers, A and B, each an operand aliased to an output
@@ -628,6 +636,12 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
     slot is written by exactly ONE block per pass, so the DMA is a plain
     overwrite; unvisited slots stay uninitialized and the wrapper masks
     them to zero from hslots.
+
+    ROUTE BY BAG (static `route_bag`, the partition that parks the rows a
+    bag leaves out): a split chunk's rows go left where the row is in the
+    bag (`_in_bag`) and right where it is not, whatever their bins, and
+    no histogram is compiled. A kernel of its own, so one that splits by
+    features carries none of it.
 
     cur_ref: [cur_l, cur_r, fl_l, fl_r, pend 4..15, dst 16..27,
     src 28..39, spill_blk 40, spill_pend 41..42, spill_dst 43..44];
@@ -770,14 +784,17 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
             rec = jnp.where(a_is_src, reca_ref[0, :, rows_t],
                             recb_ref[0, :, rows_t])
             valid = t * S + posS < cntv
-            word = rec[0, :]
-            for wj in range(1, wcnt):
-                word = jnp.where(wsel == wj, rec[wj, :], word)
-            binv = (word >> ((r1 >> R_SHIFT) & 31)) & bmask
-            if bundled:
-                binv = _unpack_bundle(binv, r2)
-            catw = _cat_word(cbits_ref, hslot, binv)
-            left = _goes_left(binv, r1, r2, valid, catw)
+            if route_bag:
+                left = _in_bag(rec, bag_lane, wcnt) & valid
+            else:
+                word = rec[0, :]
+                for wj in range(1, wcnt):
+                    word = jnp.where(wsel == wj, rec[wj, :], word)
+                binv = (word >> ((r1 >> R_SHIFT) & 31)) & bmask
+                if bundled:
+                    binv = _unpack_bundle(binv, r2)
+                catw = _cat_word(cbits_ref, hslot, binv)
+                left = _goes_left(binv, r1, r2, valid, catw)
 
             # ranks via one triangular matmul (measured FASTER on the
             # MXU than log2(C) pltpu.roll prefix sums: 3.33 vs 3.82
@@ -906,8 +923,7 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
         # copies of it made the kernel so large that EVERY split-path
         # grid step paid for it, histogram or not (45 us a chunk at 67
         # features against 6 at 8; PERF.md section 6, PR 27)
-        @pl.when(hslot != dummy)
-        def _():
+        def hist_flushed_chunks():
             cur_h = jnp.where(hside == 0, new_l, new_r)
 
             def hist_one(fl, carry):
@@ -917,8 +933,7 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
 
             lax.fori_loop(fl_h0, cur_ref[2 + hside], hist_one, 0)
 
-        @pl.when((is_last != 0) & (hslot != dummy))
-        def _():
+        def hist_to_store():
             if not spill:
                 hist_ref[hslot] += hacc[...]
             else:
@@ -942,6 +957,10 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
                         cur_ref[41 + p] = 1
                 cur_ref[40] = cur_ref[40] + 1
 
+        if not route_bag:
+            pl.when(hslot != dummy)(hist_flushed_chunks)
+            pl.when((is_last != 0) & (hslot != dummy))(hist_to_store)
+
         @pl.when(is_last != 0)
         def _():
             cur_ref[2] = 0
@@ -963,13 +982,14 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
 @functools.partial(jax.jit, static_argnames=(
     "chunk", "w_pad", "wcnt", "num_slots", "num_features", "b_pad",
     "group", "bag_lane", "bits", "grad_fn", "num_class", "w_used",
-    "gh_off", "bundled", "interpret", "subbin", "spill"))
+    "gh_off", "bundled", "interpret", "subbin", "spill", "route_bag"))
 def move_pass(records, other, src, r1, r2, basel, baser, meta, wsel,
               hslots, cbits, chunk, w_pad, wcnt, num_slots, num_features,
               b_pad, group,
               bag_lane=-1, bits=8, grad_fn=None, num_class=1,
               w_used=0, gh_off=2, bundled=False,
-              interpret=False, subbin=False, spill=False):
+              interpret=False, subbin=False, spill=False,
+              route_bag=False):
     """Stable two-way partition of every block in one streaming pass,
     with the smaller-child histograms FUSED into the same pass.
 
@@ -1005,8 +1025,14 @@ def move_pass(records, other, src, r1, r2, basel, baser, meta, wsel,
     the shape that lets wide-F x 255-bin rounds run with K well past
     the VMEM budget. `subbin` selects the sub-binned accumulation at
     b_pad > 128 (see _hist_mode).
+
+    `route_bag` (single-class records with a bag: `bag_lane` >= 0 or -2)
+    is the route mode of `park_pass`: every chunk whose copy bit is clear
+    sends its in-bag rows left and the others right, reads nothing of
+    r2 / wsel / hslots / cbits, and the returned histogram is zeros.
     """
     compile_cache.note_trace()
+    assert not route_bag or (bag_lane != -1 and num_class == 1)
     nc = records.shape[0]
     dummy = num_slots
     store_shape = _hist_store_shape(num_slots, num_features, b_pad,
@@ -1022,7 +1048,8 @@ def move_pass(records, other, src, r1, r2, basel, baser, meta, wsel,
                                bag_lane=bag_lane, bits=bits,
                                grad_fn=grad_fn, num_class=num_class,
                                gh_off=gh_off, bundled=bundled,
-                               subbin=subbin, spill=spill)
+                               subbin=subbin, spill=spill,
+                               route_bag=route_bag)
     r1p = r1 | (wsel << R_WSEL)
     blbr = basel | (baser << 16)
     # copy chunks SKIP the blocked fetch: the block index carries the
@@ -1093,6 +1120,44 @@ def move_pass(records, other, src, r1, r2, basel, baser, meta, wsel,
         hist = jnp.where((visited[:num_slots] > 0)[:, None, None, None],
                          hist, 0.0)
     return buf_a, buf_b, hist
+
+
+def park_pass(records, other, src, cnts, kept, chunk, w_pad, wcnt,
+              bag_lane, bits=8, w_used=0, interpret=False):
+    """One stable partition of EVERY row by the bag, through `move_pass`
+    (its `route_bag` mode; a `move_pass` event in a trace): the `kept`
+    rows in the bag (`_in_bag`), in the order they lie, from chunk 0 on,
+    the others as ONE block that ends at the buffer's last chunk, the
+    PARKED block. cnts: i32 [NC] rows of every chunk of the source
+    buffer, all of them live; kept: i32 scalar, how many of them are in
+    the bag, exactly (the caller's: the selection counted it).
+
+    Returns (records, other, cnts', park_begin): the two buffers as
+    `move_pass` leaves them (the rows in the one `src` does not name),
+    the new layout's per-chunk counts and the chunk the parked block
+    begins at, NC where no row is out of the bag. The layout is exact by
+    the table: full chunks and a last partial one on either side."""
+    nc = records.shape[0]
+    iota = jnp.arange(nc, dtype=jnp.int32)
+    n_out = jnp.sum(cnts).astype(jnp.int32) - kept
+    park_begin = nc - (n_out + chunk - 1) // chunk
+    # a chunk of no row is skipped (copy bit, count 0); the buffer's last
+    # chunk closes the block, rows or none
+    split = (cnts > 0) | (iota == nc - 1)
+    r1 = jnp.where(split, 0, 1 << R_COPY)
+    meta = (cnts | ((iota == 0).astype(jnp.int32) << 20)
+            | ((iota == nc - 1).astype(jnp.int32) << 21))
+    zeros = jnp.zeros(nc, jnp.int32)
+    buf_a, buf_b, _ = move_pass(
+        records, other, src, r1, zeros, zeros,
+        jnp.full(nc, park_begin, jnp.int32), meta, zeros,
+        jnp.ones(nc, jnp.int32), jnp.zeros(16, jnp.int32),
+        chunk, w_pad, wcnt, 1, 1, 16, 8, bag_lane=bag_lane, bits=bits,
+        w_used=w_used, interpret=interpret, route_bag=True)
+    new = jnp.where(iota < park_begin,
+                    jnp.clip(kept - iota * chunk, 0, chunk),
+                    jnp.clip(n_out - (iota - park_begin) * chunk, 0, chunk))
+    return buf_a, buf_b, new, park_begin
 
 
 # ---------------------------------------------------------------------------

@@ -124,9 +124,14 @@ def compile_build(eng):
             spec((), jnp.float32), spec((), jnp.bool_)]
     if eng.ext:
         args += [spec((eng.n,), jnp.float32)] * 2
+    # an engine that parks the rows its bag leaves out takes where the
+    # parked block begins, whether the lane is newer than the partition,
+    # and the in-bag count
+    park = {"park": (spec((), jnp.int32), spec((), jnp.bool_),
+                     spec((), jnp.int32))} if eng.parks else {}
     program = jax.jit(eng._build_program(external_grads=eng.ext),
                       donate_argnums=(0, 1))
-    return program.lower(*args).compile(), target
+    return program.lower(*args, **park).compile(), target
 
 
 def nbytes(shape_text: str) -> int:

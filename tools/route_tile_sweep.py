@@ -7,9 +7,14 @@ python tools/route_tile_sweep.py [shape ...] [tile ...]
 Shapes: criteo255 / criteo63 (the benchmark cells' records, W=24,
 C=2048, fused histogram of the smaller child), istella255 (the ranking
 cell's EXT record, W=64 with 59 lanes used, C=512, 220 features) and
-higgs (compact W=16, C=1024, no histogram). A tile equal to the chunk is
-the untiled kernel. Prints one JSON line per (shape, tile): ms a call
-and us a live chunk.
+higgs (compact W=16, C=1024, no histogram), and two with a bag lane, the
+24th of 24 (U = 24, where `criteo255` routes 23): criteo255bag, the
+bagged cells' record through the same split with its in-bag histogram,
+and criteo255park, the partition that parks the rows a bag leaves out
+(`park_pass`: `move_pass` routing by the bag, 30% of the rows in it, no
+histogram compiled in), so the partition round's us a chunk is read
+alone. A tile equal to the chunk is the untiled kernel. Prints one JSON
+line per (shape, tile): ms a call and us a live chunk.
 """
 import json
 import os
@@ -31,7 +36,11 @@ SHAPES = {   # W, C, wcnt, w_used, features, b_pad, bits, spill, hist, gh_off
     "criteo63": (24, 2048, 14, 20, 67, 64, 6, False, True, 2),
     "istella255": (64, 512, 55, 59, 220, 256, 8, True, True, 1),
     "higgs": (16, 1024, 7, 9, 28, 256, 8, False, False, 2),
+    "criteo255bag": (24, 2048, 17, 24, 67, 256, 8, True, True, 2),
+    "criteo255park": (24, 2048, 17, 24, 67, 256, 8, False, False, 2),
 }
+BAG_LANE = 23      # of the two shapes that have one
+IN_BAG = 0.3
 K = 256
 
 
@@ -41,6 +50,11 @@ def one(shape, tile, reps=3):
     jax.clear_caches()
     rec = jax.random.bits(jax.random.PRNGKey(tile), (NC, W, C),
                           jnp.uint32).astype(jnp.int32)
+    bag_lane = BAG_LANE if w_used > BAG_LANE else -1
+    if bag_lane >= 0:      # a 0/1 lane, IN_BAG of the rows in the bag
+        draw = jax.random.uniform(jax.random.PRNGKey(7), (NC, C)) < IN_BAG
+        rec = rec.at[:, bag_lane, :].set(jax.lax.bitcast_convert_type(
+            draw.astype(jnp.float32), jnp.int32))
     iota = jnp.arange(NC, dtype=jnp.int32)
     # one block over the first LIVE chunks, split at the middle bin of
     # feature 0: half the rows go left, to chunk 0 on, half right, to
@@ -59,18 +73,31 @@ def one(shape, tile, reps=3):
     # donated is copied before the aliased kernel may write it, and the
     # copy would be timed with the pass. Every call reads the first
     # buffer, which no pass writes: the same work each time
-    step = jax.jit(
-        lambda a, b: aligned.move_pass(
-            a, b, 0, *args, C, W, wcnt, K, F, b_pad, 4 if b_pad > 64 else 8,
-            bits=bits, w_used=w_used, gh_off=gh_off, subbin=True,
-            spill=spill),
-        donate_argnums=(0, 1))
+    if shape.endswith("park"):
+        # the partition as the build program calls it: every live row
+        # by its bag, the count of the in-bag ones the caller's
+        cnts = jnp.where(iota < LIVE, C, 0).astype(jnp.int32)
+        kept = jnp.sum(jnp.where(iota[:, None] < LIVE, draw, False),
+                       dtype=jnp.int32)
+
+        def run(a, b):
+            a, b, new, _ = aligned.park_pass(a, b, 0, cnts, kept, C, W,
+                                             wcnt, bag_lane, bits=bits,
+                                             w_used=w_used)
+            return a, b, new
+    else:
+        def run(a, b):
+            return aligned.move_pass(
+                a, b, 0, *args, C, W, wcnt, K, F, b_pad,
+                4 if b_pad > 64 else 8, bag_lane=bag_lane, bits=bits,
+                w_used=w_used, gh_off=gh_off, subbin=True, spill=spill)
+    step = jax.jit(run, donate_argnums=(0, 1))
     bufs = [rec, jnp.zeros_like(rec)]
 
     def call():
-        a, b, hist = step(*bufs)
+        a, b, out = step(*bufs)
         bufs[:] = [a, b]
-        obs_trace.force_fence(hist)
+        obs_trace.force_fence(out)
 
     t0 = time.perf_counter()
     call()
