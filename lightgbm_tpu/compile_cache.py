@@ -132,6 +132,8 @@ def clear_programs() -> None:
     buffers captured by program closures)."""
     with _lock:
         _programs.clear()
+    from .obs import phases
+    phases.forget()     # its entries hold the same jitted functions
 
 
 def array_fingerprint(*arrays) -> str:

@@ -41,6 +41,7 @@ import numpy as np
 from jax import lax
 
 from .. import compile_cache
+from ..obs import phases
 from ..obs import trace as obs_trace
 from ..ops.aligned import (META_BAG, META_LABEL, META_LABEL_MASK,
                            META_RID_MASK, R_CAT,
@@ -81,8 +82,6 @@ ROUND_STATS = (
 class AlignedSpec(NamedTuple):
     """Device outputs of one aligned speculative build (small arrays)."""
     rounds: jax.Array      # i32 scalar: while-loop rounds executed
-    norm_passes: jax.Array  # i32 scalar, 0 or 1: the copy that brought the
-                            # rows back from the round loop's second buffer
     n_exec: jax.Array      # i32 scalar
     execF: jax.Array       # f32[Sm1, SF_W]
     execI: jax.Array       # i32[Sm1, SI_W]
@@ -825,153 +824,162 @@ class AlignedEngine:
         def build(rec, cnts_pc, feature_mask_f32, scale_in, prev_ok,
                   g_rows=None, h_rows=None, pleafI=None, pcover=None,
                   pn_exec=None, pscale=None, park=None):
-            if multiclass:
-                # deferred application of the PREVIOUS dispatch's
-                # committed leaf values to ITS class lane: the valmap is
-                # defined on THIS program's starting layout (the prev
-                # build's final layout), gated by the exactness chain
-                pbegin = pleafI[:, LI_BEGIN]
-                pcount = pleafI[:, LI_COUNT]
-                slot_p, in_range_p = slot_in_any_map(pbegin, pcount,
-                                                     NC, C)
-                exists_p = jnp.arange(S + 1) <= pn_exec
-                in_any_p = in_range_p & exists_p[slot_p]
-                valmap_p = jnp.where(in_any_p & prev_ok,
-                                     pcover[slot_p], 0.0)
-                sc = _f32(rec[:, prev_lane_off, :]) \
-                    + valmap_p[:, None] * pscale
-                rec = rec.at[:, prev_lane_off, :].set(_i32(sc))
-                if class_k == 0 and self.mc_mode == "prob":
-                    # iteration boundary: refresh the PROB lanes from
-                    # the now-complete previous iteration's scores —
-                    # every class of this iteration derives gradients
-                    # from these pre-iteration probabilities
-                    # (gbdt.cpp:415-444 computes gradients once),
-                    # untouched by the same-iteration deferred score
-                    # applications
-                    scores = [_f32(rec[:, ln["score"] + j, :])
-                              for j in range(K_cls)]
-                    m = scores[0]
-                    for j in range(1, K_cls):
-                        m = jnp.maximum(m, scores[j])
-                    tot = jnp.zeros_like(m)
-                    exps = []
-                    for j in range(K_cls):
-                        e = jnp.exp(scores[j] - m)
-                        exps.append(e)
-                        tot = tot + e
-                    for j in range(K_cls):
-                        rec = rec.at[:, ln["prob"] + j, :].set(
-                            _i32(exps[j] / tot))
-            elif external_grads:
-                assert not self.compact, \
-                    "external grads need grad lanes (standard layout)"
-                rid = jnp.clip(rec[:, ln["rid"], :], 0, self.ext_n - 1)
-                ge = g_rows.reshape(-1)[rid]
-                he = h_rows.reshape(-1)[rid]
-                if bagged:
-                    bag = _f32(rec[:, ln["bag"], :])
-                    ge = ge * bag
-                    he = he * bag
-                rec = rec.at[:, ln["grad"], :].set(_i32(ge))
-                rec = rec.at[:, ln["hess"], :].set(_i32(he))
-            else:
-                rec = self._grad_lanes(rec)
+            with phases.scope("build.head"):
+                if multiclass:
+                    # deferred application of the PREVIOUS dispatch's
+                    # committed leaf values to ITS class lane: the valmap is
+                    # defined on THIS program's starting layout (the prev
+                    # build's final layout), gated by the exactness chain
+                    pbegin = pleafI[:, LI_BEGIN]
+                    pcount = pleafI[:, LI_COUNT]
+                    slot_p, in_range_p = slot_in_any_map(pbegin, pcount,
+                                                         NC, C)
+                    exists_p = jnp.arange(S + 1) <= pn_exec
+                    in_any_p = in_range_p & exists_p[slot_p]
+                    valmap_p = jnp.where(in_any_p & prev_ok,
+                                         pcover[slot_p], 0.0)
+                    sc = _f32(rec[:, prev_lane_off, :]) \
+                        + valmap_p[:, None] * pscale
+                    rec = rec.at[:, prev_lane_off, :].set(_i32(sc))
+                    if class_k == 0 and self.mc_mode == "prob":
+                        # iteration boundary: refresh the PROB lanes from
+                        # the now-complete previous iteration's scores —
+                        # every class of this iteration derives gradients
+                        # from these pre-iteration probabilities
+                        # (gbdt.cpp:415-444 computes gradients once),
+                        # untouched by the same-iteration deferred score
+                        # applications
+                        scores = [_f32(rec[:, ln["score"] + j, :])
+                                  for j in range(K_cls)]
+                        m = scores[0]
+                        for j in range(1, K_cls):
+                            m = jnp.maximum(m, scores[j])
+                        tot = jnp.zeros_like(m)
+                        exps = []
+                        for j in range(K_cls):
+                            e = jnp.exp(scores[j] - m)
+                            exps.append(e)
+                            tot = tot + e
+                        for j in range(K_cls):
+                            rec = rec.at[:, ln["prob"] + j, :].set(
+                                _i32(exps[j] / tot))
+                elif external_grads:
+                    assert not self.compact, \
+                        "external grads need grad lanes (standard layout)"
+                    with phases.scope("rank.gather"):
+                        rid = jnp.clip(rec[:, ln["rid"], :], 0,
+                                       self.ext_n - 1)
+                        ge = g_rows.reshape(-1)[rid]
+                        he = h_rows.reshape(-1)[rid]
+                    if bagged:
+                        bag = _f32(rec[:, ln["bag"], :])
+                        ge = ge * bag
+                        he = he * bag
+                    rec = rec.at[:, ln["grad"], :].set(_i32(ge))
+                    rec = rec.at[:, ln["hess"], :].set(_i32(he))
+                else:
+                    rec = self._grad_lanes(rec)
 
-            rec_b0 = jnp.zeros_like(rec)
-            root_slots = jnp.zeros(NC, jnp.int32)
-            round_stats0 = jnp.zeros((Sm1 + int(parks), len(ROUND_STATS)),
-                                     jnp.int32)
-            park_begin, park_rounds = NC, 0     # nothing parked, no partition
-            if parks:
-                # ---------- the partition by the bag ----------
-                # `park` = (where the parked block begins, whether the
-                # lane was written since the last partition, the in-bag
-                # rows under it). One round of its own ahead of the tree's
-                # and a row of the round table, run where the lane is
-                # newer and a row is, or was, out of the bag: every chunk
-                # that holds rows, live or parked, is split by the bag
-                # into the live rows from chunk 0 on and the parked block
-                # at the buffer's end. A loop of no or one trip whose
-                # carry takes the rows back into the first buffer (as the
-                # copy behind an odd round count does, below): the tree's
-                # rounds then find the rows where they always do and the
-                # parked block in BOTH buffers, so no round need carry it
-                park_begin, repark, kept = park
-                rows_all = jnp.sum(cnts_pc).astype(jnp.int32)
-                go = repark & ((rows_all > kept) | (park_begin < NC))
-                park_rounds = go.astype(jnp.int32)
-                round_stats0 = round_stats0.at[0].set(jnp.where(
-                    go, jnp.stack([jnp.sum((cnts_pc > 0).astype(jnp.int32)),
-                                   jnp.int32(0), rows_all, jnp.int32(1),
-                                   jnp.int32(0), jnp.int32(0)]), 0))
+                rec_b0 = jnp.zeros_like(rec)
+                root_slots = jnp.zeros(NC, jnp.int32)
+                round_stats0 = jnp.zeros(
+                    (Sm1 + int(parks), len(ROUND_STATS)), jnp.int32)
+                # nothing parked, no partition
+                park_begin, park_rounds = NC, 0
+            with phases.scope("build.park"):
+                if parks:
+                    # ---------- the partition by the bag ----------
+                    # `park` = (where the parked block begins, whether the
+                    # lane was written since the last partition, the in-bag
+                    # rows under it). One round of its own ahead of the tree's
+                    # and a row of the round table, run where the lane is
+                    # newer and a row is, or was, out of the bag: every chunk
+                    # that holds rows, live or parked, is split by the bag
+                    # into the live rows from chunk 0 on and the parked block
+                    # at the buffer's end. A loop of no or one trip whose
+                    # carry takes the rows back into the first buffer (as the
+                    # copy behind an odd round count does, below): the tree's
+                    # rounds then find the rows where they always do and the
+                    # parked block in BOTH buffers, so no round need carry it
+                    park_begin, repark, kept = park
+                    rows_all = jnp.sum(cnts_pc).astype(jnp.int32)
+                    go = repark & ((rows_all > kept) | (park_begin < NC))
+                    park_rounds = go.astype(jnp.int32)
+                    round_stats0 = round_stats0.at[0].set(jnp.where(
+                        go, jnp.stack([
+                            jnp.sum((cnts_pc > 0).astype(jnp.int32)),
+                            jnp.int32(0), rows_all, jnp.int32(1),
+                            jnp.int32(0), jnp.int32(0)]), 0))
 
-                def park_round(st):
-                    _, rec_b, cnts, pb = park_pass(
-                        st[0], st[1], jnp.int32(0), st[2], kept, C, W,
-                        wcnt, bag_lane, bits=bits, w_used=self.w_used,
-                        interpret=interpret)
-                    return rec_b, rec_b, cnts, pb, jnp.bool_(False)
+                    def park_round(st):
+                        _, rec_b, cnts, pb = park_pass(
+                            st[0], st[1], jnp.int32(0), st[2], kept, C, W,
+                            wcnt, bag_lane, bits=bits, w_used=self.w_used,
+                            interpret=interpret)
+                        return rec_b, rec_b, cnts, pb, jnp.bool_(False)
 
-                rec, rec_b0, cnts_pc, park_begin, _ = lax.while_loop(
-                    lambda st: st[4], park_round,
-                    (rec, rec_b0, cnts_pc, park_begin, go))
-                parked = chunk_iota >= park_begin
-                park_cnts = jnp.where(parked, cnts_pc, 0)
-                cnts_pc = jnp.where(parked, 0, cnts_pc)
-                # a parked chunk costs the root histogram a fetch
-                root_slots = parked.astype(jnp.int32)
+                    rec, rec_b0, cnts_pc, park_begin, _ = lax.while_loop(
+                        lambda st: st[4], park_round,
+                        (rec, rec_b0, cnts_pc, park_begin, go))
+                    parked = chunk_iota >= park_begin
+                    park_cnts = jnp.where(parked, cnts_pc, 0)
+                    cnts_pc = jnp.where(parked, 0, cnts_pc)
+                    # a parked chunk costs the root histogram a fetch
+                    root_slots = parked.astype(jnp.int32)
 
-            # ---------- root ----------
-            root_hist_all = slot_hist_pass(rec, root_slots, cnts_pc, 1,
-                                           G, BH, C, group, wcnt,
-                                           bag_lane=bag_lane, bits=bits,
-                                           grad_fn=gfn, num_class=K_cls,
-                                           gh_off=self.gh_off,
-                                           interpret=interpret,
-                                           subbin=subbin)
-            root_hist = _gsum(root_hist_all[0])
-            root_g = jnp.sum(root_hist[0, :, 0])
-            root_h = jnp.sum(root_hist[0, :, 1])
-            root_cnt_g = jnp.sum(root_hist[0, :, 2]).astype(jnp.int32)
-            local_cnt = jnp.sum(cnts_pc).astype(jnp.int32)
+            with phases.scope("build.root"):
+                # ---------- root ----------
+                root_hist_all = slot_hist_pass(rec, root_slots, cnts_pc, 1,
+                                               G, BH, C, group, wcnt,
+                                               bag_lane=bag_lane, bits=bits,
+                                               grad_fn=gfn, num_class=K_cls,
+                                               gh_off=self.gh_off,
+                                               interpret=interpret,
+                                               subbin=subbin)
+                root_hist = _gsum(root_hist_all[0])
+                root_g = jnp.sum(root_hist[0, :, 0])
+                root_h = jnp.sum(root_hist[0, :, 1])
+                root_cnt_g = jnp.sum(root_hist[0, :, 2]).astype(jnp.int32)
+                local_cnt = jnp.sum(cnts_pc).astype(jnp.int32)
 
-            leafF = jnp.zeros((S + 1, LF_W), jnp.float32)
-            leafF = leafF.at[:, LF_MINC].set(-jnp.inf)
-            leafF = leafF.at[:, LF_MAXC].set(jnp.inf)
-            leafF = leafF.at[0, LF_SG].set(root_g)
-            leafF = leafF.at[0, LF_SH].set(root_h)
-            leafI = jnp.zeros((S + 1, LI_W), jnp.int32)
-            leafI = leafI.at[:, LI_BEGIN].set(
-                jnp.full((S + 1,), NC, jnp.int32).at[0].set(0))
-            leafI = leafI.at[0, LI_COUNT].set(local_cnt)
-            leafI = leafI.at[0, LI_COUNTG].set(root_cnt_g)
+            with phases.scope("build.head"):
+                leafF = jnp.zeros((S + 1, LF_W), jnp.float32)
+                leafF = leafF.at[:, LF_MINC].set(-jnp.inf)
+                leafF = leafF.at[:, LF_MAXC].set(jnp.inf)
+                leafF = leafF.at[0, LF_SG].set(root_g)
+                leafF = leafF.at[0, LF_SH].set(root_h)
+                leafI = jnp.zeros((S + 1, LI_W), jnp.int32)
+                leafI = leafI.at[:, LI_BEGIN].set(
+                    jnp.full((S + 1,), NC, jnp.int32).at[0].set(0))
+                leafI = leafI.at[0, LI_COUNT].set(local_cnt)
+                leafI = leafI.at[0, LI_COUNTG].set(root_cnt_g)
 
-            hist_store = jnp.zeros((S + 1, G, BH, NUM_HIST_STATS),
-                                   jnp.float32)
-            hist_store = hist_store.at[0].set(root_hist)
-            execF = jnp.zeros((Sm1 + 1, SF_W), jnp.float32)
-            execI = jnp.zeros((Sm1 + 1, SI_W), jnp.int32)
-            execB = jnp.zeros((Sm1 + 1, 8), jnp.uint32)
+                hist_store = jnp.zeros((S + 1, G, BH, NUM_HIST_STATS),
+                                       jnp.float32)
+                hist_store = hist_store.at[0].set(root_hist)
+                execF = jnp.zeros((Sm1 + 1, SF_W), jnp.float32)
+                execI = jnp.zeros((Sm1 + 1, SI_W), jnp.int32)
+                execB = jnp.zeros((Sm1 + 1, 8), jnp.uint32)
 
-            # root eval: slot 0 only (the old all-slots eval was pure
-            # waste, and bundle expansion makes it expensive too)
-            root_eh = root_hist[None]
-            if bundled:
-                root_eh = expand_hist(root_eh, root_g[None], root_h[None],
-                                      root_cnt_g[None])
-            rF0, rI0, rB0 = eval_all(
-                feature_mask_f32, root_eh, leafF[0:1, LF_SG],
-                leafF[0:1, LF_SH], leafI[0:1, LI_COUNTG],
-                leafF[0:1, LF_MINC], leafF[0:1, LF_MAXC],
-                leafI[0:1, LI_DEPTH], jnp.ones(1, bool))
-            bestF = jnp.full((S + 1, BF_W), NEG_INF,
-                             jnp.float32).at[0].set(rF0[0])
-            bestI = jnp.zeros((S + 1, BI_W), jnp.int32).at[0].set(rI0[0])
-            bestB = jnp.zeros((S + 1, 8), jnp.uint32).at[0].set(rB0[0])
+            with phases.scope("build.root"):
+                # root eval: slot 0 only (the old all-slots eval was pure
+                # waste, and bundle expansion makes it expensive too)
+                root_eh = root_hist[None]
+                if bundled:
+                    root_eh = expand_hist(root_eh, root_g[None], root_h[None],
+                                          root_cnt_g[None])
+                rF0, rI0, rB0 = eval_all(
+                    feature_mask_f32, root_eh, leafF[0:1, LF_SG],
+                    leafF[0:1, LF_SH], leafI[0:1, LI_COUNTG],
+                    leafF[0:1, LF_MINC], leafF[0:1, LF_MAXC],
+                    leafI[0:1, LI_DEPTH], jnp.ones(1, bool))
+                bestF = jnp.full((S + 1, BF_W), NEG_INF,
+                                 jnp.float32).at[0].set(rF0[0])
+                bestI = jnp.zeros((S + 1, BI_W), jnp.int32).at[0].set(rI0[0])
+                bestB = jnp.zeros((S + 1, 8), jnp.uint32).at[0].set(rB0[0])
 
-            need0 = jnp.zeros(S + 1, bool).at[0].set(
-                bestF[0, BF_GAIN] > 0.0)
+                need0 = jnp.zeros(S + 1, bool).at[0].set(
+                    bestF[0, BF_GAIN] > 0.0)
             # the round loop PING-PONGS between two record buffers: round
             # r reads the buffer `rounds % 2` names (0 = rec) and
             # move_pass writes the other, both aliased operand to output.
@@ -988,338 +996,343 @@ class AlignedEngine:
                      jnp.int32(0), round_stats0)
 
             def cond(state):
-                done, need = state[0], state[13]
-                return (done < Sm1) & jnp.any(need)
+                with phases.scope("build.layout"):
+                    done, need = state[0], state[13]
+                    return (done < Sm1) & jnp.any(need)
 
             def body(state):
                 (done, rec_a, rec_b, cnts_pc, leafF, leafI, bestF, bestI,
                  bestB, hist_store, execF, execI, execB, need, _commit,
                  _ncommit, rounds, round_stats) = state
-                src = rounds % 2
-                s_ids = jnp.arange(S + 1, dtype=jnp.int32)
-                gains = bestF[:, BF_GAIN]
-                # K also caps per-round splits: compact hist ids must fit
-                # the VMEM-resident store (dropped needs re-offer next
-                # round via the replay)
-                budget = jnp.minimum(Sm1 - done, K)
-                # NEED-driven speculation: split exactly the slots the
-                # on-device leaf-wise replay flagged as its frontier last
-                # round — early rounds this is every positive leaf, late
-                # rounds just the deep paths still growing. The loop ends
-                # when the replay completes with an empty frontier, which
-                # certifies the replay EXACT by construction.
-                sel = need & (gains > 0.0)
-                order = jnp.argsort(-gains, stable=True)
-                sel_sorted = sel[order]
-                selrank_sorted = jnp.cumsum(
-                    sel_sorted.astype(jnp.int32)) - 1
-                selrank = jnp.zeros(S + 1, jnp.int32).at[order].set(
-                    selrank_sorted)
-                sel = sel & (selrank < budget)
-                k = jnp.sum(sel.astype(jnp.int32))
-                seq = done + selrank
-                right_slot = seq + 1
+                with phases.scope("build.layout"):
+                    src = rounds % 2
+                    s_ids = jnp.arange(S + 1, dtype=jnp.int32)
+                    gains = bestF[:, BF_GAIN]
+                    # K also caps per-round splits: compact hist ids must fit
+                    # the VMEM-resident store (dropped needs re-offer next
+                    # round via the replay)
+                    budget = jnp.minimum(Sm1 - done, K)
+                    # NEED-driven speculation: split exactly the slots the
+                    # on-device leaf-wise replay flagged as its frontier last
+                    # round — early rounds this is every positive leaf, late
+                    # rounds just the deep paths still growing. The loop ends
+                    # when the replay completes with an empty frontier, which
+                    # certifies the replay EXACT by construction.
+                    sel = need & (gains > 0.0)
+                    order = jnp.argsort(-gains, stable=True)
+                    sel_sorted = sel[order]
+                    selrank_sorted = jnp.cumsum(
+                        sel_sorted.astype(jnp.int32)) - 1
+                    selrank = jnp.zeros(S + 1, jnp.int32).at[order].set(
+                        selrank_sorted)
+                    sel = sel & (selrank < budget)
+                    k = jnp.sum(sel.astype(jnp.int32))
+                    seq = done + selrank
+                    right_slot = seq + 1
 
-                # ---- record executed splits
-                safe_seq = jnp.where(sel, seq, Sm1)
-                rowF = jnp.stack([bestF[:, BF_GAIN], bestF[:, BF_LOUT],
-                                  bestF[:, BF_ROUT], leafF[:, LF_VALUE]],
-                                 axis=1)
-                rowI = jnp.zeros((S + 1, SI_W), jnp.int32)
-                rowI = rowI.at[:, SI_SLOT].set(s_ids)
-                rowI = rowI.at[:, SI_FEAT].set(bestI[:, BI_FEAT])
-                rowI = rowI.at[:, SI_THR].set(bestI[:, BI_THR])
-                rowI = rowI.at[:, SI_DEFLEFT].set(bestI[:, BI_DEFLEFT])
-                rowI = rowI.at[:, SI_ISCAT].set(bestI[:, BI_ISCAT])
-                rowI = rowI.at[:, SI_LC].set(bestI[:, BI_LC])
-                rowI = rowI.at[:, SI_RC].set(bestI[:, BI_RC])
-                selF = sel[:, None]
-                execF = execF.at[safe_seq].set(
-                    jnp.where(selF, rowF, execF[safe_seq]))
-                execI = execI.at[safe_seq].set(
-                    jnp.where(selF, rowI, execI[safe_seq]))
-                execB = execB.at[safe_seq].set(
-                    jnp.where(selF, bestB, execB[safe_seq]))
+                    # ---- record executed splits
+                    safe_seq = jnp.where(sel, seq, Sm1)
+                    rowF = jnp.stack([bestF[:, BF_GAIN], bestF[:, BF_LOUT],
+                                      bestF[:, BF_ROUT], leafF[:, LF_VALUE]],
+                                     axis=1)
+                    rowI = jnp.zeros((S + 1, SI_W), jnp.int32)
+                    rowI = rowI.at[:, SI_SLOT].set(s_ids)
+                    rowI = rowI.at[:, SI_FEAT].set(bestI[:, BI_FEAT])
+                    rowI = rowI.at[:, SI_THR].set(bestI[:, BI_THR])
+                    rowI = rowI.at[:, SI_DEFLEFT].set(bestI[:, BI_DEFLEFT])
+                    rowI = rowI.at[:, SI_ISCAT].set(bestI[:, BI_ISCAT])
+                    rowI = rowI.at[:, SI_LC].set(bestI[:, BI_LC])
+                    rowI = rowI.at[:, SI_RC].set(bestI[:, BI_RC])
+                    selF = sel[:, None]
+                    execF = execF.at[safe_seq].set(
+                        jnp.where(selF, rowF, execF[safe_seq]))
+                    execI = execI.at[safe_seq].set(
+                        jnp.where(selF, rowI, execI[safe_seq]))
+                    execB = execB.at[safe_seq].set(
+                        jnp.where(selF, bestB, execB[safe_seq]))
 
-                exists = s_ids <= done
-                slot_of, cnt_of, first, last, in_any = chunk_maps(
-                    leafI, exists, cnts_pc=cnts_pc, root_span=(done == 0),
-                    park_begin=park_begin)
+                    exists = s_ids <= done
+                    slot_of, cnt_of, first, last, in_any = chunk_maps(
+                        leafI, exists, cnts_pc=cnts_pc, root_span=(done == 0),
+                        park_begin=park_begin)
 
-                # ---- left counts: serial mode shards see the global
-                # histogram, so the finder's exact left count (BI_LC, an
-                # exact f32 count-stat sum) IS the local left count — no
-                # counting pass over the rows needed. (A data-parallel
-                # port needs a per-shard count pass here.)
-                feat = bestI[:, BI_FEAT]
-                scol = col_dev[feat] if bundled else feat
-                wsel_s = scol // bpw
-                shift_s = (scol % bpw) * bits
-                # route words + chunk meta (shared by the count pass and
-                # the move pass; both read the OLD layout)
-                r1_s = (jnp.clip(bestI[:, BI_THR], 0, 255)
-                        | (shift_s << R_SHIFT)
-                        | (bestI[:, BI_DEFLEFT] << R_DL)
-                        | (mt_dev[feat] << R_MT)
-                        | ((1 - sel.astype(jnp.int32)) << R_COPY)
-                        | (bestI[:, BI_ISCAT] << R_CAT))
-                # compact per-round bitset table for categorical splits
-                # (tiny SMEM prefetch; row K is the never-read pad row)
-                cbits = jnp.zeros((K + 1, 8), jnp.int32).at[
-                    jnp.where(sel, jnp.clip(selrank, 0, K - 1), K)].set(
-                    jnp.where(sel[:, None],
-                              lax.bitcast_convert_type(bestB, jnp.int32),
-                              0)).reshape(-1)
-                r2_s = pack_route2(
-                    jnp.clip(db_dev[feat], 0, 255),
-                    jnp.clip(nb_dev[feat], 1, 256),
-                    boff_dev[feat] if bundled else 0,
-                    bpk_dev[feat] if bundled else 0)
-                r1_pc = r1_s[slot_of]
-                r2_pc = r2_s[slot_of]
-                wsel_pc = wsel_s[slot_of]
-                meta_pc = (cnt_of
-                           | (first.astype(jnp.int32) << 20)
-                           | (last.astype(jnp.int32) << 21))
-                if counted:
-                    # the histogram count channel cannot drive the
-                    # physical layout when it is IN-BAG only (bagging,
-                    # gbdt.cpp:209-275) or GLOBAL (data-parallel: BI_LC
-                    # is the psum-reduced count; the shard's local
-                    # layout needs its own rows' left counts,
-                    # data_parallel_tree_learner.cpp:251-257): exact i32
-                    # per-shard counts come from the dedicated count
-                    # pass (streams just the split-word sublane; the
-                    # R_COPY bit is never read there — counted chunks
-                    # are selected splits, whose copy bit is 0)
-                    ks_s = jnp.where(sel, jnp.clip(selrank, 0, K - 1), K)
-                    ks_pc = jnp.where(in_any & sel[slot_of],
-                                      ks_s[slot_of], K)
-                    phys = count_pass(rec_a, rec_b, src, r1_pc, r2_pc,
-                                      meta_pc, wsel_pc, ks_pc, cbits, K, C,
-                                      bits=bits, bundled=bundled,
-                                      interpret=interpret)
-                    left_local = jnp.where(
-                        sel, phys[jnp.clip(selrank, 0, K - 1)],
-                        leafI[:, LI_COUNT])
-                else:
-                    left_local = jnp.where(sel, bestI[:, BI_LC],
-                                           leafI[:, LI_COUNT])
-                right_local = leafI[:, LI_COUNT] - left_local
+                    # ---- left counts: serial mode shards see the global
+                    # histogram, so the finder's exact left count (BI_LC, an
+                    # exact f32 count-stat sum) IS the local left count — no
+                    # counting pass over the rows needed. (A data-parallel
+                    # port needs a per-shard count pass here.)
+                    feat = bestI[:, BI_FEAT]
+                    scol = col_dev[feat] if bundled else feat
+                    wsel_s = scol // bpw
+                    shift_s = (scol % bpw) * bits
+                    # route words + chunk meta (shared by the count pass and
+                    # the move pass; both read the OLD layout)
+                    r1_s = (jnp.clip(bestI[:, BI_THR], 0, 255)
+                            | (shift_s << R_SHIFT)
+                            | (bestI[:, BI_DEFLEFT] << R_DL)
+                            | (mt_dev[feat] << R_MT)
+                            | ((1 - sel.astype(jnp.int32)) << R_COPY)
+                            | (bestI[:, BI_ISCAT] << R_CAT))
+                    # compact per-round bitset table for categorical splits
+                    # (tiny SMEM prefetch; row K is the never-read pad row)
+                    cbits = jnp.zeros((K + 1, 8), jnp.int32).at[
+                        jnp.where(sel, jnp.clip(selrank, 0, K - 1), K)].set(
+                        jnp.where(sel[:, None],
+                                  lax.bitcast_convert_type(bestB, jnp.int32),
+                                  0)).reshape(-1)
+                    r2_s = pack_route2(
+                        jnp.clip(db_dev[feat], 0, 255),
+                        jnp.clip(nb_dev[feat], 1, 256),
+                        boff_dev[feat] if bundled else 0,
+                        bpk_dev[feat] if bundled else 0)
+                    r1_pc = r1_s[slot_of]
+                    r2_pc = r2_s[slot_of]
+                    wsel_pc = wsel_s[slot_of]
+                    meta_pc = (cnt_of
+                               | (first.astype(jnp.int32) << 20)
+                               | (last.astype(jnp.int32) << 21))
+                    if counted:
+                        # the histogram count channel cannot drive the
+                        # physical layout when it is IN-BAG only (bagging,
+                        # gbdt.cpp:209-275) or GLOBAL (data-parallel: BI_LC
+                        # is the psum-reduced count; the shard's local
+                        # layout needs its own rows' left counts,
+                        # data_parallel_tree_learner.cpp:251-257): exact i32
+                        # per-shard counts come from the dedicated count
+                        # pass (streams just the split-word sublane; the
+                        # R_COPY bit is never read there — counted chunks
+                        # are selected splits, whose copy bit is 0)
+                        ks_s = jnp.where(sel, jnp.clip(selrank, 0, K - 1), K)
+                        ks_pc = jnp.where(in_any & sel[slot_of],
+                                          ks_s[slot_of], K)
+                        phys = count_pass(rec_a, rec_b, src, r1_pc, r2_pc,
+                                          meta_pc, wsel_pc, ks_pc, cbits, K, C,
+                                          bits=bits, bundled=bundled,
+                                          interpret=interpret)
+                        left_local = jnp.where(
+                            sel, phys[jnp.clip(selrank, 0, K - 1)],
+                            leafI[:, LI_COUNT])
+                    else:
+                        left_local = jnp.where(sel, bestI[:, BI_LC],
+                                               leafI[:, LI_COUNT])
+                    right_local = leafI[:, LI_COUNT] - left_local
 
-                # ---- new layout
-                newcnt = jnp.where(exists, left_local, 0)
-                safe_right = jnp.where(sel, right_slot, S)
-                rightcnt = jnp.zeros(S + 1, jnp.int32).at[safe_right].set(
-                    jnp.where(sel, right_local, 0))
-                allcnt = newcnt + rightcnt     # disjoint: right slots fresh
-                nch_new = (allcnt + C - 1) // C
-                new_begin = jnp.concatenate(
-                    [jnp.zeros(1, jnp.int32), jnp.cumsum(nch_new)[:-1]])
+                    # ---- new layout
+                    newcnt = jnp.where(exists, left_local, 0)
+                    safe_right = jnp.where(sel, right_slot, S)
+                    rightcnt = jnp.zeros(S + 1, jnp.int32).at[safe_right].set(
+                        jnp.where(sel, right_local, 0))
+                    # disjoint: right slots are fresh
+                    allcnt = newcnt + rightcnt
+                    nch_new = (allcnt + C - 1) // C
+                    new_begin = jnp.concatenate(
+                        [jnp.zeros(1, jnp.int32), jnp.cumsum(nch_new)[:-1]])
 
-                # ---- move destinations per chunk (NEW layout)
-                copy_pc = ~sel[slot_of] & in_any
-                # unsplit blocks shift as WHOLE chunks: per-chunk direct
-                # destination (kernel bypasses all compute with one DMA)
-                direct_pc = (new_begin[slot_of] + chunk_iota
-                             - leafI[:, LI_BEGIN][slot_of])
-                bl_s = new_begin
-                br_s = jnp.where(sel, new_begin[safe_right], new_begin)
-                bl_pc = jnp.where(copy_pc, direct_pc, bl_s[slot_of])
-                br_pc = br_s[slot_of]
-                # smaller-child hist slots (COMPACT per-round ids =
-                # selection rank, so the move pass's VMEM-resident store
-                # stays small), fused into the move pass
-                smaller_is_left = bestI[:, BI_LC] <= bestI[:, BI_RC]
-                hslot_s = jnp.where(
-                    sel, jnp.clip(selrank, 0, K - 1)
-                    | ((~smaller_is_left).astype(jnp.int32) << 24),
-                    K)
-                hslots_pc = jnp.where(in_any, hslot_s[slot_of], K)
-                copied_pc = copy_pc
-                dead_pc = sel[slot_of] & ~in_any
-                if parks:
-                    # a parked chunk is a copy chunk of no row: a grid
-                    # step, and nothing of the split path (the chunk map
-                    # hands the free tail to the last slot, whose route
-                    # word may say "split"). On a tree that began with no
-                    # partition the second buffer, a temporary of this
-                    # program, lacks the parked block: its chunks ride
-                    # the first round as whole-chunk copies to their own
-                    # place, and either buffer may end the tree
-                    r1_pc = jnp.where(parked, 1 << R_COPY, r1_pc)
-                    ride = (parked & (park_cnts > 0) & (rounds == 0)
-                            & (park_rounds == 0))
-                    meta_pc = jnp.where(ride, park_cnts, meta_pc)
-                    bl_pc = jnp.where(ride, chunk_iota, bl_pc)
-                    copied_pc = copy_pc | ride
-                    dead_pc = dead_pc & ~parked
-                # ---- what this round schedules (ROUND_STATS order): the
-                # kernel's split path runs wherever the chunk's route word
-                # has the copy bit clear, live block or not, and a
-                # spilling store is flushed once per split block, on its
-                # last chunk
-                split_pc = sel[slot_of]
+                    # ---- move destinations per chunk (NEW layout)
+                    copy_pc = ~sel[slot_of] & in_any
+                    # unsplit blocks shift as WHOLE chunks: per-chunk direct
+                    # destination (kernel bypasses all compute with one DMA)
+                    direct_pc = (new_begin[slot_of] + chunk_iota
+                                 - leafI[:, LI_BEGIN][slot_of])
+                    bl_s = new_begin
+                    br_s = jnp.where(sel, new_begin[safe_right], new_begin)
+                    bl_pc = jnp.where(copy_pc, direct_pc, bl_s[slot_of])
+                    br_pc = br_s[slot_of]
+                    # smaller-child hist slots (COMPACT per-round ids =
+                    # selection rank, so the move pass's VMEM-resident store
+                    # stays small), fused into the move pass
+                    smaller_is_left = bestI[:, BI_LC] <= bestI[:, BI_RC]
+                    hslot_s = jnp.where(
+                        sel, jnp.clip(selrank, 0, K - 1)
+                        | ((~smaller_is_left).astype(jnp.int32) << 24),
+                        K)
+                    hslots_pc = jnp.where(in_any, hslot_s[slot_of], K)
+                    copied_pc = copy_pc
+                    dead_pc = sel[slot_of] & ~in_any
+                    if parks:
+                        # a parked chunk is a copy chunk of no row: a grid
+                        # step, and nothing of the split path (the chunk map
+                        # hands the free tail to the last slot, whose route
+                        # word may say "split"). On a tree that began with no
+                        # partition the second buffer, a temporary of this
+                        # program, lacks the parked block: its chunks ride
+                        # the first round as whole-chunk copies to their own
+                        # place, and either buffer may end the tree
+                        r1_pc = jnp.where(parked, 1 << R_COPY, r1_pc)
+                        ride = (parked & (park_cnts > 0) & (rounds == 0)
+                                & (park_rounds == 0))
+                        meta_pc = jnp.where(ride, park_cnts, meta_pc)
+                        bl_pc = jnp.where(ride, chunk_iota, bl_pc)
+                        copied_pc = copy_pc | ride
+                        dead_pc = dead_pc & ~parked
+                    # ---- what this round schedules (ROUND_STATS order): the
+                    # kernel's split path runs wherever the chunk's route word
+                    # has the copy bit clear, live block or not, and a
+                    # spilling store is flushed once per split block, on its
+                    # last chunk
+                    split_pc = sel[slot_of]
 
-                def nsum(x):
-                    return jnp.sum(x.astype(jnp.int32))
-                # the partition by the bag, where one ran, is row 0
-                round_stats = round_stats.at[rounds + park_rounds].set(
-                    jnp.stack([
-                        nsum(split_pc & in_any), nsum(copied_pc),
-                        nsum(jnp.where(sel, leafI[:, LI_COUNT], 0)), k,
-                        nsum(split_pc & last) if spill else jnp.int32(0),
-                        nsum(dead_pc)]))
-                rec_a, rec_b, hout = move_pass(
-                    rec_a, rec_b, src, r1_pc, r2_pc, bl_pc, br_pc,
-                    meta_pc, wsel_pc, hslots_pc, cbits,
-                    C, W, wcnt, K, G, BH, group,
-                    bag_lane=bag_lane, bits=bits, grad_fn=gfn,
-                    num_class=K_cls, w_used=self.w_used,
-                    gh_off=self.gh_off, bundled=bundled,
-                    interpret=interpret, subbin=subbin, spill=spill)
+                    def nsum(x):
+                        return jnp.sum(x.astype(jnp.int32))
+                    # the partition by the bag, where one ran, is row 0
+                    round_stats = round_stats.at[rounds + park_rounds].set(
+                        jnp.stack([
+                            nsum(split_pc & in_any), nsum(copied_pc),
+                            nsum(jnp.where(sel, leafI[:, LI_COUNT], 0)), k,
+                            nsum(split_pc & last) if spill else jnp.int32(0),
+                            nsum(dead_pc)]))
+                    rec_a, rec_b, hout = move_pass(
+                        rec_a, rec_b, src, r1_pc, r2_pc, bl_pc, br_pc,
+                        meta_pc, wsel_pc, hslots_pc, cbits,
+                        C, W, wcnt, K, G, BH, group,
+                        bag_lane=bag_lane, bits=bits, grad_fn=gfn,
+                        num_class=K_cls, w_used=self.w_used,
+                        gh_off=self.gh_off, bundled=bundled,
+                        interpret=interpret, subbin=subbin, spill=spill)
 
-                # ---- updated tables (begins relaid for ALL slots)
-                depth_new = leafI[:, LI_DEPTH] + 1
-                if mono_any:
-                    mono = mono_dev[bestI[:, BI_FEAT]]
-                    mid = (bestF[:, BF_LOUT] + bestF[:, BF_ROUT]) / 2.0
-                    minc0 = leafF[:, LF_MINC]
-                    maxc0 = leafF[:, LF_MAXC]
-                    lmax = jnp.where(mono > 0, jnp.minimum(maxc0, mid),
-                                     maxc0)
-                    rmin = jnp.where(mono > 0, jnp.maximum(minc0, mid),
-                                     minc0)
-                    lmin = jnp.where(mono < 0, jnp.maximum(minc0, mid),
-                                     minc0)
-                    rmax = jnp.where(mono < 0, jnp.minimum(maxc0, mid),
-                                     maxc0)
-                else:
-                    lmin = rmin = leafF[:, LF_MINC]
-                    lmax = rmax = leafF[:, LF_MAXC]
+                    # ---- updated tables (begins relaid for ALL slots)
+                    depth_new = leafI[:, LI_DEPTH] + 1
+                    if mono_any:
+                        mono = mono_dev[bestI[:, BI_FEAT]]
+                        mid = (bestF[:, BF_LOUT] + bestF[:, BF_ROUT]) / 2.0
+                        minc0 = leafF[:, LF_MINC]
+                        maxc0 = leafF[:, LF_MAXC]
+                        lmax = jnp.where(mono > 0, jnp.minimum(maxc0, mid),
+                                         maxc0)
+                        rmin = jnp.where(mono > 0, jnp.maximum(minc0, mid),
+                                         minc0)
+                        lmin = jnp.where(mono < 0, jnp.maximum(minc0, mid),
+                                         minc0)
+                        rmax = jnp.where(mono < 0, jnp.minimum(maxc0, mid),
+                                         maxc0)
+                    else:
+                        lmin = rmin = leafF[:, LF_MINC]
+                        lmax = rmax = leafF[:, LF_MAXC]
 
-                rrowF = jnp.zeros((S + 1, LF_W), jnp.float32)
-                rrowF = rrowF.at[:, LF_SG].set(bestF[:, BF_RG])
-                rrowF = rrowF.at[:, LF_SH].set(bestF[:, BF_RH])
-                rrowF = rrowF.at[:, LF_MINC].set(rmin)
-                rrowF = rrowF.at[:, LF_MAXC].set(rmax)
-                rrowF = rrowF.at[:, LF_VALUE].set(bestF[:, BF_ROUT])
-                rrowI = jnp.zeros((S + 1, LI_W), jnp.int32)
-                rrowI = rrowI.at[:, LI_BEGIN].set(new_begin[safe_right])
-                rrowI = rrowI.at[:, LI_COUNT].set(
-                    jnp.where(sel, right_local, 0))
-                rrowI = rrowI.at[:, LI_COUNTG].set(bestI[:, BI_RC])
-                rrowI = rrowI.at[:, LI_DEPTH].set(depth_new)
-                leafF = leafF.at[safe_right].set(
-                    jnp.where(selF, rrowF, leafF[safe_right]))
-                leafI = leafI.at[safe_right].set(
-                    jnp.where(selF, rrowI, leafI[safe_right]))
-                leafF = leafF.at[:, LF_SG].set(
-                    jnp.where(sel, bestF[:, BF_LG], leafF[:, LF_SG]))
-                leafF = leafF.at[:, LF_SH].set(
-                    jnp.where(sel, bestF[:, BF_LH], leafF[:, LF_SH]))
-                leafF = leafF.at[:, LF_MINC].set(
-                    jnp.where(sel, lmin, leafF[:, LF_MINC]))
-                leafF = leafF.at[:, LF_MAXC].set(
-                    jnp.where(sel, lmax, leafF[:, LF_MAXC]))
-                leafF = leafF.at[:, LF_VALUE].set(
-                    jnp.where(sel, bestF[:, BF_LOUT], leafF[:, LF_VALUE]))
-                leafI = leafI.at[:, LI_COUNT].set(
-                    jnp.where(sel, left_local, leafI[:, LI_COUNT]))
-                leafI = leafI.at[:, LI_COUNTG].set(
-                    jnp.where(sel, bestI[:, BI_LC], leafI[:, LI_COUNTG]))
-                leafI = leafI.at[:, LI_DEPTH].set(
-                    jnp.where(sel, depth_new, leafI[:, LI_DEPTH]))
-                # full relayout: every existing slot gets its new begin
-                exists2 = s_ids <= done + k
-                leafI = leafI.at[:, LI_BEGIN].set(
-                    jnp.where(exists2, new_begin, NC))
+                    rrowF = jnp.zeros((S + 1, LF_W), jnp.float32)
+                    rrowF = rrowF.at[:, LF_SG].set(bestF[:, BF_RG])
+                    rrowF = rrowF.at[:, LF_SH].set(bestF[:, BF_RH])
+                    rrowF = rrowF.at[:, LF_MINC].set(rmin)
+                    rrowF = rrowF.at[:, LF_MAXC].set(rmax)
+                    rrowF = rrowF.at[:, LF_VALUE].set(bestF[:, BF_ROUT])
+                    rrowI = jnp.zeros((S + 1, LI_W), jnp.int32)
+                    rrowI = rrowI.at[:, LI_BEGIN].set(new_begin[safe_right])
+                    rrowI = rrowI.at[:, LI_COUNT].set(
+                        jnp.where(sel, right_local, 0))
+                    rrowI = rrowI.at[:, LI_COUNTG].set(bestI[:, BI_RC])
+                    rrowI = rrowI.at[:, LI_DEPTH].set(depth_new)
+                    leafF = leafF.at[safe_right].set(
+                        jnp.where(selF, rrowF, leafF[safe_right]))
+                    leafI = leafI.at[safe_right].set(
+                        jnp.where(selF, rrowI, leafI[safe_right]))
+                    leafF = leafF.at[:, LF_SG].set(
+                        jnp.where(sel, bestF[:, BF_LG], leafF[:, LF_SG]))
+                    leafF = leafF.at[:, LF_SH].set(
+                        jnp.where(sel, bestF[:, BF_LH], leafF[:, LF_SH]))
+                    leafF = leafF.at[:, LF_MINC].set(
+                        jnp.where(sel, lmin, leafF[:, LF_MINC]))
+                    leafF = leafF.at[:, LF_MAXC].set(
+                        jnp.where(sel, lmax, leafF[:, LF_MAXC]))
+                    leafF = leafF.at[:, LF_VALUE].set(
+                        jnp.where(sel, bestF[:, BF_LOUT], leafF[:, LF_VALUE]))
+                    leafI = leafI.at[:, LI_COUNT].set(
+                        jnp.where(sel, left_local, leafI[:, LI_COUNT]))
+                    leafI = leafI.at[:, LI_COUNTG].set(
+                        jnp.where(sel, bestI[:, BI_LC], leafI[:, LI_COUNTG]))
+                    leafI = leafI.at[:, LI_DEPTH].set(
+                        jnp.where(sel, depth_new, leafI[:, LI_DEPTH]))
+                    # full relayout: every existing slot gets its new begin
+                    exists2 = s_ids <= done + k
+                    leafI = leafI.at[:, LI_BEGIN].set(
+                        jnp.where(exists2, new_begin, NC))
 
-                # ---- new per-chunk counts
-                slot_of2, cnt_of2, _, _, _ = chunk_maps(leafI, exists2)
-                cnts_pc = cnt_of2
+                    # ---- new per-chunk counts
+                    slot_of2, cnt_of2, _, _, _ = chunk_maps(leafI, exists2)
+                    cnts_pc = cnt_of2
 
-                # ---- child histograms + eval on CHANGED slots only,
-                # [K]-compact by selection rank: the [S+1, F, B, 3] store
-                # is touched by one gather + two scatters instead of six
-                # full-store passes, and the split finder runs on the 2k
-                # changed children instead of every slot (unchanged slots'
-                # cached best split cannot change). At F=137/B=256 shapes
-                # the full-store traffic dominated the round.
-                rk = jnp.arange(K, dtype=jnp.int32)
-                valid_rk = rk < jnp.minimum(k, K)
-                # slot_l[r] = tree slot of selection rank r (pad -> S, the
-                # dump slot: right children cap at S-1 so S is never live)
-                idx_sc = jnp.where(sel, jnp.clip(selrank, 0, K - 1), K)
-                slot_l = jnp.full(K + 1, S, jnp.int32).at[idx_sc].set(
-                    jnp.where(sel, s_ids, S))[:K]
-                slot_r = jnp.where(valid_rk, done + rk + 1, S)
-                sm_k = _gsum(hout)                      # [K, F, B, 3]
-                parent_k = hist_store[slot_l]
-                lg_k = parent_k - sm_k
-                sil_k = smaller_is_left[slot_l][:, None, None, None]
-                left_k = jnp.where(sil_k, sm_k, lg_k)
-                right_k = jnp.where(sil_k, lg_k, sm_k)
-                v4 = valid_rk[:, None, None, None]
-                hist_store = hist_store.at[slot_l].set(
-                    jnp.where(v4, left_k, parent_k))
-                # pad ranks target S with the old store row (parent_k of a
-                # pad IS hist_store[S]) -> consistent duplicate writes
-                hist_store = hist_store.at[slot_r].set(
-                    jnp.where(v4, right_k, parent_k))
+                with phases.scope("build.eval"):
+                    # ---- child histograms + eval on CHANGED slots only,
+                    # [K]-compact by selection rank: the [S+1, F, B, 3] store
+                    # is touched by one gather + two scatters instead of six
+                    # full-store passes, and the split finder runs on the 2k
+                    # changed children instead of every slot (unchanged slots'
+                    # cached best split cannot change). At F=137/B=256 shapes
+                    # the full-store traffic dominated the round.
+                    rk = jnp.arange(K, dtype=jnp.int32)
+                    valid_rk = rk < jnp.minimum(k, K)
+                    # slot_l[r] = tree slot of selection rank r (pad -> S, the
+                    # dump slot: right children cap at S-1 so S is never live)
+                    idx_sc = jnp.where(sel, jnp.clip(selrank, 0, K - 1), K)
+                    slot_l = jnp.full(K + 1, S, jnp.int32).at[idx_sc].set(
+                        jnp.where(sel, s_ids, S))[:K]
+                    slot_r = jnp.where(valid_rk, done + rk + 1, S)
+                    sm_k = _gsum(hout)                      # [K, F, B, 3]
+                    parent_k = hist_store[slot_l]
+                    lg_k = parent_k - sm_k
+                    sil_k = smaller_is_left[slot_l][:, None, None, None]
+                    left_k = jnp.where(sil_k, sm_k, lg_k)
+                    right_k = jnp.where(sil_k, lg_k, sm_k)
+                    v4 = valid_rk[:, None, None, None]
+                    hist_store = hist_store.at[slot_l].set(
+                        jnp.where(v4, left_k, parent_k))
+                    # pad ranks target S with the old store row (parent_k of a
+                    # pad IS hist_store[S]) -> consistent duplicate writes
+                    hist_store = hist_store.at[slot_r].set(
+                        jnp.where(v4, right_k, parent_k))
 
-                # children stats for the finder ([K] gathers, all tiny)
-                dep_k = depth_new[slot_l]
-                left_e, right_e = left_k, right_k
-                if bundled:
-                    left_e = expand_hist(
-                        left_k, bestF[slot_l, BF_LG],
-                        bestF[slot_l, BF_LH], bestI[slot_l, BI_LC])
-                    right_e = expand_hist(
-                        right_k, bestF[slot_l, BF_RG],
-                        bestF[slot_l, BF_RH], bestI[slot_l, BI_RC])
-                lF, lI, lB = eval_all(
-                    feature_mask_f32, left_e, bestF[slot_l, BF_LG],
-                    bestF[slot_l, BF_LH], bestI[slot_l, BI_LC],
-                    lmin[slot_l], lmax[slot_l], dep_k, valid_rk)
-                rF, rI, rB = eval_all(
-                    feature_mask_f32, right_e, bestF[slot_l, BF_RG],
-                    bestF[slot_l, BF_RH], bestI[slot_l, BI_RC],
-                    rmin[slot_l], rmax[slot_l], dep_k, valid_rk)
-                vK = valid_rk[:, None]
-                bestF = bestF.at[slot_l].set(
-                    jnp.where(vK, lF, bestF[slot_l]))
-                bestI = bestI.at[slot_l].set(
-                    jnp.where(vK, lI, bestI[slot_l]))
-                bestB = bestB.at[slot_l].set(
-                    jnp.where(vK, lB, bestB[slot_l]))
-                bestF = bestF.at[slot_r].set(
-                    jnp.where(vK, rF, bestF[slot_r]))
-                bestI = bestI.at[slot_r].set(
-                    jnp.where(vK, rI, bestI[slot_r]))
-                bestB = bestB.at[slot_r].set(
-                    jnp.where(vK, rB, bestB[slot_r]))
+                    # children stats for the finder ([K] gathers, all tiny)
+                    dep_k = depth_new[slot_l]
+                    left_e, right_e = left_k, right_k
+                    if bundled:
+                        left_e = expand_hist(
+                            left_k, bestF[slot_l, BF_LG],
+                            bestF[slot_l, BF_LH], bestI[slot_l, BI_LC])
+                        right_e = expand_hist(
+                            right_k, bestF[slot_l, BF_RG],
+                            bestF[slot_l, BF_RH], bestI[slot_l, BI_RC])
+                    lF, lI, lB = eval_all(
+                        feature_mask_f32, left_e, bestF[slot_l, BF_LG],
+                        bestF[slot_l, BF_LH], bestI[slot_l, BI_LC],
+                        lmin[slot_l], lmax[slot_l], dep_k, valid_rk)
+                    rF, rI, rB = eval_all(
+                        feature_mask_f32, right_e, bestF[slot_l, BF_RG],
+                        bestF[slot_l, BF_RH], bestI[slot_l, BI_RC],
+                        rmin[slot_l], rmax[slot_l], dep_k, valid_rk)
+                    vK = valid_rk[:, None]
+                    bestF = bestF.at[slot_l].set(
+                        jnp.where(vK, lF, bestF[slot_l]))
+                    bestI = bestI.at[slot_l].set(
+                        jnp.where(vK, lI, bestI[slot_l]))
+                    bestB = bestB.at[slot_l].set(
+                        jnp.where(vK, lB, bestB[slot_l]))
+                    bestF = bestF.at[slot_r].set(
+                        jnp.where(vK, rF, bestF[slot_r]))
+                    bestI = bestI.at[slot_r].set(
+                        jnp.where(vK, rI, bestI[slot_r]))
+                    bestB = bestB.at[slot_r].set(
+                        jnp.where(vK, rB, bestB[slot_r]))
 
-                # Replay-skip shortcut, at the PROVABLY equivalent
-                # threshold: with e = done + k execs, the capped replay
-                # pops at most e commits + (e + 1) frontier tips, so
-                # while 2e + 1 < L-1 the budget cap cannot bind and
-                # need == every positive slot — no replay required. (The
-                # old done+k < L-1 threshold over-asked by up to ~L/2
-                # execs in the transition rounds; past the new threshold
-                # the real budget-capped replay prunes the frontier to
-                # what the true leaf-wise order can still reach.)
-                def full_replay(_):
-                    return device_replay(execF, execI, bestF[:, BF_GAIN],
-                                         done + k)
+                with phases.scope("build.replay"):
+                    # Replay-skip shortcut, at the PROVABLY equivalent
+                    # threshold: with e = done + k execs, the capped replay
+                    # pops at most e commits + (e + 1) frontier tips, so
+                    # while 2e + 1 < L-1 the budget cap cannot bind and
+                    # need == every positive slot — no replay required. (The
+                    # old done+k < L-1 threshold over-asked by up to ~L/2
+                    # execs in the transition rounds; past the new threshold
+                    # the real budget-capped replay prunes the frontier to
+                    # what the true leaf-wise order can still reach.)
+                    def full_replay(_):
+                        return device_replay(execF, execI, bestF[:, BF_GAIN],
+                                             done + k)
 
-                def all_needed(_):
-                    nd = (bestF[:, BF_GAIN] > 0.0) & exists2
-                    return (jnp.zeros(Sm1 + 1, bool), nd, jnp.int32(0))
+                    def all_needed(_):
+                        nd = (bestF[:, BF_GAIN] > 0.0) & exists2
+                        return (jnp.zeros(Sm1 + 1, bool), nd, jnp.int32(0))
 
-                commit, need2, ncommit = lax.cond(
-                    2 * (done + k) + 1 < Lm1_commit, all_needed,
-                    full_replay, operand=None)
+                    commit, need2, ncommit = lax.cond(
+                        2 * (done + k) + 1 < Lm1_commit, all_needed,
+                        full_replay, operand=None)
 
                 return (done + k, rec_a, rec_b, cnts_pc, leafF, leafI,
                         bestF, bestI, bestB, hist_store, execF, execI,
@@ -1336,90 +1349,93 @@ class AlignedEngine:
             # no `lax.cond`: a loop's carry is overwritten in its place,
             # where a conditional's result is a third buffer that both
             # branches copy into (4.5 GiB more, and a copy on every tree)
-            norm_passes = rounds % 2
-            rec, _, _ = lax.while_loop(
-                lambda st: st[2] == 1,
-                lambda st: (st[1], st[1], jnp.int32(0)),
-                (rec, rec_b, norm_passes))
+            with phases.scope("build.copy_back"):
+                rec, _, _ = lax.while_loop(
+                    lambda st: st[2] == 1,
+                    lambda st: (st[1], st[1], jnp.int32(0)),
+                    (rec, rec_b, rounds % 2))
             rounds = rounds + park_rounds   # the partition is a round too
-            # authoritative final replay: the in-loop replay may have been
-            # skipped on the last round (all_needed shortcut), and a tree
-            # that stops growing early must still commit its real splits
-            commit, need_fin, ncommit = device_replay(
-                execF, execI, bestF[:, BF_GAIN], n_exec)
-            exact = ~jnp.any(need_fin)
+            with phases.scope("build.replay"):
+                # authoritative final replay: the in-loop replay may have been
+                # skipped on the last round (all_needed shortcut), and a tree
+                # that stops growing early must still commit its real splits
+                commit, need_fin, ncommit = device_replay(
+                    execF, execI, bestF[:, BF_GAIN], n_exec)
+                exact = ~jnp.any(need_fin)
 
-            # ---- committed cover value per slot (host _value_map twin,
-            # the reference's leaf outputs applied through the finer
-            # physical partition) — sequential over execs, tiny
-            def cov_step(e, cov):
-                sl = execI[e, SI_SLOT]
-                live = e < n_exec
-                com = commit[e] & live
-                parent = cov[sl]
-                newp = jnp.where(com, execF[e, SF_LOUT], parent)
-                cov = cov.at[sl].set(newp)
-                child = jnp.where(com, execF[e, SF_ROUT], parent)
-                r = jnp.clip(e + 1, 0, S)
-                cov = cov.at[r].set(jnp.where(live, child, cov[r]))
-                return cov
+            with phases.scope("build.tail"):
+                # ---- committed cover value per slot (host _value_map twin,
+                # the reference's leaf outputs applied through the finer
+                # physical partition) — sequential over execs, tiny
+                def cov_step(e, cov):
+                    sl = execI[e, SI_SLOT]
+                    live = e < n_exec
+                    com = commit[e] & live
+                    parent = cov[sl]
+                    newp = jnp.where(com, execF[e, SF_LOUT], parent)
+                    cov = cov.at[sl].set(newp)
+                    child = jnp.where(com, execF[e, SF_ROUT], parent)
+                    r = jnp.clip(e + 1, 0, S)
+                    cov = cov.at[r].set(jnp.where(live, child, cov[r]))
+                    return cov
 
-            cover = lax.fori_loop(0, Sm1, cov_step,
-                                  jnp.zeros(S + 1, jnp.float32))
+                cover = lax.fori_loop(0, Sm1, cov_step,
+                                      jnp.zeros(S + 1, jnp.float32))
 
-            # ---- committed-only chains (valid-set device walker): the
-            # committed tree's topology as slot-chain pointers, same
-            # grouping trick as device_replay but filtered to commits
-            eidx_c = jnp.arange(Sm1 + 1, dtype=jnp.int32)
-            slot_ec = execI[:, SI_SLOT]
-            valid_c = (eidx_c < n_exec) & commit
-            first_c = jnp.full(S + 1, E_INF, jnp.int32).at[
-                jnp.where(valid_c, slot_ec, S)].min(
-                jnp.where(valid_c, eidx_c, E_INF))
-            key_c = jnp.where(valid_c, slot_ec, S + 2) * (Sm1 + 2) + eidx_c
-            order_c = jnp.argsort(key_c)
-            so_c = slot_ec[order_c]
-            same_c = jnp.concatenate(
-                [(so_c[:-1] == so_c[1:]) & valid_c[order_c[1:]],
-                 jnp.zeros(1, bool)])
-            nxt_c = jnp.full(Sm1 + 1, E_INF, jnp.int32).at[order_c].set(
-                jnp.where(same_c, jnp.concatenate(
-                    [order_c[1:], jnp.full(1, E_INF, jnp.int32)]), E_INF))
+                # ---- committed-only chains (valid-set device walker): the
+                # committed tree's topology as slot-chain pointers, same
+                # grouping trick as device_replay but filtered to commits
+                eidx_c = jnp.arange(Sm1 + 1, dtype=jnp.int32)
+                slot_ec = execI[:, SI_SLOT]
+                valid_c = (eidx_c < n_exec) & commit
+                first_c = jnp.full(S + 1, E_INF, jnp.int32).at[
+                    jnp.where(valid_c, slot_ec, S)].min(
+                    jnp.where(valid_c, eidx_c, E_INF))
+                key_c = jnp.where(valid_c, slot_ec, S + 2) * (Sm1 + 2) + eidx_c
+                order_c = jnp.argsort(key_c)
+                so_c = slot_ec[order_c]
+                same_c = jnp.concatenate(
+                    [(so_c[:-1] == so_c[1:]) & valid_c[order_c[1:]],
+                     jnp.zeros(1, bool)])
+                nxt_c = jnp.full(Sm1 + 1, E_INF, jnp.int32).at[order_c].set(
+                    jnp.where(same_c, jnp.concatenate(
+                        [order_c[1:], jnp.full(1, E_INF, jnp.int32)]), E_INF))
 
-            # ---- score-lane update ON DEVICE (only when the replay is
-            # exact AND the previous dispatch committed: a program
-            # dispatched speculatively after an inexact predecessor will
-            # be discarded by the host, so prev_ok forces it to be a
-            # score no-op instead of trusting it to rebuild identically
-            # on the shifted physical layout)
-            applied = exact & prev_ok
-            if not multiclass:
-                exists_f = jnp.arange(S + 1) <= n_exec
-                slot_f, _, _, _, in_any_f = chunk_maps(leafI, exists_f)
-                valmap = jnp.where(in_any_f & applied, cover[slot_f], 0.0)
-                rec = _add_to_lane(rec, score_lane,
-                                   valmap[:, None] * scale_in)
-            if parks:
-                # the parked rows' share of the same update: no chunk map
-                # names their leaf, so the committed tree is walked over
-                # their chunks where they lie (every other chunk's count
-                # is 0 here, and the kernel skips it), under the flag the
-                # live rows' update is under. GOSS's next selection reads
-                # every row's score: once a tree, behind its build
-                tree = walk_tree(execI[:Sm1], first_c, nxt_c, cover)
-                tabs = walk_expand(tree.nodes, tree.leaves, tree.nn,
-                                   nb_dev, db_dev, mt_dev, w8=walk_w8,
-                                   bits=bits, fp=walk_fp)
-                rec = walk_pass(
-                    rec, park_cnts, applied.astype(jnp.int32),
-                    *(t[None] for t in tabs),
-                    (scale_in * tree.base)[None, :, None], chunk=C,
-                    wcnt=wcnt, bits=bits, lane=score_lane,
-                    interpret=interpret)
-                cnts_pc = cnts_pc + park_cnts
+                # ---- score-lane update ON DEVICE (only when the replay is
+                # exact AND the previous dispatch committed: a program
+                # dispatched speculatively after an inexact predecessor will
+                # be discarded by the host, so prev_ok forces it to be a
+                # score no-op instead of trusting it to rebuild identically
+                # on the shifted physical layout)
+                applied = exact & prev_ok
+                if not multiclass:
+                    exists_f = jnp.arange(S + 1) <= n_exec
+                    slot_f, _, _, _, in_any_f = chunk_maps(leafI, exists_f)
+                    valmap = jnp.where(in_any_f & applied, cover[slot_f], 0.0)
+                    rec = _add_to_lane(rec, score_lane,
+                                       valmap[:, None] * scale_in)
+                if parks:
+                    # the parked rows' share of the same update: no chunk map
+                    # names their leaf, so the committed tree is walked over
+                    # their chunks where they lie (every other chunk's count
+                    # is 0 here, and the kernel skips it), under the flag the
+                    # live rows' update is under. GOSS's next selection reads
+                    # every row's score: once a tree, behind its build
+                    tree = walk_tree(execI[:Sm1], first_c, nxt_c, cover)
+                    with phases.scope("walk.tables"):
+                        tabs = walk_expand(
+                            tree.nodes, tree.leaves, tree.nn, nb_dev,
+                            db_dev, mt_dev, w8=walk_w8, bits=bits,
+                            fp=walk_fp)
+                    rec = walk_pass(
+                        rec, park_cnts, applied.astype(jnp.int32),
+                        *(t[None] for t in tabs),
+                        (scale_in * tree.base)[None, :, None], chunk=C,
+                        wcnt=wcnt, bits=bits, lane=score_lane,
+                        interpret=interpret)
+                    cnts_pc = cnts_pc + park_cnts
 
-            spec = AlignedSpec(rounds=rounds, norm_passes=norm_passes,
-                               n_exec=n_exec,
+            spec = AlignedSpec(rounds=rounds, n_exec=n_exec,
                                execF=execF[:Sm1],
                                execI=execI[:Sm1], execB=execB[:Sm1],
                                bestF=bestF[:S], bestI=bestI[:S],
@@ -1479,6 +1495,9 @@ class AlignedEngine:
         def run(*args, **kwargs):
             traces = compile_cache.trace_count()
             before = compile_cache.persistent_cache_events()
+            # what lowers the program again, should a phase table be
+            # asked for: shapes and dtypes, no buffer
+            phases.remember(str(key), fn, args, kwargs)
             with obs_trace.seam("aligned.program", key=str(key)) as sm:
                 out = fn(*args, **kwargs)
                 after = compile_cache.persistent_cache_events()
@@ -1502,8 +1521,7 @@ class AlignedEngine:
         from jax.sharding import PartitionSpec as P
         ax = self.axis
         spec_out = AlignedSpec(
-            rounds=P(), norm_passes=P(), n_exec=P(), execF=P(), execI=P(),
-            execB=P(), bestF=P(), bestI=P(), bestB=P(), leafF=P(),
+            rounds=P(), n_exec=P(), execF=P(), execI=P(), execB=P(), bestF=P(), bestI=P(), bestB=P(), leafF=P(),
             leafI=P(ax),
             first_c=P(), nxt_c=P(), cover=P(), round_stats=P())
         if kind == "build":
@@ -1677,6 +1695,7 @@ class AlignedEngine:
         ln = self.lanes
         n, C, K = self.n, self.C, self.num_class
 
+        @phases.scoped("drain.materialise")
         def fn(rec, cnts):
             rid = self._rid_lanes(rec).reshape(-1)
             pos = jnp.arange(C, dtype=jnp.int32)
@@ -1720,6 +1739,7 @@ class AlignedEngine:
             boff = lr._boff_dev
             bpk = lr._bpk_dev
 
+        @phases.scoped("walk.apply")
         def fn(score, lane, vb, execI, execB, first_c, nxt_c, cover,
                scale, applied):
             nv = vb.shape[0]
@@ -1820,6 +1840,7 @@ class AlignedEngine:
         ne = S                  # exec ids 0 .. S - 1; S is "no exec"
         np_, lp, _, _ = self._walk_dims()
 
+        @phases.scoped("walk.tables")
         def fn(execI, first_c, nxt_c, cover):
             eidx = jnp.arange(ne, dtype=jnp.int32)
             # a committed exec is one a committed chain points at
@@ -1938,15 +1959,17 @@ class AlignedEngine:
         C, wcnt, bits = self.C, self.wcnt, self.bits
         lane, interpret = self.lanes["score"], self.interpret
 
+        @phases.scoped("walk.apply")
         def fn(rec, cnts, lo, ntrees, first, f_first, second, f_second,
                shr, bias, trees):
             # chunks below `lo` are none of this walk's
             cnts = jnp.where(jnp.arange(cnts.shape[0]) >= lo, cnts, 0)
-            tabs = jax.vmap(lambda n, l, k: walk_expand(
-                n, l, k, nb, db, mt, w8=w8, bits=bits, fp=fp))(
-                    jnp.stack([jnp.asarray(t.nodes) for t in trees]),
-                    jnp.stack([jnp.asarray(t.leaves) for t in trees]),
-                    jnp.stack([jnp.asarray(t.nn) for t in trees]))
+            with phases.scope("walk.tables"):
+                tabs = jax.vmap(lambda n, l, k: walk_expand(
+                    n, l, k, nb, db, mt, w8=w8, bits=bits, fp=fp))(
+                        jnp.stack([jnp.asarray(t.nodes) for t in trees]),
+                        jnp.stack([jnp.asarray(t.leaves) for t in trees]),
+                        jnp.stack([jnp.asarray(t.nn) for t in trees]))
             base = jnp.stack([jnp.asarray(t.base) for t in trees])
             f = jnp.where(first, f_first, jnp.where(second, f_second, 0.0))
             vals = f * (shr[:, None] * base + bias[:, None])
@@ -1971,6 +1994,7 @@ class AlignedEngine:
         db = jnp.asarray(lr.meta["default_bin"], jnp.int32)
         mt = jnp.asarray(lr.meta["missing_type"], jnp.int32)
 
+        @phases.scoped("walk.apply")
         def fn(score, lane, vb, tree, shrinkage, bias, applied, factor):
             feat, thr, dl, parent, side = jnp.asarray(tree.nodes)
             leaves = jnp.asarray(tree.leaves)
@@ -2045,6 +2069,7 @@ class AlignedEngine:
     def _bag_select_program(self, cnt):
         from ..ops.goss import bag_multipliers
 
+        @phases.scoped("sample.bag")
         def bag_select(rec, cnts, seed, ext_of_row=None):
             rid, live = self._row_ids(rec, cnts, ext_of_row)
             mult, kept = bag_multipliers(rid, live, seed, cnt)
@@ -2107,6 +2132,7 @@ class AlignedEngine:
         from ..ops.goss import goss_multipliers
         ln = self.lanes
 
+        @phases.scoped("sample.goss")
         def goss_select(rec, cnts, seed, g_rows=None, h_rows=None,
                         ext_of_row=None):
             rid, live = self._row_ids(rec, cnts, ext_of_row)
@@ -2136,6 +2162,7 @@ class AlignedEngine:
         return self.row_lane("bag")
 
     def _set_bag_program(self):
+        @phases.scoped("sample.bag")
         def fn(rec, mask, ext_of_row=None):
             rid = jnp.clip(self._rid_lanes(rec), 0, self.ext_n)
             vals = jnp.concatenate([self._rows_to_ext(mask, ext_of_row),
@@ -2186,6 +2213,9 @@ class AlignedEngine:
         n, C = self.ext_n, self.C
         ax = self.axis
 
+        # out of the records for the drain, a metric or a check in row
+        # order; between two trees for an objective with a layout of its own
+        @phases.scoped("drain.materialise" if rows else "rank.scatter")
         def fn(rec, cnts, ext_of_row=None):
             rid = self._rid_lanes(rec).reshape(-1)
             if lane == "bag" and self.compact:      # the meta lane's sign

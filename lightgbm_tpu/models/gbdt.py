@@ -60,12 +60,9 @@ class LazyTree:
         return tree
 
 
-def _record_aligned_iter(it: int, rounds, norm_passes, table,
-                         sampled=None) -> None:
+def _record_aligned_iter(it: int, rounds, table, sampled=None) -> None:
     """One `aligned.iter` seam record for a resolved aligned iteration:
-    the build program's round count, whether it copied the rows back out
-    of the round loop's second buffer (`norm_passes`: 1 after an odd
-    number of rounds) and its per-round counters
+    the build program's round count and its per-round counters
     (`aligned_builder.ROUND_STATS` order, rows up to `rounds`), as pulled
     with the exactness flags. Data-parallel: shard 0's counters.
     `sampled` = the named counters the boosting variant recorded of the
@@ -79,7 +76,6 @@ def _record_aligned_iter(it: int, rounds, norm_passes, table,
     rounds = int(rounds)
     extra = {k: np.asarray(v).item() for k, v in (sampled or {}).items()}
     obs_trace.seam_record("aligned.iter", iter=int(it), rounds=rounds,
-                          norm_passes=int(norm_passes),
                           columns=list(ROUND_STATS),
                           table=np.asarray(table)[:rounds].tolist(),
                           **extra)
@@ -1258,12 +1254,14 @@ class GBDT:
                             queued=len(q), final=final):
             flags, stats = jax.device_get((
                 q[0][0] if len(q) == 1 else jnp.stack([p[0] for p in q]),
-                [(p[5].rounds, p[5].norm_passes, p[5].round_stats, p[8])
-                 for p in q]))
-        flags = [bool(v) for v in np.atleast_1d(flags)]
-        for p, ok, counters in zip(q, flags, stats):
-            if ok:      # a discarded dispatch is rebuilt, and recorded then
-                _record_aligned_iter(p[6], *counters)
+                [(p[5].rounds, p[5].round_stats, p[8]) for p in q]))
+        # the host's own work on what it pulled: the device idles here
+        # where nothing else is queued (the drain, whose part this is)
+        with obs_trace.part("train.resolve"):
+            flags = [bool(v) for v in np.atleast_1d(flags)]
+            for p, ok, counters in zip(q, flags, stats):
+                if ok:  # a discarded dispatch is rebuilt, and recorded then
+                    _record_aligned_iter(p[6], *counters)
         if all(flags):
             return None
         j = flags.index(False)
@@ -1303,8 +1301,8 @@ class GBDT:
         with obs_trace.seam("train.flag_pull", iter=self.iter, queued=1,
                             final=True):
             exact, *counters = jax.device_get(
-                (exact_dev, spec.rounds, spec.norm_passes,
-                 spec.round_stats, self._aligned_sample_stats))
+                (exact_dev, spec.rounds, spec.round_stats,
+                 self._aligned_sample_stats))
         if not bool(exact):
             self._note_aligned_fallback(eng, "inexact replay")
             self._aligned_forget_from(self.iter)
@@ -1384,12 +1382,15 @@ class GBDT:
             if res is not None:
                 self._aligned_mc_fallback(res)
             if getattr(self, "_train_score_stale", False):
-                if getattr(eng, "num_class", 1) > 1:
-                    self.train_score.score = jnp.asarray(
-                        eng.row_scores_mc())
-                else:
-                    self.train_score.score = jnp.asarray(
-                        eng.row_scores())[None, :]
+                # the enqueue of the materialise program, the wait for
+                # it, and the scores' way to the host and back
+                with obs_trace.part("train.materialise"):
+                    if getattr(eng, "num_class", 1) > 1:
+                        self.train_score.score = jnp.asarray(
+                            eng.row_scores_mc())
+                    else:
+                        self.train_score.score = jnp.asarray(
+                            eng.row_scores())[None, :]
                 self._train_score_stale = False
 
     def _drop_aligned(self) -> None:

@@ -1,5 +1,6 @@
-"""Observability: span tracer and always-on seams, per-round metrics
-ledger, live metrics registry, HBM accountant and request traces.
+"""Observability: span tracer and always-on seams, phases (the device
+programs' own names for their XLA operations), per-round metrics ledger,
+live metrics registry, HBM accountant and request traces.
 
 The fenced tracer is OFF by default and costs nothing when off:
 `trace.span` returns a shared null context, `trace.fence` returns its
@@ -8,6 +9,8 @@ attribute-is-None checks. Enable with the `tpu_trace` / `tpu_trace_dir`
 params (both enter `compile_cache.config_signature`, so toggling tracing
 retraces rather than silently reusing a differently-fenced program).
 """
-from . import ledger, memory, metrics, reqtrace, terms, trace  # noqa: F401
+from . import (hlo, ledger, memory, metrics, phases,  # noqa: F401
+               reqtrace, terms, trace)
 
-__all__ = ["ledger", "memory", "metrics", "reqtrace", "terms", "trace"]
+__all__ = ["hlo", "ledger", "memory", "metrics", "phases", "reqtrace",
+           "terms", "trace"]

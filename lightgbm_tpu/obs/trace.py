@@ -26,8 +26,10 @@ SEAMS (ISSUE 25) are the other half: a handful of host boundaries
 (``seam()``) that record ALWAYS, whatever ``tpu_trace`` says, into one
 bounded ring (``seams()``), and always enter a
 ``jax.profiler.TraceAnnotation`` so a live profiler session sees them
-on the device operations' clock. A seam never fences and never touches
-``_block``: turning nothing on, it changes no program and no pipeline.
+on the device operations' clock. ``part()`` names a stretch INSIDE a
+seam the same way, without a record of its own. A seam never fences and
+never touches ``_block``: turning nothing on, it changes no program and
+no pipeline.
 ``span`` / ``fence`` / ``tpu_trace`` stay the operator's FENCED mode.
 """
 from __future__ import annotations
@@ -282,6 +284,46 @@ def seam(name: str, iter: Optional[int] = None, **attrs) -> _Seam:
     load while no profiler session is live. It never fences: placing one
     changes no program and no pipeline."""
     return _Seam(name, iter, attrs)
+
+
+class _Part:
+    """One open part of the seam this thread is in."""
+    __slots__ = ("name", "t0", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.t0 = 0.0
+        self._ann = None
+
+    def __enter__(self):
+        self._ann = _profiler_annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = getattr(_seam_open, "stack", None)
+        if stack:
+            parts = stack[-1].attrs.setdefault("parts", {})
+            parts[self.name] = parts.get(self.name, 0.0) + dur
+        return False
+
+
+def part(name: str) -> _Part:
+    """Context manager over a named PART of the seam the thread is in:
+    like a seam it enters a ``jax.profiler.TraceAnnotation`` of its name
+    (so a profiler session sees it nested in the seam's event, on the
+    device's clock) and never fences; unlike one it makes no record of
+    its own. Its seconds are summed into the enclosing seam's record
+    under ``parts[name]``, and outside any seam it records nothing. For
+    naming where inside a seam the time goes without adding a name to
+    the ring, whose readers take a window's set of names for what the
+    host did in it."""
+    return _Part(name)
 
 
 def seam_record(name: str, iter: Optional[int] = None, **attrs) -> None:

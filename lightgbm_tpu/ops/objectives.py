@@ -25,6 +25,7 @@ import numpy as np
 from .. import compile_cache
 from ..config import Config
 from ..io.dataset import Metadata
+from ..obs import phases
 
 
 def _sign(x):
@@ -920,6 +921,10 @@ class LambdarankNDCG(ObjectiveFunction):
                     float(self.cfg.sigmoid), lut,
                     interpret=self._fused_interpret, rows=rows))
             self._fused_fn[rows] = fn
+            # jitted outside the engine: its first call says what lowers
+            # it again for a phase table (`obs/phases.py`)
+            return phases.remember_first(
+                "rank_fused_rows" if rows else "rank_fused", fn)
         return fn
 
     def grad_layout(self):

@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import compile_cache
+from ..obs import phases
 from .ranking import dcg_discounts
 
 SUBTILE = 128   # query alignment quantum = one lane register width
@@ -366,6 +367,7 @@ def make_fused_grad_fn(num_tiles: int, tile: int, band: int,
         sigmoid=float(sigmoid), lut_bins=int(lut_bins))
     NT, T = num_tiles, tile
 
+    @phases.scoped("rank.glue")
     def slot_grads(score_t, qid, gain, label, inv, disc_tab, w_t=None):
         sc = jnp.where(qid >= 0, score_t, 0.0).astype(jnp.float32)
         bits = functools.partial(lax.bitcast_convert_type,
@@ -383,6 +385,7 @@ def make_fused_grad_fn(num_tiles: int, tile: int, band: int,
             out_specs=pl.BlockSpec((None, 2, T), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((NT, 2, T), jnp.float32),
             interpret=interpret,
+            name="rank_grad_pass",
         )(packed, disc_tab)
         g_t = jnp.where(qid >= 0, gh[:, 0, :], 0.0)
         h_t = jnp.where(qid >= 0, gh[:, 1, :], 0.0)
@@ -390,17 +393,19 @@ def make_fused_grad_fn(num_tiles: int, tile: int, band: int,
             g_t, h_t = g_t * w_t, h_t * w_t
         return g_t, h_t
 
-    # the trace names a kernel after the jitted function that calls it
     def grad_fn(score_t, qid, gain, label, inv, disc_tab, w_t=None):
         compile_cache.note_trace()
         return slot_grads(score_t, qid, gain, label, inv, disc_tab, w_t)
 
     def grad_rows_fn(score, doc_idx, slot_of_row, *tables):
         compile_cache.note_trace()
-        g_t, h_t = slot_grads(score[doc_idx], *tables)
-        at = jnp.maximum(slot_of_row, 0)
-        return (jnp.where(slot_of_row >= 0, g_t.reshape(-1)[at], 0.0),
-                jnp.where(slot_of_row >= 0, h_t.reshape(-1)[at], 0.0))
+        with phases.scope("rank.scatter"):
+            score_t = score[doc_idx]
+        g_t, h_t = slot_grads(score_t, *tables)
+        with phases.scope("rank.gather"):
+            at = jnp.maximum(slot_of_row, 0)
+            return (jnp.where(slot_of_row >= 0, g_t.reshape(-1)[at], 0.0),
+                    jnp.where(slot_of_row >= 0, h_t.reshape(-1)[at], 0.0))
 
     return jax.jit(grad_rows_fn if rows else grad_fn)
 

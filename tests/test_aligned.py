@@ -77,9 +77,11 @@ def test_aligned_matches_leafwise_binary(chunk):
 def test_rows_end_in_the_first_buffer_after_odd_and_even_round_counts():
     """The round loop ping-pongs between two record buffers, so a tree
     of an odd number of rounds ends in the second one and is copied back
-    once (`norm_passes` 1); after an even number nothing is copied. Every
-    other program reads the engine's one record matrix: after each tree,
-    the scores it holds are the walk over the model so far."""
+    once (phase `build.copy_back` of the build program: a loop of no or
+    one trip on the parity of the rounds); after an even number nothing
+    is copied. Every other program reads the engine's one record matrix:
+    after each tree, the scores it holds are the walk over the model so
+    far."""
     from benchmark import reference
     from lightgbm_tpu.obs import trace as obs_trace
     X, y = _make()
@@ -95,9 +97,12 @@ def test_rows_end_in_the_first_buffer_after_odd_and_even_round_counts():
     a.eval_train()
     recs = obs_trace.seams("aligned.iter")
     assert [r["iter"] for r in recs] == list(range(iters))
-    assert [r["norm_passes"] for r in recs] == [r["rounds"] % 2
-                                                for r in recs]
-    assert {r["norm_passes"] for r in recs} == {0, 1}
+    assert all("norm_passes" not in r for r in recs)    # gone with PR 37
+    assert {r["rounds"] % 2 for r in recs} == {0, 1}    # both were run
+    from lightgbm_tpu.obs import phases
+    back = [r for r in phases.table(only=["build"])
+            if r["phase"] == "build.copy_back"]
+    assert any(r["opcode"] == "while" for r in back)
     assert _same_trees(a, _train(X, y, "leafwise", iters=iters)) == iters
 
 

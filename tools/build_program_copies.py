@@ -8,7 +8,9 @@ path to one. The tool compiles the engine's `build` program (`build_ext`
 where the objective's gradients come from outside the record) at the
 config's own shape and lists, from the optimised HLO, every `copy` of at
 least `--min-mb` megabytes: its shape, the computation it sits in (the
-entry, a while body, a branch) and its operand. A loop-carried array
+entry, a while body, a branch), its operand, the phase the program gives
+it (`lightgbm_tpu/obs/phases.py`) and its source line, where the compiler
+left one. A loop-carried array
 that is not updated in place shows here as a copy inside the while body:
 one per round (PERF.md section 6, PR 30, found the record matrix there,
 14.6 ms a round at 4.5 GiB). It also counts the `move_pass` custom calls
@@ -25,7 +27,6 @@ gives the same HLO without the chip.
 import argparse
 import json
 import os
-import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -35,34 +36,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
-               "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
-               "f64": 8}
-SHAPE = re.compile(r"^([a-z]+[0-9]*)\[([0-9,]*)\]")
-COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
-CALLEES = ("body", "condition", "true_computation", "false_computation",
-           "branch_computations", "calls")
-
-
-def instruction(line: str):
-    """(name, shape, opcode, the rest) of one line of HLO text, or None.
-    A tuple's shape has spaces and comments in it, so no single pattern
-    takes the line apart."""
-    left, eq, right = line.strip().partition(" = ")
-    if not eq:
-        return None
-    name = left.split()[-1].lstrip("%")
-    if right.startswith("("):       # a tuple shape: to its closing bracket
-        depth = 0
-        for at, ch in enumerate(right):
-            depth += (ch == "(") - (ch == ")")
-            if depth == 0:
-                break
-        shape, right = right[:at + 1], right[at + 1:].lstrip()
-    else:
-        shape, _, right = right.partition(" ")
-    opcode, bracket, rest = right.partition("(")
-    return (name, shape, opcode, rest) if bracket else None
+from lightgbm_tpu.obs import hlo, phases  # noqa: E402
 
 
 def engine_for(config: dict, rows_made: int):
@@ -134,57 +108,34 @@ def compile_build(eng):
     return program.lower(*args, **park).compile(), target
 
 
-def nbytes(shape_text: str) -> int:
-    found = SHAPE.match(shape_text)
-    if not found or found.group(1) not in DTYPE_BYTES:
-        return 0
-    dims = [int(d) for d in found.group(2).split(",") if d]
-    return DTYPE_BYTES[found.group(1)] * int(np.prod(dims, dtype=np.int64))
-
-
-def large_copies(hlo: str, min_bytes: int) -> dict:
-    """{"copies": [...], "move_pass_calls": n} from optimised HLO text."""
-    where, instrs = None, []        # (computation, name, shape, op, rest)
-    for line in hlo.splitlines():
-        head = COMPUTATION.match(line)
-        if head:
-            where = head.group(2)
-            continue
-        found = instruction(line)
-        if found and where:
-            instrs.append((where,) + found)
+def large_copies(text: str, min_bytes: int) -> dict:
+    """{"copies": [...], "move_pass_calls": n} from optimised HLO text
+    (`lightgbm_tpu/obs/hlo.py` takes it apart): each copy with the phase
+    the program gives it (`obs/phases.py`) and the source line its
+    `op_name` came from, where the compiler left it one."""
+    instrs = hlo.instructions(text)
     # what each computation is to the one that calls it: a while's body, a
     # conditional's branch, the inside of a fusion. The round loop is the
     # while whose body holds `move_pass`
-    moves = [comp for comp, name, _, op, _ in instrs
-             if op == "custom-call" and name.startswith("move_pass")]
-    role = {}
-    for comp, name, _, op, rest in instrs:
-        for key in CALLEES:
-            for group in re.findall(key + r"=\{?([^,}\s]+(?:, [^,}\s]+)*)",
-                                    rest):
-                for callee in group.replace("%", "").split(", "):
-                    what = f"{key} of {op} {name}"
-                    if key == "body" and callee in moves:
-                        what += " (the round loop)"
-                    role[callee] = (what, comp)
-
-    def place(comp):
-        """The way down to `comp` from the entry computation."""
-        steps = []
-        while comp in role and len(steps) < 16:
-            what, comp = role[comp]
-            steps.append(what)
-        return " in ".join(steps + ["entry"])
-
+    moves = [ins.computation for ins in instrs
+             if ins.opcode == "custom-call"
+             and ins.name.startswith("move_pass")]
+    role = hlo.roles(instrs, loop_of=moves)
+    rows = {r["instruction"]: r for r in phases.rows_of("build", text)}
     copies = []
-    for comp, name, shape, op, rest in instrs:
-        if op == "copy" and nbytes(shape) >= min_bytes:
+    for ins in instrs:
+        if ins.opcode == "copy" and hlo.nbytes(ins.shape) >= min_bytes:
+            row = rows.get(ins.name, {})
             copies.append({
-                "copy": name, "shape": shape.split("{")[0],
-                "mb": round(nbytes(shape) / 1e6, 1), "in": place(comp),
-                "computation": comp,
-                "operand": rest.split(")")[0].split(",")[0].strip()})
+                "copy": ins.name, "shape": ins.shape.split("{")[0],
+                "mb": round(hlo.nbytes(ins.shape) / 1e6, 1),
+                "in": hlo.place(role, ins.computation),
+                "computation": ins.computation,
+                "operand": "%" + (hlo.operands(ins) or ["?"])[0],
+                "phase": row.get("phase"),
+                "source": None if not row.get("source_file") else
+                f"{os.path.relpath(row['source_file'], ROOT)}:"
+                f"{row['source_line']}"})
     return {"copies": copies, "move_pass_calls": len(moves)}
 
 
