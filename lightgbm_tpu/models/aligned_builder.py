@@ -216,7 +216,8 @@ class AlignedEngine:
         # route words (destinations pack 16-bit, capping NC at 65k
         # chunks); move_pass partitions it in sub-tiles of route_tile(C)
         # rows, so the permutation matmul no longer grows with it
-        from ..ops.aligned import ROUTE_SELECTORS, chunk_for, route_tile
+        from ..ops.aligned import (ROUTE_SELECTORS, ROUTE_STAGE, chunk_for,
+                                   route_tile, route_unroll)
         self.C = C = chunk_for(self.cfg, learner.num_features, learner.n)
         # host work over all rows: what the readers of this seam need
         # of the layout rides on it
@@ -229,7 +230,8 @@ class AlignedEngine:
                 C=int(C), NC=int(self.NC), bits=int(self.bits),
                 shards=int(self.nd), count_pass=self.count_pass,
                 route_tile=route_tile(C), route_tiles=C // route_tile(C),
-                route_selectors=ROUTE_SELECTORS,
+                route_selectors=ROUTE_SELECTORS, route_stage=ROUTE_STAGE,
+                route_unroll=route_unroll(C),
                 grad_layout="rows" if ext_of_row is None else "tiles",
                 grad_slots=int(self.ext_n),
                 # who writes the bag: a device program from the index

@@ -128,6 +128,26 @@ ROUTE_TILE = 256
 ROUTE_SELECTORS = 1
 
 
+# How the split path stages a tile's rows into the block's rings:
+# "carried", each side's OPEN window (the one its cursor is in) held in
+# the tile loop's carry, loaded once a grid step and plain-stored once a
+# tile (route_tile_rows); PR 32's form read, merged and wrote back two
+# windows a side a tile. And ROUTE_UNROLL tiles share one trip of the
+# loop, so that one tile's chain of matmuls and stores overlaps the
+# next's (tools/route_tile_sweep.py on the v5e, PERF.md section 6,
+# PR 38: us a full Criteo-67 chunk at 255 bins, unrolled 1 / 2 / 4 / 8:
+# 9.34 / 8.35 / 7.81 / 7.63; PR 32's form 9.64). Both static: they ride
+# the `aligned.pack` seam as `route_stage` and `route_unroll`.
+ROUTE_STAGE = "carried"
+ROUTE_UNROLL = 8
+
+
+def route_unroll(chunk: int) -> int:
+    """Tiles of a `chunk`-row chunk that share one trip of move_pass's
+    route loop: ROUTE_UNROLL, or all of the chunk's where it has fewer."""
+    return min(ROUTE_UNROLL, chunk // route_tile(chunk))
+
+
 def route_tile(chunk: int) -> int:
     """S: the sub-tile of a `chunk`-row chunk that move_pass's split
     path partitions at a time. ROUTE_TILE wherever it divides the chunk,
@@ -773,11 +793,22 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
         U = w_used
         posS = lax.broadcasted_iota(jnp.int32, (1, S), 1)[0]
 
-        def route_tile_rows(t, cur):
+        def window(side, cur_s):
+            """The S-aligned window of the side's 2C staging ring that
+            holds its cursor: staging chunk and lane offset (a dynamic
+            multiple of S >= 128, which Mosaic can slice)."""
+            win = (cur_s % (2 * C)) // S
+            return (side * 2 + win // T, slice(None),
+                    pl.ds(pl.multiple_of((win % T) * S, S), S))
+
+        def route_tile_rows(t, carry):
             """Partition rows [t*S, (t+1)*S) of the chunk into the
-            block's staging rings; cur = (left, right) rows of the block
-            staged so far. The work is S x S whatever C is."""
-            cur_l, cur_r = cur
+            block's staging rings; cur_l, cur_r = rows of the block
+            staged so far on each side, open_l, open_r = each side's
+            OPEN window (the one its cursor is in) as it stands, carried
+            in registers so that no tile loads a window. The work is
+            S x S whatever C is."""
+            cur_l, cur_r, open_l, open_r = carry
             # both buffers' tiles are loaded and one is selected: a branch
             # here, eight times a chunk, cost 0.05 us a chunk
             rows_t = pl.ds(pl.multiple_of(t * S, S), S)
@@ -820,8 +851,9 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
             # than S is injective modulo S, so a side's rows never share
             # an offset modulo S whichever window they fall in, and one
             # [U, S] block holds both windows' rows of the side at their
-            # final offsets: the two masks of the merge below pick each
-            # window's part out of it (disjoint: a + k - S <= a). And
+            # final offsets: the merge below takes window 0's part out of
+            # it, and window 1's part opens the next window (disjoint:
+            # a + k - S <= a). And
             # k_l + k_r <= S, so both sides fit ONE block: left rows at
             # their own offsets, right rows on the cyclic interval that
             # starts where the left one ends (e_l), rotated to their own
@@ -862,27 +894,50 @@ def _move_kernel(r1_ref, r2_ref, blbr_ref, meta_ref,
             # the right rows, from e_l + rank to a_r + rank (modulo S)
             mrows_r = pltpu.roll(mrows_l, (a_r - e_l + S) % S, 1)
 
-            for side, (mrows_side, a, k, cur_s) in enumerate((
-                    (mrows_l, a_l, k_l, cur_l),
-                    (mrows_r, a_r, k_r, cur_r))):
-                for w in range(2):
-                    if w == 0:
-                        m = (posS >= a) & (posS < a + k)
-                    else:
-                        m = posS < a + k - S
-                    # window -> staging chunk and lane offset (a dynamic
-                    # multiple of S >= 128, which Mosaic can slice)
-                    win = ((cur_s % (2 * C)) // S + w) % (2 * T)
-                    dst = (side * 2 + win // T, slice(None),
-                           pl.ds(pl.multiple_of((win % T) * S, S), S))
-                    stag[dst] = jnp.where(m[None, :], mrows_side,
-                                          stag[dst])
-            return cur_l + k_l, cur_r + k_r
+            # the side's rows of the open window merge into the carry,
+            # and the result is stored with no load in front of it and no
+            # branch (a branch in this loop cost more than the merge it
+            # skipped: PERF.md section 6, PR 32). Where the tile filled
+            # the window (a + k >= S) the next one opens with the
+            # window-1 rows already at their offsets in mrows: what
+            # stands past them is rewritten before any flush reads it,
+            # since a flush reads only rows under the cursor
+            opened = []
+            for side, (mrows_side, a, k, cur_s, open_s) in enumerate((
+                    (mrows_l, a_l, k_l, cur_l, open_l),
+                    (mrows_r, a_r, k_r, cur_r, open_r))):
+                m = (posS >= a) & (posS < a + k)
+                merged = jnp.where(m[None, :], mrows_side, open_s)
+                stag[window(side, cur_s)] = merged
+                opened.append(jnp.where(a + k >= S, mrows_side, merged))
+            return (cur_l + k_l, cur_r + k_r) + tuple(opened)
 
-        # tiles past the chunk's last row hold nothing to route
-        new_l, new_r = lax.fori_loop(0, (cntv + S - 1) // S,
-                                     route_tile_rows,
-                                     (cur_ref[0], cur_ref[1]))
+        # each side's open window is loaded once a grid step; tiles past
+        # the chunk's last row hold nothing to route. route_unroll(C)
+        # tiles share one loop body, where tile t + 1's decode and rank
+        # matmul can overlap tile t's one-hot, route matmul and stores;
+        # the tiles left over run one a trip, so no tile past the chunk's
+        # rows is routed. The route alone is unrolled: the histogram
+        # below keeps its one call site
+        n_tiles = (cntv + S - 1) // S
+        unroll = route_unroll(C)
+
+        def route_group(g, carry):
+            for j in range(unroll):
+                carry = route_tile_rows(g * unroll + j, carry)
+            return carry
+
+        carry = (cur_ref[0], cur_ref[1], stag[window(0, cur_ref[0])],
+                 stag[window(1, cur_ref[1])])
+        groups = n_tiles // unroll
+        carry = lax.fori_loop(0, groups, route_group, carry)
+        if unroll > 1:
+            carry = lax.fori_loop(groups * unroll, n_tiles, route_tile_rows,
+                                  carry)
+        new_l, new_r, open_l, open_r = carry
+        # and stored once, for the flush below and the block's next step
+        stag[window(0, new_l)] = open_l
+        stag[window(1, new_r)] = open_r
         cur_ref[0] = jnp.where(is_last != 0, 0, new_l)
         cur_ref[1] = jnp.where(is_last != 0, 0, new_r)
 

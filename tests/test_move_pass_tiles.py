@@ -14,14 +14,16 @@ where the window masks meet and where the rotation wraps, and the `ext`
 layout routes a record whose byte planes (4 x 59 lanes) are no multiple
 of 8 sublanes.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lightgbm_tpu.ops import aligned
 from lightgbm_tpu.ops.aligned import (META_LABEL, META_LABEL_MASK, R_COPY,
                                       R_SHIFT, ROUTE_TILE, _bpw_for_bits,
                                       lane_layout, move_pass, pack_records,
-                                      pack_route2, route_tile)
+                                      pack_route2, park_pass, route_tile)
 
 F = 6            # features the fused histogram covers
 NC = 20          # one grid for every case of a shape: one compile
@@ -85,15 +87,43 @@ def _edge_scenario(C, heavy):
     side's (under `heavy` = left: right rows only, a_r < a_l + k_l, so
     the rotation that brings the right rows home turns backwards)."""
     S = route_tile(C)
-    tiles = [(S, 5), (S, S), (S, S - 5), (S, S - 10), (S - 30, 0),
-             (S, S // 2), (S, 0)]
-    cnts, masks = [], []
-    for n, k in tiles:
-        if not cnts or cnts[-1] == C or cnts[-1] % S:
+    return _block_of_tiles(C, heavy, [
+        (S, 5, False), (S, S, False), (S, S - 5, False), (S, S - 10, False),
+        (S - 30, 0, False), (S, S // 2, False), (S, 0, False)])
+
+
+def _carry_scenario(C, heavy):
+    """One block whose CHUNK ends fall where a side's open window, which
+    the tile loop carries in registers and stores once a tile, has to
+    cross a grid step. With h and l the heavy and the light side's
+    cursors modulo S: chunk 1 ends mid-window on both sides (h = 37,
+    l = S - 87) and chunk 2 continues those windows; chunk 2's last tile
+    fills the heavy window exactly (a + k == S); the last tile of chunk
+    3 sends S rows to the heavy side from a = 20 (k == S, a > 0), so 20
+    rows of the next window cross the step in the carry alone; and the
+    block's last chunk ends with window-1 rows of the heavy side held
+    only in the carry (a = S - 8, k = S - 60). At four tiles a chunk
+    one chunk holds four tiles, which take the unrolled loop's body."""
+    S = route_tile(C)
+    return _block_of_tiles(C, heavy, [
+        (S - 50, 37, True), (S, S - 37, True), (S, 20, False),
+        (S, S, True), (S, S - 10, False), (S, S // 2 + 3, False),
+        (S, 7, False), (S - 30, 100, True), (S, S - 60, True)])
+
+
+def _block_of_tiles(C, heavy, tiles):
+    """A split block of `tiles`, each (rows, rows to the `heavy` side,
+    whether its chunk ends behind it), and a dead chunk after it. A
+    chunk also ends where it is full or its last tile is short."""
+    S = route_tile(C)
+    cnts, masks, closed = [], [], True
+    for n, k, end in tiles:
+        if closed:
             cnts.append(0)
             masks.append([])
         cnts[-1] += n
         masks[-1].append(_exact(n, k) == (heavy == "left"))
+        closed = end or cnts[-1] == C or cnts[-1] % S
     return [("split", cnts, [np.concatenate(m) for m in masks], 0),
             ("dead", 1)]
 
@@ -320,6 +350,107 @@ def test_move_pass_where_the_window_masks_meet(C, layout, heavy):
     free = sorted(set(range(NC)) - set(expect))
     np.testing.assert_array_equal(bufs[1][free], held[free])
     np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
+
+
+def _check_block(sc, C, rec_in, rt, expect, hist_ref, b_pad):
+    """One pass out of buffer 0 against the numpy partition: every row
+    where it belongs, bit for bit; what the layout does not cover as it
+    was; the histogram."""
+    held = np.full_like(rec_in, 0x5A5A5A5A)
+    bufs, hist = _call(sc, [rec_in, held], 0, rt, b_pad)
+    _check_rows(sc, bufs[1], expect)
+    free = sorted(set(range(NC)) - set(expect))
+    np.testing.assert_array_equal(bufs[1][free], held[free])
+    np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
+
+
+CARRY_CASES = [(C, layout) for C in (ROUTE_TILE, 2 * ROUTE_TILE,
+                                     4 * ROUTE_TILE) for layout in LAYOUTS]
+
+
+@pytest.mark.parametrize("heavy", ("left", "right"))
+@pytest.mark.parametrize("C,layout", CARRY_CASES)
+def test_the_open_window_crosses_grid_steps_in_the_carry(C, layout, heavy):
+    """Each side's open staging window rides the tile loop's carry and
+    is stored once a tile and once behind the loop (the kernel's
+    `route_stage`, "carried"): `_carry_scenario` ends chunks mid-window,
+    on a window filled exactly, with window-1 rows in the carry alone,
+    and the block with them."""
+    assert aligned.ROUTE_STAGE == "carried"
+    sc = _build(C, layout, heavy, seed=38, scenario=_carry_scenario)
+    rec_in, rt, expect, hist_ref, b_pad = _reference(sc, C)
+    S = route_tile(C)
+    # the block's 9 tiles: 4 S + 50 rows to the heavy side
+    assert sum(len(r) for r in expect.values()) == 9 * S - 80
+    _check_block(sc, C, rec_in, rt, expect, hist_ref, b_pad)
+
+
+@pytest.fixture
+def unrolled(request):
+    """ROUTE_UNROLL set to the test's parameter, traced afresh (it is
+    read at trace time, and a jit cache keeps what was traced)."""
+    keep = aligned.ROUTE_UNROLL
+    aligned.ROUTE_UNROLL = request.param
+    jax.clear_caches()
+    yield request.param
+    aligned.ROUTE_UNROLL = keep
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("unrolled", (2, 3), indirect=True)
+@pytest.mark.parametrize("scenario", (_scenario, _carry_scenario))
+def test_tiles_left_over_by_the_unrolled_loop(unrolled, scenario):
+    """A chunk whose tiles are no multiple of the unroll runs its last
+    ones one a trip, behind the groups, with the windows carried across
+    from one loop into the other (at four tiles a chunk: 4 = 2 + 2 or
+    3 + 1, 3 = 2 + 1 or 3 + 0)."""
+    C = 4 * ROUTE_TILE
+    sc = _build(C, "std8", "left", seed=unrolled, scenario=scenario)
+    _check_block(sc, C, *_reference(sc, C))
+
+
+@pytest.mark.parametrize("heavy", ("left", "right"))
+@pytest.mark.parametrize("layout", ("lane", "bit"))
+@pytest.mark.parametrize("C", (ROUTE_TILE, 2 * ROUTE_TILE, 4 * ROUTE_TILE))
+def test_park_pass_carries_the_open_window(C, layout, heavy):
+    """The same edges through `park_pass` (one block over the whole
+    buffer, routed by the bag: in-bag rows from chunk 0 on, the others
+    parked at the buffer's end), against numpy's stable partition by
+    the bag; the bag is the `heavy` side's mask of `_carry_scenario`."""
+    wcnt = 3
+    lanes, W = lane_layout(wcnt, with_bag=True, compact=layout == "bit")
+    w_used = max(lanes.values()) + 1
+    (_, cnts, masks, _), _ = _carry_scenario(C, heavy)
+    rng = np.random.default_rng(C + len(layout))
+    rec = rng.integers(0, 1 << 31, (NC, W, C), dtype=np.int64) \
+        .astype(np.int32)
+    cnts = np.pad(np.asarray(cnts, np.int32), (0, NC - len(cnts)))
+    bag = np.zeros((NC, C), bool)
+    for c, m in enumerate(masks):
+        bag[c, :len(m)] = m
+    if layout == "bit":
+        meta = rec[:, lanes["meta"], :] & 0x7FFFFFFF
+        rec[:, lanes["meta"], :] = np.where(bag, meta | -(1 << 31), meta)
+        bag_lane = -2
+    else:
+        rec[:, lanes["bag"], :] = bag.astype(np.float32).view(np.int32)
+        bag_lane = lanes["bag"]
+    live = np.arange(C)[None, :] < cnts[:, None]
+    rows = rec.transpose(0, 2, 1)[live][:, :w_used]       # as they lie
+    inb = bag[live]
+    kept = int(inb.sum())
+    a, b, new, park_begin = jax.jit(
+        lambda a, b: park_pass(
+            a, b, 0, jnp.asarray(cnts), jnp.int32(kept), C, W, wcnt,
+            bag_lane, bits=8, w_used=w_used, interpret=True))(
+        rec, np.full_like(rec, 7))
+    np.testing.assert_array_equal(np.asarray(a), rec)
+    new, pb = np.asarray(new), int(park_begin)
+    got = np.asarray(b).transpose(0, 2, 1)[:, :, :w_used]
+    lay = np.arange(C)[None, :] < new[:, None]
+    assert new[:pb].sum() == kept and new[pb:].sum() == len(rows) - kept
+    np.testing.assert_array_equal(got[:pb][lay[:pb]], rows[inb])
+    np.testing.assert_array_equal(got[pb:][lay[pb:]], rows[~inb])
 
 
 @pytest.mark.parametrize("src", (0, 1))

@@ -28,7 +28,8 @@ from lightgbm_tpu.models import aligned_builder
 from lightgbm_tpu.models.aligned_builder import ROUND_STATS
 from lightgbm_tpu.models.level_builder import SI_LC, SI_RC
 from lightgbm_tpu.obs import trace as obs_trace
-from lightgbm_tpu.ops.aligned import ROUTE_SELECTORS, route_tile
+from lightgbm_tpu.ops.aligned import (ROUTE_SELECTORS, ROUTE_STAGE,
+                                      route_tile, route_unroll)
 
 ALIGNED = {"tpu_grow_mode": "aligned", "tpu_aligned_interpret": True,
            "tpu_chunk": 256}
@@ -301,6 +302,11 @@ def test_pack_seam_carries_the_layout(run16):
     assert pack["route_tile"] == route_tile(eng.C)
     # and what its route matmul selects: one block a tile
     assert pack["route_selectors"] == ROUTE_SELECTORS == 1
+    # how it stages a tile's rows: each side's open window in the tile
+    # loop's carry, every width alike, and the tiles a trip of that loop
+    assert pack["route_stage"] == ROUTE_STAGE == "carried"
+    assert pack["route_unroll"] == route_unroll(eng.C) \
+        == min(8, pack["route_tiles"])
     assert pack["t1"] <= up["t0"]
 
 

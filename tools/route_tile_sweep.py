@@ -2,7 +2,7 @@
 """Which ROUTE_TILE? One `move_pass` call with every chunk on the split
 path (the root round's shape), timed on the chip for each candidate tile.
 
-python tools/route_tile_sweep.py [shape ...] [tile ...]
+python tools/route_tile_sweep.py [shape ...] [tile ...] [unroll=N ...]
 
 Shapes: criteo255 / criteo63 (the benchmark cells' records, W=24,
 C=2048, fused histogram of the smaller child), istella255 (the ranking
@@ -13,8 +13,10 @@ bagged cells' record through the same split with its in-bag histogram,
 and criteo255park, the partition that parks the rows a bag leaves out
 (`park_pass`: `move_pass` routing by the bag, 30% of the rows in it, no
 histogram compiled in), so the partition round's us a chunk is read
-alone. A tile equal to the chunk is the untiled kernel. Prints one JSON
-line per (shape, tile): ms a call and us a live chunk.
+alone. A tile equal to the chunk is the untiled kernel; `unroll=N` sets
+ROUTE_UNROLL, the tiles that share one trip of the route's loop (default:
+the module's). Prints one JSON line per (shape, tile, unroll): ms a call
+and us a live chunk, and the staging form the kernel ran (`route_stage`).
 """
 import json
 import os
@@ -44,9 +46,10 @@ IN_BAG = 0.3
 K = 256
 
 
-def one(shape, tile, reps=3):
+def one(shape, tile, unroll, reps=3):
     W, C, wcnt, w_used, F, b_pad, bits, spill, hist, gh_off = SHAPES[shape]
     aligned.ROUTE_TILE = tile
+    aligned.ROUTE_UNROLL = unroll
     jax.clear_caches()
     rec = jax.random.bits(jax.random.PRNGKey(tile), (NC, W, C),
                           jnp.uint32).astype(jnp.int32)
@@ -110,7 +113,10 @@ def one(shape, tile, reps=3):
     best = min(walls)
     print(json.dumps({
         "shape": shape, "C": C, "tile": aligned.route_tile(C),
-        "route_selectors": aligned.ROUTE_SELECTORS, "hist": hist,
+        "route_selectors": aligned.ROUTE_SELECTORS,
+        "route_stage": aligned.ROUTE_STAGE,
+        "route_unroll": aligned.route_unroll(C),
+        "hist": hist,
         "ms_per_call": round(best * 1e3, 2),
         "us_per_chunk": round(best * 1e6 / LIVE, 2),
         "first_call_s": round(first, 1),
@@ -121,10 +127,13 @@ if __name__ == "__main__":
     shapes = [a for a in sys.argv[1:] if a in SHAPES] or list(SHAPES)
     tiles = [int(a) for a in sys.argv[1:] if a.isdigit()] \
         or [256, 512, 1024, 2048]
+    unrolls = [int(a[7:]) for a in sys.argv[1:] if a.startswith("unroll=")] \
+        or [aligned.ROUTE_UNROLL]
     if jax.default_backend() != "tpu":
         sys.exit("route_tile_sweep: no TPU; a CPU time is not a "
                  "device metric")
-    for shape in shapes:
-        for tile in tiles:
-            if tile <= SHAPES[shape][1]:
-                one(shape, tile)
+    for unroll in unrolls:
+        for shape in shapes:
+            for tile in tiles:
+                if tile <= SHAPES[shape][1]:
+                    one(shape, tile, unroll)
