@@ -303,19 +303,31 @@ class AlignedEngine:
         self._park_stale = False
         self.park_counters = {}     # of the last build, device scalars
 
+    def record_walk_why(self) -> Optional[str]:
+        """Why the record walk (`walk_pass`) cannot follow this engine's
+        trees, or None where it can. By what the engine is, no option:
+        ONE score lane on ONE chip (`walk_block` asserts both), unbundled
+        bins, numerical splits and tables that fit VMEM
+        (`DART._aligned_variant_gate` names the same facts)."""
+        lr = self.learner
+        if self.num_class > 1:
+            return "K trees an iteration: the walk follows one score lane"
+        if self.axis is not None:
+            return "a mesh: the walk is not sharded"
+        if lr.bundled:
+            return "bundled features: the walk reads unbundled bins"
+        if np.any(np.asarray(lr.meta["bin_type"]) != 0):
+            return "categorical splits: the walk takes numerical splits"
+        if self.cfg.num_leaves > 1024:
+            return "over 1,024 leaves: the walk's tables are sized for VMEM"
+        return None
+
     @property
     def parks(self) -> bool:
-        """Whether the build program parks the rows a bag leaves out. By
-        what the engine is, no option: a bag, ONE score lane on ONE chip
-        (`walk_trees` asserts the same two), and trees the record walk
-        can follow (`DART._aligned_variant_gate` names the same three:
-        unbundled bins, numerical splits, tables that fit VMEM). Every
-        other bagged engine moves all of its rows, as before PR 36."""
-        lr = self.learner
-        return bool(self.bagged and self.num_class == 1
-                    and self.axis is None and not lr.bundled
-                    and not np.any(np.asarray(lr.meta["bin_type"]) != 0)
-                    and self.cfg.num_leaves <= 1024)
+        """Whether the build program parks the rows a bag leaves out: a
+        bag, and trees the record walk can follow. Every other bagged
+        engine moves all of its rows through every round."""
+        return bool(self.bagged and self.record_walk_why() is None)
 
     @property
     def count_pass(self) -> bool:
@@ -493,6 +505,54 @@ class AlignedEngine:
             cnts_all = np.concatenate(shard_cnts)
         return rec_all, cnts_all, ext_of_row
 
+    def pack_rows(self, bins, scores):
+        """Other rows, a validation set's, packed as the engine packs its
+        own (`pack_records`, the same chunk, lanes and bin words) into a
+        block of records `[ceil(n / C), W, C]` whose score lane holds
+        `scores` (f32, row order), and its per-chunk counts: numpy, ready
+        to upload. The rows never move, so record order is row order.
+        Lanes the walk does not read hold what `pack_records` puts
+        there."""
+        lr = self.learner
+        bins = np.asarray(bins)
+        if (not lr.bundled
+                and lr.num_features != lr.num_real_features):
+            bins = np.pad(bins, ((0, 0),
+                                 (0, lr.num_features - lr.num_real_features)))
+        n = bins.shape[0]
+        rec, wcnt, w, cnts, bits = pack_records(
+            bins, np.zeros(n, np.float32), None, self.C,
+            with_bag=self.bagged, compact=self.compact,
+            max_bin=lr.max_bin_global, ext=self.ext)
+        assert (wcnt, w, bits) == (self.wcnt, self.W, self.bits)
+        sc = np.zeros(rec.shape[0] * self.C, np.float32)
+        sc[:n] = np.asarray(scores, np.float32).reshape(-1)
+        rec[:, self.lanes["score"], :] = sc.reshape(-1, self.C).view(np.int32)
+        return rec, cnts
+
+    def block_scores(self, rec, n: int):
+        """The score lane of a packed block in row order, `[1, n]`, as a
+        device array: a view the metrics read (phase `valid.metric`)."""
+        lane = self.lanes["score"]
+
+        @phases.scoped("valid.metric")
+        def view(rec):
+            return _f32(rec[:, lane, :]).reshape(1, -1)[:, :n]
+        return self._program(("valid_view", rec.shape, n), lambda: view)(rec)
+
+    def block_set_scores(self, rec, scores):
+        """A packed block with its score lane set to row-order `scores`
+        (what `_ScoreUpdater`'s updates of `score` write back)."""
+        lane = self.lanes["score"]
+        nc, _, c = rec.shape
+
+        def put(rec, scores):
+            flat = jnp.pad(scores.reshape(-1), (0, nc * c - scores.size))
+            return rec.at[:, lane, :].set(_i32(flat.reshape(nc, c)))
+        return self._program(("valid_set", rec.shape), lambda: put,
+                             donate=(0,))(rec, jnp.asarray(scores,
+                                                           jnp.float32))
+
     # ------------------------------------------------------------------
     def _ext_args(self):
         """`ext_of_row` as the trailing operand of a program that maps
@@ -513,10 +573,19 @@ class AlignedEngine:
             specs=self._specs("mat") if self.axis else None)
         return fn(self.rec, self.cnts, *(self._ext_args() if rows else ()))
 
-    def row_scores_dev(self):
+    def row_scores_dev(self, ahead=None):
         """Training scores in ROW order as a DEVICE array (metrics, the
-        drain, a fallback)."""
-        return self._materialized()
+        drain, a fallback). `ahead` = (spec, applied, scale) of a round
+        dispatched ahead of its turn: the scores without its update,
+        which the lane keeps for the round's turn (one score lane, row
+        ids in the index lane, one chip)."""
+        if ahead is None:
+            return self._materialized()
+        assert self.ext_of_row is None and self.axis is None
+        spec, applied, scale = ahead
+        fn = self._program("mat_ahead", self._materialize_ahead_program)
+        return fn(self.rec, self.cnts, spec.leafI, spec.cover, spec.n_exec,
+                  applied, jnp.float32(scale))
 
     def ext_scores_dev(self):
         """Training scores in EXTERNAL order as a DEVICE array: what an
@@ -692,7 +761,7 @@ class AlignedEngine:
         counted = self.count_pass
         parks = self.parks
         if parks:
-            walk_tree = self._walk_tree_program()
+            walk_tree = self._walk_tree_program("walk.tables")
             _, _, walk_w8, walk_fp = self._walk_dims()
 
         def _gsum(x):
@@ -1741,7 +1810,7 @@ class AlignedEngine:
             boff = lr._boff_dev
             bpk = lr._bpk_dev
 
-        @phases.scoped("walk.apply")
+        @phases.scoped("valid.walk")
         def fn(score, lane, vb, execI, execB, first_c, nxt_c, cover,
                scale, applied):
             nv = vb.shape[0]
@@ -1810,19 +1879,24 @@ class AlignedEngine:
     def _undo_program(self, class_k: int = 0, sign: float = -1.0):
         """Subtract (sign=-1, the undo) or add (sign=+1, the multiclass
         deferred apply) a spec's gated valmap to class_k's score lane."""
-        C, NC, S = self.C, self.NC, self.S
         lane = self.lanes["score"] + class_k
 
         def fn(rec, leafI, cover, n_exec, applied, scale):
-            begin = leafI[:, LI_BEGIN]
-            count = leafI[:, LI_COUNT]
-            slot_of, in_range = slot_in_any_map(begin, count, NC, C)
-            exists = jnp.arange(leafI.shape[0]) <= n_exec
-            in_any = in_range & exists[slot_of]
-            valmap = jnp.where(in_any & applied, cover[slot_of], 0.0)
+            valmap = self._valmap(leafI, cover, n_exec, applied)
             sc = _f32(rec[:, lane, :]) + valmap[:, None] * (sign * scale)
             return rec.at[:, lane, :].set(_i32(sc))
-        return fn
+        # the undo is a drain's; the multiclass apply the next build's
+        return phases.scoped("drain.undo")(fn) if sign < 0 else fn
+
+    def _valmap(self, leafI, cover, n_exec, applied):
+        """[NC]: what a spec's tree added to each row of a chunk where
+        `applied` holds (0 in a chunk of no leaf), from its final leaf
+        tables: the build program's score-lane update, made again."""
+        slot_of, in_range = slot_in_any_map(
+            leafI[:, LI_BEGIN], leafI[:, LI_COUNT], self.NC, self.C)
+        exists = jnp.arange(leafI.shape[0]) <= n_exec
+        return jnp.where(in_range & exists[slot_of] & applied,
+                         cover[slot_of], 0.0)
 
     # ---- the walk of committed trees over the records as they lie
     # (`ops.aligned.walk_pass`): what lets a boosting variant take an
@@ -1831,18 +1905,22 @@ class AlignedEngine:
         from ..ops.aligned import walk_dims
         return walk_dims(self.cfg.num_leaves, self.wcnt, self.bits)
 
-    def walk_tree_of_spec(self, spec) -> WalkTree:
+    def walk_tree_of_spec(self, spec, phase: str = "walk.tables") -> WalkTree:
         """The committed tree of a device spec in the walk's compact
-        form, made on the device: nothing is pulled."""
-        fn = self._program("walk_tree", self._walk_tree_program)
+        form, made on the device: nothing is pulled. `phase`: whose work
+        it is (`valid.walk` where only a validation set's walk needs
+        it)."""
+        fn = self._program("walk_tree" if phase == "walk.tables"
+                           else ("walk_tree", phase),
+                           lambda: self._walk_tree_program(phase))
         return fn(spec.execI, spec.first_c, spec.nxt_c, spec.cover)
 
-    def _walk_tree_program(self):
+    def _walk_tree_program(self, phase: str):
         S = self.S
         ne = S                  # exec ids 0 .. S - 1; S is "no exec"
         np_, lp, _, _ = self._walk_dims()
 
-        @phases.scoped("walk.tables")
+        @phases.scoped(phase)
         def fn(execI, first_c, nxt_c, cover):
             eidx = jnp.arange(ne, dtype=jnp.int32)
             # a committed exec is one a committed chain points at
@@ -1928,30 +2006,45 @@ class AlignedEngine:
         nothing is pulled. WALK_TREES trees a `walk_pass`; returns the
         passes made. An empty list still makes one (of no tree): the
         warm-up's."""
+        self.rec, passes = self.walk_block(
+            self.rec, self.cnts, trees, first, f_first, second, f_second,
+            lo=self.park_begin if parked_only else np.int32(0))
+        self._score_cache = None
+        return passes
+
+    def walk_block(self, rec, cnts, trees, first, f_first, second=None,
+                   f_second: float = 0.0, lo=np.int32(0),
+                   phase: str = "walk.apply"):
+        """`walk_trees` over any block of records laid out as the
+        engine's (its own, or a validation set's from `pack_rows`), from
+        chunk `lo` on: returns (the block, passes made). The block is
+        donated. `phase` names whose walk it is: the training rows'
+        (`walk.apply`, tables under `walk.tables`) or a validation set's
+        (`valid.walk`, tables and kernel alike)."""
         from ..ops.aligned import WALK_TREES
         assert self.axis is None and self.num_class == 1
-        fn = self._program("walk_rec", self._walk_rec_program, donate=(0,))
+        key = "walk_rec" if phase == "walk.apply" \
+            else ("walk_rec", phase, rec.shape)
+        fn = self._program(key, lambda: self._walk_rec_program(phase),
+                           donate=(0,))
         second = first if second is None else second
         null = None
         passes = 0
-        for lo in range(0, max(len(trees), 1), WALK_TREES):
-            part = list(trees[lo:lo + WALK_TREES])
+        for at in range(0, max(len(trees), 1), WALK_TREES):
+            part = list(trees[at:at + WALK_TREES])
             if len(part) < WALK_TREES and null is None:
                 null = (self._null_walk_tree(), 0.0, 0.0)
             full = part + [null] * (WALK_TREES - len(part))
-            self.rec = fn(
-                self.rec, self.cnts,
-                self.park_begin if parked_only else np.int32(0),
-                np.int32(len(part)), first,
+            rec = fn(
+                rec, cnts, lo, np.int32(len(part)), first,
                 np.float32(f_first), second, np.float32(f_second),
                 np.asarray([t[1] for t in full], np.float32),
                 np.asarray([t[2] for t in full], np.float32),
                 tuple(t[0] for t in full))
             passes += 1
-        self._score_cache = None
-        return passes
+        return rec, passes
 
-    def _walk_rec_program(self):
+    def _walk_rec_program(self, phase: str):
         from ..ops.aligned import walk_expand, walk_pass
         lr = self.learner
         nb = jnp.asarray(lr.meta["num_bin"], jnp.int32)
@@ -1960,13 +2053,14 @@ class AlignedEngine:
         _, _, w8, fp = self._walk_dims()
         C, wcnt, bits = self.C, self.wcnt, self.bits
         lane, interpret = self.lanes["score"], self.interpret
+        tables = "walk.tables" if phase == "walk.apply" else phase
 
-        @phases.scoped("walk.apply")
+        @phases.scoped(phase)
         def fn(rec, cnts, lo, ntrees, first, f_first, second, f_second,
                shr, bias, trees):
             # chunks below `lo` are none of this walk's
             cnts = jnp.where(jnp.arange(cnts.shape[0]) >= lo, cnts, 0)
-            with phases.scope("walk.tables"):
+            with phases.scope(tables):
                 tabs = jax.vmap(lambda n, l, k: walk_expand(
                     n, l, k, nb, db, mt, w8=w8, bits=bits, fp=fp))(
                         jnp.stack([jnp.asarray(t.nodes) for t in trees]),
@@ -1996,7 +2090,7 @@ class AlignedEngine:
         db = jnp.asarray(lr.meta["default_bin"], jnp.int32)
         mt = jnp.asarray(lr.meta["missing_type"], jnp.int32)
 
-        @phases.scoped("walk.apply")
+        @phases.scoped("valid.walk")
         def fn(score, lane, vb, tree, shrinkage, bias, applied, factor):
             feat, thr, dl, parent, side = jnp.asarray(tree.nodes)
             leaves = jnp.asarray(tree.leaves)
@@ -2212,23 +2306,17 @@ class AlignedEngine:
 
     def _materialize_program(self, lane: str = "score", rows: bool = True):
         ln = self.lanes
-        n, C = self.ext_n, self.C
         ax = self.axis
 
         # out of the records for the drain, a metric or a check in row
         # order; between two trees for an objective with a layout of its own
         @phases.scoped("drain.materialise" if rows else "rank.scatter")
         def fn(rec, cnts, ext_of_row=None):
-            rid = self._rid_lanes(rec).reshape(-1)
             if lane == "bag" and self.compact:      # the meta lane's sign
-                sc = (rec[:, ln["meta"], :] < 0).astype(
-                    jnp.float32).reshape(-1)
+                sc = (rec[:, ln["meta"], :] < 0).astype(jnp.float32)
             else:
-                sc = _f32(rec[:, ln[lane], :]).reshape(-1)
-            pos = jnp.arange(C, dtype=jnp.int32)
-            valid = (pos[None, :] < cnts[:, None]).reshape(-1)
-            rid = jnp.where(valid & (rid < n), rid, n)
-            out = jnp.zeros(n + 1, jnp.float32).at[rid].set(sc)[:n]
+                sc = _f32(rec[:, ln[lane], :])
+            out = self._to_ext(rec, cnts, sc)
             if ax is not None:
                 # each shard scatters only its own rows; the psum
                 # assembles the full row-order vector on every shard
@@ -2237,3 +2325,24 @@ class AlignedEngine:
                 return out.reshape(self.ext_shape)
             return out if ext_of_row is None else out[ext_of_row]
         return fn
+
+    def _materialize_ahead_program(self):
+        lane = self.lanes["score"]
+
+        @phases.scoped("drain.materialise")
+        def fn(rec, cnts, leafI, cover, n_exec, applied, scale):
+            valmap = self._valmap(leafI, cover, n_exec, applied)
+            return self._to_ext(rec, cnts, _f32(rec[:, lane, :])
+                                - valmap[:, None] * scale)
+        return fn
+
+    def _to_ext(self, rec, cnts, vals):
+        """`vals` [NC, C], one a record cell, in external order [ext_n] by
+        the index lane; cells of no row are dropped."""
+        n = self.ext_n
+        rid = self._rid_lanes(rec).reshape(-1)
+        pos = jnp.arange(self.C, dtype=jnp.int32)
+        valid = (pos[None, :] < cnts[:, None]).reshape(-1)
+        rid = jnp.where(valid & (rid < n), rid, n)
+        return jnp.zeros(n + 1, jnp.float32).at[rid].set(
+            vals.reshape(-1))[:n]

@@ -485,15 +485,16 @@ class DART(GBDT):
             eng.walk_trees(self._dart_out, out[3], sample.keep, prev_ok, 1.0)
         self._commit_sample(sample)
 
-    def _aligned_valid_sample(self, eng, sample, score, vbins, applied):
+    def _aligned_valid_walks(self, eng, sample) -> list:
         # the valid set held the dropped trees whole, at the weight they
-        # had before `_commit_sample` cut it to `keep` of that
-        for i in sample.dropped:
-            w = self._walked(eng, i)
-            if w is not None:
-                score = eng.walk_rows(score, 0, vbins, *w, applied,
-                                      1.0 - 1.0 / sample.keep)
-        return score
+        # had before `_commit_sample` cut it to `keep` of that: each goes
+        # in again at 1 - 1 / keep of the weight it has now
+        walks = [w for w in (self._walked(eng, i) for i in sample.dropped)
+                 if w is not None]
+        if not walks:
+            return []
+        f = 1.0 - 1.0 / sample.keep
+        return [(t, shrinkage * f, bias * f) for t, shrinkage, bias in walks]
 
     def _aligned_forget_from(self, first_iter: int) -> None:
         while self._dart_undo and self._dart_undo[-1][0] >= first_iter:
@@ -504,6 +505,10 @@ class DART(GBDT):
                     self.tree_weight[i] = weight
                 if i < len(self.models):
                     self._unscale_tree(i, old)
+
+    def _keeps_ahead(self, eng) -> bool:
+        # the round ahead also took its dropped trees out of the lane
+        return False
 
     def _discard_eager(self) -> None:
         stash = getattr(self, "_aligned_next", None)

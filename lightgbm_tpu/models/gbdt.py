@@ -143,6 +143,59 @@ class _ScoreUpdater:
     def numpy(self) -> np.ndarray:
         return np.asarray(self.score, np.float64)
 
+    @property
+    def nbytes(self) -> int:
+        return int(self.score.nbytes)
+
+
+class _RecordScores(_ScoreUpdater):
+    """A validation set's scores as the score lane of its rows packed
+    into records once (`AlignedEngine.pack_rows`), where `walk_pass` adds
+    each committed tree (`walk`). The rows never move, so record order is
+    row order and `score` is a view of the lane, `[1, n]`, on the device;
+    what sets `score` (the updater's own updates, a resume) writes the
+    lane back."""
+
+    def __init__(self, eng, bins, su: _ScoreUpdater) -> None:
+        self.eng = eng
+        self.num_data = su.num_data
+        self.num_class = 1
+        self.has_init_score = su.has_init_score
+        rec, cnts = eng.pack_rows(bins, np.asarray(su.score))
+        self.rec, self.cnts = jnp.asarray(rec), jnp.asarray(cnts)
+
+    @property
+    def score(self):
+        return self.eng.block_scores(self.rec, self.num_data)
+
+    @score.setter
+    def score(self, value) -> None:
+        self.rec = self.eng.block_set_scores(self.rec, value)
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.rec.nbytes) + int(self.cnts.nbytes)
+
+    def walk(self, trees, applied) -> int:
+        """Scores += the sum over `trees` = [(WalkTree, shrinkage, bias)]
+        where the device flag `applied` holds; returns the passes."""
+        self.rec, passes = self.eng.walk_block(
+            self.rec, self.cnts, trees, applied, 1.0, phase="valid.walk")
+        return passes
+
+    def add_tree(self, tree: Tree, factor: float = 1.0) -> None:
+        """Scores += factor x a host tree's leaf values (a fallback's)."""
+        self.walk([(self.eng.walk_tree_of_host(tree), factor, 0.0)],
+                  jnp.asarray(True))
+
+    def unpacked(self) -> _ScoreUpdater:
+        """The scores as a row-order `_ScoreUpdater`, for a path off the
+        engine."""
+        su = _ScoreUpdater(self.num_data, 1, None)
+        su.has_init_score = self.has_init_score
+        su.score = self.score
+        return su
+
 
 class GBDT:
     """reference `GBDT` (gbdt.h:41+)."""
@@ -278,8 +331,8 @@ class GBDT:
         from ..obs import memory as obs_memory
         obs_memory.track(
             "train/scores", self,
-            lambda g: int(g.train_score.score.nbytes)
-            + sum(int(su.score.nbytes) for su in g.valid_scores))
+            lambda g: g.train_score.nbytes
+            + sum(su.nbytes for su in g.valid_scores))
         # resilience (resilience/): deterministic fault plan (param/env)
         # and the retry wrapper around device dispatches. None/False on
         # the default path — _dispatch_device is then a plain call
@@ -312,8 +365,7 @@ class GBDT:
         self.valid_sets.append(ds)
         su = _ScoreUpdater(ds.num_data, self.num_tree_per_iteration,
                            self._reshape_init_score(ds))
-        if self.use_fused:
-            self._valid_bins_dev.append(jnp.asarray(ds.bins))
+        self._valid_bins_dev.append(None)   # uploaded where walked by rows
         # replay existing model onto the new valid set
         if self.models:
             models = self.materialized_models()
@@ -328,6 +380,35 @@ class GBDT:
         for m in ms:
             m.init(ds.metadata, ds.num_data)
         self.valid_metrics.append(ms)
+        if getattr(self, "_aligned_eng_ref", None) is not None:
+            self._pack_valid(len(self.valid_sets) - 1)
+
+    def _valid_bins(self, i: int) -> jax.Array:
+        """Valid set i's row-order bins on the device, for the walks over
+        rows (the fused loop, the XLA walkers); uploaded at first use, so
+        a set packed into records never holds them."""
+        if self._valid_bins_dev[i] is None:
+            self._valid_bins_dev[i] = jnp.asarray(self.valid_sets[i].bins)
+        return self._valid_bins_dev[i]
+
+    def _pack_valid(self, i: int) -> None:
+        """Valid set i onto the aligned engine: where the record walk can
+        follow the engine's trees (`AlignedEngine.record_walk_why`, static
+        facts), its rows packed into records once and its scores moved
+        into their score lane; else its row-order bins for the XLA
+        walkers. One `valid.pack` seam says which and why."""
+        eng = self._aligned_eng_ref
+        ds, su = self.valid_sets[i], self.valid_scores[i]
+        why = eng.record_walk_why()
+        with obs_trace.seam("valid.pack", rows=int(ds.num_data),
+                            walk="rows" if why else "records",
+                            why=why) as sm:
+            if why is None:
+                su = self.valid_scores[i] = _RecordScores(eng, ds.bins, su)
+                sm.attrs.update(bytes=su.nbytes, chunks=int(su.rec.shape[0]))
+            else:
+                sm.attrs.update(bytes=int(self._valid_bins(i).nbytes),
+                                chunks=0)
 
     # ------------------------------------------------------------------
     def _bagging(self, iter_idx: int) -> None:
@@ -427,7 +508,11 @@ class GBDT:
 
     def apply_tree_to_score(self, su: "_ScoreUpdater", bins, tree: Tree,
                             class_id: int, scale: float = 1.0) -> None:
-        """Add scale * tree(x) into a score updater via binned traversal."""
+        """Add scale * tree(x) into a score updater via binned traversal
+        (a set packed into records: by the record walk)."""
+        if isinstance(su, _RecordScores):
+            su.add_tree(tree, scale)
+            return
         pred = TreePredictor([tree])
         leaves = pred.predict_binned_leaves(bins, self._bundle_arrays())[0]
         su.add_tree_by_leaves(
@@ -727,12 +812,18 @@ class GBDT:
     def _apply_record_to_valid_scores(self, rec, trav=None,
                                       class_id: int = 0):
         """Add one tree record's predictions to every valid-set score
-        (shared by the fused/mega/aligned iteration paths)."""
+        (shared by the fused/mega/aligned iteration paths). A set packed
+        into records takes the tree as a host tree by the record walk (a
+        fallback's: cold)."""
         cfg = self.cfg
         for i, su in enumerate(self.valid_scores):
+            if isinstance(su, _RecordScores):
+                su.add_tree(self.learner.record_to_tree(
+                    jax.device_get(rec), self.shrinkage_rate))
+                continue
             if trav is None:
                 trav = traversal_arrays(rec, max(cfg.num_leaves - 1, 1))
-            vb = self._valid_bins_dev[i]
+            vb = self._valid_bins(i)
             bundled = getattr(self.learner, "bundled", False)
             su.score = su.score.at[class_id].set(
                 add_record_score(su.score[class_id], vb, trav,
@@ -806,6 +897,8 @@ class GBDT:
                 init_row_scores=np.asarray(self.train_score.score),
                 bagged=self._will_bag(), num_class=K)
             self._aligned_eng_ref = eng
+            for i in range(len(self.valid_sets)):
+                self._pack_valid(i)
         self._maybe_rebag(eng)
         fmasks = [self.learner.feature_mask() for _ in range(K)]
         outs = [self._dispatch_device(
@@ -841,12 +934,12 @@ class GBDT:
             sc = su.score
             for k, (spec, _nc, _ex, applied) in enumerate(outs):
                 sc = eng.apply_spec_to_scores(
-                    sc, k, self._valid_bins_dev[i], spec, applied,
+                    sc, k, self._valid_bins(i), spec, applied,
                     self.shrinkage_rate)
             su.score = sc
         if self.valid_scores:
             self._valid_eval_stash = [
-                [m.eval_dev(su.score, self.objective) for m in ms]
+                self._eval_dev(su.score, ms, "valid.metric")
                 for su, ms in zip(self.valid_scores, self.valid_metrics)]
         if len(self._pending_numsplits) >= 16 * K:
             res = self._resolve_aligned_pending_mc()
@@ -901,7 +994,7 @@ class GBDT:
                 -self.shrinkage_rate)
             for i, su in enumerate(self.valid_scores):
                 su.score = eng.apply_spec_to_scores(
-                    su.score, k, self._valid_bins_dev[i], specs[k],
+                    su.score, k, self._valid_bins(i), specs[k],
                     applieds[k], -self.shrinkage_rate)
         self.train_score.score = scores
         self._train_score_stale = False
@@ -954,6 +1047,8 @@ class GBDT:
                 bag_multiplier=self._bag_multiplier,
                 bag_device=self._bag_on_device)
             self._aligned_eng_ref = eng
+            for i in range(len(self.valid_sets)):
+                self._pack_valid(i)
         stash = getattr(self, "_aligned_next", None)
         if stash is not None:
             # this iteration was dispatched EAGERLY at the end of the
@@ -1016,38 +1111,54 @@ class GBDT:
         # spec, still pipelined — the walk is gated by the program's own
         # applied flag, so a dispatch the host later discards (inexact
         # predecessor / fallback) contributed exactly 0 and the exact
-        # fallback's host application stays correct
+        # fallback's host application stays correct. A set packed into
+        # records takes the tree, and what `sample` did to earlier trees,
+        # in ONE `walk_pass`; one walked by rows takes each by an XLA walk
+        walks = self._aligned_valid_walks(eng, sample)
+        rows = passes = 0
         for i, su in enumerate(self.valid_scores):
+            if isinstance(su, _RecordScores):
+                if lazy.walk is None:
+                    lazy.walk = eng.walk_tree_of_spec(spec, "valid.walk")
+                passes += su.walk([(lazy.walk, self.shrinkage_rate, 0.0)]
+                                  + walks, applied_dev)
+                rows += su.num_data
+                continue
             # the whole [K, Nv] buffer is donated and updated in place
             # at lane 0 — no gather/scatter copy pair per valid set
             su.score = eng.apply_spec_to_scores(
-                su.score, 0, self._valid_bins_dev[i], spec,
+                su.score, 0, self._valid_bins(i), spec,
                 applied_dev, self.shrinkage_rate)
-            su.score = self._aligned_valid_sample(
-                eng, sample, su.score, self._valid_bins_dev[i], applied_dev)
+            for w in walks:
+                su.score = eng.walk_rows(su.score, 0, self._valid_bins(i),
+                                         *w, applied_dev, 1.0)
+        if passes:
+            q[-1][8].update(valid_rows_walked=rows,
+                            valid_walk_passes=passes)
         if self.valid_scores:
             # queue the device metric programs for THIS iteration before
             # the eager next build: the device executes in queue order,
             # so eval scalars resolve right after the walks instead of
             # behind the whole next build
             self._valid_eval_stash = [
-                [m.eval_dev(su.score, self.objective) for m in ms]
+                self._eval_dev(su.score, ms, "valid.metric")
                 for su, ms in zip(self.valid_scores, self.valid_metrics)]
             # train metrics likewise (valid_sets often include the train
             # set): queue device scalars over the materialized score
             # lane so per-iteration train eval doesn't have to discard
-            # the eager dispatch. Gated on eval_train having actually
-            # been called (otherwise every iteration would pay a wasted
-            # full-N materialization + metric program)
+            # the eager dispatch. Only where eval_train followed each of
+            # the last two updates: a caller that evaluates the training
+            # set now and then (at a drain) would pay a full-N
+            # materialization and metric programs for nothing
             self._train_eval_stash = None
-            if (getattr(self, "_train_eval_wanted", False)
+            if (getattr(self, "_train_eval_at", None)
+                    == (self.iter - 2, self.iter - 1)
                     and self.train_metrics and all(
                         type(m).eval_dev is not Metric.eval_dev
                         for m in self.train_metrics)):
-                view = eng.row_scores_dev()[None, :]
-                self._train_eval_stash = [
-                    m.eval_dev(view, self.objective)
-                    for m in self.train_metrics]
+                self._train_eval_stash = self._eval_dev(
+                    eng.row_scores_dev()[None, :], self.train_metrics,
+                    "train.metric")
             # per-iteration eval is about to BLOCK on this iteration's
             # completion; dispatch the next build now so the device never
             # idles (if training stops instead, _discard_eager undoes the
@@ -1099,6 +1210,39 @@ class GBDT:
             mask[:] = 1.0
         eng.set_bag(mask)
 
+    def _keeps_ahead(self, eng) -> bool:
+        """Whether a drain may leave the round dispatched ahead
+        (`_aligned_next`) for its turn: where its one trace on the records
+        is its tree's score-lane update (no parked rows walked; a variant
+        whose round does more to the records says False), on one score
+        lane on one chip, rows in row order."""
+        return (not eng.parks and eng.num_class == 1
+                and eng.axis is None and eng.ext_of_row is None)
+
+    _recorded_ahead = None      # the iteration `_record_ahead` recorded
+
+    def _record_ahead(self) -> None:
+        """The `aligned.iter` record of the round a drain kept for its
+        turn, made at the drain, where its build is over: a window that
+        ends in a drain then holds the records of the builds it ran. It
+        carries the validation walk its tree is queued for; its turn
+        records it no more. An inexact round is left to its turn."""
+        nxt = getattr(self, "_aligned_next", None)
+        if nxt is None or self._recorded_ahead == self.iter:
+            return
+        spec, _nc, exact_dev, _applied = nxt[0]
+        exact, rounds, table = jax.device_get(
+            (exact_dev, spec.rounds, spec.round_stats))
+        if not bool(exact):
+            return
+        packed = [su for su in self.valid_scores
+                  if isinstance(su, _RecordScores)]
+        walked = {"valid_rows_walked": sum(su.num_data for su in packed),
+                  "valid_walk_passes": len(packed)} if packed else {}
+        _record_aligned_iter(self.iter, rounds, table,
+                             dict(self._aligned_sample_stats or {}, **walked))
+        self._recorded_ahead = self.iter
+
     def _discard_eager(self) -> None:
         """Drop a speculatively-dispatched next iteration: undo its
         (gated) score-lane contribution so the engine lane is
@@ -1108,6 +1252,7 @@ class GBDT:
         if stash is None:
             return
         self._aligned_next = None
+        self._recorded_ahead = None
         (spec, _nc, _ex, applied_dev), _fmask, rng_snap = stash
         eng = self._aligned_eng_ref
         eng.undo_spec_scores(spec, applied_dev, self.shrinkage_rate)
@@ -1164,9 +1309,10 @@ class GBDT:
         (spec, ncommit, exact, applied), `prev_ok` the chain flag it was
         dispatched under."""
 
-    def _aligned_valid_sample(self, eng, sample, score, vbins, applied):
-        """A valid set's score after what `sample` did to earlier trees."""
-        return score
+    def _aligned_valid_walks(self, eng, sample) -> list:
+        """What `sample` did to earlier trees, as a valid set takes it:
+        [(WalkTree, shrinkage, bias)] to add to its scores."""
+        return []
 
     def _aligned_forget_from(self, first_iter: int) -> None:
         """Iterations `first_iter` and later were dispatched and are
@@ -1226,9 +1372,12 @@ class GBDT:
             return 1
         return 8
 
-    def _resolve_aligned_pending(self, final: bool):
+    def _resolve_aligned_pending(self, final: bool, ride=None):
         """Resolve queued speculative rounds' exactness flags (one
-        batched device_get — see _aligned_pipeline_depth). Returns:
+        batched device_get — see _aligned_pipeline_depth). `ride`: a
+        one-item list whose item (device values) is pulled in that same
+        device_get and put back as host values (`eval_valid`'s metrics).
+        Returns:
         - None: queue not full yet, or every queued round was exact;
         - ("redo", init_scores, eng, fmask, bag_idx, bag_cnt, sample):
           `_aligned_fallback_iter`'s arguments, final=False
@@ -1243,24 +1392,34 @@ class GBDT:
           the stop signal."""
         q = getattr(self, "_aligned_pending", None)
         if not q:
+            if ride is not None and ride[0] is not None:
+                ride[0] = jax.device_get(ride[0])
             return None
         if not final and len(q) < self._aligned_pipeline_depth():
             return None
         self._aligned_pending = None
         # the one place the loop's host blocks. The per-round counters of
         # the queued programs ride the same pull as they are (no stack,
-        # no concatenate: nothing here may compile a new program)
+        # no concatenate: nothing here may compile a new program), and
+        # so does whatever the caller hands in `ride`
         with obs_trace.seam("train.flag_pull", iter=self.iter,
                             queued=len(q), final=final):
-            flags, stats = jax.device_get((
+            flags, stats, rode = jax.device_get((
                 q[0][0] if len(q) == 1 else jnp.stack([p[0] for p in q]),
-                [(p[5].rounds, p[5].round_stats, p[8]) for p in q]))
+                [(p[5].rounds, p[5].round_stats, p[8]) for p in q],
+                None if ride is None else ride[0]))
+        if ride is not None:
+            ride[0] = rode
         # the host's own work on what it pulled: the device idles here
         # where nothing else is queued (the drain, whose part this is)
         with obs_trace.part("train.resolve"):
             flags = [bool(v) for v in np.atleast_1d(flags)]
             for p, ok, counters in zip(q, flags, stats):
-                if ok:  # a discarded dispatch is rebuilt, and recorded then
+                # a discarded dispatch is rebuilt, and recorded then; one
+                # a drain kept was recorded there (`_record_ahead`)
+                if ok and p[6] == self._recorded_ahead:
+                    self._recorded_ahead = None
+                elif ok:
                     _record_aligned_iter(p[6], *counters)
         if all(flags):
             return None
@@ -1399,6 +1558,8 @@ class GBDT:
         self._discard_eager()
         self._resolve_aligned_pending(final=True)
         self._sync_train_score()
+        self.valid_scores = [su.unpacked() if isinstance(su, _RecordScores)
+                             else su for su in self.valid_scores]
         self._aligned_disabled = True
         self._aligned_eng_ref = None
         if hasattr(self.learner, "drop_aligned_engine"):
@@ -1598,7 +1759,11 @@ class GBDT:
 
     # ------------------------------------------------------------------
     def eval_train(self) -> List[Tuple[str, str, float, bool]]:
-        self._train_eval_wanted = True
+        # the iterations of the last two calls: a caller that evaluates
+        # the training set after every update gets its metrics queued
+        # with each round (`_train_one_iter_aligned`)
+        at = getattr(self, "_train_eval_at", None)
+        self._train_eval_at = (None if at is None else at[1], self.iter)
         # aligned engine: evaluate from a DEVICE score view when every
         # metric supports it — the permuted->row materialization stays on
         # device instead of bouncing [N] f32 through the host
@@ -1618,29 +1783,45 @@ class GBDT:
         if (eng is not None and self.train_metrics
                 and all(type(m).eval_dev is not Metric.eval_dev
                         for m in self.train_metrics)):
-            self._discard_eager()
-            self._resolve_aligned_pending(final=True)
-            if getattr(self, "_train_score_stale", False):
-                view = _DeviceScoreView(eng.row_scores_dev()[None, :])
-                return self._eval(view, self.train_metrics, "training")
+            # the drain, as `_sync_train_score` has it, with the scores
+            # kept on the device for the metrics. A round dispatched
+            # ahead of its turn stays for its turn where all it left on
+            # the records is its tree's score-lane update: the scores are
+            # read without it, and no finished build is thrown away
+            with obs_trace.seam("train.drain", iter=self.iter):
+                if not self._keeps_ahead(eng):
+                    self._discard_eager()
+                self._resolve_aligned_pending(final=True)
+                if getattr(self, "_train_score_stale", False):
+                    nxt = getattr(self, "_aligned_next", None)
+                    ahead = None if nxt is None else (
+                        nxt[0][0], nxt[0][3], self.shrinkage_rate)
+                    with obs_trace.part("train.materialise"):
+                        view = _DeviceScoreView(
+                            eng.row_scores_dev(ahead)[None, :])
+                    out = self._eval(view, self.train_metrics, "training")
+                    self._record_ahead()
+                    return out
         self._sync_train_score()
         return self._eval(self.train_score, self.train_metrics, "training")
 
     def eval_valid(self) -> List[Tuple[str, str, float, bool]]:
         # an inexact pending aligned iteration contributed 0 to the valid
         # scores (applied gate): resolve it NOW so the exact fallback tree
-        # is applied before its metrics are recorded
-        fell_back = self._resolve_aligned_pending(final=True) is not None
-        stash = getattr(self, "_valid_eval_stash", None)
+        # is applied before its metrics are recorded. The round's flag and
+        # every metric scalar queued with it come in ONE pull
+        ride = [getattr(self, "_valid_eval_stash", None)]
         self._valid_eval_stash = None
+        fell_back = self._resolve_aligned_pending(final=True,
+                                                  ride=ride) is not None
+        stash = ride[0]
         out = []
         for i, (su, ms) in enumerate(zip(self.valid_scores,
                                          self.valid_metrics)):
             name = f"valid_{i}"
             if stash is not None and not fell_back:
-                # pre-queued device scalars (resolve ahead of the eager
-                # next build in the device queue); host-only metrics
-                # still evaluate here
+                # metric values queued on the device with the round and
+                # pulled with its flag; host-only metrics evaluate here
                 scores = None
                 if any(d is None for d in stash[i]):
                     scores = su.numpy()
@@ -1656,6 +1837,11 @@ class GBDT:
                 out.extend(self._eval(su, ms, name))
         return out
 
+    def _eval_dev(self, scores, metrics: List[Metric], phase: str) -> list:
+        """Each metric's device values over `scores` (a device `[K, N]`),
+        or None for one without a device implementation."""
+        return [m.eval_dev(scores, self.objective, phase) for m in metrics]
+
     def _eval(self, su, metrics: List[Metric],
               name: str) -> List[Tuple[str, str, float, bool]]:
         if not metrics:
@@ -1663,7 +1849,9 @@ class GBDT:
         # dispatch all device-capable metrics first (async), then emit in
         # the USER'S metric order — first_metric_only early stopping keys
         # on position 0 of the result list
-        dev_vals = [m.eval_dev(su.score, self.objective) for m in metrics]
+        dev_vals = self._eval_dev(
+            su.score, metrics,
+            "train.metric" if name == "training" else "valid.metric")
         scores = su.numpy() if any(d is None for d in dev_vals) else None
         out = []
         for m, dev in zip(metrics, dev_vals):

@@ -61,9 +61,17 @@ PHASES: Dict[str, str] = {
     "rank.gather": "gradients and hessians back by the index lane",
     "walk.tables": "a committed tree as the record walk's tables "
                    "(spec to compact tree, walk_expand)",
-    "walk.apply": "the walk of trees over rows: walk_pass's operands, "
-                  "or the XLA walk over a valid set",
+    "walk.apply": "the walk of trees over the training records: "
+                  "walk_pass's operands",
+    "valid.walk": "a validation set's walk: the tree's tables and "
+                  "walk_pass over its packed records, or the XLA walk "
+                  "over its row-order bins",
+    "valid.metric": "metrics over a validation set's scores (and the "
+                    "view of its packed score lane they read)",
+    "train.metric": "metrics over the training scores",
     "drain.materialise": "a record lane back in row order",
+    "drain.undo": "the score-lane update of a round dispatched ahead of "
+                  "its turn, taken back where a drain discards it",
 }
 
 

@@ -46,13 +46,39 @@ class Metric:
     def eval(self, scores: np.ndarray, objective) -> List[Tuple[str, float]]:
         raise NotImplementedError
 
-    def eval_dev(self, scores_dev, objective):
+    def eval_dev(self, scores_dev, objective, phase: str = "valid.metric"):
         """Device-side eval over a DEVICE score matrix, returning
         [(name, device_scalar)] — or None when this metric has no device
         implementation (the caller falls back to the host path). Lets
         per-iteration valid evals avoid pulling full score arrays over
-        the host link."""
+        the host link. `phase` (`obs.phases`): whose scores these are,
+        `valid.metric` or `train.metric`."""
         return None
+
+    def _device(self, phase: str, make):
+        """The jitted device program of this metric under `phase`, made
+        once by `make()`; its first call at a shape is remembered for
+        the phase table (`obs.phases.remember`)."""
+        import jax
+
+        from ..obs import phases
+        progs = self.__dict__.setdefault("_dev_fns", {})
+        if phase not in progs:
+            body = make()
+
+            def scoped(*args):
+                with phases.scope(phase):
+                    return body(*args)
+            progs[phase] = (jax.jit(scoped), set())
+        fn, seen = progs[phase]
+
+        def run(*args):
+            shape = tuple(getattr(a, "shape", ()) for a in args)
+            if shape not in seen:
+                seen.add(shape)
+                phases.remember(f"{phase}.{self.name}", fn, args)
+            return fn(*args)
+        return run
 
 
 class _PointwiseMetric(Metric):
@@ -191,6 +217,47 @@ class BinaryLoglossMetric(_PointwiseMetric):
                                 -np.log(K_EPSILON)))
         return out
 
+    def eval_dev(self, scores_dev, objective, phase: str = "valid.metric"):
+        """The mean log loss on the device in f32 from the raw score z =
+        sigmoid x score: softplus(-z) for a positive row, softplus(z) for
+        a negative one (the host's -log p and -log(1 - p) without the
+        cancellation of 1 - p), capped at -log(K_EPSILON) as the host
+        clips p; summed pairwise. Only under the binary objective, whose
+        ConvertOutput this is."""
+        if objective is None or objective.name != "binary":
+            return None
+        import jax.numpy as jnp
+        if not hasattr(self, "_y_dev"):
+            self._y_dev = jnp.asarray(self.label > 0)
+            self._w_dev = jnp.asarray(
+                self.weight if self.weight is not None
+                else np.ones(1), jnp.float32)
+        cap = float(-np.log(K_EPSILON))
+        sig = float(objective.cfg.sigmoid)
+        total = float(self.sum_weights)
+
+        def make():
+            def fn(score, y, w):
+                z = sig * score
+                loss = jnp.minimum(jnp.logaddexp(0.0, jnp.where(y, -z, z)),
+                                   cap)
+                return pairwise_sum(loss * w) / total
+            return fn
+        return [(self.name, self._device(phase, make)(
+            scores_dev[0], self._y_dev, self._w_dev))]
+
+
+def pairwise_sum(x):
+    """Sum of a 1-D f32 array by halves (log2(n) adds, each over half of
+    what is left): its rounding error grows with log n, not n."""
+    import jax.numpy as jnp
+    n = 1 << max(0, (int(x.shape[0]) - 1).bit_length())
+    x = jnp.pad(x, (0, n - x.shape[0]))
+    while x.shape[0] > 1:
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return x[0]
+
 
 class BinaryErrorMetric(_PointwiseMetric):
     name = "binary_error"
@@ -230,60 +297,64 @@ class AUCMetric(Metric):
             return [(self.name, 1.0)]
         return [(self.name, acc / (total_pos * total_neg))]
 
-    def eval_dev(self, scores_dev, objective):
-        import jax
+    def eval_dev(self, scores_dev, objective, phase: str = "valid.metric"):
         import jax.numpy as jnp
-        fn = getattr(self, "_dev_fn", None)
-        if fn is None:
-            weighted = self.weight is not None
+        from jax import lax
+        weighted = self.weight is not None
+        if not hasattr(self, "_y_dev"):
+            self._y_dev = jnp.asarray((self.label > 0).astype(np.int32))
+            self._w_dev = (jnp.asarray(self.weight, jnp.float32)
+                           if weighted else jnp.zeros(1, jnp.float32))
 
-            @jax.jit
+        def make():
             def fn(score, y, w):
-                order = jnp.argsort(score)
-                s = score[order]
-                yo = y[order]
-                newg = jnp.concatenate(
-                    [jnp.zeros(1, jnp.int32),
-                     (s[1:] != s[:-1]).astype(jnp.int32)])
-                gid = jnp.cumsum(newg)
+                # one sort carries the labels (and weights) with the
+                # scores: no gather by an argsort's order
+                if weighted:
+                    s, yo, wo = lax.sort((score, y, w), num_keys=1)
+                else:
+                    s, yo = lax.sort((score, y), num_keys=1)
                 n = s.shape[0]
+                step = s[1:] != s[:-1]      # a new run of equal scores
                 if weighted:
                     # f32 scatter/scan path: log-depth reductions keep
                     # relative error ~1e-6 — consistent across
                     # iterations, so early-stopping comparisons are
                     # stable even where the absolute value drifts from
                     # the host f64 metric in the 6th decimal
-                    wo = w[order]
-                    pos_w = wo * yo
-                    neg_w = wo * (1.0 - yo)
-                    gneg = jnp.zeros(n, jnp.float32).at[gid].add(neg_w)
-                    gpos = jnp.zeros(n, jnp.float32).at[gid].add(pos_w)
-                    cumneg = jnp.cumsum(gneg)
+                    gid = jnp.cumsum(jnp.concatenate(
+                        [jnp.zeros(1, jnp.int32), step.astype(jnp.int32)]))
+                    yf = yo.astype(jnp.float32)
+                    gneg = jnp.zeros(n, jnp.float32).at[gid].add(
+                        wo * (1.0 - yf))
+                    gpos = jnp.zeros(n, jnp.float32).at[gid].add(wo * yf)
+                    before = jnp.cumsum(gneg) - gneg
+                    acc = jnp.sum(gpos * (before + 0.5 * gneg))
+                    tp, tn = jnp.sum(gpos), jnp.sum(gneg)
                 else:
-                    # unweighted: integer counts — scatter-adds and the
-                    # cumsum are EXACT (counts < 2^31); only the final
-                    # per-group products drop to f32
-                    yi = yo.astype(jnp.int32)
-                    gpos = jnp.zeros(n, jnp.int32).at[gid].add(yi)
-                    gneg = jnp.zeros(n, jnp.int32).at[gid].add(1 - yi)
-                    cumneg = jnp.cumsum(gneg)
-                before = (cumneg - gneg).astype(jnp.float32)
-                acc = jnp.sum(gpos.astype(jnp.float32)
-                              * (before
-                                 + 0.5 * gneg.astype(jnp.float32)))
-                tp = jnp.sum(gpos).astype(jnp.float32)
-                tn = jnp.sum(gneg).astype(jnp.float32)
+                    # unweighted: integer counts by scans over the sorted
+                    # runs of equal scores, EXACT (counts < 2^31), and no
+                    # scatter; only the final sum drops to f32. A row's
+                    # negatives below its run are the count at the run's
+                    # first row, its run's the count at the run's last
+                    # row less that: both carried along the run by a
+                    # running max / min of a count that never decreases
+                    neg = jnp.cumsum(1 - yo)
+                    first = jnp.concatenate([jnp.ones(1, bool), step])
+                    last = jnp.concatenate([step, jnp.ones(1, bool)])
+                    below = lax.cummax(jnp.where(first, neg - (1 - yo), 0))
+                    upto = lax.cummin(jnp.where(last, neg, n), reverse=True)
+                    run = (upto - below).astype(jnp.float32)
+                    acc = pairwise_sum(jnp.where(
+                        yo > 0, below.astype(jnp.float32) + 0.5 * run, 0.0))
+                    tp = jnp.sum(yo).astype(jnp.float32)
+                    tn = jnp.float32(n) - tp
                 bad = (tp <= 0) | (tn <= 0)
                 return jnp.where(bad, 1.0,
                                  acc / jnp.maximum(tp * tn, 1e-30))
-            self._dev_fn = fn
-            self._y_dev = jnp.asarray(
-                (self.label > 0).astype(np.float32))
-            self._w_dev = (jnp.asarray(self.weight, jnp.float32)
-                           if self.weight is not None
-                           else jnp.zeros(1, jnp.float32))
-        return [(self.name, self._dev_fn(scores_dev[0], self._y_dev,
-                                         self._w_dev))]
+            return fn
+        return [(self.name, self._device(phase, make)(
+            scores_dev[0], self._y_dev, self._w_dev))]
 
 
 class MultiLoglossMetric(Metric):

@@ -38,6 +38,10 @@ KINDS = {
                  "feature_fraction": 0.8}, ["sample.bag"] + PARKED),
     "dart": ({"boosting": "dart", "drop_rate": 0.5, "skip_drop": 0.3,
               "learning_rate": 0.3}, ["walk.tables", "walk.apply"]),
+    # a validation set watched every iteration: its walk and metrics,
+    # and the training metrics at the drain
+    "valid": ({"metric": "auc,binary_logloss"},
+              ["valid.walk", "valid.metric", "train.metric"]),
     "lambdarank": ({"objective": "lambdarank", "num_leaves": 7,
                     "max_bin": 31, "min_data_in_leaf": 5,
                     "min_sum_hessian_in_leaf": 1e-3,
@@ -64,10 +68,17 @@ def _data(kind, seed=0):
 def _run(kind, iters=5):
     X, y, group = _data(kind)
     params = dict(BASE, **KINDS[kind][0])
-    ds = lgb.Dataset(X, label=y, group=group, params=params).construct()
+    ds = lgb.Dataset(X[:1500], label=y[:1500], group=group,
+                     params=params).construct() if kind == "valid" else \
+        lgb.Dataset(X, label=y, group=group, params=params).construct()
     bst = lgb.Booster(params=params, train_set=ds)
+    if kind == "valid":
+        bst.add_valid(lgb.Dataset(X[1500:], label=y[1500:], reference=ds,
+                                  params=params).construct(), "v")
     for _ in range(iters):
         bst.update()
+        if kind == "valid":
+            bst.eval_valid()
     bst.eval_train()
     return bst
 
@@ -168,7 +179,11 @@ def test_the_build_programs_heavy_instructions_carry_a_phase(table_of, kind):
     ("lambdarank", "mat_ext", "rank.scatter"),
     ("lambdarank", "rank_fused", "rank.glue"),
     ("lambdarank", "build_ext", "rank.gather"),
-    ("plain", "mat", "drain.materialise")])
+    ("plain", "mat", "drain.materialise"),
+    ("valid", "walk_rec", "valid.walk"),
+    ("valid", "valid_view", "valid.metric"),
+    ("valid", "valid.metric.auc", "valid.metric"),
+    ("valid", "train.metric.binary_logloss", "train.metric")])
 def test_a_program_outside_the_build_names_its_own_work(table_of, kind,
                                                         program, phase):
     rows = [r for r in table_of(kind)[0] if program in r["program"]

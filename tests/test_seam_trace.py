@@ -310,6 +310,38 @@ def test_pack_seam_carries_the_layout(run16):
     assert pack["t1"] <= up["t0"]
 
 
+def test_valid_pack_seam_carries_the_block():
+    """A validation set on the engine leaves one `valid.pack` seam, where
+    the engine is built: its rows, the packed block's bytes and chunks,
+    the walk that takes it and why (None: the record walk)."""
+    X, y = _data()
+    Xv, yv = _data(seed=5, n=700)
+    params = {"objective": "binary", "num_leaves": 8, "max_bin": 63,
+              "learning_rate": 0.1, "min_data_in_leaf": 20,
+              "verbosity": -1, "metric": "auc", **ALIGNED}
+    ds = lgb.Dataset(X, label=y, params=params).construct()
+    vs = lgb.Dataset(Xv, label=yv, reference=ds, params=params).construct()
+    obs_trace.reset()
+    bst = lgb.Booster(params=params, train_set=ds)
+    bst.add_valid(vs, "v")
+    for _ in range(2):
+        bst.update()
+        bst.eval_valid()
+    eng = bst._gbdt._aligned_eng_ref
+    su = bst._gbdt.valid_scores[0]
+    (pack,) = obs_trace.seams("valid.pack")
+    assert {k: pack[k] for k in ("rows", "bytes", "chunks", "walk", "why")} \
+        == dict(rows=700, bytes=su.rec.nbytes + su.cnts.nbytes,
+                chunks=-(-700 // eng.C), walk="records", why=None)
+    assert su.rec.shape[1:] == (eng.W, eng.C)
+    (train_pack,) = obs_trace.seams("aligned.pack")
+    assert train_pack["t1"] <= pack["t0"]
+    # the iterations' records count what the walk read
+    recs = obs_trace.seams("aligned.iter")
+    assert [(r["valid_rows_walked"], r["valid_walk_passes"])
+            for r in recs] == [(700, 1)] * 2
+
+
 def test_seams_of_one_iteration_share_its_number(run16):
     seams = run16["seams"]
     by_id = {r["id"]: r for r in seams}
