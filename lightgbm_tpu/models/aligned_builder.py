@@ -46,8 +46,8 @@ from ..obs import trace as obs_trace
 from ..ops.aligned import (META_BAG, META_LABEL, META_LABEL_MASK,
                            META_RID_MASK, R_CAT,
                            R_COPY, R_DL, R_MT, R_SHIFT, _bpw_for_bits,
-                           count_pass, lane_layout, move_pass,
-                           pack_records, pack_route2, park_pass,
+                           bin_bits, count_pass, lane_layout, move_pass,
+                           pack_device, pack_route2, park_pass,
                            slot_hist_pass)
 from ..utils import log
 from ..ops.histogram import NUM_HIST_STATS
@@ -219,13 +219,17 @@ class AlignedEngine:
         from ..ops.aligned import (ROUTE_SELECTORS, ROUTE_STAGE, chunk_for,
                                    route_tile, route_unroll)
         self.C = C = chunk_for(self.cfg, learner.num_features, learner.n)
-        # host work over all rows: what the readers of this seam need
-        # of the layout rides on it
+        # the records packed on the device from the bins, until the
+        # device holds them: what the readers of this seam need of the
+        # layout rides on it
         with obs_trace.seam("aligned.pack", rows=int(learner.n)) as sm:
-            rec_all, cnts_all, ext_of_row = self._pack_host(
+            cnts_all, ext_of_row, info = self._pack_device(
                 learner, objective, init_row_scores, bagged, num_class)
-            nbytes = int(rec_all.nbytes) + int(cnts_all.nbytes)
+            self.rec.block_until_ready()  # graftlint: disable=LGT002 the pack's one wait at construction, not a round-loop fence: the seam ends when the device holds the records
+            nbytes = int(self.rec.nbytes) + int(cnts_all.nbytes)
             sm.attrs.update(
+                pack="device", pack_blocks=info["blocks"],
+                upload_bytes=info["upload_bytes"],
                 bytes=nbytes, W=int(self.W), w_used=int(self.w_used),
                 C=int(C), NC=int(self.NC), bits=int(self.bits),
                 shards=int(self.nd), count_pass=self.count_pass,
@@ -238,19 +242,17 @@ class AlignedEngine:
                 # lane, or the host's mask through `set_bag`
                 bag=("device" if self.bag_device else "host") if bagged
                 else "none")
-        # the span is the ENQUEUE of the transfer: nothing here waits for
-        # it, so what the host does not copy synchronously lands in the
-        # first program's wait (the first train.drain)
+        # the records are on the device already: what is left is the
+        # ENQUEUE of the chunk counts' transfer (the span's `bytes` are
+        # still the record matrix's)
         with obs_trace.seam("aligned.upload", bytes=nbytes):
             if self.axis is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 sh = NamedSharding(self.mesh, P(self.axis))
-                self.rec = jax.device_put(rec_all, sh)
                 self.cnts = jax.device_put(cnts_all, sh)
             else:
-                self.rec = jnp.asarray(rec_all)
                 self.cnts = jnp.asarray(cnts_all)
-            # device int32[n], or None for the identity (see _pack_host)
+            # device int32[n], or None for the identity (see _pack_device)
             self.ext_of_row = (None if ext_of_row is None
                                else jnp.asarray(ext_of_row))
         from ..obs import memory as obs_memory
@@ -346,24 +348,24 @@ class AlignedEngine:
         return bool(self.bagged or self.axis is not None or big_n)
 
     # ------------------------------------------------------------------
-    def _pack_host(self, learner, objective, init_row_scores, bagged,
-                   num_class):
-        """The host-side half of construction: choose the record layout,
-        pack every shard's rows into [nc_local, W, C] records
-        (`pack_records`), pad to the static chunk grid and fill the score
-        lanes. Returns (rec_all, cnts_all, ext_of_row) as numpy, ready to
-        upload."""
+    def _pack_device(self, learner, objective, init_row_scores, bagged,
+                     num_class):
+        """Construction's pack: choose the record layout and pack every
+        shard's rows, with `init_row_scores` [K, n] (host or device) in
+        the score lanes, into `self.rec` on the device (`pack_device`,
+        block by block; each shard [nc_data + S + 2, W, C], the fresh
+        chunks zero). Returns (cnts_all numpy, ext_of_row, the pack's
+        info)."""
         C = self.C
         bins = np.asarray(learner.ds.bins)
         # feature-parallel zero-padding only; under EFB bundling
         # ds.bins holds the [N, G] bundled storage whose column count
         # LEGITIMATELY differs from the feature count (bundling is
         # serial-gated, so the two conditions never overlap)
+        self.ncols = bins.shape[1]
         if (not learner.bundled
                 and learner.num_features != learner.num_real_features):
-            pad = learner.num_features - learner.num_real_features
-            bins = np.pad(bins, ((0, 0), (0, pad)))
-        self.ncols = bins.shape[1]
+            self.ncols += learner.num_features - learner.num_real_features
         pack_max_bin = (learner.hist_bins if learner.bundled
                         else learner.max_bin_global)
         label = objective._label_np if objective._label_np is not None \
@@ -448,87 +450,47 @@ class AlignedEngine:
                                              1.5)))
         import math as _math
         self.per_shard = int(_math.ceil(self.n / self.nd))
-        label_arr = np.asarray(label) if label is not None else None
-        weight_arr = np.asarray(weight) if weight is not None else None
-        isc = None
-        if init_row_scores is not None:
-            isc = np.asarray(init_row_scores, np.float32)
-            if isc.ndim == 1:
-                isc = isc[None, :]
-        shard_recs = []
-        shard_cnts = []
-        for sh in range(self.nd):
-            lo = min(self.n, sh * self.per_shard)   # empty trailing shard
-            hi = min(self.n, lo + self.per_shard)
-            rec, self.wcnt, self.W, cnts, self.bits = pack_records(
-                bins[lo:hi],
-                label_arr[lo:hi] if label_arr is not None else None,
-                weight_arr[lo:hi] if weight_arr is not None else None,
-                self.C, with_bag=bagged, compact=self.compact,
-                num_class=num_class, with_prob=with_prob,
-                max_bin=pack_max_bin, ext=self.ext,
-                rid_base=lo,
-                index=None if ext_of_row is None
-                else (ext_of_row, self.ext_n))
-            # every shard's chunk grid has IDENTICAL static shape:
-            # ceil(per_shard/C) data chunks + S + 2 fresh
-            nc_data = (self.per_shard + C - 1) // C
-            nc_local = nc_data + self.S + 2
-            rec_full = np.zeros((nc_local, self.W, self.C), np.int32)
-            rec_full[:rec.shape[0]] = rec
-            cnts_full = np.zeros(nc_local, np.int32)
-            cnts_full[:len(cnts)] = cnts
-            shard_recs.append(rec_full)
-            shard_cnts.append(cnts_full)
-        self.NC = shard_recs[0].shape[0]     # per-shard chunk count
+        scores = init_row_scores
+        if scores is not None and scores.ndim == 1:
+            scores = scores[None, :]
+        self.bits = bin_bits(bins, pack_max_bin)
+        # every shard's chunk grid has IDENTICAL static shape:
+        # ceil(per_shard/C) data chunks + S + 2 fresh
+        self.NC = (self.per_shard + C - 1) // C + self.S + 2
+        self.rec, self.wcnt, self.W, cnts_all, info = pack_device(
+            bins, label, weight, C, self.NC, bits=self.bits,
+            cols=self.ncols, with_bag=bagged, compact=self.compact,
+            num_class=num_class, with_prob=with_prob, ext=self.ext,
+            index=None if ext_of_row is None else (ext_of_row, self.ext_n),
+            scores=scores, mesh=self.mesh if self.axis else None,
+            axis=self.axis,
+            per_shard=self.per_shard)
         self.lanes, _ = lane_layout(self.wcnt, with_bag=bagged,
                                     compact=self.compact,
                                     num_class=num_class,
                                     with_prob=with_prob, ext=self.ext)
-        if isc is not None:
-            nc_data = (self.per_shard + C - 1) // C
-            for sh in range(self.nd):
-                lo = min(self.n, sh * self.per_shard)
-                hi = min(self.n, lo + self.per_shard)
-                for k in range(num_class):
-                    sc = np.zeros(nc_data * self.C, np.float32)
-                    sc[:hi - lo] = isc[k, lo:hi]
-                    shard_recs[sh][:nc_data, self.lanes["score"] + k, :] = \
-                        sc.reshape(nc_data, self.C).view(np.int32)
         # lanes actually carrying data (w_used <= W): only these ride
         # the move pass's route matmul
         self.w_used = max(self.lanes.values()) + 1
-        if self.nd == 1:    # serial: no copy of the full record matrix
-            rec_all, cnts_all = shard_recs[0], shard_cnts[0]
-        else:
-            rec_all = np.concatenate(shard_recs, axis=0)
-            cnts_all = np.concatenate(shard_cnts)
-        return rec_all, cnts_all, ext_of_row
+        return cnts_all, ext_of_row, info
 
     def pack_rows(self, bins, scores):
         """Other rows, a validation set's, packed as the engine packs its
-        own (`pack_records`, the same chunk, lanes and bin words) into a
+        own (`pack_device`, the same chunk, lanes and bin words) into a
         block of records `[ceil(n / C), W, C]` whose score lane holds
-        `scores` (f32, row order), and its per-chunk counts: numpy, ready
-        to upload. The rows never move, so record order is row order.
-        Lanes the walk does not read hold what `pack_records` puts
-        there."""
-        lr = self.learner
+        `scores` (f32 [1, n], row order, host or device), and its
+        per-chunk counts: device arrays, with the pack's info. The rows
+        never move, so record order is row order. Lanes the walk does not
+        read hold what the pack puts there."""
         bins = np.asarray(bins)
-        if (not lr.bundled
-                and lr.num_features != lr.num_real_features):
-            bins = np.pad(bins, ((0, 0),
-                                 (0, lr.num_features - lr.num_real_features)))
         n = bins.shape[0]
-        rec, wcnt, w, cnts, bits = pack_records(
-            bins, np.zeros(n, np.float32), None, self.C,
-            with_bag=self.bagged, compact=self.compact,
-            max_bin=lr.max_bin_global, ext=self.ext)
-        assert (wcnt, w, bits) == (self.wcnt, self.W, self.bits)
-        sc = np.zeros(rec.shape[0] * self.C, np.float32)
-        sc[:n] = np.asarray(scores, np.float32).reshape(-1)
-        rec[:, self.lanes["score"], :] = sc.reshape(-1, self.C).view(np.int32)
-        return rec, cnts
+        rec, wcnt, w, cnts, info = pack_device(
+            bins, np.zeros(n, np.float32), None, self.C, -(-n // self.C),
+            bits=self.bits, cols=self.ncols, with_bag=self.bagged,
+            compact=self.compact, ext=self.ext,
+            scores=jnp.reshape(scores, (1, n)))
+        assert (wcnt, w) == (self.wcnt, self.W)
+        return rec, jnp.asarray(cnts), info
 
     def block_scores(self, rec, n: int):
         """The score lane of a packed block in row order, `[1, n]`, as a

@@ -161,8 +161,7 @@ class _RecordScores(_ScoreUpdater):
         self.num_data = su.num_data
         self.num_class = 1
         self.has_init_score = su.has_init_score
-        rec, cnts = eng.pack_rows(bins, np.asarray(su.score))
-        self.rec, self.cnts = jnp.asarray(rec), jnp.asarray(cnts)
+        self.rec, self.cnts, self.pack_info = eng.pack_rows(bins, su.score)
 
     @property
     def score(self):
@@ -405,10 +404,15 @@ class GBDT:
                             why=why) as sm:
             if why is None:
                 su = self.valid_scores[i] = _RecordScores(eng, ds.bins, su)
-                sm.attrs.update(bytes=su.nbytes, chunks=int(su.rec.shape[0]))
+                su.rec.block_until_ready()  # graftlint: disable=LGT002 the validation pack's one wait at load time, not a round-loop fence
+                sm.attrs.update(bytes=su.nbytes, chunks=int(su.rec.shape[0]),
+                                pack="device",
+                                pack_blocks=su.pack_info["blocks"],
+                                upload_bytes=su.pack_info["upload_bytes"])
             else:
-                sm.attrs.update(bytes=int(self._valid_bins(i).nbytes),
-                                chunks=0)
+                nbytes = int(self._valid_bins(i).nbytes)
+                sm.attrs.update(bytes=nbytes, chunks=0, pack="none",
+                                pack_blocks=0, upload_bytes=nbytes)
 
     # ------------------------------------------------------------------
     def _bagging(self, iter_idx: int) -> None:
@@ -894,7 +898,7 @@ class GBDT:
         if eng is None:
             eng = self.learner.aligned_engine(
                 self.objective,
-                init_row_scores=np.asarray(self.train_score.score),
+                init_row_scores=self.train_score.score,
                 bagged=self._will_bag(), num_class=K)
             self._aligned_eng_ref = eng
             for i in range(len(self.valid_sets)):
@@ -1042,7 +1046,7 @@ class GBDT:
         if eng is None:
             eng = self.learner.aligned_engine(
                 self.objective,
-                init_row_scores=np.asarray(self.train_score.score[0]),
+                init_row_scores=self.train_score.score[0],
                 bagged=self._will_bag(),
                 bag_multiplier=self._bag_multiplier,
                 bag_device=self._bag_on_device)

@@ -70,6 +70,8 @@ PHASES: Dict[str, str] = {
                     "view of its packed score lane they read)",
     "train.metric": "metrics over the training scores",
     "drain.materialise": "a record lane back in row order",
+    "setup.pack": "the record pack: a block of uint8 bins and the row "
+                  "lanes into the records, on the device",
     "drain.undo": "the score-lane update of a round dispatched ahead of "
                   "its turn, taken back where a drain discards it",
 }

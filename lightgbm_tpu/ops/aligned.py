@@ -228,86 +228,302 @@ def lane_layout(wcnt: int, with_bag: bool = False, compact: bool = False,
     return lanes, w_pad
 
 
+def bin_bits(bins, max_bin: int = 0) -> int:
+    """The width the records pack their bin fields at: the narrowest the
+    MAPPERS' bin range allows (max_bin = max num_bin over used mappers;
+    the observed data max where the caller has no mappers): 4-bit
+    (8/word, the reference's dense_nbits_bin.hpp:42 two-bins-per-byte at
+    twice the density) under 16 bins, 6-bit (5/word) under 64, 8-bit
+    (4/word) otherwise — for EVERY lane layout; the kernels parameterize
+    on `bits` throughout. Deriving from num_bin rather than bins.max()
+    means a split threshold in the (possibly data-empty) upper bin range
+    is always representable in-width. The bins are read only where
+    max_bin leaves the width open."""
+    bmax = max_bin - 1
+    if bmax < 64:
+        bmax = max(bmax, int(np.max(bins, initial=0)))
+    if bmax < 16:
+        return 4
+    return 6 if bmax < 64 else 8
+
+
+# Bytes of uint8 bins one call of the device pack takes: the block of
+# chunks is as many whole chunks as fit (at least one, at most the data),
+# so the pack's program has one shape an engine and at most a few blocks
+# of bins are on the device at once, beside the records. Compiled for a
+# v5e at Criteo's shape (67 columns, C = 2048) the program holds no
+# temporaries at 8 MiB a block and 122 MiB at 16; the pack's peak adds
+# to what the device already holds, so the block stays small (the
+# upload, not the block count, sets the pack's time).
+PACK_BLOCK_BYTES = 8 << 20
+
+
+def pack_block_chunks(chunk: int, cols: int, data_chunks: int) -> int:
+    """Chunks of one device-pack block (see PACK_BLOCK_BYTES)."""
+    per_chunk = chunk * max(cols, 1)
+    return max(1, min(data_chunks, PACK_BLOCK_BYTES // per_chunk))
+
+
+def _pack_block(rec, bins, first, facts, rows, *, chunk, blk, cols, bits,
+                wcnt, lanes, w_pad, kind, with_bag, num_class):
+    """One block of `blk` chunks packed into `rec` [NC, W, C] in place,
+    on the device: the rows `first` .. `first + blk * chunk` of one shard.
+
+    bins: uint8 [blk * chunk * cols], the block's bins row-major (rows
+    past the shard's end are zero); facts: int32 [1, 3], the shard's row
+    count, its first row id and the EXT index lane's pad value; rows: the
+    shard's row lanes ("label", "weight", "index" [L], "scores" [K, L]),
+    L at least the block's rows, of which the block's are sliced here.
+    Chunks past the shard's own rows are written zero. Returns (rec, a
+    token that is ready when the block is written)."""
+    compile_cache.note_trace()
+    from ..obs import phases
+    with phases.scope("setup.pack"):
+        r_blk = blk * chunk
+        bpw = _bpw_for_bits(bits)
+        n, rid_base, pad_id = facts[0, 0], facts[0, 1], facts[0, 2]
+        row = first + lax.iota(jnp.int32, r_blk)
+        valid = row < n
+        live = row < (n + chunk - 1) // chunk * chunk
+
+        def lane_of(name):
+            # a block that runs past the lane's end (the last, by less
+            # than a chunk) reads the lane's last r_blk rows and rolls
+            # them into place; what wraps around lies past the rows
+            x = rows[name]
+            start = jnp.minimum(first, x.shape[-1] - r_blk)
+            return jnp.roll(lax.dynamic_slice_in_dim(x, start, r_blk, axis=-1),
+                            start - first, axis=-1)
+
+        def on_valid(x):
+            return jnp.where(valid, x, 0)
+
+        # rows to the minor dimension, at 32 bits; a word ORs its columns,
+        # each shifted to its field. No reshape splits the column axis:
+        # on a v5e, a split of 70 uint8 columns into 14 words of 5 made
+        # this program pack wrong words (that reshape alone packed right)
+        b = bins.reshape(r_blk, cols).T.astype(jnp.uint32)
+        out = []
+        for w in range(wcnt):
+            word = jnp.zeros(r_blk, jnp.uint32)
+            for i in range(min(bpw, cols - w * bpw)):
+                word = word | (b[w * bpw + i] << (bits * i))
+            out.append(lax.bitcast_convert_type(word, jnp.int32))
+        lane = {}
+        one = jnp.int32(np.float32(1.0).view(np.int32))
+        rid = rid_base + row
+        if "scores" in rows:
+            sc = lax.bitcast_convert_type(lane_of("scores"), jnp.int32)
+            for k in range(sc.shape[0]):
+                lane[lanes["score"] + k] = on_valid(sc[k])
+        if kind == "ext":
+            lane[lanes["rid"]] = (jnp.where(valid, lane_of("index"), pad_id)
+                                  if "index" in rows else rid)
+        elif kind == "compact":
+            label = lane_of("label")
+            lab = ((label.astype(jnp.int32) & META_LABEL_MASK)
+                   if num_class > 1 else (label > 0).astype(jnp.int32))
+            meta = (rid & META_RID_MASK).astype(jnp.uint32) | on_valid(
+                (lab.astype(jnp.uint32) << META_LABEL)
+                | jnp.uint32(1 << META_BAG))     # all rows in-bag
+            lane[lanes["meta"]] = lax.bitcast_convert_type(meta, jnp.int32)
+        else:
+            lane[lanes["label"]] = on_valid(lax.bitcast_convert_type(
+                lane_of("label"), jnp.int32))
+            lane[lanes["rid"]] = rid
+            lane[lanes["weight"]] = on_valid(
+                lax.bitcast_convert_type(lane_of("weight"), jnp.int32)
+                if "weight" in rows else one)
+        if with_bag and kind != "compact":
+            lane[lanes["bag"]] = on_valid(one)
+        zero = jnp.zeros(r_blk, jnp.int32)
+        out += [lane.get(w, zero) for w in range(wcnt, w_pad)]
+        block = jnp.where(live, jnp.stack(out), 0)
+        block = block.reshape(w_pad, blk, chunk).transpose(1, 0, 2)
+        rec = lax.dynamic_update_slice(rec, block, (first // chunk, 0, 0))
+        return rec, jnp.sum(valid, keepdims=True, dtype=jnp.int32)
+
+
+def _pack_program(mesh, axis, row_names, **static):
+    """The jitted device pack of one block for a layout, `rec` donated;
+    under a mesh, shard_mapped so every shard packs its own block."""
+    key = ("pack_block", str(mesh), axis, row_names,
+           tuple(sorted((k, v if not isinstance(v, dict)
+                         else tuple(sorted(v.items())))
+                        for k, v in static.items())))
+
+    def factory():
+        body = functools.partial(_pack_block, **static)
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as P
+            rows = {k: P(None, axis) if k == "scores" else P(axis)
+                    for k in row_names}
+            body = jax.shard_map(
+                body, mesh=mesh,
+                in_specs=(P(axis), P(axis), P(), P(axis), rows),
+                out_specs=(P(axis), P(axis)), check_vma=False)
+        return jax.jit(body, donate_argnums=(0,))
+    return compile_cache.program(key, factory)
+
+
+def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
+                cols: int = 0, with_bag: bool = False, compact: bool = False,
+                num_class: int = 1, with_prob: bool = False,
+                ext: bool = False, rid_base: int = 0, index=None,
+                scores=None, mesh=None, axis=None, per_shard: int = 0):
+    """[N, F] uint8 host bins -> int32 records [shards * nc, W, C] on the
+    device, packed there block by block (`_pack_block`): only the bins
+    and the row lanes cross, each block's bins a row-major slice of
+    `bins` uploaded flat while the block before it packs.
+
+    The rows split into `shards` (the size of `mesh`'s `axis`, 1 without
+    a mesh) contiguous ranges of `per_shard` rows (ceil(N / shards) where
+    0; a layout may be sized for more rows than the bins hold);
+    shard s's records are chunks s * nc .. of the result (its device's
+    under `mesh`), row ids from rid_base + its first row, and every chunk
+    past its rows is zero (`nc` counts the fresh chunks a caller keeps
+    behind the data). `cols`: the columns the bin words cover, at least
+    F (the rest are zero bins). `index` = (int32[N] ids, their count)
+    puts each row's id in another index space into the EXT record's
+    index lane, pads the count; `scores` [K, N] (host or device) fill the
+    score lanes. Returns (rec, wcnt, W, cnts numpy [shards * nc], info:
+    blocks, upload_bytes)."""
+    n, fin = bins.shape
+    cols = max(cols, fin)
+    bpw = _bpw_for_bits(bits)
+    wcnt = (cols + bpw - 1) // bpw
+    lanes, w_pad = lane_layout(wcnt, with_bag, compact, num_class,
+                               with_prob, ext=ext)
+    shards = 1 if mesh is None else mesh.shape[axis]
+    per = per_shard or -(-n // shards)
+    nc_data = -(-per // chunk)
+    assert nc >= nc_data
+    span = nc_data * chunk
+    bounds = [(min(n, s * per), min(n, s * per + per)) for s in range(shards)]
+    cnts = np.zeros((shards, nc), np.int32)
+    for s, (lo, hi) in enumerate(bounds):
+        cnts[s, :(hi - lo) // chunk] = chunk
+        if (hi - lo) % chunk:
+            cnts[s, (hi - lo) // chunk] = (hi - lo) % chunk
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        def place(x, two_d=False):
+            return jax.device_put(x, NamedSharding(
+                mesh, P(None, axis) if two_d else P(axis)))
+        rec = jax.jit(lambda: jnp.zeros((shards * nc, w_pad, chunk),
+                                        jnp.int32),
+                      out_shardings=NamedSharding(mesh, P(axis)))()
+    else:
+        def place(x, two_d=False):
+            return jax.device_put(x)
+        rec = jnp.zeros((nc, w_pad, chunk), jnp.int32)
+    crossed = 0
+    blk = pack_block_chunks(chunk, fin, nc_data)
+
+    def by_shard(x, dtype):
+        """Row-order `x` [..., N] on the device as `_pack_block` reads
+        it: each shard's rows at the start of its `span`, zero behind
+        them, under a mesh; else as it is (no copy of a device array),
+        padded only where it is shorter than a block."""
+        nonlocal crossed
+        if mesh is None:
+            if not isinstance(x, jax.Array):
+                x = np.asarray(x, dtype)
+                crossed += x.nbytes
+            x = jnp.asarray(x, dtype)
+            short = blk * chunk - x.shape[-1]
+            if short > 0:
+                x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)])
+            return x
+        x = np.asarray(x, dtype)
+        out = np.zeros(x.shape[:-1] + (shards * span,), dtype)
+        for s, (lo, hi) in enumerate(bounds):
+            out[..., s * span:s * span + hi - lo] = x[..., lo:hi]
+        crossed += out.nbytes
+        return place(out, x.ndim == 2)
+
+    rows = {}
+    kind = "ext" if ext else "compact" if compact else "standard"
+    if kind != "ext":
+        rows["label"] = by_shard(np.zeros(n, np.float32) if label is None
+                                 else label, np.float32)
+    if kind == "standard" and weight is not None:
+        rows["weight"] = by_shard(weight, np.float32)
+    if ext and index is not None:
+        rows["index"] = by_shard(index[0], np.int32)
+    if scores is not None:
+        rows["scores"] = by_shard(scores, np.float32)
+    pad_id = 0 if index is None else int(index[1])
+    facts = np.asarray([[hi - lo, rid_base + lo, pad_id]
+                        for lo, hi in bounds], np.int32)
+    facts = place(facts)
+    blocks = -(-nc_data // blk)
+    if blocks == 0:
+        return rec, wcnt, w_pad, cnts.reshape(-1), dict(blocks=0,
+                                                       upload_bytes=crossed)
+    program = _pack_program(
+        mesh, axis, tuple(sorted(rows)), chunk=chunk, blk=blk, cols=fin,
+        bits=bits, wcnt=wcnt, lanes=lanes, w_pad=w_pad, kind=kind,
+        with_bag=with_bag, num_class=num_class)
+    width = blk * chunk * fin
+    if mesh is not None:
+        sharding = NamedSharding(mesh, P(axis))
+        shard_of = {d: i.start // width for d, (i,) in
+                    sharding.addressable_devices_indices_map(
+                        (shards * width,)).items()}
+    tokens = []
+    for b in range(blocks):
+        # the last block ends with the data: it starts early instead,
+        # writing chunks of the block before it once more, alike
+        first = min(b * blk, nc_data - blk) * chunk
+        parts = []
+        for lo, hi in bounds:
+            part = np.ascontiguousarray(
+                bins[min(hi, lo + first):min(hi, lo + first + blk * chunk)])
+            part = part.reshape(-1)
+            if part.size < width:
+                part = np.concatenate([part, np.zeros(width - part.size,
+                                                      np.uint8)])
+            parts.append(part)
+            crossed += part.nbytes
+        if mesh is None:
+            block = jax.device_put(parts[0])
+        else:
+            block = jax.make_array_from_single_device_arrays(
+                (shards * width,), sharding,
+                [jax.device_put(parts[s], d) for d, s in shard_of.items()])
+        rec, token = program(rec, block, np.int32(first), facts, rows)
+        tokens.append(token)
+        if len(tokens) > 2:     # at most two blocks in flight
+            tokens.pop(0).block_until_ready()  # graftlint: disable=LGT002 load-time pacing of the pack's uploads, not a round-loop fence
+    return rec, wcnt, w_pad, cnts.reshape(-1), dict(blocks=blocks,
+                                                   upload_bytes=crossed)
+
+
 def pack_records(bins: np.ndarray, label: np.ndarray,
                  weight, chunk: int, with_bag: bool = False,
                  compact: bool = False, num_class: int = 1,
                  with_prob: bool = False, max_bin: int = 0,
                  ext: bool = False, rid_base: int = 0, index=None):
-    """Host-side ingest: [N, F] uint8 bins -> [NC, W, C] int32 records.
+    """[N, F] uint8 bins -> [NC, W, C] int32 records, as numpy: the
+    device pack (`pack_device`) of every row, read back.
 
-    Returns (records, wcnt, W, cnts) where cnts[i] is the number of valid
-    rows in chunk i (C except the last). rid_base offsets the stored row
-    ids (data-parallel shards pack their local rows with GLOBAL ids).
-    `index` = (int32[N] ids, their count) puts each row's id in another
-    index space into the EXT record's index lane in place of its row id;
-    pad cells get the count, one past every id, as they do in row ids.
+    Returns (records, wcnt, W, cnts, bits) where cnts[i] is the number of
+    valid rows in chunk i (C except the last). rid_base offsets the
+    stored row ids (data-parallel shards pack their local rows with
+    GLOBAL ids). `index` = (int32[N] ids, their count) puts each row's id
+    in another index space into the EXT record's index lane in place of
+    its row id; pad cells get the count, one past every id, as they do in
+    row ids.
     """
-    n, f = bins.shape
-    # bin words pack at the narrowest width the MAPPERS' bin range
-    # allows (max_bin = max num_bin over used mappers; falls back to the
-    # observed data max when the caller has no mappers): 4-bit (8/word,
-    # the reference's dense_nbits_bin.hpp:42 two-bins-per-byte at twice
-    # the density) under 16 bins, 6-bit (5/word) under 64, 8-bit (4/word)
-    # otherwise — for EVERY lane layout; the kernels parameterize on
-    # `bits` throughout. Deriving from num_bin rather than bins.max()
-    # means a split threshold in the (possibly data-empty) upper bin
-    # range is always representable in-width.
-    bmax = max(int(bins.max(initial=0)), max_bin - 1)
-    if bmax < 16:
-        bits = 4
-    elif bmax < 64:
-        bits = 6
-    else:
-        bits = 8
-    bpw = _bpw_for_bits(bits)
-    wcnt = (f + bpw - 1) // bpw
-    lanes, w_pad = lane_layout(wcnt, with_bag, compact, num_class,
-                               with_prob, ext=ext)
-    nc = (n + chunk - 1) // chunk
-    n_pad = nc * chunk
-    padded = np.zeros((n_pad, wcnt * bpw), np.uint8)
-    padded[:n, :f] = bins
-    words = padded.reshape(n_pad, wcnt, bpw).astype(np.uint32)
-    packed = np.zeros((n_pad, wcnt), np.uint32)
-    for i in range(bpw):
-        packed |= words[:, :, i] << (bits * i)
-    rec = np.zeros((n_pad, w_pad), np.int32)
-    rec[:, :wcnt] = packed.astype(np.int64).astype(np.int32)
-    if ext:
-        if index is None:
-            rec[:, lanes["rid"]] = rid_base + np.arange(n_pad,
-                                                        dtype=np.int32)
-        else:
-            rec[:n, lanes["rid"]] = index[0]
-            rec[n:, lanes["rid"]] = index[1]
-        if with_bag:
-            rec[:n, lanes["bag"]] = np.ones(n, np.float32).view(np.int32)
-    elif compact:
-        if num_class > 1:
-            lab = np.asarray(label).astype(np.int64) & META_LABEL_MASK
-        else:
-            lab = (np.asarray(label) > 0).astype(np.int64)
-        meta = (rid_base + np.arange(n_pad, dtype=np.int64)) \
-            & META_RID_MASK
-        meta[:n] |= lab << META_LABEL
-        meta[:n] |= 1 << META_BAG     # all rows in-bag initially
-        rec[:, lanes["meta"]] = meta.astype(np.int64).astype(np.uint32) \
-            .view(np.int32)
-    else:
-        rec[:n, lanes["label"]] = np.asarray(label, np.float32) \
-            .view(np.int32)
-        rec[:, lanes["rid"]] = rid_base + np.arange(n_pad, dtype=np.int32)
-        wv = np.ones(n, np.float32) if weight is None \
-            else np.asarray(weight, np.float32)
-        rec[:n, lanes["weight"]] = wv.view(np.int32)
-        if with_bag:
-            rec[:n, lanes["bag"]] = np.ones(n, np.float32).view(np.int32)
-    rec3 = np.ascontiguousarray(
-        rec.reshape(nc, chunk, w_pad).transpose(0, 2, 1))
-    cnts = np.full(nc, chunk, np.int32)
-    if nc:      # zero-row shards (uneven DP split) pack an empty grid
-        cnts[-1] = n - (nc - 1) * chunk
-    return rec3, wcnt, w_pad, cnts, bits
+    bits = bin_bits(bins, max_bin)
+    nc = -(-bins.shape[0] // chunk)
+    rec, wcnt, w_pad, cnts, _ = pack_device(
+        bins, label, weight, chunk, nc, bits=bits, with_bag=with_bag,
+        compact=compact, num_class=num_class, with_prob=with_prob, ext=ext,
+        rid_base=rid_base, index=index)
+    return np.asarray(rec), wcnt, w_pad, cnts, bits
 
 
 # ---------------------------------------------------------------------------
