@@ -747,11 +747,13 @@ class Dataset:
 
         Returns the cache dict: ``mesh``, ``axis_name``, ``nd``,
         ``per_shard``, ``pad_rows``, row-sharded ``bins`` and its
-        column-sharded transpose ``bins_T``.
+        column-sharded transpose ``bins_T``, and ``owners``, the HBM
+        ledger's names of its per-device rows.
         """
         import math as _math
 
         import jax
+        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         key = (tuple(int(d.id) for d in mesh.devices.flat), axis_name)
@@ -771,10 +773,14 @@ class Dataset:
         bins_sharded = jax.device_put(
             bins_np, NamedSharding(mesh, P(axis_name)))
         # transposed copy, row-sharded along its second axis, for the
-        # contiguous split-column reads inside the tree build
-        bins_t = jax.device_put(
-            np.ascontiguousarray(bins_np.T),
-            NamedSharding(mesh, P(None, axis_name)))
+        # contiguous split-column reads inside the tree build: made on
+        # the devices, each from its own rows (no exchange), where a host
+        # transpose of the matrix took a minute at 96M x 67 and crossed
+        # to the devices a second time
+        bins_t = jax.jit(
+            jnp.transpose,
+            out_shardings=NamedSharding(mesh, P(None, axis_name)))(
+                bins_sharded)
         cache = {"key": key, "mesh": mesh, "axis_name": axis_name,
                  "nd": nd, "per_shard": per_shard, "pad_rows": pad_rows,
                  "bins": bins_sharded, "bins_T": bins_t}
@@ -797,12 +803,14 @@ class Dataset:
         dt = np.dtype(cache["bins"].dtype)
         per_dev = 2 * per_shard * int(cache["bins"].shape[1]) * dt.itemsize
         from ..obs import memory as obs_memory
-        for i in range(nd):
-            obs_memory.track(
-                f"dist/shard_bytes/d{i}", self,
-                lambda d, nb=per_dev, k=cache["key"]: (
-                    nb if (getattr(d, "_shard_cache", None) is not None
-                           and d._shard_cache["key"] == k) else 0))
+        # the ledger names this dataset's rows got: `#k`-suffixed where
+        # another live dataset holds the plain name
+        cache["owners"] = [obs_memory.track(
+            f"dist/shard_bytes/d{i}", self,
+            lambda d, nb=per_dev, k=cache["key"]: (
+                nb if (getattr(d, "_shard_cache", None) is not None
+                       and d._shard_cache["key"] == k) else 0))
+            for i in range(nd)]
         from ..utils import log
         log.event("dist_shard", shards=nd, rows_per_shard=per_shard,
                   pad_rows=cache["pad_rows"], bytes_per_device=per_dev,

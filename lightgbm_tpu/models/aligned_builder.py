@@ -65,7 +65,8 @@ from .level_builder import (SF_GAIN, SF_IVAL, SF_LOUT, SF_ROUT, SF_W,
 
 # columns of AlignedSpec.round_stats, one row per while-loop round: what
 # the round scheduled, summed over vectors the round's body already has
-# (per shard under data-parallel; the host records shard 0's)
+# (per shard under data-parallel, gathered to [shards, rounds, R] on every
+# chip: the host records their mean and each shard's)
 ROUND_STATS = (
     "chunks_split",    # live chunks routed through move_pass's compute path
     "chunks_copied",   # live chunks shifted whole by one HBM->HBM DMA
@@ -98,6 +99,7 @@ class AlignedSpec(NamedTuple):
     nxt_c: jax.Array       # i32[Sm1+1]
     cover: jax.Array       # f32[S+1]
     round_stats: jax.Array  # i32[Sm1, len(ROUND_STATS)], rows < rounds
+    #                         ([shards, Sm1, ...] under data-parallel)
 
 
 class WalkTree(NamedTuple):
@@ -160,6 +162,19 @@ def _add_to_lane(rec, lane: int, addend):
     return lax.dynamic_update_slice_in_dim(rec, win, lo, axis=1)
 
 
+# AlignedEngine trace signature -> {"root": bytes, "round": bytes}: the
+# histogram all-reduces of its build program, filled as it is traced
+_PSUM_BYTES = {}
+
+
+def hist_psum(x, axis):
+    """The data-parallel learner's histogram all-reduce: every shard's
+    histogram of the same leaves summed over the mesh's `axis`
+    (`Network::ReduceScatter` + the global histogram of upstream's
+    `DataParallelTreeLearner`)."""
+    return lax.psum(x, axis)
+
+
 def replay_spec(spec_host, num_leaves):
     """Host leaf-wise replay over a pulled AlignedSpec (exec/leaf tables
     are the level builder's format, so `replay_leafwise` applies as-is).
@@ -218,7 +233,8 @@ class AlignedEngine:
         # rows, so the permutation matmul no longer grows with it
         from ..ops.aligned import (ROUTE_SELECTORS, ROUTE_STAGE, chunk_for,
                                    route_tile, route_unroll)
-        self.C = C = chunk_for(self.cfg, learner.num_features, learner.n)
+        self.C = C = chunk_for(self.cfg, learner.num_features,
+                               learner.aligned_shard_rows)
         # the records packed on the device from the bins, until the
         # device holds them: what the readers of this seam need of the
         # layout rides on it
@@ -242,6 +258,14 @@ class AlignedEngine:
                 # lane, or the host's mask through `set_bag`
                 bag=("device" if self.bag_device else "host") if bagged
                 else "none")
+            if self.axis is not None:
+                # each shard's own share: the rows its blocks wrote, as
+                # the pack program counted them, and the bytes sent to
+                # its device
+                sm.attrs.update(
+                    rows_by_shard=info["rows_by_shard"],
+                    upload_bytes_by_shard=info["upload_bytes_by_shard"])
+        self.rows_by_shard = info["rows_by_shard"]
         # the records are on the device already: what is left is the
         # ENQUEUE of the chunk counts' transfer (the span's `bytes` are
         # still the record matrix's)
@@ -286,7 +310,7 @@ class AlignedEngine:
         # exactness of the LAST dispatched program (device scalar): the
         # next dispatch gates its score update on it, so a successor of
         # an inexact tree is a guaranteed score no-op (see build())
-        self._last_exact = jnp.asarray(True)
+        self._last_exact = self._true_flag()
         # multiclass deferred application: (spec, class_k, scale) of the
         # last dispatch, applied at the start of the NEXT dispatch (or by
         # flush_pending_apply), gated by the exactness CHAIN self._gate
@@ -330,6 +354,28 @@ class AlignedEngine:
         bag, and trees the record walk can follow. Every other bagged
         engine moves all of its rows through every round."""
         return bool(self.bagged and self.record_walk_why() is None)
+
+    def _true_flag(self):
+        """A device True placed as the build program returns its flags:
+        replicated over the mesh where there is one, so that the first
+        build and every later one take operands of one sharding and
+        compile once (an unplaced flag made the second build compile the
+        program again)."""
+        flag = jnp.asarray(True)
+        if self.axis is None:
+            return flag
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(flag, NamedSharding(self.mesh, P()))
+
+    @property
+    def psum_bytes(self):
+        """(bytes all-reduced for the root's histogram, for one round's
+        children) by the build program as it was traced, or None: one
+        chip, or no build traced yet."""
+        sites = _PSUM_BYTES.get(self._trace_sig) if self.axis else None
+        if not sites or set(sites) != {"root", "round"}:
+            return None
+        return sites["root"], sites["round"]
 
     @property
     def count_pass(self) -> bool:
@@ -726,8 +772,18 @@ class AlignedEngine:
             walk_tree = self._walk_tree_program("walk.tables")
             _, _, walk_w8, walk_fp = self._walk_dims()
 
-        def _gsum(x):
-            return lax.psum(x, axis) if dp else x
+        # bytes of each all-reduce site as traced: what `psum_bytes` reads
+        psum_sites = _PSUM_BYTES.setdefault(self._trace_sig, {}) if dp \
+            else None
+
+        def _gsum(x, site):
+            """A histogram all-reduced over the mesh (phase `dp.psum`);
+            one chip's as it is."""
+            if not dp:
+                return x
+            psum_sites[site] = int(x.size) * x.dtype.itemsize
+            with phases.scope("dp.psum"):
+                return hist_psum(x, axis)
 
         chunk_iota = jnp.arange(NC, dtype=jnp.int32)
         E_INF = Sm1 + 1     # "no exec" sentinel for replay pointers
@@ -969,7 +1025,7 @@ class AlignedEngine:
                                                gh_off=self.gh_off,
                                                interpret=interpret,
                                                subbin=subbin)
-                root_hist = _gsum(root_hist_all[0])
+                root_hist = _gsum(root_hist_all[0], "root")
                 root_g = jnp.sum(root_hist[0, :, 0])
                 root_h = jnp.sum(root_hist[0, :, 1])
                 root_cnt_g = jnp.sum(root_hist[0, :, 2]).astype(jnp.int32)
@@ -1299,7 +1355,7 @@ class AlignedEngine:
                     slot_l = jnp.full(K + 1, S, jnp.int32).at[idx_sc].set(
                         jnp.where(sel, s_ids, S))[:K]
                     slot_r = jnp.where(valid_rk, done + rk + 1, S)
-                    sm_k = _gsum(hout)                      # [K, F, B, 3]
+                    sm_k = _gsum(hout, "round")             # [K, F, B, 3]
                     parent_k = hist_store[slot_l]
                     lg_k = parent_k - sm_k
                     sil_k = smaller_is_left[slot_l][:, None, None, None]
@@ -1468,6 +1524,11 @@ class AlignedEngine:
                         interpret=interpret)
                     cnts_pc = cnts_pc + park_cnts
 
+            if dp:
+                # every shard's counters, not one shard's: [shards, Sm1, R]
+                # on each chip
+                with phases.scope("build.tail"):
+                    round_stats = lax.all_gather(round_stats, axis)
             spec = AlignedSpec(rounds=rounds, n_exec=n_exec,
                                execF=execF[:Sm1],
                                execI=execI[:Sm1], execB=execB[:Sm1],
@@ -1836,7 +1897,7 @@ class AlignedEngine:
             self.walk_trees([(self.walk_tree_of_spec(spec), scale, 0.0)],
                             applied, -1.0, parked_only=True)
         self._score_cache = None
-        self._last_exact = jnp.asarray(True)
+        self._last_exact = self._true_flag()
 
     def _undo_program(self, class_k: int = 0, sign: float = -1.0):
         """Subtract (sign=-1, the undo) or add (sign=+1, the multiclass
@@ -2232,7 +2293,7 @@ class AlignedEngine:
         """Re-ingest ROW-order scores into the score lane (leaf-wise
         fallback path: the fallback tree updated scores in row order)."""
         self.set_row_scores_lane(0, row_scores)
-        self._last_exact = jnp.asarray(True)   # lane is authoritative again
+        self._last_exact = self._true_flag()   # lane is authoritative again
 
     def _rid_lanes(self, rec):
         """External ids per record cell: row ids unless the objective

@@ -60,11 +60,16 @@ class LazyTree:
         return tree
 
 
-def _record_aligned_iter(it: int, rounds, table, sampled=None) -> None:
+def _record_aligned_iter(it: int, rounds, table, sampled=None,
+                         eng=None) -> None:
     """One `aligned.iter` seam record for a resolved aligned iteration:
     the build program's round count and its per-round counters
     (`aligned_builder.ROUND_STATS` order, rows up to `rounds`), as pulled
-    with the exactness flags. Data-parallel: shard 0's counters.
+    with the exactness flags. Data-parallel (`table` [shards, rows, R]):
+    the table holds each counter's mean over the shards, a chip's share,
+    and `<counter>_by_shard` each round's counts by shard; `psum_bytes`
+    is what the build's histogram all-reduces summed over the mesh (the
+    root's and one a round: `eng.psum_bytes`).
     `sampled` = the named counters the boosting variant recorded of the
     iteration, device scalars or the host's own numbers: GOSS's
     selection (`goss_kept_top`, `goss_kept_other`, `goss_threshold`),
@@ -75,10 +80,18 @@ def _record_aligned_iter(it: int, rounds, table, sampled=None) -> None:
     from .aligned_builder import ROUND_STATS
     rounds = int(rounds)
     extra = {k: np.asarray(v).item() for k, v in (sampled or {}).items()}
+    table = np.asarray(table)
+    if table.ndim == 3:
+        by_shard = table[:, :rounds]
+        table = by_shard.mean(axis=0)
+        extra.update({f"{name}_by_shard": by_shard[:, :, i].T.tolist()
+                      for i, name in enumerate(ROUND_STATS)})
+        psum = None if eng is None else eng.psum_bytes
+        if psum is not None:
+            extra["psum_bytes"] = psum[0] + rounds * psum[1]
     obs_trace.seam_record("aligned.iter", iter=int(it), rounds=rounds,
                           columns=list(ROUND_STATS),
-                          table=np.asarray(table)[:rounds].tolist(),
-                          **extra)
+                          table=table[:rounds].tolist(), **extra)
 
 
 class LazyAlignedTree(LazyTree):
@@ -1244,7 +1257,8 @@ class GBDT:
         walked = {"valid_rows_walked": sum(su.num_data for su in packed),
                   "valid_walk_passes": len(packed)} if packed else {}
         _record_aligned_iter(self.iter, rounds, table,
-                             dict(self._aligned_sample_stats or {}, **walked))
+                             dict(self._aligned_sample_stats or {}, **walked),
+                             eng=self._aligned_eng_ref)
         self._recorded_ahead = self.iter
 
     def _discard_eager(self) -> None:
@@ -1405,11 +1419,17 @@ class GBDT:
         # the one place the loop's host blocks. The per-round counters of
         # the queued programs ride the same pull as they are (no stack,
         # no concatenate: nothing here may compile a new program), and
-        # so does whatever the caller hands in `ride`
+        # so does whatever the caller hands in `ride`. One chip's flags
+        # cross as one stack; a mesh's ride as they are, since a stack of
+        # flags replicated over the mesh is a program of its own, for
+        # every count of them
         with obs_trace.seam("train.flag_pull", iter=self.iter,
                             queued=len(q), final=final):
+            flags = q[0][0] if len(q) == 1 else [p[0] for p in q]
+            if len(q) > 1 and len(flags[0].sharding.device_set) < 2:
+                flags = jnp.stack(flags)
             flags, stats, rode = jax.device_get((
-                q[0][0] if len(q) == 1 else jnp.stack([p[0] for p in q]),
+                flags,
                 [(p[5].rounds, p[5].round_stats, p[8]) for p in q],
                 None if ride is None else ride[0]))
         if ride is not None:
@@ -1424,7 +1444,8 @@ class GBDT:
                 if ok and p[6] == self._recorded_ahead:
                     self._recorded_ahead = None
                 elif ok:
-                    _record_aligned_iter(p[6], *counters)
+                    _record_aligned_iter(p[6], *counters,
+                                         eng=self._aligned_eng_ref)
         if all(flags):
             return None
         j = flags.index(False)
@@ -1471,7 +1492,7 @@ class GBDT:
             self._aligned_forget_from(self.iter)
             return self._aligned_fallback_iter(init_scores, eng, fmask,
                                                sample=sample)
-        _record_aligned_iter(self.iter, *counters)
+        _record_aligned_iter(self.iter, *counters, eng=eng)
         self._train_score_stale = True
         lazy = LazyAlignedTree(spec, self.shrinkage_rate, init_scores[0],
                                self.learner,

@@ -45,6 +45,8 @@ PHASES: Dict[str, str] = {
                     "counts, the round's counters",
     "build.eval": "build program, every round: child histograms and "
                   "split evaluation over the changed slots",
+    "dp.psum": "build program under a mesh: the histograms of the root "
+               "and of each round's children all-reduced over the chips",
     "build.replay": "build program: the leaf-wise replay on the device, "
                     "in a round and once behind the rounds",
     "build.copy_back": "build program: the rows out of the second buffer "

@@ -387,7 +387,9 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
     puts each row's id in another index space into the EXT record's
     index lane, pads the count; `scores` [K, N] (host or device) fill the
     score lanes. Returns (rec, wcnt, W, cnts numpy [shards * nc], info:
-    blocks, upload_bytes)."""
+    blocks, upload_bytes, and by shard `upload_bytes_by_shard` and
+    `rows_by_shard`, the rows each shard's blocks wrote as the pack
+    program counted them, each row once)."""
     n, fin = bins.shape
     cols = max(cols, fin)
     bpw = _bpw_for_bits(bits)
@@ -418,7 +420,7 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
         def place(x, two_d=False):
             return jax.device_put(x)
         rec = jnp.zeros((nc, w_pad, chunk), jnp.int32)
-    crossed = 0
+    crossed = np.zeros(shards, np.int64)     # bytes sent to each shard
     blk = pack_block_chunks(chunk, fin, nc_data)
 
     def by_shard(x, dtype):
@@ -430,7 +432,7 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
         if mesh is None:
             if not isinstance(x, jax.Array):
                 x = np.asarray(x, dtype)
-                crossed += x.nbytes
+                crossed[0] += x.nbytes
             x = jnp.asarray(x, dtype)
             short = blk * chunk - x.shape[-1]
             if short > 0:
@@ -440,7 +442,7 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
         out = np.zeros(x.shape[:-1] + (shards * span,), dtype)
         for s, (lo, hi) in enumerate(bounds):
             out[..., s * span:s * span + hi - lo] = x[..., lo:hi]
-        crossed += out.nbytes
+        crossed += out.nbytes // shards
         return place(out, x.ndim == 2)
 
     rows = {}
@@ -459,9 +461,29 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
                         for lo, hi in bounds], np.int32)
     facts = place(facts)
     blocks = -(-nc_data // blk)
+
+    def info(written=()):
+        """The pack's counters; `written`: each block's rows by shard, as
+        its program counted them, read under a mesh (where a shard can
+        pack apart from the others) once the records are written. On one
+        chip the rows are the bounds'."""
+        if mesh is None:
+            return dict(blocks=len(written), upload_bytes=int(crossed.sum()),
+                        upload_bytes_by_shard=crossed.tolist(),
+                        rows_by_shard=[hi - lo for lo, hi in bounds])
+        rows = np.zeros(shards, np.int64)
+        for b, got in enumerate(jax.device_get(list(written))):
+            # the last block starts early: the rows it writes once more
+            # were counted by the block before it
+            first = min(b * blk, nc_data - blk) * chunk
+            again = [max(0, min(b * blk * chunk, hi - lo) - first)
+                     for lo, hi in bounds]
+            rows += np.asarray(got).reshape(-1) - np.asarray(again)
+        return dict(blocks=len(written), upload_bytes=int(crossed.sum()),
+                    upload_bytes_by_shard=crossed.tolist(),
+                    rows_by_shard=rows.tolist())
     if blocks == 0:
-        return rec, wcnt, w_pad, cnts.reshape(-1), dict(blocks=0,
-                                                       upload_bytes=crossed)
+        return rec, wcnt, w_pad, cnts.reshape(-1), info()
     program = _pack_program(
         mesh, axis, tuple(sorted(rows)), chunk=chunk, blk=blk, cols=fin,
         bits=bits, wcnt=wcnt, lanes=lanes, w_pad=w_pad, kind=kind,
@@ -472,13 +494,13 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
         shard_of = {d: i.start // width for d, (i,) in
                     sharding.addressable_devices_indices_map(
                         (shards * width,)).items()}
-    tokens = []
+    tokens, written = [], []
     for b in range(blocks):
         # the last block ends with the data: it starts early instead,
         # writing chunks of the block before it once more, alike
         first = min(b * blk, nc_data - blk) * chunk
         parts = []
-        for lo, hi in bounds:
+        for s, (lo, hi) in enumerate(bounds):
             part = np.ascontiguousarray(
                 bins[min(hi, lo + first):min(hi, lo + first + blk * chunk)])
             part = part.reshape(-1)
@@ -486,7 +508,7 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
                 part = np.concatenate([part, np.zeros(width - part.size,
                                                       np.uint8)])
             parts.append(part)
-            crossed += part.nbytes
+            crossed[s] += part.nbytes
         if mesh is None:
             block = jax.device_put(parts[0])
         else:
@@ -495,10 +517,10 @@ def pack_device(bins, label, weight, chunk: int, nc: int, *, bits: int,
                 [jax.device_put(parts[s], d) for d, s in shard_of.items()])
         rec, token = program(rec, block, np.int32(first), facts, rows)
         tokens.append(token)
+        written.append(token)
         if len(tokens) > 2:     # at most two blocks in flight
             tokens.pop(0).block_until_ready()  # graftlint: disable=LGT002 load-time pacing of the pack's uploads, not a round-loop fence
-    return rec, wcnt, w_pad, cnts.reshape(-1), dict(blocks=blocks,
-                                                   upload_bytes=crossed)
+    return rec, wcnt, w_pad, cnts.reshape(-1), info(written)
 
 
 def pack_records(bins: np.ndarray, label: np.ndarray,
