@@ -6,9 +6,12 @@ reference's priority-queue leaf-wise order, `serial_tree_learner.cpp:
 173-237`, is replayed exactly on the host), but the physical work per
 round is three streaming passes instead of a global 11-operand sort:
 
-1. count pass (XLA): per-chunk left counts of every splitting block ->
-   the new chunk-aligned layout (left child at the parent's slot, right
-   child at a fresh slot, every block's begin rounded up to a chunk).
+1. layout (XLA): the finder's left count of every splitting block (exact:
+   the histograms count in i32, `ops/aligned.py` COUNT_UNIT; the
+   `count_pass` kernel counts the rows instead where that count is not
+   theirs, `AlignedEngine.count_why`) -> the new chunk-aligned layout
+   (left child at the parent's slot, right child at a fresh slot, every
+   block's begin rounded up to a chunk).
 2. `move_pass` (Pallas): stable two-way partition of every block straight
    into the new layout — 4.5 ns/row vs 18 for the sort.
 3. `slot_hist_pass` (Pallas): histograms of each split's SMALLER child
@@ -46,9 +49,10 @@ from ..obs import trace as obs_trace
 from ..ops.aligned import (META_BAG, META_LABEL, META_LABEL_MASK,
                            META_RID_MASK, R_CAT,
                            R_COPY, R_DL, R_MT, R_SHIFT, _bpw_for_bits,
-                           bin_bits, count_pass, lane_layout, move_pass,
-                           pack_device, pack_route2, park_pass,
-                           slot_hist_pass)
+                           bin_bits, count_bits, count_pass, hist_counts,
+                           hist_sub, lane_layout, move_pass, pack_device,
+                           pack_route2, park_pass, slot_hist_pass,
+                           with_counts)
 from ..utils import log
 from ..ops.histogram import NUM_HIST_STATS
 from .device_learner import (BF_GAIN, BF_LG, BF_LH, BF_LOUT, BF_RG, BF_RH,
@@ -167,12 +171,20 @@ def _add_to_lane(rec, lane: int, addend):
 _PSUM_BYTES = {}
 
 
+def hist_parts(x):
+    """A finished histogram as its all-reduce sends it: the f32 g and h
+    channels, and the count channel as the i32 it holds."""
+    return x[..., :2], hist_counts(x)
+
+
 def hist_psum(x, axis):
     """The data-parallel learner's histogram all-reduce: every shard's
     histogram of the same leaves summed over the mesh's `axis`
     (`Network::ReduceScatter` + the global histogram of upstream's
-    `DataParallelTreeLearner`)."""
-    return lax.psum(x, axis)
+    `DataParallelTreeLearner`). The counts are summed as integers: a sum
+    of the f32 that holds their bits would be no count."""
+    gh, cnt = lax.psum(hist_parts(x), axis)
+    return jnp.concatenate([gh, count_bits(cnt)[..., None]], axis=-1)
 
 
 def replay_spec(spec_host, num_leaves):
@@ -249,6 +261,7 @@ class AlignedEngine:
                 bytes=nbytes, W=int(self.W), w_used=int(self.w_used),
                 C=int(C), NC=int(self.NC), bits=int(self.bits),
                 shards=int(self.nd), count_pass=self.count_pass,
+                count_why=self.count_why,
                 route_tile=route_tile(C), route_tiles=C // route_tile(C),
                 route_selectors=ROUTE_SELECTORS, route_stage=ROUTE_STAGE,
                 route_unroll=route_unroll(C),
@@ -378,20 +391,28 @@ class AlignedEngine:
         return sites["root"], sites["round"]
 
     @property
+    def count_why(self) -> Optional[str]:
+        """Why every round of the build program runs `count_pass`, or
+        None where the finder's left count places the rows: the
+        histograms count in exact i32 at any row count, so that count is
+        the rows' wherever the histogram counts every row the layout
+        places. By what the engine is, no option: on a mesh the finder
+        sees the all-reduced count and each shard's layout needs its own
+        (data_parallel_tree_learner.cpp:251-257); a bag that is not
+        parked leaves its out-of-bag rows in the rounds, and the
+        histogram counts in-bag rows only (gbdt.cpp:209-275)."""
+        if self.axis is not None:
+            return "mesh"
+        if self.bagged and not self.parks:
+            return "bag, not parked"
+        return None
+
+    @property
     def count_pass(self) -> bool:
         """Whether every round of the build program runs `count_pass`.
-        Fixed per engine, so it rides the `aligned.pack` seam and is no
-        per-round counter."""
-        # above 2^24 rows the f32 histogram count sums lose row-level
-        # exactness for the biggest leaves, so the PHYSICAL layout takes
-        # its counts from the exact i32 count pass (split-decision
-        # counts stay histogram-driven: only leaves larger than 2^24
-        # rows see sub-ppm count fuzz there, far from any min_data
-        # guard; documented divergence)
-        big_n = (self.n > (1 << 24)
-                 or bool(getattr(self.cfg, "tpu_force_big_n", False)))
-        # bagging and data-parallel: see the round body
-        return bool(self.bagged or self.axis is not None or big_n)
+        Fixed per engine, so it rides the `aligned.pack` seam with its
+        `count_why` and is no per-round counter."""
+        return self.count_why is not None
 
     # ------------------------------------------------------------------
     def _pack_device(self, learner, objective, init_row_scores, bagged,
@@ -449,9 +470,9 @@ class AlignedEngine:
                 and learner.n <= (1 << 24)   # rid must fit 24 meta bits
                 # the compact record's bag is one BIT of the meta lane
                 and not self.bag_multiplier
-                # tpu_force_big_n exercises the big-n physical layout
-                # (exact i32 count pass + route-word repack) at small n,
-                # which the compact layout would otherwise shadow
+                # tpu_force_big_n exercises the standard record and its
+                # route-word repack at small n, which the compact layout
+                # would otherwise shadow
                 and not bool(getattr(self.cfg, "tpu_force_big_n", False)))
         with_prob = self.mc_mode == "prob"
         # external-gradient objectives (ranking) drop the label/weight
@@ -720,16 +741,23 @@ class AlignedEngine:
 
             def expand_hist(h, sg, sh, cnt):
                 """[Ks, G, BH, 3] bundle hists -> [Ks, F, B, 3]; sg/sh/
-                cnt are the leaves' totals [Ks]."""
+                cnt are the leaves' totals [Ks]. The counts stay exact
+                i32 (`hist_counts`), for the min_data guards and the
+                layout."""
                 flat = h.reshape(h.shape[0], G * BH, NUM_HIST_STATS)
                 safe = jnp.clip(emap, 0, G * BH - 1)
-                out = flat[:, safe] * (emap >= 0)[None, :, :, None]
-                totals = jnp.stack([sg, sh, cnt.astype(jnp.float32)],
+                out = jnp.where((emap >= 0)[None, :, :, None],
+                                flat[:, safe], 0.0)
+                totals = jnp.stack([sg, sh, jnp.zeros_like(sg)],
                                    axis=-1)                   # [Ks, 3]
                 fix = totals[:, None, :] - jnp.sum(out, axis=2)
-                # counts must stay exact integers for min_data guards
-                fix = fix.at[..., 2].set(jnp.round(fix[..., 2]))
-                return out + edef[None, :, :, None] * fix[:, :, None, :]
+                c_out = hist_counts(out)                      # [Ks, F, B]
+                fix_c = cnt[:, None] - jnp.sum(c_out, axis=2)
+                c_out = c_out + jnp.where(edef[None] > 0,
+                                          fix_c[:, :, None], 0)
+                return with_counts(
+                    out + edef[None, :, :, None] * fix[:, :, None, :],
+                    c_out)
         wcnt, W = self.wcnt, self.W
         ln = self.lanes
         finder = lr.finder
@@ -781,7 +809,8 @@ class AlignedEngine:
             one chip's as it is."""
             if not dp:
                 return x
-            psum_sites[site] = int(x.size) * x.dtype.itemsize
+            psum_sites[site] = sum(int(p.size) * p.dtype.itemsize
+                                   for p in hist_parts(x))
             with phases.scope("dp.psum"):
                 return hist_psum(x, axis)
 
@@ -902,7 +931,9 @@ class AlignedEngine:
             return slot_of, cnt_of, first, last, in_any
 
         def eval_one(fmask, hist, sg, sh, cnt, minc, maxc, depth, exists):
-            out = finder(hist, sg, sh, cnt, minc, maxc)
+            # the finder sums the exact counts as i32
+            out = finder(hist, sg, sh, cnt, minc, maxc,
+                         counts=hist_counts(hist))
             gain = jnp.where(fmask > 0, out["gain"], NEG_INF)
             gain = jnp.where((depth >= depth_limit) | ~exists,
                              jnp.full_like(gain, NEG_INF), gain)
@@ -1028,7 +1059,7 @@ class AlignedEngine:
                 root_hist = _gsum(root_hist_all[0], "root")
                 root_g = jnp.sum(root_hist[0, :, 0])
                 root_h = jnp.sum(root_hist[0, :, 1])
-                root_cnt_g = jnp.sum(root_hist[0, :, 2]).astype(jnp.int32)
+                root_cnt_g = jnp.sum(hist_counts(root_hist[0]))
                 local_cnt = jnp.sum(cnts_pc).astype(jnp.int32)
 
             with phases.scope("build.head"):
@@ -1145,11 +1176,11 @@ class AlignedEngine:
                         leafI, exists, cnts_pc=cnts_pc, root_span=(done == 0),
                         park_begin=park_begin)
 
-                    # ---- left counts: serial mode shards see the global
-                    # histogram, so the finder's exact left count (BI_LC, an
-                    # exact f32 count-stat sum) IS the local left count — no
-                    # counting pass over the rows needed. (A data-parallel
-                    # port needs a per-shard count pass here.)
+                    # ---- left counts: the finder's left count (BI_LC, a
+                    # sum of the histograms' exact i32 counts) IS the rows'
+                    # left count wherever the histogram counts every row
+                    # the layout places; elsewhere (`count_why`) the
+                    # count pass counts them
                     feat = bestI[:, BI_FEAT]
                     scol = col_dev[feat] if bundled else feat
                     wsel_s = scol // bpw
@@ -1181,8 +1212,9 @@ class AlignedEngine:
                                | (first.astype(jnp.int32) << 20)
                                | (last.astype(jnp.int32) << 21))
                     if counted:
-                        # the histogram count channel cannot drive the
-                        # physical layout when it is IN-BAG only (bagging,
+                        # the histogram count cannot drive the physical
+                        # layout when it is IN-BAG only and out-of-bag rows
+                        # ride the rounds (a bag that is not parked,
                         # gbdt.cpp:209-275) or GLOBAL (data-parallel: BI_LC
                         # is the psum-reduced count; the shard's local
                         # layout needs its own rows' left counts,
@@ -1357,7 +1389,7 @@ class AlignedEngine:
                     slot_r = jnp.where(valid_rk, done + rk + 1, S)
                     sm_k = _gsum(hout, "round")             # [K, F, B, 3]
                     parent_k = hist_store[slot_l]
-                    lg_k = parent_k - sm_k
+                    lg_k = hist_sub(parent_k, sm_k)
                     sil_k = smaller_is_left[slot_l][:, None, None, None]
                     left_k = jnp.where(sil_k, sm_k, lg_k)
                     right_k = jnp.where(sil_k, lg_k, sm_k)

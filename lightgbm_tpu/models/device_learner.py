@@ -1271,8 +1271,8 @@ class DeviceTreeLearner:
         # histograms expand at eval only. packed-prefetch limits: 16-bit
         # destination chunk ids (NC <= 65535 at the EFFECTIVE chunk size,
         # ~67M rows at C=1024) and 8-bit word selectors (features <=
-        # 1020). Above 2^24 rows the physical layout switches to the
-        # exact i32 count pass (see aligned_builder big_n)
+        # 1020). Above 2^24 rows the histograms' counts stay exact i32
+        # (ops/aligned.py COUNT_UNIT), and so does the layout they place
         if nc > 65535:
             return f"chunk count {nc} > 65535"
         if self.num_features > 1020:

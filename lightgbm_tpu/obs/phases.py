@@ -40,7 +40,8 @@ PHASES: Dict[str, str] = {
     "build.root": "build program: the root histogram (slot_hist_pass), "
                   "its expansion and its evaluation",
     "build.layout": "build program, every round: splits chosen, left "
-                    "counts (count_pass), the new layout, move "
+                    "counts (the finder's, or count_pass where the "
+                    "engine counts the rows), the new layout, move "
                     "destinations (move_pass), updated tables, per-chunk "
                     "counts, the round's counters",
     "build.eval": "build program, every round: child histograms and "

@@ -140,7 +140,12 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
     monotone, penalty.
 
     Returns fn(hist[F,B,3], sum_grad, sum_hess, num_data, min_constr,
-    max_constr) -> dict of per-feature arrays + 'best_feature'.
+    max_constr, counts=None) -> dict of per-feature arrays +
+    'best_feature'. `counts` (i32 [F, B]), where given, are the bins'
+    exact counts and take the place of the count channel: the left and
+    right counts are then summed as i32 and exact at any row count, and
+    the min_data tests compare the same integers. Without it the counts
+    are the f32 channel's.
     """
     nb = jnp.asarray(feature_meta["num_bin"], jnp.int32)[:, None]     # [F,1]
     db = jnp.asarray(feature_meta["default_bin"], jnp.int32)[:, None]
@@ -164,23 +169,23 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
     min_data_f = float(h.min_data_in_leaf)
     min_hess = float(h.min_sum_hessian_in_leaf)
 
-    def _numerical(hist, sum_grad, sum_hess, num_data_f, min_c, max_c,
-                   min_gain_shift):
+    def _numerical(hist, c, sum_grad, sum_hess, num_data_c, min_data_c,
+                   min_c, max_c, min_gain_shift):
+        # c, num_data_c, min_data_c: all f32, or (exact counts) all i32
         g = hist[..., 0]
         hs = hist[..., 1]
-        c = hist[..., 2]
 
         # ---- dir = +1: accumulate from the left; missing/default -> right
         inc1 = in_range & ~(skip_def & (bins == db))
         pg = jnp.cumsum(jnp.where(inc1, g, 0.0), axis=1)
         ph = jnp.cumsum(jnp.where(inc1, hs, 0.0), axis=1)
-        pc = jnp.cumsum(jnp.where(inc1, c, 0.0), axis=1)
+        pc = jnp.cumsum(jnp.where(inc1, c, 0), axis=1)
         lg1, lh1, lc1 = pg, ph + K_EPSILON, pc
         rg1 = sum_grad - lg1
         rh1 = sum_hess - lh1          # sum_hess already carries +2*kEps
-        rc1 = num_data_f - lc1
+        rc1 = num_data_c - lc1
         valid1 = (two_scan & (bins <= nb - 2) & ~(skip_def & (bins == db))
-                  & (lc1 >= min_data_f) & (rc1 >= min_data_f)
+                  & (lc1 >= min_data_c) & (rc1 >= min_data_c)
                   & (lh1 >= min_hess) & (rh1 >= min_hess))
         gain1 = _split_gains(lg1, lh1, rg1, rh1, h.lambda_l1, h.lambda_l2,
                              h.max_delta_step, min_c, max_c, mono[:, None])
@@ -193,17 +198,17 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
                 & (bins <= nb - 1 - use_na.astype(jnp.int32)))
         pg2 = jnp.cumsum(jnp.where(inc2, g, 0.0), axis=1)
         ph2 = jnp.cumsum(jnp.where(inc2, hs, 0.0), axis=1)
-        pc2 = jnp.cumsum(jnp.where(inc2, c, 0.0), axis=1)
+        pc2 = jnp.cumsum(jnp.where(inc2, c, 0), axis=1)
         tg2, th2, tc2 = pg2[:, -1:], ph2[:, -1:], pc2[:, -1:]
         rg2 = tg2 - pg2
         rh2 = (th2 - ph2) + K_EPSILON
         rc2 = tc2 - pc2
         lg2 = sum_grad - rg2
         lh2 = sum_hess - rh2
-        lc2 = num_data_f - rc2
+        lc2 = num_data_c - rc2
         valid2 = ((bins <= nb - 2 - use_na.astype(jnp.int32))
                   & ~(skip_def & (bins + 1 == db))
-                  & (rc2 >= min_data_f) & (lc2 >= min_data_f)
+                  & (rc2 >= min_data_c) & (lc2 >= min_data_c)
                   & (rh2 >= min_hess) & (lh2 >= min_hess))
         gain2 = _split_gains(lg2, lh2, rg2, rh2, h.lambda_l1, h.lambda_l2,
                              h.max_delta_step, min_c, max_c, mono[:, None])
@@ -239,13 +244,12 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
                     left_g=lg, left_h=lh, left_c=lc,
                     left_output=lo, right_output=ro)
 
-    def _categorical(hist, sum_grad, sum_hess, num_data_f, min_c, max_c,
-                     min_gain_shift):
+    def _categorical(hist, c, sum_grad, sum_hess, num_data_c, min_data_c,
+                     min_c, max_c, min_gain_shift):
         """One-hot and CTR-sorted categorical splits
-        (feature_histogram.hpp:118-240)."""
+        (feature_histogram.hpp:118-240); counts as in `_numerical`."""
         g = hist[..., 0]
         hs = hist[..., 1]
-        c = hist[..., 2]
         # used_bin = num_bin - 1 + (missing == none)  (:129-130)
         used_bin = nb - 1 + (mt == 0).astype(jnp.int32)
         # the device-side category bitset spans 8 u32 words = 256 bins
@@ -259,9 +263,9 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
         lh_oh = hs + K_EPSILON
         rg_oh = sum_grad - g
         rh_oh = sum_hess - hs - K_EPSILON
-        rc_oh = num_data_f - c
-        valid_oh = (cand & (c >= min_data_f) & (hs >= min_hess)
-                    & (rc_oh >= min_data_f) & (rh_oh >= min_hess))
+        rc_oh = num_data_c - c
+        valid_oh = (cand & (c >= min_data_c) & (hs >= min_hess)
+                    & (rc_oh >= min_data_c) & (rh_oh >= min_hess))
         # gain computed as (other, t) but symmetric without monotone
         gain_oh = _split_gains(rg_oh, rh_oh, g, lh_oh, h.lambda_l1,
                                h.lambda_l2, h.max_delta_step, min_c, max_c, 0)
@@ -303,12 +307,12 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
             step_ok = in_elig & (pos < max_num_cat[:, None])
             lg = jnp.cumsum(jnp.where(step_ok, gg, 0.0), axis=1)
             lh = jnp.cumsum(jnp.where(step_ok, hh, 0.0), axis=1) + K_EPSILON
-            lc = jnp.cumsum(jnp.where(step_ok, cc, 0.0), axis=1)
+            lc = jnp.cumsum(jnp.where(step_ok, cc, 0), axis=1)
             rg = sum_grad - lg
             rh = sum_hess - lh
-            rc = num_data_f - lc
-            left_ok = (lc >= min_data_f) & (lh >= min_hess)
-            right_ok = (rc >= min_data_f) & (rc >= h.min_data_per_group) \
+            rc = num_data_c - lc
+            left_ok = (lc >= min_data_c) & (lh >= min_hess)
+            right_ok = (rc >= min_data_c) & (rc >= h.min_data_per_group) \
                 & (rh >= min_hess)
 
             # sequential min_data_per_group grouping (:198-222): a candidate
@@ -316,14 +320,14 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
             # evaluated candidate reaches min_data_per_group
             def body(cnt_group, xs):
                 cc_i, lok, rok, sok = xs
-                cnt_group = cnt_group + jnp.where(sok, cc_i, 0.0)
+                cnt_group = cnt_group + jnp.where(sok, cc_i, 0)
                 evalable = lok & rok & sok
                 do_eval = evalable & (cnt_group >= h.min_data_per_group)
-                cnt_group = jnp.where(do_eval, 0.0, cnt_group)
+                cnt_group = jnp.where(do_eval, 0, cnt_group)
                 return cnt_group, do_eval
 
             xs = (cc.T, left_ok.T, right_ok.T, step_ok.T)
-            _, do_eval_T = lax.scan(body, jnp.zeros((F,), jnp.float32), xs)
+            _, do_eval_T = lax.scan(body, jnp.zeros((F,), c.dtype), xs)
             do_eval = do_eval_T.T
             gain = _split_gains(lg, lh, rg, rh, h.lambda_l1, l2c,
                                 h.max_delta_step, min_c, max_c, 0)
@@ -389,10 +393,17 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
 
     @jax.jit
     def find_best_splits(hist, sum_grad, sum_hess, num_data, min_constraint,
-                         max_constraint):
+                         max_constraint, counts=None):
         sum_grad = sum_grad.astype(jnp.float32)
         sum_hess = sum_hess.astype(jnp.float32) + 2 * K_EPSILON
-        num_data_f = num_data.astype(jnp.float32)
+        if counts is None:
+            c = hist[..., 2]
+            num_data_c = num_data.astype(jnp.float32)
+            min_data_c = min_data_f
+        else:
+            c = counts.astype(jnp.int32)
+            num_data_c = num_data.astype(jnp.int32)
+            min_data_c = int(h.min_data_in_leaf)
         min_c = min_constraint.astype(jnp.float32)
         max_c = max_constraint.astype(jnp.float32)
         # gain_shift from the epsilon-adjusted parent hessian and plain L2
@@ -402,11 +413,11 @@ def make_split_finder(hyper: SplitHyper, feature_meta: Dict[str, np.ndarray],
                                 h.max_delta_step)
         min_gain_shift = gain_shift + h.min_gain_to_split
 
-        num = _numerical(hist, sum_grad, sum_hess, num_data_f, min_c, max_c,
-                         min_gain_shift)
+        num = _numerical(hist, c, sum_grad, sum_hess, num_data_c,
+                         min_data_c, min_c, max_c, min_gain_shift)
         if has_cat:
-            cat = _categorical(hist, sum_grad, sum_hess, num_data_f, min_c,
-                               max_c, min_gain_shift)
+            cat = _categorical(hist, c, sum_grad, sum_hess, num_data_c,
+                               min_data_c, min_c, max_c, min_gain_shift)
             sel = lambda k: jnp.where(is_cat[:, 0], cat[k], num[k])
         else:
             cat = None

@@ -1,7 +1,10 @@
-"""`tpu_force_big_n` parity: the big-n physical layout (exact i32 count
-pass + 9-bit route repack) only engages naturally above 2^24 rows, where
-no tier-1 test can reach it. The knob forces that layout at small n; the
-trees it grows must match the default layout exactly.
+"""`tpu_force_big_n` parity: the big-n record (the standard layout and
+its route-word repack) only engages naturally above 2^24 rows, where no
+tier-1 test can reach it. The knob forces that record at small n; the
+trees it grows must match the default layout exactly. It no longer
+forces the `count_pass` kernel: the histograms count in exact i32 at any
+row count, so the layout takes its left counts from the finder, and
+those counts must place every row where the trees send it.
 """
 import numpy as np
 
@@ -42,11 +45,11 @@ def test_force_big_n_matches_default_layout():
 
 
 def test_force_big_n_leaf_counts_exact_i32():
-    """The big-n count pass must deliver EXACT integer leaf populations
-    (the f32 histogram-sum shortcut loses integer exactness past 2^24
-    rows — the whole reason the i32 count pass exists). Certify by
-    routing every training row through the finished trees host-side and
-    demanding integer equality with the recorded per-leaf counts."""
+    """The big-n record must deliver EXACT integer leaf populations, now
+    from the histograms' exact i32 counts (an f32 count sum loses integer
+    exactness past 2^24 rows). Certify by routing every training row
+    through the finished trees host-side and demanding integer equality
+    with the recorded per-leaf counts."""
     rng = np.random.default_rng(19)
     n = 900
     X = rng.standard_normal((n, 6)).astype(np.float32)

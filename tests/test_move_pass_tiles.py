@@ -286,7 +286,8 @@ EDGE_CASES = [(C, "std8") for C in (ROUTE_TILE, 2 * ROUTE_TILE,
     + [(2 * ROUTE_TILE, "ext")]
 
 
-def _call(sc, bufs, src, rt, b_pad, spill=False):
+def _call(sc, bufs, src, rt, b_pad, spill=False, subbin=True,
+          fold_every=None, interpret=True):
     """One pass from buffer `src` of `bufs` into the other."""
     C = sc["rec"].shape[2]
     a, b, hist = move_pass(
@@ -296,9 +297,14 @@ def _call(sc, bufs, src, rt, b_pad, spill=False):
         jnp.zeros((K + 1) * 8, jnp.int32), C, sc["W"], sc["wcnt"], K, F,
         b_pad, 4 if b_pad > 64 else 8, bits=sc["bits"],
         grad_fn=_point_grad if sc["compact"] else None,
-        w_used=sc["w_used"], gh_off=sc["gh_off"], interpret=True,
-        subbin=True, spill=spill)
-    return [np.asarray(a), np.asarray(b)], np.asarray(hist)
+        w_used=sc["w_used"], gh_off=sc["gh_off"], interpret=interpret,
+        subbin=subbin, spill=spill, fold_every=fold_every)
+    # the count channel holds the bits of exact i32 counts: read them as
+    # the numbers they are, for the comparison with numpy's
+    counts = np.asarray(aligned.hist_counts(hist))
+    hist = np.asarray(hist).copy()
+    hist[..., 2] = counts
+    return [np.asarray(a), np.asarray(b)], hist
 
 
 def _check_rows(sc, got, expect):
@@ -328,7 +334,52 @@ def test_move_pass_matches_numpy_partition(C, layout, spill, heavy, src):
     free = sorted(set(range(NC)) - set(expect))
     np.testing.assert_array_equal(bufs[1 - src][free], held[free])
     np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
+    np.testing.assert_array_equal(hist[..., 2], hist_ref[..., 2])
     assert hist_ref[..., 2].sum() > C       # histograms were asked for
+
+
+# (layout, subbin): the three accumulation modes, `_hist_mode`
+FOLD_MODES = {"subbin": ("std8", True), "nibble": ("std8", False),
+              "group": ("std6", True)}
+
+
+@pytest.fixture
+def small_unit():
+    """COUNT_UNIT = 8, traced afresh (it is read at trace time): a fold
+    then moves counts at toy size, where COUNT_UNIT's 4,096 rows never
+    gather in one bin between two folds."""
+    keep = aligned.COUNT_UNIT
+    aligned.COUNT_UNIT = 8
+    jax.clear_caches()
+    yield
+    aligned.COUNT_UNIT = keep
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("fold_every", (1, 2))
+@pytest.mark.parametrize("spill", (False, True))
+@pytest.mark.parametrize("mode", sorted(FOLD_MODES))
+def test_move_pass_counts_fold_inside_a_block(mode, spill, fold_every,
+                                              small_unit):
+    """A block's counts fold out of the f32 accumulator every
+    `fold_every` chunks (`count_fold`: 8,190 at C = 2,048, so that no
+    count half passes 2^24); folded after every chunk or every second
+    one, in multiples of 8, the counts are numpy's exactly and g and h
+    are the unfolded pass's bit for bit. The scenario's first block
+    flushes four chunks of its heavy side."""
+    layout, subbin = FOLD_MODES[mode]
+    C = ROUTE_TILE
+    sc = _build(C, layout, "left", seed=43)
+    rec_in, rt, expect, hist_ref, b_pad = _reference(sc, C)
+    held = np.full_like(rec_in, 0x5A5A5A5A)
+    _, plain = _call(sc, [rec_in, held], 0, rt, b_pad, spill, subbin,
+                     fold_every=0)
+    bufs, hist = _call(sc, [rec_in, held], 0, rt, b_pad, spill, subbin,
+                       fold_every=fold_every)
+    _check_rows(sc, bufs[1], expect)
+    np.testing.assert_array_equal(hist[..., 2], hist_ref[..., 2])
+    np.testing.assert_array_equal(hist, plain)
+    np.testing.assert_allclose(hist, hist_ref, rtol=2e-5, atol=2e-4)
 
 
 @pytest.mark.parametrize("heavy", ("left", "right"))

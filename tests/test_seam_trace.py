@@ -375,15 +375,32 @@ def test_second_booster_at_the_same_shapes_traces_nothing(run16):
 # the branches a toy run does not take by itself
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("extra,spills,counts", [
-    ({"tpu_hist_spill_vmem_mb": 0.001}, True, False),
-    ({"tpu_force_big_n": True}, False, True),
-    ({}, False, False),
+def _unparked_bag():
+    """(params, data) of an engine with a bag it does not park: feature
+    0 is categorical, and the record walk takes numerical splits only.
+    Its out-of-bag rows ride the rounds, so the rounds count the rows."""
+    X, y = _data()
+    X = X.copy()
+    X[:, 0] = np.floor(np.abs(X[:, 0]) * 3)
+    return ({"categorical_feature": "0", "bagging_fraction": 0.7,
+             "bagging_freq": 1, "bagging_seed": 3}, (X, y))
+
+
+@pytest.mark.parametrize("extra,spills,why", [
+    ({"tpu_hist_spill_vmem_mb": 0.001}, True, None),
+    ("unparked_bag", False, "bag, not parked"),
+    # the standard record, and no count pass: the histograms' counts
+    # are exact at any row count
+    ({"tpu_force_big_n": True}, False, None),
+    ({}, False, None),
 ])
 def test_spill_column_and_count_pass_follow_the_program(extra, spills,
-                                                        counts):
+                                                        why):
     obs_trace.reset()
-    bst = _booster(extra)
+    data = None
+    if extra == "unparked_bag":
+        extra, data = _unparked_bag()
+    bst = _booster(extra, data)
     for _ in range(2):
         bst.update()
     bst.eval_train()
@@ -392,7 +409,8 @@ def test_spill_column_and_count_pass_follow_the_program(extra, spills,
     assert getattr(bst._gbdt._aligned_eng_ref, "hist_spill", False) == spills
     # fixed per engine, so a fact of the pack seam and no counter
     (pack,) = obs_trace.seams("aligned.pack")
-    assert pack["count_pass"] is counts
+    assert pack["count_pass"] is (why is not None)
+    assert pack["count_why"] == why
     recs = obs_trace.seams("aligned.iter")
     assert len(recs) == 2
     for rec in recs:
@@ -447,11 +465,14 @@ def test_lowered_build_program_names_its_three_kernels():
     """Each `pallas_call` carries its name itself, so wrapping or inlining
     the jitted function around it cannot rename the kernel in a trace.
     Lowered for the TPU from here; nothing is compiled or run."""
-    bst = _booster({"tpu_force_big_n": True})
+    bst = _booster(*_unparked_bag())
     bst.update()
     cpu_eng = bst._gbdt._aligned_eng_ref
+    assert cpu_eng.count_why == "bag, not parked"
     eng = aligned_builder.AlignedEngine(cpu_eng.learner, cpu_eng.objective,
-                                        interpret=False)
+                                        interpret=False, bagged=True,
+                                        bag_device=cpu_eng.bag_device)
+    assert eng.count_pass
     fmask = cpu_eng.learner._fmask_arr(None)
     lowered = jax.jit(eng._build_program()).trace(
         eng.rec, eng.cnts, fmask, jnp.float32(0.1), jnp.asarray(True)
